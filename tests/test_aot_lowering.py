@@ -1,17 +1,24 @@
-"""AOT TPU-lowering smoke tests (VERDICT weak #2).
+"""AOT TPU smoke tests: lowerings and real compiles for a described chip.
 
-Five rounds produced zero TPU executions, so Mosaic/layout failures in
-the flagship kernels could hide until a chip appears. ``jax.export``
-lowers a jitted program for an EXPLICIT target platform without
-initializing that platform's backend — Pallas kernels go through the
-real Mosaic lowering and sharded programs through SPMD partitioning —
-so tile/layout violations surface right here on the CPU-only CI host.
-(The original segment-reduce block spec really did fail this lowering:
-a (1, C) block over an (n_chunks, C) array breaks the (8, 128) sublane
-tiling rule whenever n_chunks > 1; it only ever ran in interpret mode.)
+No test here executes on a TPU. Two strengths of check:
 
-These assert lowering SUCCEEDS; executing the artifacts still needs
-hardware (the bench's job).
+* ``jax.export`` lowers a jitted program for an EXPLICIT target platform
+  without that platform's backend — sharded programs go through SPMD
+  partitioning, Pallas kernels through the Mosaic *lowering*. It stops
+  at MLIR and never calls the chip's compiler.
+* the ``*_compiles_for_v5e`` tests hand the program to the chip's own
+  compiler for a DESCRIBED (not attached) ``v5e:2x2`` topology, at the
+  widths the served SF1 path runs (pages of 65,536 rows). The Pallas
+  segment-reduce passed every ``jax.export`` case while Mosaic refused
+  all six at compile (bool-vector reshape, i64 literals under x64,
+  unproven slice alignment, i64 index-map results) — only a compile
+  shows that.
+
+The topology is described inside the module-scoped ``topo`` fixture —
+never at import — so every xdist worker collects the same tests and only
+the worker that runs this file loads the TPU library. Keep every
+described-topology test in THIS file. A compile that passes is not a
+chip run (that is ``chip_smoke.py``).
 """
 
 import jax
@@ -20,33 +27,225 @@ import numpy as np
 import pytest
 
 from jax import export
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
+    SingleDeviceSharding
 
 from trino_tpu import types as T
 
 sds = jax.ShapeDtypeStruct
+
+#: TpchConnector.page_rows of the served SF1 deployment
+PAGE = 1 << 16
 
 
 def _export_tpu(fn, *args):
     return export.export(fn, platforms=["tpu"])(*args)
 
 
+# ------------------------------------------------- described v5e chip ----
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip (the next run would warn
+    # and recompile): keep these compiles out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.asarray(topo.devices[:4]), ("x",))
+
+
+def _on(sharding, tree):
+    """The pytree of shapes with ``sharding`` on every leaf."""
+    return jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype, sharding=sharding), tree)
+
+
+def _compile(fn, sharding, *shapes):
+    return jax.jit(fn).lower(*_on(sharding, shapes)).compile()
+
+
 @pytest.mark.parametrize("kind", ["sum", "min", "max"])
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
-def test_pallas_segment_reduce_lowers_for_tpu(kind, dtype):
-    """The compiled (interpret=False) Pallas path must pass Mosaic
-    lowering for every kind x dtype it claims to support — including
-    the multi-chunk grid (n > _CHUNK) that the old block spec broke."""
-    from trino_tpu.ops.pallas_kernels import _CHUNK, _segment_reduce_pallas
-
-    n = 4 * _CHUNK  # multi-chunk: exercises the blocked grid
+def test_pallas_segment_reduce_compiles_for_v5e(kind, dtype, one_chip):
+    """The compiled (interpret=False) kernel for every kind x dtype it
+    claims, at the aggregation's per-page call: 65,536 rows into
+    cap + 1 segments."""
+    from trino_tpu.ops.pallas_kernels import _segment_reduce_pallas
 
     def fn(col, gid):
-        return _segment_reduce_pallas(col, gid, 200, kind,
+        return _segment_reduce_pallas(col, gid, PAGE + 1, kind,
                                       interpret=False)
 
-    ex = _export_tpu(jax.jit(fn), sds((n,), dtype), sds((n,), jnp.int32))
-    assert "tpu" in ex.platforms
+    compiled = _compile(fn, one_chip, sds((PAGE,), dtype),
+                        sds((PAGE,), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pallas_segment_reduce_compiles_at_largest_table(one_chip):
+    """The kernel keeps its whole output block in VMEM, so the group
+    table it accepts is bounded (``_MAX_SEGMENTS``; larger reductions
+    take ``jax.ops.segment_*``). The bound itself must compile."""
+    from trino_tpu.ops.pallas_kernels import (_MAX_SEGMENTS,
+                                              _segment_reduce_pallas)
+
+    n = _MAX_SEGMENTS - 1
+
+    def fn(col, gid):
+        return _segment_reduce_pallas(col, gid, _MAX_SEGMENTS, "min",
+                                      interpret=False)
+
+    compiled = _compile(fn, one_chip, sds((n,), jnp.int32),
+                        sds((n,), jnp.int32))
+    print(compiled.memory_analysis())
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _q1_grouping_shapes():
+    """q1's grouping layout at one page: two pooled string keys (rank
+    operands), eight int64 states plus one int32 state (a date min)."""
+    key_ops = (sds((PAGE,), jnp.uint8), sds((PAGE,), jnp.uint64)) * 2
+    key_raws = (sds((PAGE,), jnp.int32),) * 2
+    states = (sds((PAGE,), jnp.int64),) * 8 + (sds((PAGE,), jnp.int32),)
+    kinds = ("sum",) * 8 + ("min",)
+    return key_ops, key_raws, states, kinds
+
+
+def test_hash_grouping_compiles_for_v5e(one_chip):
+    """Both programs of the hash grouping path; with ``pallas="tpu"``
+    the reduce sorts by gid and takes the kernel for the int32 state."""
+    from trino_tpu.ops.hashtable import (hash_group_ids,
+                                         hash_segment_reduce)
+
+    key_ops, key_raws, states, kinds = _q1_grouping_shapes()
+    valid = sds((PAGE,), jnp.bool_)
+    _compile(lambda k, v: hash_group_ids.jit(k, v, exact=True),
+             one_chip, key_ops, valid)
+
+    def reduce(gid, group_rows, ngroups, raws, key_nulls, cols):
+        return hash_segment_reduce.jit(gid, group_rows, ngroups, raws,
+                                       key_nulls, cols, kinds,
+                                       pallas="tpu")
+
+    i32 = sds((PAGE,), jnp.int32)
+    compiled = _compile(reduce, one_chip, i32, i32, sds((), jnp.int32),
+                        key_raws, (valid,) * 2, states)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sort_group_reduce_compiles_for_v5e(one_chip):
+    from trino_tpu.ops.aggregation import _group_reduce
+
+    key_ops, key_raws, states, kinds = _q1_grouping_shapes()
+
+    def fn(ops, raws, cols, valid):
+        return _group_reduce.jit(ops, raws, cols, valid, num_keys=2,
+                                 num_states=len(cols), kinds=kinds,
+                                 pallas="tpu")
+
+    compiled = _compile(fn, one_chip, key_ops, key_raws, states,
+                        sds((PAGE,), jnp.bool_))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_q1_device_step_compiles_for_v5e(one_chip):
+    """The fused q1 step at a full 65,536-row page (int64 states, x64
+    on): no custom call, plain XLA."""
+    from trino_tpu.benchmarks import q1_example_args
+
+    step, args = q1_example_args()
+    cols, nulls, valid, luts = jax.eval_shape(lambda: args)
+
+    def widen(tree):
+        return jax.tree_util.tree_map(
+            lambda a: sds((PAGE,), a.dtype), tree)
+
+    _compile(step, one_chip, widen(cols), widen(nulls), widen(valid),
+             luts)
+
+
+def test_join_kernels_compile_for_v5e(one_chip):
+    """Sorted-index join at q3-SF1 sizes: 1.5 M ``orders`` build rows
+    padded to 2^21, one 65,536-row probe page."""
+    from trino_tpu.ops.join import (_build_sorted, _expand_matches,
+                                    _probe_counts)
+
+    build = 1 << 21
+    u64 = sds((build,), jnp.uint64)
+    flag = sds((build,), jnp.bool_)
+    cols = (sds((build,), jnp.int64), sds((build,), jnp.int32),
+            sds((build,), jnp.int32))
+    _compile(_build_sorted.jit, one_chip, u64, flag, cols,
+             (flag,) * 3, flag)
+    _compile(_probe_counts.jit, one_chip, u64, flag,
+             sds((PAGE,), jnp.uint64), sds((PAGE,), jnp.bool_))
+    _compile(lambda lo, count: _expand_matches.jit(lo, count,
+                                                   out_cap=2 * PAGE),
+             one_chip, sds((PAGE,), jnp.int64), sds((PAGE,), jnp.int64))
+
+
+def test_sort_by_compiles_for_v5e(one_chip):
+    """ORDER BY / TopN over a trimmed aggregation output (q3 at SF1
+    sorts ~11,600 groups: 16,384 lanes) — two keys, chained single-key
+    sorts."""
+    from trino_tpu.ops.sort import _sorted_by
+
+    n = 1 << 14
+    key_ops = (sds((n,), jnp.uint8), sds((n,), jnp.uint64)) * 2
+    cols = (sds((n,), jnp.int64), sds((n,), jnp.float64),
+            sds((n,), jnp.int32))
+    nulls = (sds((n,), jnp.bool_),) * 3
+
+    def fn(k, c, nl, v):
+        return _sorted_by.jit(k, c, nl, v, num_key_ops=len(k))
+
+    _compile(fn, one_chip, key_ops, cols, nulls, sds((n,), jnp.bool_))
+
+
+def test_device_exchange_compiles_for_v5e_mesh(mesh4):
+    """The count and data collectives over the four described devices
+    at ``(4, 65536)`` slabs: the data program must hold an all-to-all."""
+    from trino_tpu.parallel.device_exchange import (_count_program,
+                                                    _exchange_program)
+
+    types_ = (T.BIGINT, T.BIGINT)
+    rows = NamedSharding(mesh4, PartitionSpec("x"))
+    whole = NamedSharding(mesh4, PartitionSpec())
+    cols = _on(rows, tuple(sds((4, PAGE), jnp.int64) for _ in types_))
+    nulls = _on(rows, tuple(sds((4, PAGE), jnp.bool_) for _ in types_))
+    valid = sds((4, PAGE), jnp.bool_, sharding=rows)
+    count = _count_program(mesh4, types_, (0,), 4, 4).jit
+    count.lower(cols, nulls, valid, ()).compile()
+    prog = _exchange_program(mesh4, types_, (0,), 4, 4, PAGE // 2).jit
+    compiled = prog.lower(cols, nulls, valid, (),
+                          sds((4,), jnp.int32, sharding=whole)).compile()
+    assert "all-to-all" in compiled.as_text()
+
+
+# ------------------------------------------- jax.export lowerings ----
 
 
 def test_device_exchange_program_lowers_for_tpu():

@@ -54,13 +54,13 @@ def runner(conn):
                             Session(catalog="tpch", schema=SCHEMA))
 
 
-@pytest.fixture(scope="module")
-def oracle(conn):
-    """sqlite3 loaded with the same generated data."""
+def load_sqlite(conn, schema: str):
+    """sqlite3 loaded with the same generated data (also the oracle of
+    ``chip_smoke.py``'s CPU rehearsal)."""
     db = sqlite3.connect(":memory:")
     meta = conn.metadata()
-    for table in meta.list_tables(SCHEMA):
-        handle = meta.get_table_handle(SCHEMA, table)
+    for table in meta.list_tables(schema):
+        handle = meta.get_table_handle(schema, table)
         cols = meta.get_columns(handle)
         names = [c.name for c in cols]
         db.execute(f"create table {table} ({', '.join(names)})")
@@ -84,6 +84,11 @@ def oracle(conn):
                     f"insert into {table} values ({ph})", rows)
     db.commit()
     return db
+
+
+@pytest.fixture(scope="module")
+def oracle(conn):
+    return load_sqlite(conn, SCHEMA)
 
 
 _DATE_INTERVAL = re.compile(

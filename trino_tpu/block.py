@@ -412,6 +412,24 @@ class DevicePage:
         """Live row count (device sync)."""
         return int(np.asarray(self.valid).sum())
 
+    def trimmed(self) -> "DevicePage":
+        """This page cut to the pow2 capacity that still holds its last
+        live lane (one scalar device sync). A grouping output keeps its
+        groups in a dense prefix of a page as wide as everything that was
+        merged into it; every downstream program is shaped — and
+        compiled — by capacity, so the dead tail is cut here."""
+        import jax.numpy as jnp
+
+        cap = self.capacity
+        last = int(jnp.max(jnp.where(
+            self.valid, jnp.arange(1, cap + 1, dtype=jnp.int32), 0)))
+        keep = padded_size(last)
+        if keep >= cap:
+            return self
+        return DevicePage(self.types, [c[:keep] for c in self.cols],
+                          [n[:keep] for n in self.nulls],
+                          self.valid[:keep], self.dictionaries)
+
     @staticmethod
     def from_page(page: Page, capacity: Optional[int] = None) -> "DevicePage":
         import jax.numpy as jnp

@@ -84,20 +84,21 @@ def default_node_memory_bytes(fallback: int = 16 << 30) -> int:
     """Auto default for ``node_max_memory_bytes``: the accelerator's
     own reported capacity (``Device.memory_stats()['bytes_limit']`` on
     TPU/GPU backends), so the node pool tracks real HBM instead of a
-    hardwired constant. CPU backends report no stats — fall back.
-    Never raises: a worker must come up even on an odd backend."""
-    try:
-        import jax
+    hardwired constant. The CPU backend reports no stats and gets
+    ``fallback``; an accelerator that reports no limit raises — a pool
+    sized by guesswork would hide the device."""
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats()
-        if stats:
-            limit = stats.get("bytes_limit") \
-                or stats.get("bytes_reservable_limit")
-            if limit:
-                return int(limit)
-    except Exception:
-        pass
-    return fallback
+    dev = jax.local_devices()[0]
+    stats = dev.memory_stats() or {}
+    limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+    if limit:
+        return int(limit)
+    if dev.platform == "cpu":
+        return fallback
+    raise RuntimeError(
+        f"{dev.platform} device {dev.device_kind!r} reports no "
+        f"bytes_limit in memory_stats(); cannot size the node pool")
 
 
 def device_page_bytes(page) -> int:

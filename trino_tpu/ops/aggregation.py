@@ -48,6 +48,7 @@ the intermediate layout the final step re-groups anyway.
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass
 from functools import partial
 from typing import List, Optional, Sequence, Tuple
@@ -64,7 +65,7 @@ from ..types import TypeError_
 from .hashtable import (_mix_operands, hash_group_ids,
                         hash_segment_reduce, hashable_key_types)
 from .operator import Operator
-from .sortkeys import group_operands
+from .sortkeys import group_operands, sort_carrying
 
 #: adaptive partial aggregation: minimum observed input rows before the
 #: reduction ratio is trusted (reference default: 100k rows)
@@ -386,15 +387,12 @@ def _group_reduce_impl(key_ops: Tuple, key_raws: Tuple,
     jit_stats.bump("sort_group_reduce")
     cap = valid.shape[0]
     # invalid lanes sort last: leading operand = ~valid
-    operands = [(~valid).astype(jnp.uint8)] + list(key_ops) \
-        + list(key_raws) + list(state_cols) + [valid]
-    sorted_ops = jax.lax.sort(operands, num_keys=1 + 2 * num_keys,
-                              is_stable=False)
-    s_invalid = sorted_ops[0]
-    s_keyops = sorted_ops[1:1 + 2 * num_keys]
-    s_keyraws = sorted_ops[1 + 2 * num_keys:1 + 2 * num_keys + num_keys]
-    s_states = sorted_ops[1 + 2 * num_keys + num_keys:-1]
-    s_valid = sorted_ops[-1]
+    (_, *s_keyops), carried = sort_carrying(
+        [(~valid).astype(jnp.uint8)] + list(key_ops),
+        list(key_raws) + list(state_cols) + [valid])
+    s_keyraws = carried[:num_keys]
+    s_states = carried[num_keys:-1]
+    s_valid = carried[-1]
 
     # boundary: first row, or any key operand differs from previous row
     diff = jnp.zeros(cap, dtype=bool).at[0].set(True)
@@ -493,6 +491,17 @@ def _key_range_pass_mask(key_ops: Tuple, pass_buckets, buckets: int):
 _key_range_pass_mask = instrument(
     "agg_key_range_mask", _key_range_pass_mask,
     static_argnames=("buckets",))
+
+
+#: process-wide pages per grouping path (the per-operator
+#: ``path_counts`` summed; chip_smoke / test observability)
+_path_totals = {"hash": 0, "sort": 0, "passthrough": 0, "range_split": 0}
+_path_totals_lock = threading.Lock()
+
+
+def grouping_path_totals() -> dict:
+    with _path_totals_lock:
+        return dict(_path_totals)
 
 
 class HashAggregationOperator(Operator):
@@ -594,6 +603,11 @@ class HashAggregationOperator(Operator):
     def needs_input(self) -> bool:
         return not self._finishing
 
+    def _count_path(self, path: str):
+        self.path_counts[path] += 1
+        with _path_totals_lock:
+            _path_totals[path] += 1
+
     def add_input(self, page: DevicePage):
         # capture group-key dictionaries (assumed stable pools per column)
         for i, c in enumerate(self.group_channels):
@@ -625,7 +639,7 @@ class HashAggregationOperator(Operator):
         if self.passthrough:
             # adaptive partial aggregation tripped: emit the page in the
             # intermediate keys+states layout without grouping at all
-            self.path_counts["passthrough"] += 1
+            self._count_path("passthrough")
             self._pending.append(self._passthrough_page(page))
             return
         key_operands = None
@@ -636,7 +650,7 @@ class HashAggregationOperator(Operator):
             # The grouping operands feed both the mask and the
             # aggregation below (they don't depend on validity), so
             # compute them once.
-            self.path_counts["range_split"] += 1
+            self._count_path("range_split")
             key_types = [self.input_types[c] for c in self.group_channels]
             key_operands = self._grouping_operands(
                 page, self.group_channels, key_types)
@@ -714,7 +728,7 @@ class HashAggregationOperator(Operator):
                                            key_channels, state_cols, mode,
                                            observe=not intermediate)
         if result is None:
-            self.path_counts["sort"] += 1
+            self._count_path("sort")
             result = _group_reduce(
                 tuple(key_ops), tuple(key_raws), tuple(state_cols),
                 page.valid, num_keys=len(self.group_channels),
@@ -782,7 +796,7 @@ class HashAggregationOperator(Operator):
                 and not self._adaptive_decided:
             self._observe_reduction(key_ops, page.valid, group_rows,
                                     ngroups)
-        self.path_counts["hash"] += 1
+        self._count_path("hash")
         return result
 
     def _states_rank_to_code(self, state_cols: List) -> List:
@@ -865,7 +879,10 @@ class HashAggregationOperator(Operator):
             merged = self._finalize(merged)
         if self._ctx is not None:
             self._ctx.close()  # output page is in flight, not retained
-        return merged
+        # the merge ran at the summed capacity of every partial (millions
+        # of lanes at SF1 for a handful of groups): hand downstream a
+        # page as wide as the groups
+        return merged.trimmed()
 
     def _merge_partials(self) -> DevicePage:
         types = self._intermediate_types()

@@ -191,30 +191,35 @@ def _hash_segment_reduce_impl(gid, group_rows, ngroups, key_raws: Tuple,
     callers use the jitted+instrumented ``hash_segment_reduce`` below.
 
     The Pallas segment kernel requires non-decreasing gids (steps <= 1),
-    so when it is active the states take one cheap single-operand sort
-    on the int32 gid — still far lighter than the sort path's
-    full (1 + 2k)-operand key sort dragging raw keys along. Off-TPU,
-    ``jax.ops.segment_*`` handles unsorted gids directly and no sort
-    runs at all.
+    so the states it takes (int32/float32 on a TPU backend) are sorted
+    by gid (``sort_carrying``: one two-operand sort, a gather each).
+    The other states reduce by the unsorted gid in
+    ``jax.ops.segment_*`` and are never sorted — a carried column is
+    what makes a sort slow to compile for the TPU.
 
     Returns (group_key_raws, group_key_nulls, reduced_states, out_valid)
     in the exact shape contract of ``aggregation._group_reduce``.
     """
     jit_stats.bump("hash_segment_reduce")
-    from .pallas_kernels import segment_reduce
+    from .pallas_kernels import segment_reduce, takes_kernel
+    from .sortkeys import sort_carrying
 
     cap = gid.shape[0]
-    # state_cols is a tuple: pytree arity is trace-static, not traced
-    if pallas and state_cols:  # qlint: ignore[recompile] tuple arity is pytree structure: trace-static, never a tracer bool
-        ops = [gid] + list(state_cols)
-        sorted_ = jax.lax.sort(ops, num_keys=1, is_stable=False)
-        r_gid, r_states = sorted_[0], sorted_[1:]
-    else:
-        r_gid, r_states = gid, state_cols
+    in_kernel = [takes_kernel(c.dtype, cap + 1, pallas)
+                 for c in state_cols]
+    if any(in_kernel):
+        (s_gid,), s_states = sort_carrying(
+            [gid], [c for c, k in zip(state_cols, in_kernel) if k])
+        s_states = iter(s_states)
     reduced = []
-    for kind, col in zip(kinds, r_states):
-        r = segment_reduce(col, r_gid, num_segments=cap + 1, kind=kind,
-                           mode=pallas)
+    for kind, col, kernel in zip(kinds, state_cols, in_kernel):
+        if kernel:
+            r = segment_reduce(next(s_states), s_gid,
+                               num_segments=cap + 1, kind=kind,
+                               mode=pallas)
+        else:
+            r = segment_reduce(col, gid, num_segments=cap + 1, kind=kind,
+                               mode="")
         reduced.append(r[:cap])
 
     out_valid = jnp.arange(cap, dtype=jnp.int32) < ngroups

@@ -40,7 +40,7 @@ from .. import types as T
 from ..block import DevicePage, padded_size
 from ..telemetry.profiler import instrument
 from .operator import Operator
-from .sortkeys import group_operands
+from .sortkeys import group_operands, sort_carrying
 
 
 def _canonical_codes(codes, dictionary):
@@ -108,10 +108,10 @@ def _build_sorted(key_u64, anynull, cols, nulls, valid):
     jit_stats.bump("join_build_sorted")
     usable = valid & ~anynull if anynull is not None else valid
     sort_key = jnp.where(usable, key_u64, np.uint64(0xFFFFFFFFFFFFFFFF))
-    operands = [sort_key, usable, valid] + list(cols) + list(nulls)
-    s = jax.lax.sort(operands, num_keys=1, is_stable=False)
+    (s_key,), s = sort_carrying(
+        [sort_key], [usable, valid] + list(cols) + list(nulls))
     n = len(cols)
-    return s[0], s[1], s[2], tuple(s[3:3 + n]), tuple(s[3 + n:])
+    return s_key, s[0], s[1], tuple(s[2:2 + n]), tuple(s[2 + n:])
 
 
 # profiled entry point (telemetry.profiler): cost/compile attribution

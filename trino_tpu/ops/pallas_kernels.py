@@ -58,11 +58,7 @@ def pallas_mode() -> str:
         "TRINO_TPU_PALLAS", "")
     if forced in ("0", "off"):
         return ""
-    try:
-        backend = jax.default_backend()
-    except Exception:  # backend init failure: the caller's problem
-        return ""
-    if backend == "tpu":
+    if jax.default_backend() == "tpu":
         return "tpu"
     if forced:
         return "interpret"
@@ -74,6 +70,13 @@ def pallas_mode() -> str:
 #: does not exist and the engine runs 32-bit storage)
 _SUPPORTED = ("int32", "float32")
 _SUPPORTED_INTERPRET = _SUPPORTED + ("int64", "float64", "uint64")
+
+#: largest ``num_segments`` the kernel takes. Its output block stays
+#: whole in VMEM across the grid; the v5e compiler accepts that for a
+#: pow2 group table of 2^23 (+ the dump segment) and refuses 2^24
+#: (scoped vmem). Larger reductions take ``jax.ops.segment_*`` — a
+#: shape-static choice made at trace time, on every backend.
+_MAX_SEGMENTS = (1 << 23) + 1
 
 _IDENTITY = {
     ("sum", "int32"): 0,
@@ -98,7 +101,7 @@ kernel_calls = 0
 
 
 def _kernel(starts_ref, col_ref, gid_ref, out_ref, *, kind: str,
-            dtype: str, n_chunks: int):
+            dtype: str):
     i = pl.program_id(0)
     ident = _IDENTITY[(kind, dtype)]
 
@@ -106,13 +109,17 @@ def _kernel(starts_ref, col_ref, gid_ref, out_ref, *, kind: str,
     def _init():
         out_ref[:] = jnp.full(out_ref.shape, ident, out_ref.dtype)
 
-    start = starts_ref[i]
+    # window starts are lane-aligned by construction (see the caller);
+    # Mosaic needs the hint to prove the dynamic slice is tile-aligned
+    start = pl.multiple_of(starts_ref[i], _LANE)
     col = col_ref[0, 0, :]                   # (C,)
     local = gid_ref[0, 0, :] - start         # (C,) window offsets
-    in_win = (local >= 0) & (local < _WIN)
-    # one-hot binning matrix: onehot[r, w] == row r feeds window slot w
+    # one-hot binning matrix: onehot[r, w] == row r feeds window slot w.
+    # wslots spans exactly [0, _WIN), so rows outside the window match
+    # no slot — no separate in-window mask (Mosaic cannot reshape a
+    # 1-D bool vector to a column)
     wslots = jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, _WIN), 1)
-    onehot = (local[:, None] == wslots) & in_win[:, None]
+    onehot = local[:, None] == wslots
 
     if kind == "sum":
         if dtype in ("int64", "uint64", "float64"):
@@ -137,11 +144,14 @@ def _kernel(starts_ref, col_ref, gid_ref, out_ref, *, kind: str,
                     preferred_element_type=jnp.float32,
                     precision=jax.lax.Precision.HIGHEST)[0]
 
-            lo_s = dot((col & 0xFFF).astype(jnp.float32))
-            mid_s = dot(((col >> 12) & 0xFFF).astype(jnp.float32))
-            hi_s = dot(jnp.right_shift(col, 24).astype(jnp.float32))
-            win = ((hi_s.astype(jnp.int32) << 24)
-                   + (mid_s.astype(jnp.int32) << 12)
+            # int32 scalars, not Python ints: with x64 on a bare literal
+            # is weakly typed i64, which Mosaic cannot lower
+            m12, s12, s24 = jnp.int32(0xFFF), jnp.int32(12), jnp.int32(24)
+            lo_s = dot((col & m12).astype(jnp.float32))
+            mid_s = dot(((col >> s12) & m12).astype(jnp.float32))
+            hi_s = dot((col >> s24).astype(jnp.float32))
+            win = ((hi_s.astype(jnp.int32) << s24)
+                   + (mid_s.astype(jnp.int32) << s12)
                    + lo_s.astype(jnp.int32))
         else:
             oh = onehot.astype(jnp.float32)
@@ -196,17 +206,18 @@ def _segment_reduce_pallas(col, gid, num_segments: int, kind: str,
     # former 2-D (1, C) block over a (n_chunks, C) array violated the
     # sublane rule whenever n_chunks > 1 and only ever lowered in
     # interpret mode (caught by the AOT lowering smoke test)
+    # (x64 is on: index maps must return int32 zeros, not Python ints)
+    z = np.int32(0)
     out = pl.pallas_call(
-        functools.partial(_kernel, kind=kind, dtype=dtype,
-                          n_chunks=n_chunks),
+        functools.partial(_kernel, kind=kind, dtype=dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_chunks,),
             in_specs=[
-                pl.BlockSpec((1, 1, _CHUNK), lambda i, s: (i, 0, 0)),
-                pl.BlockSpec((1, 1, _CHUNK), lambda i, s: (i, 0, 0)),
+                pl.BlockSpec((1, 1, _CHUNK), lambda i, s: (i, z, z)),
+                pl.BlockSpec((1, 1, _CHUNK), lambda i, s: (i, z, z)),
             ],
-            out_specs=pl.BlockSpec((1, s_alloc), lambda i, s: (0, 0)),
+            out_specs=pl.BlockSpec((1, s_alloc), lambda i, s: (z, z)),
         ),
         out_shape=jax.ShapeDtypeStruct((1, s_alloc), col.dtype),
         interpret=interpret,
@@ -223,6 +234,14 @@ _segment_reduce_pallas = instrument(
     static_argnames=("num_segments", "kind", "interpret"))
 
 
+def takes_kernel(dtype, num_segments: int, mode: str) -> bool:
+    """Whether ``segment_reduce`` hands this reduction to the Pallas
+    kernel under ``mode`` — a trace-static choice by dtype and size."""
+    ok = _SUPPORTED if mode == "tpu" else _SUPPORTED_INTERPRET
+    return bool(mode) and str(dtype) in ok \
+        and num_segments <= _MAX_SEGMENTS
+
+
 def segment_reduce(col, gid, num_segments: int, kind: str,
                    mode: str = None):
     """Segment reduction over SORTED group ids (steps of <= 1, larger
@@ -236,8 +255,7 @@ def segment_reduce(col, gid, num_segments: int, kind: str,
     hit."""
     if mode is None:
         mode = pallas_mode()
-    ok = _SUPPORTED if mode == "tpu" else _SUPPORTED_INTERPRET
-    if mode and str(col.dtype) in ok:
+    if takes_kernel(col.dtype, num_segments, mode):
         global kernel_calls
         kernel_calls += 1
         return _segment_reduce_pallas(col, gid, num_segments, kind,

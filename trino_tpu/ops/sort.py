@@ -23,7 +23,7 @@ from .. import types as T
 from ..block import DevicePage, padded_size
 from ..telemetry.profiler import instrument
 from .operator import Operator
-from .sortkeys import SortKey, sort_operands
+from .sortkeys import SortKey, sort_carrying, sort_operands
 
 
 @partial(jax.jit, static_argnames=("num_key_ops",))
@@ -32,13 +32,11 @@ def _sorted_by(key_ops, cols, nulls, valid, num_key_ops: int):
     from .. import jit_stats
 
     jit_stats.bump("sort_by")
-    operands = [(~valid).astype(jnp.uint8)] + list(key_ops) + list(cols) \
-        + list(nulls) + [valid]
-    s = jax.lax.sort(operands, num_keys=1 + num_key_ops, is_stable=True)
+    _, s = sort_carrying(
+        [(~valid).astype(jnp.uint8)] + list(key_ops),
+        list(cols) + list(nulls) + [valid], is_stable=True)
     n = len(cols)
-    base = 1 + num_key_ops
-    return (tuple(s[base:base + n]), tuple(s[base + n:base + 2 * n]),
-            s[-1])
+    return tuple(s[:n]), tuple(s[n:2 * n]), s[-1]
 
 
 # profiled entry point (telemetry.profiler): cost/compile attribution

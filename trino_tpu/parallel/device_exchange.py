@@ -72,7 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import jit_stats
 from .. import types as T
@@ -201,6 +201,14 @@ class ExchangeSizingHistory:
 #: the process-wide sizing history (one engine process = one history,
 #: like the jit caches it protects)
 SIZING_HISTORY = ExchangeSizingHistory()
+
+
+def task_device(task: int, n_tasks: int, devices: Sequence):
+    """The device task/partition ``task`` of ``n_tasks`` lives on: THE
+    one p-on-d layout (``p % d`` over the first ``min(n, len)``
+    devices) shared by the runner's task placement and the exchange's
+    sender and receiver slabs."""
+    return devices[task % min(n_tasks, len(devices))]
 
 
 class DeviceExchange:
@@ -371,35 +379,48 @@ class DeviceExchange:
             return jnp.concatenate(
                 [a, jnp.zeros((cap - k,), dtype=a.dtype)])
 
-        for ps in dev_pages:
+        # each sender slab is assembled ON its own device (its task's
+        # pages already live there) and becomes that device's shard of
+        # the (d, cap) global arrays the collective reads
+        for dev, ps in zip(self.devices, dev_pages):
             total_rows += sum(p.count() for p in ps)
-            page_cols = [unified_cols(p) for p in ps]
-            for c in range(nch):
+            with jax.default_device(dev):
+                page_cols = [unified_cols(p) for p in ps]
+                for c in range(nch):
+                    if ps:
+                        s_cols[c].append(pad(jnp.concatenate(
+                            [pc[c] for pc in page_cols])))
+                        s_nulls[c].append(pad(jnp.concatenate(
+                            [p.nulls[c] for p in ps])))
+                    else:
+                        s_cols[c].append(jnp.zeros(
+                            (cap,), dtype=types_[c].storage))
+                        s_nulls[c].append(jnp.zeros((cap,), dtype=bool))
                 if ps:
-                    s_cols[c].append(pad(jnp.concatenate(
-                        [pc[c] for pc in page_cols])))
-                    s_nulls[c].append(pad(jnp.concatenate(
-                        [p.nulls[c] for p in ps])))
+                    s_valid.append(pad(jnp.concatenate(
+                        [p.valid for p in ps])))
                 else:
-                    s_cols[c].append(jnp.zeros((cap,),
-                                               dtype=types_[c].storage))
-                    s_nulls[c].append(jnp.zeros((cap,), dtype=bool))
-            if ps:
-                s_valid.append(pad(jnp.concatenate([p.valid for p in ps])))
-            else:
-                s_valid.append(jnp.zeros((cap,), dtype=bool))
+                    s_valid.append(jnp.zeros((cap,), dtype=bool))
 
         if total_rows == 0:
             return [[] for _ in range(n)]
 
-        cols = tuple(jnp.stack(s_cols[c]) for c in range(nch))
-        nulls = tuple(jnp.stack(s_nulls[c]) for c in range(nch))
-        valid = jnp.stack(s_valid)
+        mesh = Mesh(np.asarray(self.devices), ("x",))
+        by_sender = NamedSharding(mesh, P("x"))
+
+        def sharded(slabs):
+            return jax.make_array_from_single_device_arrays(
+                (d, cap), by_sender,
+                [jax.device_put(a[None], dev)
+                 for a, dev in zip(slabs, self.devices)])
+
+        cols = tuple(sharded(s_cols[c]) for c in range(nch))
+        nulls = tuple(sharded(s_nulls[c]) for c in range(nch))
+        valid = sharded(s_valid)
 
         luts = tuple(jnp.asarray(string_hash_lut(target[c]))
                      for c in self.key_channels if types_[c].is_string)
 
-        mesh = Mesh(np.asarray(self.devices), ("x",))
         tkey = tuple(types_)
         kkey = tuple(self.key_channels)
         hkey = self.history_key or (
@@ -537,6 +558,16 @@ class DeviceExchange:
         # ~2x the exchanged bytes in HBM for the rest of the query
         self._by_task.clear()
         out_dicts = list(target)
+
+        def slabs(a):
+            """Per receiver device, its (lanes,) slab of a (d, lanes)
+            result — read as that device's own shard, where it lies."""
+            by_dev = {s.device: s.data for s in a.addressable_shards}
+            return [by_dev[dev][0] for dev in self.devices]
+
+        col_slabs = [slabs(c) for c in out_cols]
+        null_slabs = [slabs(x) for x in out_nulls]
+        valid_slabs, part_slabs = slabs(out_valid), slabs(out_part)
         result: List[List[DevicePage]] = []
         for p in range(n):
             if p in hot:
@@ -546,18 +577,24 @@ class DeviceExchange:
                 devs = devs_for[p] or [p % d]
             else:
                 devs = [p % d]
+            # the consumer task of partition p runs on device p % d:
+            # only a split partition's foreign sub-buckets move
+            home = self.devices[p % d]
             pages: List[DevicePage] = []
             for dev in devs:
-                pv = out_valid[dev]
+                pv = valid_slabs[dev]
                 if d < n or hot:
                     # split the device slab by carried partition id
                     # (with any split active, even n == d slabs hold
                     # foreign partitions' sub-buckets)
-                    pv = pv & (out_part[dev] == p)
-                pages.append(DevicePage(list(types_),
-                                        [c[dev] for c in out_cols],
-                                        [x[dev] for x in out_nulls],
-                                        pv, out_dicts))
+                    pv = pv & (part_slabs[dev] == p)
+                page_cols = [c[dev] for c in col_slabs]
+                page_nulls = [x[dev] for x in null_slabs]
+                if dev != p % d:
+                    page_cols, page_nulls, pv = jax.device_put(
+                        (page_cols, page_nulls, pv), home)
+                pages.append(DevicePage(list(types_), page_cols,
+                                        page_nulls, pv, out_dicts))
             result.append(pages)
         return result
 

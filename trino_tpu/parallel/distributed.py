@@ -475,6 +475,32 @@ class DistributedQueryRunner:
                   buffers, stage, root: Optional[OutputNode],
                   results: Optional[List[List[Page]]],
                   streaming: bool = False):
+        """Task ``t`` placed on its device: worker ``t``'s operators run
+        on device ``t % d`` — the same layout the device exchange uses
+        for its slabs, so a task's pages are already where the
+        collective reads them and its partition arrives where it runs."""
+        import jax
+
+        from .device_exchange import task_device
+
+        steps = self._task_steps(frag, ntasks, t, out, buffers, stage,
+                                 root, results, streaming)
+        device = task_device(t, self.n_workers, jax.devices())
+        # jax.default_device is thread-local and the executor may resume
+        # a task on another thread: enter it around each quantum, never
+        # across a yield
+        while True:
+            with jax.default_device(device):
+                try:
+                    item = next(steps)
+                except StopIteration:
+                    return
+            yield item
+
+    def _task_steps(self, frag: PlanFragment, ntasks: int, t: int, out,
+                    buffers, stage, root: Optional[OutputNode],
+                    results: Optional[List[List[Page]]],
+                    streaming: bool = False):
         """One task of one fragment as a cooperative generator. ``out``
         is the fragment's output (OutputBuffer | DeviceExchange | None
         for the output fragment, which collects into ``results[t]``).

@@ -34,34 +34,11 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
 from .. import jit_stats
 from .. import types as T
-
-try:  # jax >= 0.4.35 exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax: the experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# newer jax spells the replication-check kwarg ``check_vma``, older
-# releases ``check_rep``; detect once instead of catching TypeError at
-# call time (which would mask unrelated argument mistakes)
-import inspect as _inspect
-
-_SM_PARAMS = _inspect.signature(_shard_map).parameters
-_SM_CHECK_KW = ("check_vma" if "check_vma" in _SM_PARAMS
-                else "check_rep" if "check_rep" in _SM_PARAMS else None)
-
-
-def shard_map(*args, **kwargs):
-    """Version-compat ``shard_map``: call sites write ``check_vma``;
-    the shim renames (or drops) it to whatever this jax supports."""
-    if "check_vma" in kwargs and _SM_CHECK_KW != "check_vma":
-        kwargs = dict(kwargs)
-        val = kwargs.pop("check_vma")
-        if _SM_CHECK_KW is not None:
-            kwargs[_SM_CHECK_KW] = val
-    return _shard_map(*args, **kwargs)
+from ..ops.sortkeys import sort_carrying
 
 
 def string_hash_lut(d) -> np.ndarray:
@@ -151,9 +128,8 @@ def repartition_a2a(cols: Tuple, nulls: Tuple, valid, part_ids,
     cap = valid.shape[0]
     # sort rows by (invalid, destination): live rows grouped by dest
     dest = jnp.where(valid, part_ids, num_partitions)
-    operands = [dest.astype(jnp.int32)] + list(cols) + list(nulls) + [valid]
-    s = jax.lax.sort(operands, num_keys=1, is_stable=False)
-    s_dest, s_rest = s[0], s[1:]
+    (s_dest,), s_rest = sort_carrying(
+        [dest.astype(jnp.int32)], list(cols) + list(nulls) + [valid])
     ncols = len(cols)
     s_cols, s_nulls, s_valid = (s_rest[:ncols], s_rest[ncols:2 * ncols],
                                 s_rest[-1])

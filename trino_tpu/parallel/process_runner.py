@@ -347,8 +347,9 @@ class ProcessQueryRunner:
     def _spawn_worker_process(self, generation: int = 0,
                               reason: str = "initial",
                               index: int = -1) -> WorkerHandle:
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   JAX_COMPILATION_CACHE_DIR="/tmp/trino_tpu_jax_cache")
+        # workers run on the CPU backend: a coordinator that holds the
+        # chip must never start a child that reaches for it
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env.pop("XLA_FLAGS", None)  # workers need no virtual mesh
         proc = subprocess.Popen(
             [sys.executable, "-m", "trino_tpu.parallel.worker"],

@@ -1130,13 +1130,13 @@ class WorkerServer:
 
 
 def main():
-    # workers are CPU-pinned: the TPU chip belongs to the in-process
-    # mesh path; this runtime validates the process architecture
+    # the worker runtime runs on the CPU backend today: a chip belongs
+    # to one process at a time, and that process is the coordinator's
+    # in-process path (LocalQueryRunner / DistributedQueryRunner)
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
+    from ..compile_cache import enable_compile_cache
 
-    jax.config.update("jax_platforms",
-                      os.environ.get("JAX_PLATFORMS", "cpu"))
+    enable_compile_cache()
     port = int(sys.argv[1]) if len(sys.argv) > 1 else 0
     server = WorkerServer(port)
     print(f"WORKER_READY {server.port}", flush=True)

@@ -42,7 +42,7 @@ from typing import Dict, List, Optional, Tuple
 
 __all__ = [
     "enabled", "enable", "profiling", "instrument", "snapshot",
-    "totals", "thread_totals", "reset", "device_memory_stats",
+    "compiled_programs", "totals", "thread_totals", "reset", "device_memory_stats",
     "diff_profiles", "validate_profile", "ProfiledFunction",
 ]
 
@@ -432,6 +432,13 @@ def snapshot() -> List[dict]:
         entries = list(_STATE.entries.values())
     return sorted((e.to_dict() for e in entries),
                   key=lambda d: (d["name"], d["key"]))
+
+
+def compiled_programs() -> List[tuple]:
+    """(name, compiled executable) of every registry entry — lets a
+    caller inspect the programs themselves (``compiled.as_text()``)."""
+    with _STATE.lock:
+        return [(e.name, e.compiled) for e in _STATE.entries.values()]
 
 
 def totals() -> dict:
