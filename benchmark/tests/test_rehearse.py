@@ -1,0 +1,67 @@
+"""``run.py --rehearse-cpu`` end to end on ``tiny`` for each cell's
+traffic file, and the last line's keys."""
+
+import argparse
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from benchmark import run
+
+with open(os.path.join(run.ROOT, "BENCHMARK.json")) as _f:
+    BENCH = json.load(_f)
+
+
+def tiny_config():
+    name, = [c["name"] for c in BENCH["configs"]
+             if json.load(open(os.path.join(run.ROOT, c["file"])))["schema"]
+             == "tiny"]
+    return name
+
+
+@pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
+@pytest.mark.parametrize("trace", [0, 1])
+def test_traffic_file_runs_on_tiny(cell, trace):
+    """Each cell's traffic against the tiny configuration, in process."""
+    cell = dict(cell, config=tiny_config())
+    args = argparse.Namespace(seed=2147483659, seconds=1.0, trace=trace,
+                              rehearse_cpu=True)
+    line = run.run_cell(BENCH, cell, args)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["device"]["platform"] == "cpu"
+    wanted = BENCH["per_layer"] if trace else BENCH["end_to_end"]
+    listed = {m["name"] for m in wanted
+              if cell["name"] in m.get("workloads", [cell["name"]])}
+    assert set(line["metrics"]) <= listed
+    if not trace:
+        assert set(line["metrics"]) == listed
+        assert all(m["value"] > 0 for m in line["metrics"].values())
+
+
+def test_command_prints_the_contract_line_last():
+    cell, = [w["name"] for w in BENCH["workloads"]
+             if w["config"] == tiny_config()]
+    proc = subprocess.run(
+        [sys.executable, os.path.join(run.HERE, "run.py"), "--workload",
+         cell, "--seed", "7", "--seconds", "1", "--trace", "0",
+         "--rehearse-cpu"], capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert set(line) >= {"correct", "attempted", "failed", "metrics",
+                         "device"}
+    assert set(line["device"]) >= {"platform", "kind", "count",
+                                   "memory_peak_bytes"}
+    assert line["device"]["platform"] == "cpu"
+
+
+def test_no_tpu_is_an_error():
+    proc = subprocess.run(
+        [sys.executable, os.path.join(run.HERE, "run.py"), "--workload",
+         BENCH["workloads"][0]["name"], "--seed", "7", "--seconds", "1",
+         "--trace", "0"], capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert proc.returncode != 0
+    assert "correct" not in proc.stdout
