@@ -410,7 +410,9 @@ class DevicePage:
 
     def count(self) -> int:
         """Live row count (device sync)."""
-        return int(np.asarray(self.valid).sum())
+        from .telemetry.tracing import host_read
+
+        return int(host_read(self.valid, "page_count").sum())
 
     def trimmed(self) -> "DevicePage":
         """This page cut to the pow2 capacity that still holds its last
@@ -420,9 +422,12 @@ class DevicePage:
         compiled — by capacity, so the dead tail is cut here."""
         import jax.numpy as jnp
 
+        from .telemetry.tracing import host_read
+
         cap = self.capacity
-        last = int(jnp.max(jnp.where(
-            self.valid, jnp.arange(1, cap + 1, dtype=jnp.int32), 0)))
+        last = int(host_read(jnp.max(jnp.where(
+            self.valid, jnp.arange(1, cap + 1, dtype=jnp.int32), 0)),
+            "page_trim"))
         keep = padded_size(last)
         if keep >= cap:
             return self
@@ -457,13 +462,18 @@ class DevicePage:
 
     def to_page(self) -> Page:
         """Compact live lanes back to a host Page."""
-        keep = np.nonzero(np.asarray(self.valid))[0]
+        from .telemetry.tracing import host_sync
+
+        with host_sync("page_to_host"):
+            valid = np.asarray(self.valid)
+            host = [(np.asarray(c), np.asarray(nl))
+                    for c, nl in zip(self.cols, self.nulls)]
+        keep = np.nonzero(valid)[0]
         blocks = []
-        for t, c, nl, d in zip(self.types, self.cols, self.nulls,
-                               self.dictionaries):
-            data = np.asarray(c)[keep]
-            nulls = np.asarray(nl)[keep]
-            blocks.append(Block(t, data, nulls if nulls.any() else None, d))
+        for t, (c, nl), d in zip(self.types, host, self.dictionaries):
+            nulls = nl[keep]
+            blocks.append(Block(t, c[keep], nulls if nulls.any() else None,
+                                d))
         return Page(blocks, len(keep))
 
 

@@ -76,6 +76,7 @@ from ..ops.operator import (FilterProjectOperator, OutputCollectorOperator,
                             TableScanOperator)
 from ..ops.sortkeys import group_operands
 from ..telemetry.profiler import instrument
+from ..telemetry.tracing import host_read, span_set
 
 
 class BatchIneligible(Exception):
@@ -666,7 +667,8 @@ def execute_batched(plan, param_types, bindings: Sequence[Tuple],
         # capacity must be a static shape. Already-spilled lanes are
         # excluded so their (re-run serially anyway) fan-out cannot
         # inflate the shared capacity.
-        totals = np.where(spill, 0, np.asarray(jnp.sum(count, axis=-1)))
+        totals = np.where(spill, 0, host_read(jnp.sum(count, axis=-1),
+                                              "batched_join_totals"))
         need = int(totals.max()) if totals.size else 16
         lane_cap = KERNEL_SIZING.suggest(
             ("batched_join_expand",) + cfg,
@@ -727,13 +729,17 @@ def execute_batched(plan, param_types, bindings: Sequence[Tuple],
         run_from(0, _BatchPage(list(dpage.types), tuple(dpage.cols),
                                tuple(dpage.nulls), dpage.valid,
                                list(dpage.dictionaries), False))
+    # the shared scan's host-side counters, on the span around this call
+    # (no driver ran it, so no operator span carries them)
+    for key, value in (getattr(scan, "metrics", dict)() or {}).items():
+        span_set(key, value)
 
     # agg barriers drain in stage order: each finalize feeds the
     # remaining stages (which may include another barrier downstream)
     for k in sorted(agg_accs):
         acc = agg_accs[k]
         page = acc.finalize()
-        spill[:] = spill | np.asarray(acc.overflow)
+        spill[:] = spill | host_read(acc.overflow, "batched_agg_overflow")
         note_rows(k, stages[k][1], page.valid)
         run_from(k + 1, page)
 
@@ -750,12 +756,12 @@ def execute_batched(plan, param_types, bindings: Sequence[Tuple],
             host = member.to_page()
             if host.num_rows:
                 out_pages[m].append(host)
-    scan_rows = int(np.asarray(scan_rows_acc)) \
+    scan_rows = int(host_read(scan_rows_acc, "batched_stage_rows")) \
         if scan_rows_acc is not None else 0
     stage_rows = [
         {"fp": getattr(stages[k][1], "_hbo_fp", None),
          "name": type(stages[k][1]).__name__,
-         "rows": np.asarray(rows_acc[k])}
+         "rows": host_read(rows_acc[k], "batched_stage_rows")}
         for k in sorted(rows_acc)]
     if getattr(scan, "_hbo_fp", None) is not None:
         # the shared scan is lane-invariant: every lane observed it

@@ -8,13 +8,16 @@ Synchronous for now; the task executor adds cooperative quanta on top
 
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from typing import List, Optional, Sequence
 
+import numpy as np
+
 from .. import jit_stats
 from ..connectors.spi import ConnectorSplit
-from ..telemetry import profiler
+from ..telemetry import profiler, tracing
 from ..ops.operator import Operator, SourceOperator
 
 
@@ -28,8 +31,16 @@ class OperatorStats:
     bug, and this counter makes it assertable."""
 
     name: str
+    #: live rows handed downstream.  The driver keeps each page's mask
+    #: and reads the masks once, when it has finished (or when
+    #: ``collect_operator_metrics`` is called): 0 until then
     output_rows: int = 0
     output_pages: int = 0
+    #: wall time inside this operator's calls.  JAX dispatch is
+    #: asynchronous and the driver itself never waits for the device,
+    #: so this is DISPATCH time wherever the operator does not sync of
+    #: its own accord (the statement root's ``host_sync_by_why`` says
+    #: how long the traced blocking reads waited); it is not device time
     wall_ns: int = 0
     compile_count: int = 0
     #: XLA cost attribution (telemetry.profiler thread deltas): flops /
@@ -92,6 +103,11 @@ class OperatorStats:
         return base
 
 
+#: an operator's pending masks are read early once they hold this many
+#: bytes (a thousand full pages): bounds what a long scan keeps alive
+_PENDING_MASK_BYTES = 64 << 20
+
+
 class Driver:
     """Executes one operator chain to completion."""
 
@@ -111,6 +127,14 @@ class Driver:
         #: the stats' first_ns/last_ns to wall-clock span timestamps
         self.epoch_anchor = (time.time(), time.perf_counter_ns()) \
             if collect_stats else None
+        #: per operator, the live-row masks of the pages it handed
+        #: downstream whose rows are not counted yet, and their bytes
+        self._pending_masks: List[list] = [[] for _ in operators]
+        self._pending_bytes = [0] * len(self.operators)
+        #: the statement is traced: operator calls are profiler
+        #: annotations too
+        self._traced = collect_stats and \
+            tracing.current_span() is not None
 
     @property
     def source(self) -> Optional[SourceOperator]:
@@ -127,17 +151,22 @@ class Driver:
         if src is not None:
             src.no_more_splits()
 
-    def _timed_call(self, idx: int, fn):
+    def _timed_call(self, idx: int, call: str, fn, *args):
         """Run one operator call attributing wall/compiles/activity to
-        stats[idx] — the same attribution the page-move hot path does
-        inline (finish propagation and tail drains can do real work:
-        an aggregation's finish builds its output state)."""
-        t0 = time.perf_counter_ns()
+        stats[idx] (finish propagation and tail drains can
+        do real work: an aggregation's finish builds its output state).
+        With tracing on the call is the profiler annotation
+        ``op:<Operator>.<call>``."""
+        st = self.stats[idx]
         c0 = jit_stats.thread_total()
         p0 = profiler.thread_totals()
-        out = fn()
-        t1 = time.perf_counter_ns()
-        st = self.stats[idx]
+        with tracing.annotation(f"op:{st.name}.{call}") \
+                if self._traced else contextlib.nullcontext():
+            t0 = time.perf_counter_ns()
+            try:
+                out = fn(*args)
+            finally:
+                t1 = time.perf_counter_ns()
         st.wall_ns += t1 - t0
         st.compile_count += jit_stats.thread_total() - c0
         self._attribute_cost(st, p0)
@@ -166,64 +195,77 @@ class Driver:
             # finish propagation
             if cur.is_finished() and not nxt._finishing:
                 if self.collect_stats:
-                    self._timed_call(i + 1, nxt.finish)
+                    self._timed_call(i + 1, "finish", nxt.finish)
                 else:
                     nxt.finish()
             if nxt.needs_input():
                 if self.collect_stats:
-                    t0 = time.perf_counter_ns()
-                    c0 = jit_stats.thread_total()
-                    p0 = profiler.thread_totals()
-                    page = cur.get_output()
-                    t1 = time.perf_counter_ns()
-                    st = self.stats[i]
-                    st.wall_ns += t1 - t0
-                    st.compile_count += jit_stats.thread_total() - c0
-                    self._attribute_cost(st, p0)
-                    if st.first_ns == 0:
-                        st.first_ns = t0
-                    st.last_ns = t1
+                    page = self._timed_call(i, "get_output",
+                                            cur.get_output)
                     if page is not None:
-                        st.output_pages += 1
-                        st.output_rows += page.count()
+                        self._note_rows(i, page)
                 else:
                     page = cur.get_output()
                 if page is not None:
                     if self.collect_stats:
-                        t0 = time.perf_counter_ns()
-                        c0 = jit_stats.thread_total()
-                        p0 = profiler.thread_totals()
-                        nxt.add_input(page)
-                        t1 = time.perf_counter_ns()
-                        st1 = self.stats[i + 1]
-                        st1.wall_ns += t1 - t0
-                        st1.compile_count += jit_stats.thread_total() - c0
-                        self._attribute_cost(st1, p0)
-                        if st1.first_ns == 0:
-                            st1.first_ns = t0
-                        st1.last_ns = t1
+                        self._timed_call(i + 1, "add_input",
+                                         nxt.add_input, page)
                     else:
                         nxt.add_input(page)
                     moved = True
         # drain the tail operator (sinks produce no output)
         if self.collect_stats:
-            self._timed_call(len(ops) - 1, ops[-1].get_output)
+            self._timed_call(len(ops) - 1, "get_output",
+                             ops[-1].get_output)
         else:
             ops[-1].get_output()
         if not moved:
             # nothing moved: push finish from the head if it is done
             if ops[0].is_finished() and not ops[0]._finishing:
                 if self.collect_stats:
-                    self._timed_call(0, ops[0].finish)
+                    self._timed_call(0, "finish", ops[0].finish)
                 else:
                     ops[0].finish()
         self.last_moved = moved
-        return ops[-1].is_finished()
+        done = ops[-1].is_finished()
+        if done and self.collect_stats:
+            self.resolve_row_counts()
+        return done
+
+    def _note_rows(self, i: int, page):
+        """Operator ``i`` handed ``page`` downstream.  Its rows are
+        counted later, from its mask: no host round trip per page and
+        no program of the driver's own."""
+        self.stats[i].output_pages += 1
+        self._pending_masks[i].append(page.valid)
+        self._pending_bytes[i] += page.valid.size
+        if self._pending_bytes[i] > _PENDING_MASK_BYTES:
+            self._count_pending(i)
+
+    def _count_pending(self, i: int):
+        masks = self._pending_masks[i]
+        if masks:
+            import jax
+
+            with tracing.host_sync("driver_row_counts"):
+                self.stats[i].output_rows += sum(
+                    int(np.count_nonzero(m)) for m in jax.device_get(masks))
+            del masks[:]
+            self._pending_bytes[i] = 0
+
+    def resolve_row_counts(self):
+        """Count the pending masks into ``stats[i].output_rows``: one
+        blocking read per operator, made when the driver has finished
+        (or by whoever reads the stats of a driver that was cut
+        short)."""
+        for i in range(len(self.stats)):
+            self._count_pending(i)
 
     def collect_operator_metrics(self):
         """Pull per-operator metrics (exchange skew stats etc.) into the
         stats entries. Call after the driver finished: exchange sources
         only know their stats once the upstream collective ran."""
+        self.resolve_row_counts()
         for op, st in zip(self.operators, self.stats):
             m = getattr(op, "metrics", None)
             if callable(m):

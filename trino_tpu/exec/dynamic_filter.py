@@ -28,6 +28,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from ..block import padded_size
+from ..telemetry.tracing import host_read, host_sync
 
 #: value sets larger than this keep only min/max (reference analog:
 #: dynamic-filtering.small.max-distinct-values-per-driver)
@@ -56,8 +57,9 @@ class DynamicFilter:
         at HashBuilder publish; one device->host transfer)."""
         import jax.numpy as jnp
 
-        live = np.asarray(valid & ~nulls)
-        vals = np.asarray(col)[live]
+        with host_sync("dynamic_filter_collect"):
+            live = np.asarray(valid & ~nulls)
+            vals = np.asarray(col)[live]
         self.build_rows = int(vals.shape[0])
         if np.issubdtype(vals.dtype, np.floating):
             # NaN build keys: np.unique sorts NaN last, so hi would be
@@ -135,11 +137,13 @@ class DynamicFilter:
 
     @property
     def pruned_rows(self) -> int:
-        return 0 if self._pruned_dev is None else int(self._pruned_dev)
+        return 0 if self._pruned_dev is None else int(
+            host_read(self._pruned_dev, "dynamic_filter_stats"))
 
     @property
     def scanned_rows(self) -> int:
-        return 0 if self._seen_dev is None else int(self._seen_dev)
+        return 0 if self._seen_dev is None else int(
+            host_read(self._seen_dev, "dynamic_filter_stats"))
 
     def stats(self) -> dict:
         return {

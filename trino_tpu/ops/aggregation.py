@@ -61,6 +61,7 @@ from .. import jit_stats
 from .. import types as T
 from ..block import DevicePage, padded_size
 from ..telemetry.profiler import instrument
+from ..telemetry.tracing import host_read
 from ..types import TypeError_
 from .hashtable import (_mix_operands, hash_group_ids,
                         hash_segment_reduce, hashable_key_types)
@@ -790,7 +791,7 @@ class HashAggregationOperator(Operator):
                                      tuple(state_cols), self._kinds,
                                      pallas=mode)
         if exact:
-            if bool(overflow):
+            if bool(host_read(overflow, "agg_overflow")):
                 return None
         elif observe and self.adaptive_partial \
                 and not self._adaptive_decided:
@@ -817,9 +818,10 @@ class HashAggregationOperator(Operator):
         buckets non-reducing -> whole-stream pass-through (the classic
         switch), a mix -> range split (reference: adaptive partial
         aggregation; "Partial Partial Aggregates", PAPERS.md)."""
-        stats = np.asarray(_bucket_reduction_stats(
+        stats = host_read(_bucket_reduction_stats(
             tuple(key_ops), valid, group_rows, ngroups,
-            self.adaptive_key_buckets)).astype(np.int64)
+            self.adaptive_key_buckets),
+            "agg_adaptive_stats").astype(np.int64)
         self._bucket_stats += stats
         self._adaptive_rows += int(stats[0].sum())
         self._adaptive_groups += int(stats[1].sum())

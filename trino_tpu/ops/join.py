@@ -39,6 +39,7 @@ import numpy as np
 from .. import types as T
 from ..block import DevicePage, padded_size
 from ..telemetry.profiler import instrument
+from ..telemetry.tracing import host_read
 from .operator import Operator
 from .sortkeys import group_operands, sort_carrying
 
@@ -1149,7 +1150,7 @@ class LookupJoinOperator(Operator):
         -> re-expand at the now-known exact size, chunked under the
         lane budget."""
         rec = self._pending.pop(0)
-        tot = int(rec["total"])
+        tot = int(host_read(rec["total"], "join_expand_total"))
         self._ratio = 0.75 * self._ratio \
             + 0.25 * (tot / max(rec["rows"], 1))
         if tot <= rec["cap"]:
@@ -1174,7 +1175,7 @@ class LookupJoinOperator(Operator):
         if padded_size(max(total, 16)) <= self.max_lanes:
             return [(page, pkey_cols, pusable, lo, count,
                      padded_size(max(total, 16)))]
-        counts = np.asarray(count)
+        counts = host_read(count, "join_chunk_counts")
         units: List = []
         n = counts.shape[0]
         i = 0
@@ -1328,7 +1329,7 @@ class LookupJoinOperator(Operator):
             else page.valid
         lo, count = _probe_counts(b.key_sorted, b.usable_sorted, pkey,
                                   pusable)
-        tot = int(jnp.sum(count))
+        tot = int(host_read(jnp.sum(count), "join_expand_total"))
         rec = {"b": b, "page": page, "pkey_cols": pkey_cols,
                "pusable": pusable, "lo": lo, "count": count}
         for unit in self._chunk_units(rec, tot):

@@ -12,6 +12,7 @@ output (device -> numpy).
 
 from __future__ import annotations
 
+import time
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -19,6 +20,7 @@ import numpy as np
 from ..block import DevicePage, Page
 from ..connectors.spi import ColumnHandle, Connector, ConnectorSplit
 from ..expr.compiler import PageProcessor
+from ..telemetry import tracing
 
 
 class Operator:
@@ -96,6 +98,13 @@ class TableScanOperator(SourceOperator):
         self._source = None
         self._no_more_splits = False
         self._done = False
+        #: host-side counters of a traced statement (None: tracing off):
+        #: seconds in the connector's page generation and the coalescing
+        #: concat, seconds of the host-to-device upload — the host's
+        #: share of a scan, which overlaps device work and so shows in
+        #: no idle gap
+        self._counters: Optional[dict] = None
+        self._counters_known = False
 
     def add_split(self, split: ConnectorSplit):
         self._splits.append(split)
@@ -103,7 +112,27 @@ class TableScanOperator(SourceOperator):
     def no_more_splits(self):
         self._no_more_splits = True
 
+    def metrics(self) -> Optional[dict]:
+        return self._counters
+
+    def _timed(self, name: str, key: str, fn, *args):
+        """``fn(*args)``; in a traced statement its wall is added to the
+        counter ``key`` and the call is the annotation ``name``."""
+        c = self._counters
+        if c is None:
+            return fn(*args)
+        with tracing.annotation(name):
+            t0 = time.perf_counter()
+            try:
+                return fn(*args)
+            finally:
+                c[key] += time.perf_counter() - t0
+
     def _upload(self, page: Page) -> DevicePage:
+        return self._timed("scan.upload", "upload_s",
+                           self._upload_page, page)
+
+    def _upload_page(self, page: Page) -> DevicePage:
         dp = DevicePage.from_page(page)
         for ch, df in self.dynamic_filters:
             dp = DevicePage(dp.types, dp.cols, dp.nulls,
@@ -116,9 +145,16 @@ class TableScanOperator(SourceOperator):
         pages, self._buffer = self._buffer, []
         self._buffered_rows = 0
         return self._upload(pages[0] if len(pages) == 1
-                            else Page.concat(pages))
+                            else self._timed("scan.generate",
+                                             "generate_s",
+                                             Page.concat, pages))
 
     def get_output(self) -> Optional[DevicePage]:
+        if not self._counters_known:
+            # first call: the statement's span, if any, is current now
+            self._counters_known = True
+            if tracing.current_span() is not None:
+                self._counters = {"generate_s": 0.0, "upload_s": 0.0}
         while True:
             if self._source is None:
                 if self._splits:
@@ -132,7 +168,8 @@ class TableScanOperator(SourceOperator):
                     return None
                 else:
                     return self._flush() if self._buffer else None
-            page = self._source.get_next_page()
+            page = self._timed("scan.generate", "generate_s",
+                               self._source.get_next_page)
             if page is None:
                 if self._source.is_finished():
                     self._source.close()
@@ -239,7 +276,8 @@ class LimitOperator(Operator):
         if self._seen is not None:
             # the async copy issued in add_input has usually landed;
             # this read is then free
-            self._known_seen = int(np.asarray(self._seen))
+            self._known_seen = int(tracing.host_read(self._seen,
+                                                     "limit_seen"))
         return (self._pending is None and self._known_seen < self.limit
                 and not self._finishing)
 

@@ -9,6 +9,8 @@ with exchanges between fragments (parallel/ package).
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -23,6 +25,12 @@ from .planner.plan import OutputNode, plan_tree_str
 from .sql import ast
 from .sql.analyzer import AnalysisError, Session
 from .sql.parser import parse_statement
+
+#: the spans of an ``execute_batch`` call's members, positionally, when
+#: the caller opened them itself (``ProtocolServer``: each statement's
+#: ``statement.run``); unset, a traced call opens its own
+BATCH_MEMBER_SPANS: contextvars.ContextVar = contextvars.ContextVar(
+    "trino_tpu_batch_member_spans", default=None)
 
 
 @dataclass
@@ -213,7 +221,25 @@ class LocalQueryRunner:
         admission path + QueryMonitor).  ``user`` overrides the session
         user for admission routing (multi-tenant protocol serving);
         ``progress`` is an optional telemetry.progress.QueryProgress
-        the execution feeds live (protocol GET /v1/query/{id})."""
+        the execution feeds live (protocol GET /v1/query/{id}).
+
+        The statement's spans hang under the caller's current span
+        (``ProtocolServer`` enters ``statement.run``); with none the
+        call opens a ``statement`` root of its own
+        (``telemetry.tracing.root_scope``)."""
+        from .telemetry import tracing
+
+        with tracing.root_scope("statement", self._tracing_on(),
+                                served_by="solo", batch_size=1):
+            return self._admitted_execute(sql, user, progress)
+
+    def _tracing_on(self) -> bool:
+        from . import session_properties as SP
+
+        return SP.value(self.session, "query_tracing_enabled")
+
+    def _admitted_execute(self, sql: str, user: Optional[str],
+                          progress) -> QueryResult:
         user = user or self.session.user
         self.access_control.check_can_execute_query(user)
         if self.resource_groups is not None:
@@ -291,6 +317,7 @@ class LocalQueryRunner:
         — loudly, negative-cached by reason — never at member
         execution."""
         from . import session_properties as SP
+        from .telemetry import tracing
 
         if not SP.value(self.session, "plan_template_enabled"):
             return None
@@ -318,6 +345,8 @@ class LocalQueryRunner:
         hit = tc.lookup(tkey)
         if hit is not None:
             kind, val = hit
+            if kind != "hit":
+                tracing.span_set("template", val)
             return val if kind == "hit" else None
         hint = None
         if hbo_ctx is not None:
@@ -335,11 +364,11 @@ class LocalQueryRunner:
             if seeded_reason is not None:
                 # another node already proved the shape value-dependent:
                 # negative-cache locally without paying a trial plan
-                tc.store_fallback(tkey, seeded_reason, max_entries)
+                self._template_fallback(tkey, seeded_reason, max_entries)
                 return None
         reason = self._template_ineligible_reason(pq.shape)
         if reason is not None:
-            tc.store_fallback(tkey, reason, max_entries)
+            self._template_fallback(tkey, reason, max_entries)
             if seeds is not None:
                 seeds.note_fallback_shape(shape_fp, reason)
             return None
@@ -351,7 +380,7 @@ class LocalQueryRunner:
             lits = analyze_literal_tokens(pq.literals, self.session)
             ptypes = tuple(lit.type for lit in lits)
             if any(getattr(t, "is_pooled", False) for t in ptypes):
-                tc.store_fallback(tkey, "string_param", max_entries)
+                self._template_fallback(tkey, "string_param", max_entries)
                 if seeds is not None:
                     seeds.note_fallback_shape(shape_fp, "string_param")
                 return None
@@ -372,7 +401,7 @@ class LocalQueryRunner:
         except T.TrinoError:
             # AnalysisError / TypeError_ / NOT_SUPPORTED — planning or
             # compilation genuinely needs a literal value
-            tc.store_fallback(tkey, "value_dependent", max_entries)
+            self._template_fallback(tkey, "value_dependent", max_entries)
             if seeds is not None:
                 seeds.note_fallback_shape(shape_fp, "value_dependent")
             return None
@@ -380,6 +409,15 @@ class LocalQueryRunner:
                                 scan_refs=self._scan_refs(root))
         tc.store(tkey, template, max_entries)
         return template
+
+    def _template_fallback(self, tkey, reason: str, max_entries: int):
+        """Negative-cache the template key by ``reason`` and say so on
+        the ``plan`` span around this call."""
+        from .telemetry import tracing
+
+        self.query_cache.templates.store_fallback(tkey, reason,
+                                                  max_entries)
+        tracing.span_set("template", reason)
 
     def _template_binding(self, template, pq) -> Optional[Tuple]:
         """This member's literal values per ParamRef slot under
@@ -416,14 +454,42 @@ class LocalQueryRunner:
         batch."""
         user = user or self.session.user
         self.access_control.check_can_execute_query(user)
-        if self.resource_groups is not None:
-            from . import session_properties as SP
+        with self._batch_spans(len(sqls)) as members:
+            if self.resource_groups is not None:
+                from . import session_properties as SP
 
-            group = self.resource_groups.select(user)
-            with group.run(memory_bytes=SP.value(
-                    self.session, "query_max_memory_bytes")):
-                return self._run_batch(sqls, user)
-        return self._run_batch(sqls, user)
+                group = self.resource_groups.select(user)
+                with group.run(memory_bytes=SP.value(
+                        self.session, "query_max_memory_bytes")):
+                    return self._run_batch(sqls, user, members)
+            return self._run_batch(sqls, user, members)
+
+    @contextlib.contextmanager
+    def _batch_spans(self, n: int):
+        """The members' spans of one ``execute_batch`` call of ``n``
+        statements, positionally, or None with tracing off: the
+        caller's (``BATCH_MEMBER_SPANS``), else — with no current span
+        and tracing on — a ``batch.run`` root of the call's own and one
+        ``statement`` root per member that says ``batch=<its span id>``.
+        A member's span lasts as long as the call: its wait inside the
+        batch."""
+        from .telemetry import tracing
+
+        members = BATCH_MEMBER_SPANS.get()
+        if members is not None or tracing.current_span() is not None \
+                or not self._tracing_on():
+            yield members
+            return
+        with tracing.Tracer(ring=tracing.RING).span(
+                "batch.run", batch_size=n) as batch:
+            members = [tracing.Tracer(ring=tracing.RING).span(
+                "statement", batch_size=n, batch=batch.span_id)
+                for _ in range(n)]
+            try:
+                yield members
+            finally:
+                for m in members:
+                    m.finish()
 
     def _coalescable(self, sql: str) -> bool:
         # only deterministic plain queries may demux one execution to
@@ -436,9 +502,34 @@ class LocalQueryRunner:
             return False
         return pq.is_query and pq.deterministic
 
-    def _run_batch(self, sqls: Sequence[str], user: str) -> List:
-        from . import session_properties as SP
+    @staticmethod
+    def _served(member, res, how: str):
+        """A batch member's result is ready: its span (``statement.run``
+        under ``ProtocolServer``; it lasts until the whole batch
+        returns) says how it was served, and the result it gets carries
+        its own statement's trace — a result several submitters share
+        is copied for that."""
+        if not member:
+            return res
+        member.set("served_by", how)
+        if isinstance(res, Exception):
+            member.set("error", repr(res))
+        elif how not in ("solo", "serial_in_batch"):
+            # (a member served serially got its own result and trace)
+            res = QueryResult(res.column_names, res.types, res.rows,
+                              stats=dict(res.stats or {},
+                                         trace=member.tracer.finished()))
+        return res
 
+    def _run_batch(self, sqls: Sequence[str], user: str,
+                   members=None) -> List:
+        """``members``: the batch members' spans, positionally
+        (``_batch_spans``), or None with tracing off."""
+        from . import session_properties as SP
+        from .telemetry import tracing
+
+        if members is None:
+            members = [tracing.NULL_SPAN] * len(sqls)
         out: List = [None] * len(sqls)
         done = [False] * len(sqls)
         coalesced = 0
@@ -458,8 +549,8 @@ class LocalQueryRunner:
                     continue  # nothing to amortize into one launch
                 served = self._try_batched(
                     [(i, sqls[i]) for i in idxs], user)
-                for i, res in served.items():
-                    out[i] = res
+                for i, (res, how) in served.items():
+                    out[i] = self._served(members[i], res, how)
                     done[i] = True
         memo: Dict[str, object] = {}
         for i, sql in enumerate(sqls):
@@ -467,10 +558,14 @@ class LocalQueryRunner:
                 continue
             if sql in memo:
                 coalesced += 1
-                out[i] = memo[sql]
+                out[i] = self._served(members[i], memo[sql], "coalesced")
                 continue
+            how = "serial_in_batch" if len(sqls) > 1 else "solo"
             try:
-                res = self._monitored_execute(sql, user)
+                # the member's span is current meanwhile: its serial
+                # work hangs under its own statement
+                with tracing.use_span(members[i]):
+                    res = self._monitored_execute(sql, user)
             except Exception as e:  # demuxed per statement
                 out[i] = e
                 if self._coalescable(sql):
@@ -479,6 +574,7 @@ class LocalQueryRunner:
                 out[i] = res
                 if self._coalescable(sql):
                     memo[sql] = res
+            self._served(members[i], out[i], how)
         self.query_cache.note_batch(len(out), coalesced)
         return out
 
@@ -493,17 +589,23 @@ class LocalQueryRunner:
         from . import session_properties as SP
         from .block import padded_size
         from .exec.batched import BatchIneligible, execute_batched
+        from .telemetry import tracing
 
-        served: Dict[int, object] = {}
-        pqs = {i: self.query_cache.parse(sql, self.session)
-               for i, sql in members}
+        # position -> (QueryResult|Exception, how it was served)
+        served: Dict[int, tuple] = {}
+        with tracing.span("parse"):
+            pqs = {i: self.query_cache.parse(sql, self.session)
+                   for i, sql in members}
         pq0 = pqs[members[0][0]]
-        try:
-            hbo_ctx = self._hbo_context(pq0.stmt)
-        except Exception:
-            hbo_ctx = None
-        template = self._plan_template(pq0, user, hbo_ctx,
-                                       uses=len(members))
+        with tracing.span("plan", plan_cache="miss") as plan_span:
+            try:
+                hbo_ctx = self._hbo_context(pq0.stmt)
+            except Exception:
+                hbo_ctx = None
+            template = self._plan_template(pq0, user, hbo_ctx,
+                                           uses=len(members))
+            if template is not None:
+                plan_span.set("template", "hit")
         if template is None:
             return served
         tc = self.query_cache.templates
@@ -516,9 +618,11 @@ class LocalQueryRunner:
             pq = pqs[pos]
             try:
                 # per-tenant ACL per statement, exactly as serial
-                self._check_table_access(pq.stmt, template.root, user)
+                with tracing.span("access_check"):
+                    self._check_table_access(pq.stmt, template.root,
+                                             user)
             except Exception as e:
-                served[pos] = e
+                served[pos] = (e, "vmapped")
                 continue
             key = self.query_cache.cache_key(pq, self.session, user=user)
             if result_caching and key is not None:
@@ -531,11 +635,11 @@ class LocalQueryRunner:
                             self.access_control.check_can_select(
                                 user, catalog, schema, table, cols)
                     except Exception as e:
-                        served[pos] = e
+                        served[pos] = (e, "result_cache")
                         continue
-                    served[pos] = QueryResult(
+                    served[pos] = (QueryResult(
                         list(names), list(types_), list(rows),
-                        stats={"result_cache": "hit"})
+                        stats={"result_cache": "hit"}), "result_cache")
                     with self.query_cache._lock:
                         self.query_cache.result_shortcircuits += 1
                     continue
@@ -587,9 +691,11 @@ class LocalQueryRunner:
                         for i, t in enumerate(template.param_types)})
             try:
                 try:
-                    plan = local.plan(template.root)
-                    result = execute_batched(
-                        plan, template.param_types, padded, B)
+                    with tracing.span("local_plan"):
+                        plan = local.plan(template.root)
+                    with tracing.span("execute"):
+                        result = execute_batched(
+                            plan, template.param_types, padded, B)
                 except BatchIneligible as e:
                     tc.note_fallback(e.reason)
                     return served  # remaining members run serially
@@ -597,7 +703,7 @@ class LocalQueryRunner:
                     # execution error: every lane would hit it serially
                     for _, positions, _ in chunk:
                         for pos in positions:
-                            served[pos] = e
+                            served[pos] = (e, "vmapped")
                             self._batch_member_event(
                                 members, pos, user, error=e)
                     continue
@@ -610,8 +716,9 @@ class LocalQueryRunner:
                     B - len(result.spilled)
                 self.query_cache.batched_spills += len(result.spilled)
             if hbo_ctx is not None:
-                self._record_batched_hbo(hbo_ctx, pq0.shape,
-                                         template.root, result, B)
+                with tracing.span("hbo_record"):
+                    self._record_batched_hbo(hbo_ctx, pq0.shape,
+                                             template.root, result, B)
             for lane_i, (values, positions, key) in enumerate(chunk):
                 if lane_i in result.spilled:
                     # this lane overflowed a unified per-lane capacity
@@ -620,9 +727,10 @@ class LocalQueryRunner:
                     # rides the template serially
                     tc.note_fallback("lane_overflow")
                     continue
-                rows: List[tuple] = []
-                for p in result.pages[lane_i]:
-                    rows.extend(p.to_rows())
+                with tracing.span("fetch_rows"):
+                    rows: List[tuple] = []
+                    for p in result.pages[lane_i]:
+                        rows.extend(p.to_rows())
                 res = QueryResult(
                     plan.column_names, plan.output_types, rows,
                     stats={"plan_template": "hit",
@@ -635,7 +743,8 @@ class LocalQueryRunner:
                         key, res.column_names, res.types, list(rows),
                         scans=template.scan_refs)
                 for extra, pos in enumerate(positions):
-                    served[pos] = res
+                    served[pos] = (res, "coalesced" if extra
+                                   else "vmapped")
                     self._batch_member_event(members, pos, user,
                                              rows=len(rows))
                     if extra:
@@ -679,6 +788,14 @@ class LocalQueryRunner:
                 monitor.failed(e)
             raise
         wall_s = _time.perf_counter() - t0
+        from .telemetry import tracing
+
+        cur = tracing.current_span()
+        if cur is not None:
+            # the tracer's live span list: spans that finish after this
+            # call returns (the root, the protocol's deliver) are in it
+            res.stats = dict(res.stats or {},
+                             trace=cur.tracer.finished())
         if monitor:
             # the QueryStatistics analog: peak memory + wall ride the
             # completed event into the history ring buffer that backs
@@ -720,8 +837,11 @@ class LocalQueryRunner:
         # memoized parse + shape analysis: repeat statement texts skip
         # the parser entirely (the cache also feeds the admission
         # batcher's shape grouping)
+        from .telemetry import tracing
+
         user = user or self.session.user
-        pq = self.query_cache.parse(sql, self.session)
+        with tracing.span("parse"):
+            pq = self.query_cache.parse(sql, self.session)
         stmt = pq.stmt
         if isinstance(stmt, ast.Explain):
             if stmt.analyze:
@@ -837,38 +957,47 @@ class LocalQueryRunner:
                 return QueryResult(list(names), list(types_),
                                    list(rows),
                                    stats={"result_cache": "hit"})
-        hbo_ctx = self._hbo_context(stmt)
-        root = self.query_cache.plans.lookup(key) \
-            if key is not None else None
-        plan_hit = root is not None
-        template_params: Optional[Dict] = None
-        if root is None and key is not None:
-            # a shape template serves EVERY literal vector of this
-            # shape: one optimized root, literal values bound as
-            # ParamRef inputs at execution (the same programs the
-            # vmapped batch path traces, so serial statements keep
-            # them warm).  Template roots are never stored in the
-            # plan cache — plan-cache executions pass no params.
-            template = self._plan_template(pq, user, hbo_ctx)
-            if template is not None:
-                values = self._template_binding(template, pq)
-                if values is None:
-                    self.query_cache.templates.note_fallback(
-                        "param_type_drift")
-                else:
-                    from .expr.compiler import param_raw
+        from .telemetry import tracing
 
-                    template_params = {
-                        i: param_raw(t, v) for i, (t, v) in
-                        enumerate(zip(template.param_types, values))}
-                    root = template.root
-        if root is None:
-            root = self.plan_statement(stmt, hbo=hbo_ctx)
-            if key is not None:
-                self.query_cache.plans.store(
-                    key, root,
-                    SP.value(self.session, "plan_cache_entries"))
-        self._check_table_access(stmt, root, user)  # on EVERY run
+        with tracing.span("plan") as plan_span:
+            hbo_ctx = self._hbo_context(stmt)
+            root = self.query_cache.plans.lookup(key) \
+                if key is not None else None
+            plan_hit = root is not None
+            plan_span.set("plan_cache", "hit" if plan_hit else "miss")
+            plan_span.set("template", "not_consulted")
+            template_params: Optional[Dict] = None
+            if root is None and key is not None:
+                # a shape template serves EVERY literal vector of this
+                # shape: one optimized root, literal values bound as
+                # ParamRef inputs at execution (the same programs the
+                # vmapped batch path traces, so serial statements keep
+                # them warm).  Template roots are never stored in the
+                # plan cache — plan-cache executions pass no params.
+                plan_span.set("template", "miss")
+                template = self._plan_template(pq, user, hbo_ctx)
+                if template is not None:
+                    values = self._template_binding(template, pq)
+                    if values is None:
+                        self.query_cache.templates.note_fallback(
+                            "param_type_drift")
+                        plan_span.set("template", "param_type_drift")
+                    else:
+                        from .expr.compiler import param_raw
+
+                        template_params = {
+                            i: param_raw(t, v) for i, (t, v) in
+                            enumerate(zip(template.param_types, values))}
+                        root = template.root
+                        plan_span.set("template", "hit")
+            if root is None:
+                root = self.plan_statement(stmt, hbo=hbo_ctx)
+                if key is not None:
+                    self.query_cache.plans.store(
+                        key, root,
+                        SP.value(self.session, "plan_cache_entries"))
+        with tracing.span("access_check"):
+            self._check_table_access(stmt, root, user)  # on EVERY run
         if progress is not None:
             # rows-based completion estimate from connector statistics
             progress.total_rows = self._scan_rows_estimate(root)
@@ -890,14 +1019,23 @@ class LocalQueryRunner:
         with profiling(SP.value(self.session,
                                 "query_profiling_enabled")):
             try:
-                plan = local.plan(root)
+                with tracing.span("local_plan"):
+                    plan = local.plan(root)
                 # per-node actuals need per-operator row counts: the
                 # stats-collecting driver path runs exactly when HBO
-                # records (off = the byte-identical pre-HBO hot path)
-                pages = plan.execute(collect_stats=hbo_ctx is not None)
-                rows: List[tuple] = []
-                for p in pages:
-                    rows.extend(p.to_rows())
+                # records (off = the byte-identical pre-HBO hot path);
+                # the operator spans come from its stats
+                with tracing.span("execute") as exec_span:
+                    pages = plan.execute(
+                        collect_stats=hbo_ctx is not None)
+                    if exec_span:
+                        for d in plan.drivers:
+                            tracing.add_driver_spans(
+                                exec_span.tracer, d, exec_span)
+                with tracing.span("fetch_rows"):
+                    rows: List[tuple] = []
+                    for p in pages:
+                        rows.extend(p.to_rows())
                 stats = {"memory": local.memory_pool.stats()}
             finally:
                 # reap spill files + free residue on success AND
@@ -907,9 +1045,10 @@ class LocalQueryRunner:
         if progress is not None:
             progress.state = "FINISHED"
         if hbo_ctx is not None:
-            summary = self._hbo_record(hbo_ctx, pq.shape, root,
-                                       getattr(plan, "drivers", []),
-                                       stats.get("memory"))
+            with tracing.span("hbo_record"):
+                summary = self._hbo_record(hbo_ctx, pq.shape, root,
+                                           getattr(plan, "drivers", []),
+                                           stats.get("memory"))
             if summary:
                 stats["hbo"] = summary
         if local.dynamic_filters:
@@ -994,20 +1133,30 @@ class LocalQueryRunner:
         and the run's actuals fold into the history store."""
         import time as _time
 
-        from .telemetry import profiler
+        from .telemetry import profiler, tracing
 
-        hbo_ctx = self._hbo_context(stmt)
-        root = self.plan_statement(stmt, hbo=hbo_ctx)
-        self._check_table_access(stmt, root)  # ANALYZE executes the query
+        with tracing.span("plan", plan_cache="miss",
+                          template="not_consulted"):
+            hbo_ctx = self._hbo_context(stmt)
+            root = self.plan_statement(stmt, hbo=hbo_ctx)
+        with tracing.span("access_check"):
+            # ANALYZE executes the query
+            self._check_table_access(stmt, root)
         local = self._make_local_planner(hbo=hbo_ctx)
         pool = local.memory_pool
         before = profiler.totals() if verbose else None
         with profiler.profiling(verbose):
             try:
-                plan = local.plan(root)
-                t0 = _time.perf_counter()
-                pages = plan.execute(collect_stats=True)
-                wall = _time.perf_counter() - t0
+                with tracing.span("local_plan"):
+                    plan = local.plan(root)
+                with tracing.span("execute") as exec_span:
+                    t0 = _time.perf_counter()
+                    pages = plan.execute(collect_stats=True)
+                    wall = _time.perf_counter() - t0
+                    if exec_span:
+                        for d in plan.drivers:
+                            tracing.add_driver_spans(
+                                exec_span.tracer, d, exec_span)
                 m = pool.stats()
             finally:
                 pool.close()
@@ -1024,8 +1173,10 @@ class LocalQueryRunner:
 
             shape = normalize_statement(stmt)[0] \
                 if isinstance(stmt, ast.QueryStatement) else None
-            summary = self._hbo_record(hbo_ctx, shape, root,
-                                       plan.drivers, m, estimates=est)
+            with tracing.span("hbo_record"):
+                summary = self._hbo_record(hbo_ctx, shape, root,
+                                           plan.drivers, m,
+                                           estimates=est)
         lines = plan_tree_str(root).splitlines()
         lines.append("")
         lines.append(f"Query: {wall * 1e3:.1f}ms, {out_rows} rows")
@@ -1056,6 +1207,10 @@ class LocalQueryRunner:
                 f"(q={w['qerror']:.2f})")
         if verbose:
             lines.append(_kernels_line(before, profiler.totals()))
+        spans = tracing.snapshot()
+        for line in (tracing.sync_line(spans), tracing.trace_line(spans)):
+            if line:
+                lines.append(line)
         return QueryResult(["Query Plan"], [T.VARCHAR],
                            [(line,) for line in lines])
 

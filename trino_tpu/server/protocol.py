@@ -24,6 +24,17 @@ identical texts coalesce to a single execution, and any divergent
 shape falls back to its own drain (serial, byte-equal).  The tenant
 arrives via the ``X-Trino-User`` header (reference: the dispatcher's
 session context resolution).
+
+Spans (``telemetry.tracing``, when the runner's session has
+``query_tracing_enabled``): every statement is one tree whose id is the
+query id — ``statement`` (submit -> the poll that served the last page,
+or failure/cancel) over ``statement.queued`` (submit -> an executor
+thread takes it), ``statement.run`` (around the runner call; the
+runner's own spans hang under it through the context's current span)
+and ``statement.deliver`` (runner returned -> final page).  A batch's
+shared work is one ``batch.run`` tree of its own; its members'
+``statement.run`` say ``batch=<its span id>``.  Finished trees go to
+``tracing.RING``.
 """
 
 from __future__ import annotations
@@ -38,6 +49,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, List, Optional
 
 from .. import types as T
+from ..telemetry.tracing import NULL_SPAN, RING, Span, Tracer
 
 EPOCH = datetime.date(1970, 1, 1)
 
@@ -68,6 +80,56 @@ class _QueryState:
         self.result = None
         self.created = time.time()
         self.last_poll = self.created
+        #: the statement's spans (NULL_SPAN with tracing off); ``root``
+        #: ends in ``end_spans``.  An executor thread starts delivery
+        #: and an HTTP thread (last page, cancel) or the reaper ends the
+        #: spans: both under ``_span_lock``
+        self.root = self.queued = self.run = self.deliver = NULL_SPAN
+        self._span_lock = threading.Lock()
+
+    def open_spans(self):
+        """Submitted: ``statement`` and ``statement.queued`` start."""
+        tracer = Tracer(trace_id=self.id, ring=RING)
+        self.root = tracer.span("statement", query_id=self.id,
+                                user=self.user, state=self.state)
+        self.queued = tracer.span("statement.queued", parent=self.root)
+
+    def start_run(self, batch_size: int = 1, **attrs) -> "Span":
+        """An executor thread took the statement."""
+        self.queued.finish()
+        if self.root:
+            self.root.set("batch_size", batch_size)
+            self.run = self.root.tracer.span(
+                "statement.run", parent=self.root, **attrs)
+        return self.run
+
+    def start_deliver(self):
+        """The runner call returned: ``run`` ends (a batch member's
+        lasts as long as the batch), delivery starts.  Called BEFORE
+        the terminal state is published, so a poll that sees the state
+        finds the deliver span open."""
+        with self._span_lock:
+            self.run.finish()
+            if self.root and self.root.end is None:
+                self.root.set("served_by",
+                              self.run.attrs.get("served_by", "solo"))
+                self.deliver = self.root.tracer.span(
+                    "statement.deliver", parent=self.root)
+
+    def end_spans(self, state: Optional[str] = None):
+        """Last page served, failure reported, cancelled or evicted:
+        every span still open ends, the root last.  Only the first call
+        does anything (a FAILED query is polled again and again)."""
+        with self._span_lock:
+            if not self.root or self.root.end is not None:
+                return
+            self.queued.finish()
+            self.run.finish()
+            self.deliver.finish()
+            self.root.set("state", state or self.state)
+            self.root.set("rows", len(self.result.rows)
+                          if self.result is not None else 0)
+            self.root.finish()
 
 
 class ProtocolServer:
@@ -263,6 +325,7 @@ class ProtocolServer:
                     (q.state in ("FINISHED", "FAILED")
                      or idle > 10 * self.query_ttl):
                 self.queries.pop(qid, None)
+                q.end_spans("ABANDONED")
 
     def _batching_enabled(self) -> bool:
         from .. import session_properties as SP
@@ -272,11 +335,20 @@ class ProtocolServer:
             and hasattr(self.runner, "execute_batch") \
             and SP.value(session, "admission_batching_enabled")
 
+    def _tracing_enabled(self) -> bool:
+        from .. import session_properties as SP
+
+        session = getattr(self.runner, "session", None)
+        return session is not None \
+            and SP.value(session, "query_tracing_enabled")
+
     def submit(self, sql: str, user: Optional[str] = None) -> dict:
         from ..telemetry import progress as progress_mod
 
         qid = uuid.uuid4().hex[:16]
         q = _QueryState(qid, sql, user=user)
+        if self._tracing_enabled():
+            q.open_spans()
         self.queries[qid] = q
         if self._progress_capable:
             progress_mod.register(qid)
@@ -308,34 +380,48 @@ class ProtocolServer:
         from ..telemetry import progress as progress_mod
 
         q.state = "RUNNING"
+        run = q.start_run()
         t0 = time.perf_counter()
         prog = progress_mod.get(q.id) if self._progress_capable \
             else None
         try:
-            # per-tenant admission routing needs the user-aware execute
-            # (LocalQueryRunner); other runners keep their session user
-            if q.user is not None and hasattr(self.runner,
-                                              "execute_batch"):
-                q.result = self.runner.execute(q.sql, user=q.user,
-                                               progress=prog)
-            elif prog is not None:
-                q.result = self.runner.execute(q.sql, progress=prog)
-            else:
-                q.result = self.runner.execute(q.sql)
-            q.state = "FINISHED"
-            self._http_queries.inc(state="FINISHED")
+            # the statement's span is this thread's current span while
+            # the runner works: its spans hang under it
+            with run:
+                # per-tenant admission routing needs the user-aware
+                # execute (LocalQueryRunner); other runners keep their
+                # session user
+                if q.user is not None and hasattr(self.runner,
+                                                  "execute_batch"):
+                    res = self.runner.execute(q.sql, user=q.user,
+                                              progress=prog)
+                elif prog is not None:
+                    res = self.runner.execute(q.sql, progress=prog)
+                else:
+                    res = self.runner.execute(q.sql)
         except Exception as e:
-            self._fail(q, e)
+            res = e
+        self._returned(q, res)
         self._record_finished(q, (time.perf_counter() - t0) * 1e3)
 
-    def _fail(self, q: _QueryState, e: Exception):
-        q.error = {
-            "message": str(e),
-            "errorCode": getattr(e, "code", "GENERIC_INTERNAL_ERROR"),
-            "errorType": type(e).__name__,
-        }
-        q.state = "FAILED"
-        self._http_queries.inc(state="FAILED")
+    def _returned(self, q: _QueryState, res):
+        """The runner call gave ``res`` (a result, or the exception it
+        raised) for ``q``: delivery starts, then the terminal state is
+        published — in that order, so no poll serves the last page
+        before the deliver span is open."""
+        q.start_deliver()
+        if isinstance(res, Exception):
+            q.error = {
+                "message": str(res),
+                "errorCode": getattr(res, "code",
+                                     "GENERIC_INTERNAL_ERROR"),
+                "errorType": type(res).__name__,
+            }
+            q.state = "FAILED"
+        else:
+            q.result = res
+            q.state = "FINISHED"
+        self._http_queries.inc(state=q.state)
 
     def _take_batch(self) -> List[_QueryState]:
         """Pop the backlog head plus every same-(shape, user) statement
@@ -363,28 +449,37 @@ class ProtocolServer:
     def _drain_batch(self):
         import time
 
+        from ..runner import BATCH_MEMBER_SPANS
+
         batch = self._take_batch()
         if not batch:
             return  # a sibling drain absorbed this submission's work
         self._batches.inc(size=min(len(batch), 16))
         for q in batch:
             q.state = "RUNNING"
+        # the batch's shared work is a tree of its own; each member's
+        # statement.run names it and spans the member's wait inside
+        batch_span = NULL_SPAN
+        if batch[0].root:
+            batch_span = Tracer(ring=RING).span("batch.run",
+                                                batch_size=len(batch))
+        members = [q.start_run(batch_size=len(batch),
+                               batch=batch_span.span_id) for q in batch]
         t0 = time.perf_counter()
+        token = BATCH_MEMBER_SPANS.set(members if batch_span else None)
         try:
-            results = self.runner.execute_batch(
-                [q.sql for q in batch], user=batch[0].user)
+            with batch_span:
+                results = self.runner.execute_batch(
+                    [q.sql for q in batch], user=batch[0].user)
         except Exception as e:
             # admission-level failure (queue full, rejected budget):
             # fails the whole burst — each statement reports it
             results = [e] * len(batch)
+        finally:
+            BATCH_MEMBER_SPANS.reset(token)
         wall_ms = (time.perf_counter() - t0) * 1e3
         for q, res in zip(batch, results):
-            if isinstance(res, Exception):
-                self._fail(q, res)
-            else:
-                q.result = res
-                q.state = "FINISHED"
-                self._http_queries.inc(state="FINISHED")
+            self._returned(q, res)
             self._record_finished(q, wall_ms)
 
     def _record_finished(self, q: _QueryState, wall_ms: float):
@@ -396,12 +491,17 @@ class ProtocolServer:
 
         stats = (q.result.stats if q.result is not None
                  and q.result.stats else {}) or {}
+        # the runner's trace where it made one (the local runner hands
+        # this statement's own live span list: the deliver span and the
+        # root are in it once they end), else the protocol's spans
+        trace = stats.get("trace") or \
+            (q.root.tracer.finished() if q.root else None)
         tree = QueryStatsTree(
             wall_ms=wall_ms,
             memory=stats.get("memory"),
             cluster_memory=stats.get("cluster_memory"),
             recovery=stats.get("recovery"),
-            trace=stats.get("trace"))
+            trace=trace)
         info = {
             "queryId": q.id, "state": q.state, "query": q.sql,
             "rows": len(q.result.rows) if q.result is not None else 0,
@@ -460,6 +560,10 @@ class ProtocolServer:
             merge_families(runner_fams, self.registry.collect()))
 
     def poll(self, qid: str, token: int) -> dict:
+        """One GET of the statement protocol.  The reply that carries
+        the last page or the error ends the statement's spans — here,
+        before the handler writes it: the client may have the page, and
+        its own clock stopped, before this thread runs again."""
         q = self.queries.get(qid)
         if q is None:
             return {"error": {"message": f"unknown query {qid}",
@@ -467,13 +571,15 @@ class ProtocolServer:
         import time
 
         q.last_poll = time.time()
-        doc: dict = {"id": qid, "stats": {"state": q.state}}
-        if q.state in ("QUEUED", "RUNNING"):
+        state = q.state  # read once: an executor thread publishes it
+        doc: dict = {"id": qid, "stats": {"state": state}}
+        if state in ("QUEUED", "RUNNING"):
             doc["nextUri"] = \
                 f"{self.uri}/v1/statement/executing/{qid}/{token}"
             return doc
-        if q.state == "FAILED":
+        if state == "FAILED":
             doc["error"] = q.error
+            q.end_spans()
             return doc
         res = q.result
         doc["columns"] = [{"name": n, "type": str(t)}
@@ -502,7 +608,10 @@ class ProtocolServer:
                     doc["stats"]["dynamicFilters"] = \
                         res.stats["dynamic_filters"]
             self.queries.pop(qid, None)  # final page delivered
+            q.end_spans()
         return doc
 
     def cancel(self, qid: str):
-        self.queries.pop(qid, None)
+        q = self.queries.pop(qid, None)
+        if q is not None:
+            q.end_spans("CANCELED")

@@ -12,17 +12,51 @@ Perfetto / chrome://tracing, one pid lane per process).
 Cost model: tracing must be zero-cost when off — ``NULL_TRACER.span()``
 returns a shared no-op span, and spans are NEVER opened inside jit'd
 code (host-side boundaries only), so the bench ratchet is untouched.
+In-memory spans are per statement, stage and operator (tens per
+statement), never per page: per-page detail is a profiler annotation
+(``annotation``: one flag test unless a profile is being taken) plus a
+plain add on the owning span's attributes (``host_sync``).
 
-Clock model: span ``start`` is epoch seconds (``time.time()`` — the only
-clock that aligns across processes on one host) and duration is measured
-on ``perf_counter`` so short spans keep sub-ms resolution.
+Clock model: span ``start``/``end`` are epoch seconds (``time.time()`` —
+the only clock that aligns across processes on one host) with the
+duration measured on ``perf_counter``; ``t0``/``t1`` are the same
+instants as ``time.perf_counter()`` seconds, the clock the benchmark's
+``RunFacts`` use.  A span entered with ``with`` is also a
+``jax.profiler.TraceAnnotation``: an event of ``/host:CPU`` on the
+profiler's clock, the one the device planes are on.
+
+Linkage inside a process: a ``ContextVar`` holds the span a ``with``
+entered; ``span(name)`` opens a child of it.  ``ProtocolServer`` enters a
+statement's ``statement.run`` on the executor thread, so the runner below
+needs no argument; a runner called with no current span opens its own
+root (``root_scope``).  Finished trees of served statements go to
+``RING``.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import contextvars
 import os
+import threading
 import time
 from typing import Dict, Iterable, List, Optional, Tuple
+
+import numpy as np
+
+#: the span entered by the innermost ``with`` on this thread / context
+_CURRENT: contextvars.ContextVar = contextvars.ContextVar(
+    "trino_tpu_current_span", default=None)
+
+
+def annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``: a host event of the
+    profiler's own trace while one is being taken, a flag test when
+    none is."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
 
 
 def _new_id(nbytes: int = 8) -> str:
@@ -34,10 +68,12 @@ class Span:
     failed (``error`` attribute) and still finish it."""
 
     __slots__ = ("tracer", "trace_id", "span_id", "parent_id", "name",
-                 "process", "start", "end", "attrs", "_pc0")
+                 "process", "start", "end", "t0", "t1", "attrs", "parent",
+                 "root", "_scope")
 
     def __init__(self, tracer: "Tracer", name: str,
-                 parent_id: Optional[str], **attrs):
+                 parent_id: Optional[str], parent: Optional["Span"] = None,
+                 **attrs):
         self.tracer = tracer
         self.trace_id = tracer.trace_id
         self.span_id = _new_id()
@@ -45,9 +81,16 @@ class Span:
         self.name = name
         self.process = tracer.process
         self.start = time.time()
-        self._pc0 = time.perf_counter()
+        self.t0 = time.perf_counter()
         self.end: Optional[float] = None
+        self.t1: Optional[float] = None
         self.attrs = attrs
+        #: the parent span when it is of this tracer, and the root of
+        #: this span's tree in this process: the statement's counters
+        #: (``host_sync``) are kept on the root
+        self.parent = parent
+        self.root: "Span" = parent.root if parent is not None else self
+        self._scope = None
 
     def set(self, key: str, value):
         self.attrs[key] = value
@@ -65,24 +108,39 @@ class Span:
 
     def finish(self):
         if self.end is None:
-            self.end = self.start + (time.perf_counter() - self._pc0)
+            self.t1 = time.perf_counter()
+            self.end = self.start + (self.t1 - self.t0)
             self.tracer._record(self.to_dict())
+            if self.parent_id is None and self.tracer.ring is not None:
+                self.tracer.ring.publish(self.tracer.finished(), self.t1)
 
     def to_dict(self) -> dict:
+        """The finished-span dict; of a span still open, a snapshot
+        that ends now."""
+        t1 = self.t1 if self.t1 is not None else time.perf_counter()
         return {
             "trace_id": self.trace_id, "span_id": self.span_id,
             "parent_id": self.parent_id, "name": self.name,
             "process": self.process, "start": self.start,
-            "end": self.end if self.end is not None else self.start,
+            "end": self.start + (t1 - self.t0),
+            "t0": self.t0, "t1": t1,
             "attrs": dict(self.attrs),
         }
 
     def __enter__(self) -> "Span":
+        """Entered on one thread and left on the same: the span is the
+        context's current span and a profiler annotation meanwhile.
+        Spans that start on one thread and end on another are waits;
+        they are opened and ``finish()``ed without ``with``."""
+        self._scope = use_span(self)
+        self._scope.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if exc is not None:
             self.attrs.setdefault("error", repr(exc))
+        scope, self._scope = self._scope, None
+        scope.__exit__(None, None, None)
         self.finish()
         return False
 
@@ -139,10 +197,14 @@ class Tracer:
     coordinator-side into one tree."""
 
     def __init__(self, process: str = "coordinator",
-                 trace_id: Optional[str] = None, enabled: bool = True):
+                 trace_id: Optional[str] = None, enabled: bool = True,
+                 ring: Optional["TraceRing"] = None):
         self.enabled = enabled
         self.process = process
         self.trace_id = trace_id or _new_id(8)
+        #: where the finished tree goes when a root span of this tracer
+        #: finishes (the served path passes ``RING``)
+        self.ring = ring
         self._finished: List[dict] = []
 
     def span(self, name: str, parent=None, **attrs):
@@ -150,15 +212,18 @@ class Tracer:
         dict, or None (root)."""
         if not self.enabled:
             return NULL_SPAN
+        local_parent = None
         if isinstance(parent, Span):
             parent_id = parent.span_id
+            if parent.tracer is self:
+                local_parent = parent
         elif parent is None or isinstance(parent, _NullSpan):
             parent_id = None
         else:
             tid, parent_id = parse_context(parent)
             if tid:
                 self.trace_id = tid
-        return Span(self, name, parent_id, **attrs)
+        return Span(self, name, parent_id, local_parent, **attrs)
 
     def _record(self, span_dict: dict):
         self._finished.append(span_dict)
@@ -169,10 +234,140 @@ class Tracer:
             self._finished.extend(spans)
 
     def finished(self) -> List[dict]:
-        return list(self._finished)
+        """The live list of finished spans, not a copy: what
+        ``QueryResult.stats["trace"]``, the server's ``finished`` ring
+        and ``RING`` all hold, so a span that finishes after the runner
+        returned (``statement.deliver``, the root) is in all three."""
+        return self._finished
 
 
 NULL_TRACER = Tracer(enabled=False)
+
+
+class TraceRing:
+    """Bounded process-wide store of finished statement traces (each the
+    tracer's live span list), oldest evicted first."""
+
+    def __init__(self, capacity: int = 4096):
+        self.capacity = capacity
+        self._entries: collections.deque = collections.deque()
+        self._lock = threading.Lock()
+        #: ``t1`` (perf_counter) of the newest root evicted so far
+        self._evicted_t1 = float("-inf")
+
+    def publish(self, spans: List[dict], t1: float):
+        with self._lock:
+            self._entries.append((t1, spans))
+            while len(self._entries) > self.capacity:
+                self._evicted_t1 = max(self._evicted_t1,
+                                       self._entries.popleft()[0])
+
+    def since(self, t: float) -> Tuple[List[List[dict]], bool]:
+        """(the traces whose root finished at or after ``t`` on
+        ``perf_counter``, whether any such trace was evicted)."""
+        with self._lock:
+            return ([spans for t1, spans in self._entries if t1 >= t],
+                    self._evicted_t1 >= t)
+
+
+#: finished traces of the statements this process served (roots
+#: ``statement``) and of the batches they rode in (roots ``batch.run``)
+RING = TraceRing()
+
+
+def current_span() -> Optional[Span]:
+    return _CURRENT.get()
+
+
+def span(name: str, **attrs):
+    """A child of the context's current span (``NULL_SPAN`` when there
+    is none: tracing is off for this statement)."""
+    cur = _CURRENT.get()
+    if cur is None:
+        return NULL_SPAN
+    return cur.tracer.span(name, parent=cur, **attrs)
+
+
+def snapshot() -> List[dict]:
+    """The current statement's tree as it stands: its finished spans
+    and, ending now, the open ones from the current span up to its
+    root (EXPLAIN ANALYZE renders its ``Trace:`` line from inside the
+    statement)."""
+    cur = _CURRENT.get()
+    if cur is None:
+        return []
+    spans = list(cur.tracer.finished())
+    while cur is not None:
+        spans.append(cur.to_dict())
+        cur = cur.parent
+    return spans
+
+
+@contextlib.contextmanager
+def use_span(span):
+    """``span`` is the context's current span (and a profiler
+    annotation) inside the block, and is NOT ended by it: for a span
+    that somebody else ends (a batch member's ``statement.run``)."""
+    if not span:
+        yield span
+        return
+    with annotation(span.name):
+        token = _CURRENT.set(span)
+        try:
+            yield span
+        finally:
+            _CURRENT.reset(token)
+
+
+def span_set(key: str, value):
+    """Set an attribute on the context's current span, if any."""
+    cur = _CURRENT.get()
+    if cur is not None:
+        cur.attrs[key] = value
+
+
+@contextlib.contextmanager
+def root_scope(name: str, enabled: bool, **attrs):
+    """The scope of one runner call.  Under a caller's span
+    (``ProtocolServer`` entered one) opens nothing; with
+    no current span opens — when ``enabled`` — a root of its own, whose
+    finished tree goes to ``RING``."""
+    if _CURRENT.get() is not None or not enabled:
+        yield
+        return
+    with Tracer(ring=RING).span(name, **attrs):
+        yield
+
+
+@contextlib.contextmanager
+def host_sync(why: str):
+    """Around a blocking device-to-host read.  Counts one sync and its
+    wall seconds on the statement's root (``host_syncs``,
+    ``host_sync_s``, and by ``why`` under ``host_sync_by_why``, which
+    EXPLAIN ANALYZE prints: ``sync_line``) and is the annotation
+    ``sync:<why>``; with no current span, nothing."""
+    cur = _CURRENT.get()
+    if cur is None:
+        yield
+        return
+    with annotation("sync:" + why):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            dt = time.perf_counter() - t0
+            attrs = cur.root.attrs
+            attrs["host_syncs"] = attrs.get("host_syncs", 0) + 1
+            attrs["host_sync_s"] = attrs.get("host_sync_s", 0.0) + dt
+            by_why = attrs.setdefault("host_sync_by_why", {})
+            n, s = by_why.get(why, (0, 0.0))
+            by_why[why] = (n + 1, s + dt)
+
+
+def host_read(x, why: str) -> np.ndarray:
+    """``np.asarray(x)`` of a device array, as one ``host_sync``."""
+    with host_sync(why):
+        return np.asarray(x)
 
 
 def add_driver_spans(tracer: Tracer, driver, parent) -> int:
@@ -204,6 +399,9 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
             "parent_id": parent_id, "name": st.name,
             "process": tracer.process, "start": start,
             "end": start + st.wall_ns / 1e9,
+            # perf_counter_ns and perf_counter are one clock
+            "t0": st.first_ns / 1e9,
+            "t1": (st.first_ns + st.wall_ns) / 1e9,
             "attrs": {"rows": st.output_rows, "pages": st.output_pages,
                       "busy_ms": round(st.wall_ns / 1e6, 3),
                       "compiles": st.compile_count,
@@ -218,6 +416,10 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
             span["attrs"]["device_bytes"] = st.device_bytes
             span["attrs"]["compile_ms"] = round(st.compile_ms, 3)
         if st.metrics:
+            # the scan operator's host-side counters, under their names
+            for key in ("generate_s", "upload_s"):
+                if st.metrics.get(key) is not None:
+                    span["attrs"][key] = st.metrics[key]
             for key in ("kind", "first_page_ms", "reconnects",
                         "replayed_frames", "skew_ratio",
                         "lane_skew_ratio", "splits", "rebalances",
@@ -296,6 +498,24 @@ def trace_line(spans: List[dict]) -> Optional[str]:
         line += (f" [compile {compile_ms:.1f}ms / execute "
                  f"{max(total_ms - compile_ms, 0.0):.1f}ms]")
     return line
+
+
+def sync_line(spans: List[dict]) -> Optional[str]:
+    """One EXPLAIN ANALYZE line: the statement's blocking
+    device-to-host reads (``host_sync``) by site, longest wait first —
+    where the host stood waiting for the device."""
+    sites: Dict[str, Tuple[int, float]] = {}
+    for s in spans:
+        if s.get("parent_id") is None:
+            sites.update(s.get("attrs", {}).get("host_sync_by_why", {}))
+    if not sites:
+        return None
+    by_wait = sorted(sites.items(), key=lambda kv: -kv[1][1])
+    return (f"Host syncs: {sum(n for n, _ in sites.values())} blocking "
+            f"reads, {sum(t for _, t in sites.values()) * 1e3:.1f}ms "
+            "waiting (" + ", ".join(
+                f"{why} {n}x {t * 1e3:.1f}ms" for why, (n, t) in by_wait)
+            + ")")
 
 
 def slow_query_record(spans: Optional[List[dict]], wall_ms: float,
