@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import pytest
 
 from trino_tpu import types as T
-from trino_tpu.block import DevicePage, Dictionary, Page
+from trino_tpu.block import DevicePage, Dictionary, Page, padded_size
 from trino_tpu.ops.aggregation import AggCall, HashAggregationOperator, \
     resolve_agg_type
 from trino_tpu.ops.hashtable import hash_group_ids, hashable_key_types
@@ -116,6 +116,17 @@ def _sorted_rows(rows):
         (v is None, 0 if v is None else v) for v in r))
 
 
+def _drain(op):
+    """Finish ``op`` and return its output rows, sorted."""
+    op.finish()
+    pages = []
+    while not op.is_finished():
+        p = op.get_output()
+        if p is not None:
+            pages.append(p.to_page())
+    return _sorted_rows(Page.concat(pages).to_rows())
+
+
 def _run_single(input_types, columns, group_channels, aggs,
                 hash_grouping, page_rows=None):
     """Run a single-step aggregation over the columns split into pages."""
@@ -130,13 +141,7 @@ def _run_single(input_types, columns, group_channels, aggs,
         chunk = [c[lo:lo + page_rows] for c in columns]
         page = Page.from_pylists(input_types, chunk, dicts)
         op.add_input(DevicePage.from_page(page))
-    op.finish()
-    pages = []
-    while not op.is_finished():
-        p = op.get_output()
-        if p is not None:
-            pages.append(p.to_page())
-    return _sorted_rows(Page.concat(pages).to_rows())
+    return _drain(op)
 
 
 def _assert_rows_equal(a, b):
@@ -288,13 +293,7 @@ def _run_partial_final(input_types, columns, group_channels, aggs,
         out = partial.get_output()
         if out is not None:
             final.add_input(out)
-    final.finish()
-    pages = []
-    while not final.is_finished():
-        p = final.get_output()
-        if p is not None:
-            pages.append(p.to_page())
-    return partial, _sorted_rows(Page.concat(pages).to_rows())
+    return partial, _drain(final)
 
 
 def test_partial_final_hash_matches_single_sort():
@@ -388,3 +387,180 @@ def test_adaptive_single_bucket_keeps_legacy_whole_stream_decision():
             pass
     assert partial_.passthrough
     assert partial_._pass_buckets is None
+
+
+# ------------------------------------------- partials as wide as their groups
+
+
+def _narrow_case(case):
+    """(types, group_channels, aggs, pages): ``pages`` is a list of
+    (columns, live) — ``live`` None keeps every row, else a bool mask."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    sum1 = AggCall("sum", 1, T.BIGINT, resolve_agg_type("sum", T.BIGINT))
+    count = AggCall("count_star", None, None, T.BIGINT)
+    types, group, aggs = [T.BIGINT, T.BIGINT], [0], [sum1, count]
+
+    def page(keys):
+        return ([list(keys), [int(v) for v in
+                              rng.integers(-99, 99, size=len(keys))]],
+                None)
+
+    if case == "few_groups":
+        pages = [page(rng.integers(0, 4, size=200)) for _ in range(3)]
+    elif case == "pow2_groups":
+        # exactly 32 groups a page: the partial keeps 32 lanes, not 64
+        pages = [page(np.arange(300) % 32), page(np.arange(90) % 32 + 7)]
+    elif case == "over_half":
+        # 40 groups in 64 lanes: padded_size(40) is the page, no narrowing
+        pages = [page(np.arange(60) % 40), page(np.arange(64) % 40 + 20)]
+    elif case == "empty_page":
+        cols, _ = page(rng.integers(0, 5, size=100))
+        pages = [page(rng.integers(0, 5, size=100)),
+                 (cols, np.zeros(100, dtype=bool)),
+                 page(rng.integers(3, 9, size=100))]
+    elif case == "keyless":
+        group = []
+        pages = [page(rng.integers(0, 9, size=150)) for _ in range(3)]
+    elif case == "null_keys":
+        pages = [page([int(k) if rng.random() > 0.3 else None
+                       for k in rng.integers(0, 6, size=120)])
+                 for _ in range(3)]
+    elif case == "varchar_min":
+        vt = T.varchar_type(8)
+        types, group = [vt, vt], [0]
+        aggs = [AggCall("min", 1, vt, vt), count]
+        words = ["pear", "fig", "apple", "quince", "date", "lime"]
+        pages = [([[str(rng.choice(["aa", "bb", "cc"])) if rng.random() > .2
+                    else None for _ in range(130)],
+                   [str(rng.choice(words)) if rng.random() > .2 else None
+                    for _ in range(130)]], None) for _ in range(3)]
+    else:
+        raise AssertionError(case)
+    return types, group, aggs, pages
+
+
+def _page_groups(columns, live, group_channels):
+    live = np.ones(len(columns[0]), bool) if live is None else live
+    return len({tuple(columns[c][i] for c in group_channels)
+                for i in np.flatnonzero(live)})
+
+
+@pytest.mark.parametrize("case", [
+    "few_groups", "pow2_groups", "over_half", "empty_page", "keyless",
+    "null_keys", "varchar_min"])
+def test_partials_are_as_wide_as_their_groups(case):
+    """Step ``single`` on the hash path keeps each page's partial at
+    ``padded_size(ngroups)`` lanes — the count rides the page's overflow
+    read — and merges at the padded sum of what it kept; answers equal
+    the sort oracle's."""
+    types, group, aggs, pages = _narrow_case(case)
+    answers = {}
+    for hashed in (True, False):
+        dicts = [Dictionary() if t.is_pooled else None for t in types]
+        op = HashAggregationOperator(types, group, aggs, "single",
+                                     hash_grouping=hashed)
+        in_lanes = 0
+        for columns, live in pages:
+            dp = DevicePage.from_page(
+                Page.from_pylists(types, columns, dicts))
+            if live is not None:
+                mask = np.zeros(dp.capacity, dtype=bool)
+                mask[:len(live)] = live
+                dp = DevicePage(dp.types, dp.cols, dp.nulls,
+                                jnp.asarray(mask), dp.dictionaries)
+            in_lanes += dp.capacity
+            op.add_input(dp)
+        kept = [p.capacity for p in op._partials]
+        answers[hashed] = _drain(op)
+        if not hashed:
+            continue
+        assert op.path_counts == {"hash": len(pages) + 1, "sort": 0,
+                                  "passthrough": 0, "range_split": 0}
+        want = [padded_size(_page_groups(c, live, group))
+                for c, live in pages]
+        assert kept == want
+        if case == "over_half":
+            assert kept == [64, 64]          # as wide as the pages
+        assert op.metrics()["partial_lanes"] == {
+            "pages": len(pages), "in": in_lanes, "kept": sum(kept),
+            "merge": padded_size(sum(kept))}
+    _assert_rows_equal(answers[True], answers[False])
+
+
+def test_final_step_merges_partials_of_unequal_width():
+    """Step ``final`` over intermediate pages of q3's shape — unequal
+    capacities, some with few groups (narrowed), some nearly full (kept
+    as they are) — answers as the sort oracle does, and a memory context
+    is charged the partials' narrow bytes, not their pages'."""
+    from trino_tpu.exec.memory import QueryMemoryPool, device_page_bytes
+
+    rng = np.random.default_rng(26)
+    # sum(bigint) states: (sum, count)
+    final_aggs = [AggCall("sum", None, T.BIGINT,
+                          resolve_agg_type("sum", T.BIGINT))]
+    inter = [T.BIGINT, T.BIGINT, T.BIGINT]
+    # (capacity, live rows, distinct keys)
+    shapes = [(2048, 1500, 9), (256, 250, 200), (4096, 3000, 700),
+              (64, 64, 64), (1024, 10, 3)]
+    pages = []
+    for cap, rows, nkeys in shapes:
+        keys = rng.permutation(np.arange(rows) % nkeys + (cap % 7))
+        pages.append((cap, [[int(k) for k in keys],
+                            [int(v) for v in rng.integers(-50, 50, rows)],
+                            [int(v) for v in rng.integers(1, 4, rows)]]))
+    answers = {}
+    for hashed in (True, False):
+        pool = QueryMemoryPool(1 << 30, spill_enabled=True)
+        ctx = pool.create_context("agg")
+        op = HashAggregationOperator(inter, [0], final_aggs, "final",
+                                     memory_context=ctx,
+                                     hash_grouping=hashed)
+        for cap, columns in pages:
+            op.add_input(DevicePage.from_page(
+                Page.from_pylists(inter, columns), capacity=cap))
+        if hashed:
+            want = [padded_size(nkeys) for _, _, nkeys in shapes]
+            assert [p.capacity for p in op._partials] == want
+            assert want[1] == 256 and want[3] == 64   # not narrowed
+            lane_bytes = 3 * (8 + 1) + 1   # 3 int64 + null masks + valid
+            assert ctx.reserved == sum(want) * lane_bytes
+            assert ctx.reserved == sum(map(device_page_bytes,
+                                           op._partials))
+            assert op.metrics()["partial_lanes"]["in"] == \
+                sum(cap for cap, _ in pages)
+        answers[hashed] = _drain(op)
+        assert pool.reserved == 0
+        if hashed:
+            assert op.metrics()["partial_lanes"]["merge"] == \
+                padded_size(sum(want))
+    _assert_rows_equal(answers[True], answers[False])
+
+
+def test_q1_sync_contract_has_no_page_trim():
+    """q1 at tiny through the runner, tracing on: the aggregation reads
+    the device once a page and once a merge (``agg_overflow``: overflow
+    flag and group count together) and never to find the output's width
+    (``page_trim``)."""
+    from trino_tpu.connectors.tpch import TpchConnector
+    from trino_tpu.runner import LocalQueryRunner
+    from trino_tpu.sql.analyzer import Session
+
+    runner = LocalQueryRunner(
+        {"tpch": TpchConnector(page_rows=4096)},
+        Session(catalog="tpch", schema="tiny"), desired_splits=8)
+    res = runner.execute(
+        "select l_returnflag, l_linestatus, sum(l_quantity), "
+        "avg(l_extendedprice), count(*) from lineitem "
+        "where l_shipdate <= date '1998-09-02' "
+        "group by l_returnflag, l_linestatus order by 1, 2")
+    assert len(res.rows) == 4
+    spans = res.stats["trace"]
+    root, = [s for s in spans if s["name"] == "statement"]
+    lanes = [s["attrs"]["partial_lanes"] for s in spans
+             if "partial_lanes" in s["attrs"]]
+    assert len(lanes) == 1 and lanes[0]["pages"] > 1
+    assert lanes[0]["kept"] == 16 * lanes[0]["pages"]
+    assert lanes[0]["merge"] == padded_size(lanes[0]["kept"])
+    by_why = root["attrs"]["host_sync_by_why"]
+    assert "page_trim" not in by_why
+    assert by_why["agg_overflow"][0] == lanes[0]["pages"] + 1

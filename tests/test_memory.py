@@ -24,18 +24,26 @@ JOIN_SQL = ("select o_orderpriority, count(*) from orders o, lineitem l "
             "where o.o_orderkey = l.l_orderkey and l_quantity > 30 "
             "group by o_orderpriority order by o_orderpriority")
 
+# An aggregation's partials are as wide as their groups, so parking them
+# on the host compacts nothing: an aggregation spills AND completes under
+# a cap only where groups repeat across many pages, so that the chunked
+# merge reduces them. 200 parts over ~24 pages of 256 rows do.
+AGG_SPILL_SQL = ("select l_partkey, sum(l_quantity) qty from lineitem "
+                 "group by l_partkey order by qty desc, l_partkey limit 10")
+AGG_SPILL_PAGE_ROWS = 64
 
-def make_runner(**props):
+
+def make_runner(page_rows=1024, **props):
     session = Session(catalog="tpch", schema="micro")
     session.properties.update(props)
-    return LocalQueryRunner({"tpch": TpchConnector(page_rows=1024)},
+    return LocalQueryRunner({"tpch": TpchConnector(page_rows=page_rows)},
                             session, desired_splits=8)
 
 
 @pytest.fixture(scope="module")
 def baseline_rows():
-    return {SQL: make_runner().execute(SQL).rows,
-            JOIN_SQL: make_runner().execute(JOIN_SQL).rows}
+    return {sql: make_runner().execute(sql).rows
+            for sql in (SQL, JOIN_SQL, AGG_SPILL_SQL)}
 
 
 def test_accounting_records_peak():
@@ -54,16 +62,20 @@ def test_low_cap_without_spill_fails():
 
 
 def test_low_cap_with_spill_completes(baseline_rows):
-    r = make_runner(query_max_memory_bytes=600_000, spill_enabled=True)
-    res = r.execute(SQL)
-    assert res.rows == baseline_rows[SQL]
+    r = make_runner(AGG_SPILL_PAGE_ROWS, query_max_memory_bytes=300_000,
+                    spill_enabled=True)
+    res = r.execute(AGG_SPILL_SQL)
+    assert res.rows == baseline_rows[AGG_SPILL_SQL]
     mem = res.stats["memory"]
     assert mem["spill_events"] > 0
     assert mem["spilled_bytes"] > 0
 
 
 def test_join_spill_matches_baseline(baseline_rows):
-    r = make_runner(query_max_memory_bytes=150_000, spill_enabled=True)
+    # the join's build is what spills (the aggregation above it keeps
+    # 16 lanes a page); HBO off so a recorded run does not re-size it
+    r = make_runner(query_max_memory_bytes=60_000, spill_enabled=True,
+                    hbo_enabled=False)
     res = r.execute(JOIN_SQL)
     assert res.rows == baseline_rows[JOIN_SQL]
     assert res.stats["memory"]["spill_events"] > 0

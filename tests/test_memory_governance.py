@@ -35,18 +35,25 @@ from trino_tpu.runner import LocalQueryRunner
 from trino_tpu.sql.analyzer import Session
 from trino_tpu.types import TrinoError
 
-AGG_SQL = ("select l_orderkey, sum(l_quantity) qty from lineitem "
-           "group by l_orderkey order by qty desc, l_orderkey limit 10")
+# An aggregation's partials are as wide as their groups, so parking them
+# on the host compacts nothing: an aggregation spills AND completes under
+# a cap only where groups repeat across many pages, so that the chunked
+# merge reduces them. 200 parts over ~24 pages of 256 rows do
+# (AGG_PAGE_ROWS connector rows a page, AGG_CAP bytes).
+AGG_SQL = ("select l_partkey, sum(l_quantity) qty from lineitem "
+           "group by l_partkey order by qty desc, l_partkey limit 10")
+AGG_PAGE_ROWS = 64
+AGG_CAP = 300_000
 JOIN_SQL = ("select o_orderpriority, count(*) from orders o, lineitem l "
             "where o.o_orderkey = l.l_orderkey and l_quantity > 30 "
             "group by o_orderpriority order by o_orderpriority")
 SORT_SQL = "select * from lineitem order by l_extendedprice, l_orderkey"
 
 
-def make_runner(**props):
+def make_runner(page_rows=1024, **props):
     session = Session(catalog="tpch", schema="micro")
     session.properties.update(props)
-    return LocalQueryRunner({"tpch": TpchConnector(page_rows=1024)},
+    return LocalQueryRunner({"tpch": TpchConnector(page_rows=page_rows)},
                             session, desired_splits=8)
 
 
@@ -60,16 +67,20 @@ def baselines():
 # ------------------------------------------------- disk spill oracle ----
 
 
-@pytest.mark.parametrize("sql,cap", [(AGG_SQL, 600_000),
-                                     (JOIN_SQL, 150_000),
-                                     (SORT_SQL, 1_000_000)])
-def test_disk_spill_oracle(sql, cap, baselines):
+@pytest.mark.parametrize("sql,cap,page_rows,props", [
+    (AGG_SQL, AGG_CAP, AGG_PAGE_ROWS, {}),
+    # the join's build is what spills; HBO off as for the hybrid join
+    # below, so a recorded run does not re-size it
+    (JOIN_SQL, 60_000, 1024, {"hbo_enabled": False}),
+    (SORT_SQL, 1_000_000, 1024, {})])
+def test_disk_spill_oracle(sql, cap, page_rows, props, baselines):
     """agg / join / sort forced through the DISK tier
     (spill_host_memory_bytes=0 demotes every parked page) must return
     byte-equal rows to the unconstrained run — the acceptance bar for
     the spill subsystem."""
-    r = make_runner(query_max_memory_bytes=cap, spill_enabled=True,
-                    spill_to_disk_enabled=True, spill_host_memory_bytes=0)
+    r = make_runner(page_rows, query_max_memory_bytes=cap,
+                    spill_enabled=True, spill_to_disk_enabled=True,
+                    spill_host_memory_bytes=0, **props)
     res = r.execute(sql)
     mem = res.stats["memory"]
     assert mem["spill_events"] > 0
@@ -83,8 +94,9 @@ def test_disk_spill_oracle(sql, cap, baselines):
 
 
 def test_disk_spill_files_reaped_after_query():
-    r = make_runner(query_max_memory_bytes=600_000, spill_enabled=True,
-                    spill_to_disk_enabled=True, spill_host_memory_bytes=0)
+    r = make_runner(AGG_PAGE_ROWS, query_max_memory_bytes=AGG_CAP,
+                    spill_enabled=True, spill_to_disk_enabled=True,
+                    spill_host_memory_bytes=0)
     res = r.execute(AGG_SQL)
     assert res.stats["memory"]["disk_spill_events"] > 0
     root = os.path.join("/tmp/trino_tpu_spill", str(os.getpid()))
@@ -98,8 +110,8 @@ def test_disk_spill_files_reaped_after_query():
 def test_host_tier_preferred_until_ledger_full(baselines):
     """With a roomy host budget the disk tier must stay cold — the
     tiers are ordered, not parallel."""
-    r = make_runner(query_max_memory_bytes=600_000, spill_enabled=True,
-                    spill_to_disk_enabled=True,
+    r = make_runner(AGG_PAGE_ROWS, query_max_memory_bytes=AGG_CAP,
+                    spill_enabled=True, spill_to_disk_enabled=True,
                     spill_host_memory_bytes=1 << 30)
     res = r.execute(AGG_SQL)
     mem = res.stats["memory"]
@@ -635,8 +647,9 @@ def test_scan_coalesce_upload_batches():
 
 
 def test_local_explain_analyze_shows_disk_spill():
-    r = make_runner(query_max_memory_bytes=600_000, spill_enabled=True,
-                    spill_to_disk_enabled=True, spill_host_memory_bytes=0)
+    r = make_runner(AGG_PAGE_ROWS, query_max_memory_bytes=AGG_CAP,
+                    spill_enabled=True, spill_to_disk_enabled=True,
+                    spill_host_memory_bytes=0)
     res = r.execute("explain analyze " + AGG_SQL)
     text = "\n".join(row[0] for row in res.rows)
     assert "disk" in text and "spills" in text

@@ -87,6 +87,12 @@ class OperatorStats:
                 # the adaptive partial-agg decision (pass-through or
                 # per-key-range split) — no 'strategy' key on agg ops
                 base += f" [adaptive {m['adaptive']}]"
+            if m.get("partial_lanes", {}).get("pages"):
+                # aggregation partials: lanes in, lanes kept for the
+                # merge, and the width the last merge ran at
+                pl = m["partial_lanes"]
+                base += (f" [partial lanes {pl['in']}->{pl['kept']} over "
+                         f"{pl['pages']} pages, merge {pl['merge']}]")
             extras = " ".join(
                 f"{k}={m[k]}" for k in ("skew_ratio", "lane_skew_ratio",
                                         "per_dest", "a2a_retries",

@@ -20,11 +20,13 @@ Grouping runs one of two paths:
   group ids, segment-reduce. Forceable via the ``hash_grouping_enabled``
   session property for cross-checking.
 
-Streaming: each input page is partially aggregated on device (bounded
-output = its own row count), partials accumulate; ``finish`` re-groups the
-concatenated partials and applies final projections. This mirrors the
-reference's partial/final adapter split and keeps memory proportional to
-groups, not input rows.
+Streaming: each input page is partially aggregated on device, partials
+accumulate; ``finish`` re-groups the concatenated partials and applies
+final projections. This mirrors the reference's partial/final adapter
+split and keeps memory proportional to groups, not input rows: where the
+host reads a page's flags anyway (steps ``single``/``final`` on the hash
+path) the group count comes with them, and the partial is kept
+``padded_size(ngroups)`` lanes wide; elsewhere it is as wide as its page.
 
 **Adaptive partial aggregation** (reference:
 ``adaptive_partial_aggregation_enabled``; "Partial Partial Aggregates",
@@ -61,7 +63,7 @@ from .. import jit_stats
 from .. import types as T
 from ..block import DevicePage, padded_size
 from ..telemetry.profiler import instrument
-from ..telemetry.tracing import host_read
+from ..telemetry.tracing import host_read, host_sync
 from ..types import TypeError_
 from .hashtable import (_mix_operands, hash_group_ids,
                         hash_segment_reduce, hashable_key_types)
@@ -494,6 +496,18 @@ _key_range_pass_mask = instrument(
     static_argnames=("buckets",))
 
 
+@partial(jax.jit, static_argnames=("keep",))
+def _narrow_lanes(arrays, keep: int):
+    """Every array of the pytree cut to its first ``keep`` lanes, in one
+    program: a grouping result holds its groups in a dense prefix."""
+    jit_stats.bump("agg_narrow_partial")
+    return jax.tree_util.tree_map(lambda a: a[:keep], arrays)
+
+
+_narrow_lanes = instrument("agg_narrow_partial", _narrow_lanes,
+                           static_argnames=("keep",))
+
+
 #: process-wide pages per grouping path (the per-operator
 #: ``path_counts`` summed; chip_smoke / test observability)
 _path_totals = {"hash": 0, "sort": 0, "passthrough": 0, "range_split": 0}
@@ -551,6 +565,12 @@ class HashAggregationOperator(Operator):
         self.path_counts = {"hash": 0, "sort": 0, "passthrough": 0,
                             "range_split": 0}
         self._partials: List = []  # DevicePage | SpilledPage entries
+        #: group count of the newest ``_aggregate_page`` result, where the
+        #: page's ``agg_overflow`` read brought one (exact hash path)
+        self._newest_groups: Optional[int] = None
+        #: pages aggregated, their summed capacity, the summed capacity of
+        #: the partials kept for them, the capacity of the last merge call
+        self._lanes = {"pages": 0, "in": 0, "kept": 0, "merge": 0}
         self._emitted = False
         self._done = False
         self._group_dicts: List = [None] * len(group_channels)
@@ -661,8 +681,11 @@ class HashAggregationOperator(Operator):
             self._pending.append(self._passthrough_page(
                 _masked_page(page, page.valid & mask)))
             page = _masked_page(page, page.valid & ~mask)
-        partial = self._aggregate_page(page, intermediate=intermediate,
-                                       key_operands=key_operands)
+        partial, self._newest_groups = self._aggregate_page(
+            page, intermediate=intermediate, key_operands=key_operands)
+        self._lanes["pages"] += 1
+        self._lanes["in"] += page.capacity
+        self._lanes["kept"] += partial.capacity
         if self._ctx is None:
             self._partials.append(partial)
             return
@@ -680,13 +703,18 @@ class HashAggregationOperator(Operator):
                            self._ctx.lock)
 
     def _aggregate_page(self, page: DevicePage, intermediate: bool,
-                        key_operands=None) -> DevicePage:
+                        key_operands=None
+                        ) -> Tuple[DevicePage, Optional[int]]:
         """intermediate=False: page is raw input rows (layout:
         self.input_types, keys at self.group_channels).
         intermediate=True: page is partial-agg output (layout:
         _intermediate_types — keys at channels [0..nkeys), then states).
         ``key_operands``: precomputed (key_ops, key_raws) from the
-        range-split path (raw layout only) — skips recomputing them."""
+        range-split path (raw layout only) — skips recomputing them.
+
+        Returns the partial and its group count where the grouping path
+        read one (exact hash path): the partial is then
+        ``padded_size(ngroups)`` lanes wide, else as wide as ``page``."""
         nkeys = len(self.group_channels)
         if intermediate:
             key_channels = list(range(nkeys))
@@ -723,11 +751,11 @@ class HashAggregationOperator(Operator):
         from .pallas_kernels import pallas_mode
 
         mode = pallas_mode()
-        result = None
+        result = ngroups = None
         if self.hash_grouping and hashable_key_types(key_types):
-            result = self._hash_group_page(page, key_ops, key_raws,
-                                           key_channels, state_cols, mode,
-                                           observe=not intermediate)
+            result, ngroups = self._hash_group_page(
+                page, key_ops, key_raws, key_channels, state_cols, mode,
+                observe=not intermediate)
         if result is None:
             self._count_path("sort")
             result = _group_reduce(
@@ -735,21 +763,25 @@ class HashAggregationOperator(Operator):
                 page.valid, num_keys=len(self.group_channels),
                 num_states=len(state_cols), kinds=self._kinds,
                 pallas=mode)
+        keep = page.capacity if ngroups is None else padded_size(ngroups)
+        if keep < page.capacity:
+            # the groups are lanes [0, ngroups): keep a partial as wide as
+            # they are, not as wide as the page that made it — the merge
+            # pays by lane, and the cap-wide outputs are dropped here
+            result = _narrow_lanes(result, keep=keep)
         out_keys, out_key_nulls, reduced, out_valid = result
 
         # string min/max: reduced RANK -> representative CODE in the
         # captured pool (dead/sentinel lanes clamp; count==0 nulls them)
         reduced = self._states_rank_to_code(list(reduced))
 
-        cols, nulls = list(out_keys), [jnp.asarray(n) for n in out_key_nulls]
-        for r in reduced:
-            cols.append(r)
-            nulls.append(jnp.zeros_like(out_valid))
-        types = self._intermediate_types()
-        dicts = list(self._group_dicts) + [
-            self._state_dicts[k] if self._str_state[k] else None
-            for k in range(len(self._str_state))]
-        return DevicePage(types, cols, nulls, out_valid, dicts)
+        no_nulls = jnp.zeros_like(out_valid)
+        cols = list(out_keys) + reduced
+        nulls = [jnp.asarray(n) for n in out_key_nulls] \
+            + [no_nulls] * len(reduced)
+        dicts = list(self._group_dicts) + self._state_dict_tail()
+        return DevicePage(self._intermediate_types(), cols, nulls,
+                          out_valid, dicts), ngroups
 
     def _grouping_operands(self, page: DevicePage, key_channels,
                            key_types):
@@ -777,8 +809,10 @@ class HashAggregationOperator(Operator):
     def _hash_group_page(self, page: DevicePage, key_ops, key_raws,
                          key_channels, state_cols, mode: str,
                          observe: bool):
-        """Hash-path grouping of one page; None => the caller falls
-        back to the sort oracle (probe-budget overflow)."""
+        """Hash-path grouping of one page: (result, ngroups). A None
+        result => the caller falls back to the sort oracle (probe-budget
+        overflow); ngroups is the page's group count on the host where
+        the step reads the page's flags anyway (exact), else None."""
         exact = self.step != "partial"
         gid, group_rows, ngroups, overflow = hash_group_ids(
             tuple(key_ops), page.valid, exact=exact)
@@ -790,15 +824,20 @@ class HashAggregationOperator(Operator):
                                      tuple(key_raws), key_nulls,
                                      tuple(state_cols), self._kinds,
                                      pallas=mode)
+        count = None
         if exact:
-            if bool(host_read(overflow, "agg_overflow")):
-                return None
+            # one wait for two scalars of the one program
+            with host_sync("agg_overflow"):
+                overflow, count = jax.device_get((overflow, ngroups))
+            if overflow:
+                return None, None
+            count = int(count)
         elif observe and self.adaptive_partial \
                 and not self._adaptive_decided:
             self._observe_reduction(key_ops, page.valid, group_rows,
                                     ngroups)
         self._count_path("hash")
-        return result
+        return result, count
 
     def _states_rank_to_code(self, state_cols: List) -> List:
         """String min/max value states: lexicographic RANK -> the
@@ -875,18 +914,20 @@ class HashAggregationOperator(Operator):
             return None
         self._emitted = True
         self._done = True
-        merged = self._merge_partials()
+        merged, ngroups = self._merge_partials()
         self._partials = []
         if self.step in ("single", "final"):
             merged = self._finalize(merged)
         if self._ctx is not None:
             self._ctx.close()  # output page is in flight, not retained
-        # the merge ran at the summed capacity of every partial (millions
-        # of lanes at SF1 for a handful of groups): hand downstream a
-        # page as wide as the groups
-        return merged.trimmed()
+        # every downstream program is shaped by capacity: hand on a page
+        # as wide as the groups. With a count it already is; without one
+        # (sort path, partial step) it is as wide as what was merged
+        return merged if ngroups is not None else merged.trimmed()
 
-    def _merge_partials(self) -> DevicePage:
+    def _merge_partials(self) -> Tuple[DevicePage, Optional[int]]:
+        """The partials as one page, and its group count where known
+        (as ``_aggregate_page`` returns them)."""
         types = self._intermediate_types()
         nkeys = len(self.group_channels)
         # a task that saw no input never captured key dictionaries;
@@ -915,13 +956,14 @@ class HashAggregationOperator(Operator):
             if nkeys == 0:
                 valid = valid.at[0].set(True)
             dicts = list(self._group_dicts) + self._state_dict_tail()
-            return DevicePage(types, cols, nulls, valid, dicts)
+            return DevicePage(types, cols, nulls, valid, dicts), \
+                int(nkeys == 0)
         from ..exec.memory import SpilledPage, device_page_bytes
 
         parts = self._partials
         if len(parts) == 1 and self.step != "partial" \
                 and not isinstance(parts[0], SpilledPage):
-            return parts[0]
+            return parts[0], self._newest_groups  # the sole partial's
         # merge in budget-bounded chunks: each round touches at most
         # ~budget bytes of HBM (uploads + concat), so spilled state
         # re-enters the device incrementally (reference analog:
@@ -946,11 +988,13 @@ class HashAggregationOperator(Operator):
             chunks.append(cur)
             if len(chunks) == 1:
                 return self._merge_chunk(chunks[0])
-            parts = [self._merge_chunk(c) for c in chunks]
+            parts = [self._merge_chunk(c)[0] for c in chunks]
 
-    def _merge_chunk(self, chunk: List) -> DevicePage:
+    def _merge_chunk(self, chunk: List
+                     ) -> Tuple[DevicePage, Optional[int]]:
         """Concatenate one chunk of partials (uploading spilled ones) and
-        re-group with merge semantics."""
+        re-group with merge semantics: (page, ngroups) as
+        ``_aggregate_page`` returns them."""
         from ..exec.memory import SpilledPage, device_page_bytes
 
         types = self._intermediate_types()
@@ -968,9 +1012,10 @@ class HashAggregationOperator(Operator):
                for p in chunk]
         if len(dev) == 1 and self.step != "partial" and \
                 isinstance(chunk[0], SpilledPage):
-            out = dev[0]
+            out, ngroups = dev[0], None
         else:
             cap = padded_size(sum(p.capacity for p in dev))
+            self._lanes["merge"] = cap
             cols, nulls = [], []
             for i in range(len(types)):
                 c = jnp.concatenate([p.cols[i] for p in dev])
@@ -981,7 +1026,7 @@ class HashAggregationOperator(Operator):
             page = DevicePage(
                 types, cols, nulls, valid,
                 list(self._group_dicts) + self._state_dict_tail())
-            out = self._aggregate_page(page, intermediate=True)
+            out, ngroups = self._aggregate_page(page, intermediate=True)
         if self._ctx is not None:
             # release the transient + the chunk inputs' reservations,
             # keep the merged result reserved
@@ -989,7 +1034,7 @@ class HashAggregationOperator(Operator):
                                     if not isinstance(p, SpilledPage))
             self._ctx.free(freed)
             self._ctx.reserve(device_page_bytes(out), revocable=False)
-        return out
+        return out, ngroups
 
     def _finalize(self, merged: DevicePage) -> DevicePage:
         nkeys = len(self.group_channels)
@@ -1031,7 +1076,8 @@ class HashAggregationOperator(Operator):
         path and, once the adaptive window decided, what it decided
         (whole-stream pass-through vs the per-key-range split)."""
         out = {"grouping_paths": {k: v for k, v in
-                                  self.path_counts.items() if v}}
+                                  self.path_counts.items() if v},
+               "partial_lanes": dict(self._lanes)}
         seeded = " (seeded by hbo)" \
             if self._adaptive_source == "hbo" else ""
         if self.passthrough:

@@ -416,8 +416,9 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
             span["attrs"]["device_bytes"] = st.device_bytes
             span["attrs"]["compile_ms"] = round(st.compile_ms, 3)
         if st.metrics:
-            # the scan operator's host-side counters, under their names
-            for key in ("generate_s", "upload_s"):
+            # the scan operator's host-side counters and the
+            # aggregation's partial widths, under their names
+            for key in ("generate_s", "upload_s", "partial_lanes"):
                 if st.metrics.get(key) is not None:
                     span["attrs"][key] = st.metrics[key]
             for key in ("kind", "first_page_ms", "reconnects",
