@@ -1,17 +1,18 @@
 """One run of one benchmark cell: ``python benchmark/run.py --workload
 <name> --seed <n> --seconds <s> --trace <0|1>``.
 
-One process holds the chip: ``ProtocolServer`` thread, client threads and
-the ``LocalQueryRunner`` together, SQL text in through
-``POST /v1/statement``.  A run is set-up (JAX up, server up, warm-up of
-every statement of the seed's pool, a fixed number of passes)
--> window (closed loop; streams stop issuing ``--seconds`` after it
+One process holds the chip(s): ``ProtocolServer`` thread, client threads
+and the configuration's runner (``systems/<runner.kind>.py``) together,
+SQL text in through ``POST /v1/statement``.  A run is set-up (JAX up,
+server up, warm-up of every statement of the seed's pool, a fixed number
+of passes) -> window (closed loop; streams stop issuing ``--seconds`` after it
 opened, it closes when the last statement in flight completes, every
 statement issued in it counts) -> check (every statement's rows against
 the numpy reference, outside both clocks) -> one JSON line.
 
-Nothing here names a cell, a query, a configuration or a metric: they are
-entries of ``BENCHMARK.json`` and files beside this one (README.md).
+Nothing here names a cell, a query, a configuration, a runner kind or a
+metric: they are entries of ``BENCHMARK.json`` and files beside this one
+(README.md).
 """
 
 from __future__ import annotations
@@ -21,6 +22,7 @@ import time
 T_START = time.perf_counter()          # process start, for setup_s
 
 import argparse                         # noqa: E402
+import functools                        # noqa: E402
 import importlib                        # noqa: E402
 import json                             # noqa: E402
 import os                               # noqa: E402
@@ -125,14 +127,29 @@ class CompileCounter:
 class TimedRunner:
     """Delegates to the runner and records when each ``execute`` /
     ``execute_batch`` call ran: the benchmark's own span at the boundary
-    between protocol and engine (the program opens none on this path)."""
+    between protocol and engine (the program opens none on this path).
+
+    ``ProtocolServer`` decides what to hand a runner from what it shows
+    (the parameters of ``execute``, whether there is an
+    ``execute_batch``), so the wrapper shows what the runner shows: each
+    wrapped call keeps the runner's own signature, and there is an
+    ``execute_batch`` only where the runner has one."""
 
     def __init__(self, runner, calls: list):
         self._runner = runner
         self._calls = calls
+        self.execute = self._wrap(runner.execute, lambda sql: [sql])
+        if hasattr(runner, "execute_batch"):
+            self.execute_batch = self._wrap(runner.execute_batch, list)
 
     def __getattr__(self, name):
         return getattr(self._runner, name)
+
+    def _wrap(self, fn, sqls_of):
+        @functools.wraps(fn)        # inspect.signature reads the runner's
+        def call(first, *args, **kwargs):
+            return self._timed(fn, sqls_of(first), first, *args, **kwargs)
+        return call
 
     def _timed(self, fn, sqls, *args, **kwargs):
         import jax
@@ -144,35 +161,23 @@ class TimedRunner:
         finally:
             self._calls.append((t0, time.perf_counter(), list(sqls)))
 
-    def execute(self, sql, user=None, progress=None):
-        return self._timed(self._runner.execute, [sql], sql, user=user,
-                           progress=progress)
-
-    def execute_batch(self, sqls, user=None):
-        return self._timed(self._runner.execute_batch, sqls, sqls,
-                           user=user)
-
 
 def build_system(config: dict, calls: list):
-    """Connector, runner and protocol server as the configuration file
-    states them; the server is started."""
-    from trino_tpu.connectors.tpch import TpchConnector
-    from trino_tpu.runner import LocalQueryRunner
+    """The runner as ``systems/<runner.kind>.py`` builds it from the
+    configuration file, behind a started protocol server."""
     from trino_tpu.server.protocol import ProtocolServer
-    from trino_tpu.sql.analyzer import Session
 
-    if config["runner"]["kind"] != "local":
-        raise NotImplementedError(
-            f"runner kind {config['runner']['kind']!r}: a later PR brings "
-            "the distributed runner's set-up with its first cell")
-    conn = TpchConnector(page_rows=config["connector"]["page_rows"])
-    session = Session(catalog=config["connector"]["catalog"],
-                      schema=config["schema"])
-    session.properties.update(config["session_properties"])
-    runner = LocalQueryRunner(
-        {config["connector"]["catalog"]: conn}, session,
-        desired_splits=config["runner"]["desired_splits"])
-    return ProtocolServer(TimedRunner(runner, calls)).start()
+    kind = config["runner"]["kind"]
+    module = f"benchmark.systems.{kind}"
+    try:
+        system = importlib.import_module(module)
+    except ModuleNotFoundError as e:
+        if e.name != module:
+            raise
+        raise ValueError(
+            f"runner kind {kind!r}: no benchmark/systems/{kind}.py "
+            "(a runner kind is a file there with build(config))") from e
+    return ProtocolServer(TimedRunner(system.build(config), calls)).start()
 
 
 # -- driving the streams -----------------------------------------------------
@@ -307,6 +312,23 @@ def check(statements, tables) -> dict:
 
 # -- one run -------------------------------------------------------------------
 
+def force_cpu_devices(n: int):
+    """Ask XLA's CPU backend for ``n`` virtual devices (a rehearsal of a
+    cell on ``n`` chips); has to run before jax starts, and leaves a
+    count the environment already states alone."""
+    forced = "--xla_force_host_platform_device_count"
+    flags = os.environ.get("XLA_FLAGS", "")
+    if n > 1 and forced not in flags:
+        os.environ["XLA_FLAGS"] = f"{flags} {forced}={n}".strip()
+
+
+def unused_devices(per_device) -> list:
+    """The devices of the cell that held no memory or, in a traced run,
+    ran no operation in the traced slice."""
+    return [d for d in per_device if not d["peak_bytes_in_use"]
+            or not d.get("busy_s", True)]
+
+
 def read_metrics(package, entries, cell, facts) -> dict:
     """``{name: {"value", "unit"}}`` for the metrics of ``entries`` that
     list this cell (or list none); a reader that returns None is left
@@ -344,6 +366,7 @@ def run_cell(bench, cell, args) -> dict:
     os.environ["JAX_COMPILATION_CACHE_DIR"] = COMPILE_CACHE
     if args.rehearse_cpu:
         os.environ["JAX_PLATFORMS"] = "cpu"
+        force_cpu_devices(cell["chips"])
     import jax
 
     devices = jax.devices()
@@ -352,11 +375,13 @@ def run_cell(bench, cell, args) -> dict:
         sys.exit(f"run.py: JAX found no TPU (platform {dev.platform!r})")
     if dev.platform == "tpu" and args.rehearse_cpu:
         sys.exit("run.py: --rehearse-cpu on a TPU backend")
-    if len(devices) < cell["chips"] and not args.rehearse_cpu:
+    if len(devices) < cell["chips"]:
         sys.exit(f"run.py: the cell asks for {cell['chips']} chip(s), "
                  f"JAX reports {len(devices)}")
     import trino_tpu  # noqa: F401  (x64 on before any array)
     from trino_tpu.compile_cache import enable_compile_cache
+
+    from trino_tpu.parallel.device_exchange import DeviceExchange
 
     from benchmark import roofline, trace_reduce
     from benchmark.references.hosttables import HostTables
@@ -397,6 +422,7 @@ def run_cell(bench, cell, args) -> dict:
             tracer.start()
             tracer.started.wait()
         compiles0 = counter.requests
+        collectives0 = DeviceExchange.total_collectives
         # each stream walks the pool again from its first cycle
         sequences = [stream_sequence(pool, args.seed, s)
                      for s in range(traffic["streams"])]
@@ -423,9 +449,11 @@ def run_cell(bench, cell, args) -> dict:
     from trino_tpu import jit_stats
     from trino_tpu.ops.aggregation import grouping_path_totals
 
-    stats = [d.memory_stats() or {} for d in devices[:cell["chips"]]]
+    per_device = [{"id": d.id, "peak_bytes_in_use": int(
+        (d.memory_stats() or {}).get("peak_bytes_in_use", 0))}
+        for d in devices[:cell["chips"]]]
     facts.memory_peak_bytes = max(
-        int(ms.get("peak_bytes_in_use", 0)) for ms in stats)
+        d["peak_bytes_in_use"] for d in per_device)
     say(phase="window", seconds_asked=args.seconds,
         window_s=facts.window_s, statements=len(facts.statements),
         finished=len(facts.finished),
@@ -433,6 +461,11 @@ def run_cell(bench, cell, args) -> dict:
                             if s.instance.template.name == t)
                      for t in facts.rows_read},
         compiles_in_window=facts.compiles_in_window,
+        # device collectives of the exchange (0 on one chip), over the
+        # statements the window issued
+        collectives_per_statement=(
+            DeviceExchange.total_collectives - collectives0)
+        / max(len(facts.statements), 1),
         jit_traces=jit_stats.total(),
         grouping_path_counts=grouping_path_totals(),
         xla_programs=counter.requests,
@@ -445,7 +478,8 @@ def run_cell(bench, cell, args) -> dict:
 
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices),
-              "memory_peak_bytes": facts.memory_peak_bytes}
+              "memory_peak_bytes": facts.memory_peak_bytes,
+              "per_device": per_device}
     line = {"correct": correct, "attempted": len(facts.statements),
             "failed": failed}
     if args.trace:
@@ -457,12 +491,30 @@ def run_cell(bench, cell, args) -> dict:
                                        cell, facts)
         device["busy_s"] = facts.trace["busy_s"]
         device["window_s"] = facts.trace_close - facts.trace_open
+        planes = {p["plane"]: p for p in facts.trace["per_device"]}
+        for d in per_device:        # a device with no plane ran nothing
+            d.update(planes.get(f"{trace_reduce.DEVICE_PREFIX}{d['id']}",
+                                {"busy_s": 0.0}))
         line["breakdown"] = {"device_ops": facts.trace["device_ops"],
                              "idle_gaps": facts.trace["idle_gaps"]}
     else:
         line["metrics"] = read_metrics("end_to_end", bench["end_to_end"],
                                        cell, facts)
     line["device"] = device
+    # each number compared beside its limit: the line's last key, and
+    # the last lines on standard error
+    line["compared"] = {"failed_statements": {"value": failed, "limit": 0},
+                        **{key: {"value": rep["mismatched_values"],
+                                 "limit": rep["limit"],
+                                 "statements": rep["statements"]}
+                           for key, rep in report.items()}}
+    for name, entry in line["compared"].items():
+        print(json.dumps({"compared": name, **entry}), file=sys.stderr,
+              flush=True)
+    unused = unused_devices(per_device)
+    if dev.platform != "cpu" and unused:
+        # a run of another deployment than the cell's: no result line
+        sys.exit(f"run.py: devices of the cell that did no work: {unused}")
     print(json.dumps(line), flush=True)
     return line
 

@@ -8,3 +8,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
+
+from benchmark.run import force_cpu_devices  # noqa: E402
+
+# four virtual CPU devices, for the four-chip cell's rehearsal in process
+force_cpu_devices(4)

@@ -1,5 +1,6 @@
 """``run.py --rehearse-cpu`` end to end on ``tiny`` for each cell's
-traffic file, and the last line's keys."""
+traffic file and runner kind (the four-chip cell on four virtual CPU
+devices), and the last line's keys."""
 
 import argparse
 import json
@@ -15,23 +16,37 @@ with open(os.path.join(run.ROOT, "BENCHMARK.json")) as _f:
     BENCH = json.load(_f)
 
 
-def tiny_config():
-    name, = [c["name"] for c in BENCH["configs"]
-             if json.load(open(os.path.join(run.ROOT, c["file"])))["schema"]
-             == "tiny"]
-    return name
+def config_of(cell):
+    """The cell's entry in ``configs`` and its configuration file."""
+    entry, = [c for c in BENCH["configs"] if c["name"] == cell["config"]]
+    with open(os.path.join(run.ROOT, entry["file"])) as f:
+        return entry, json.load(f)
+
+
+def on_tiny(cell, tmp_path):
+    """``(bench, cell)`` with the cell's own configuration cut to the
+    ``tiny`` schema: same runner kind, workers and session properties."""
+    entry, config = config_of(cell)
+    config["schema"] = "tiny"
+    path = tmp_path / "config.json"
+    path.write_text(json.dumps(config))
+    bench = dict(BENCH, configs=[dict(entry, name="on_tiny",
+                                      file=str(path))])
+    return bench, dict(cell, config="on_tiny")
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
 @pytest.mark.parametrize("trace", [0, 1])
-def test_traffic_file_runs_on_tiny(cell, trace):
-    """Each cell's traffic against the tiny configuration, in process."""
-    cell = dict(cell, config=tiny_config())
+def test_traffic_file_runs_on_tiny(cell, trace, tmp_path):
+    """Each cell's traffic and runner kind on the tiny schema, in
+    process."""
+    bench, cell = on_tiny(cell, tmp_path)
     args = argparse.Namespace(seed=2147483659, seconds=1.0, trace=trace,
                               rehearse_cpu=True)
-    line = run.run_cell(BENCH, cell, args)
+    line = run.run_cell(bench, cell, args)
     assert line["correct"] is True and line["failed"] == 0
     assert line["device"]["platform"] == "cpu"
+    assert len(line["device"]["per_device"]) == cell["chips"]
     wanted = BENCH["per_layer"] if trace else BENCH["end_to_end"]
     listed = {m["name"] for m in wanted
               if cell["name"] in m.get("workloads", [cell["name"]])}
@@ -43,7 +58,7 @@ def test_traffic_file_runs_on_tiny(cell, trace):
 
 def test_command_prints_the_contract_line_last():
     cell, = [w["name"] for w in BENCH["workloads"]
-             if w["config"] == tiny_config()]
+             if config_of(w)[1]["schema"] == "tiny"]
     proc = subprocess.run(
         [sys.executable, os.path.join(run.HERE, "run.py"), "--workload",
          cell, "--seed", "7", "--seconds", "1", "--trace", "0",

@@ -4,9 +4,12 @@ and the longest idle gaps labelled by what the host was doing.
 
 Device planes are named ``/device:TPU:<n>``.  On each, the line
 ``XLA Ops`` holds one event per operation run and ``XLA Modules`` one per
-program execution (a launch).  Host threads are lines of ``/host:CPU``;
-the benchmark's own spans (``jax.profiler.TraceAnnotation``) are events
-there, beside the runtime's own.
+program execution (a launch).  Collective operations (the exchange's
+``all-to-all`` and the reductions that size it) are events of that line
+too, told by their opcode or result name.  Host threads are lines of
+``/host:CPU``; the benchmark's own spans
+(``jax.profiler.TraceAnnotation``) are events there, beside the
+runtime's own.
 """
 
 from __future__ import annotations
@@ -17,11 +20,18 @@ import os
 import re
 from collections import defaultdict
 
+import numpy as np
+
 DEVICE_PREFIX = "/device:TPU:"
 HOST_PLANE = "/host:CPU"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 _OPCODE = re.compile(r"\s([a-z][a-z0-9-]*)\(")
+#: HLO collectives, as an opcode (``all-to-all(``) or, where the
+#: compiler made them asynchronous, as the result name of the
+#: ``async-start`` / ``async-done`` pair (``%all-to-all-start.1``)
+COLLECTIVES = ("all-to-all", "all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "collective-broadcast")
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -61,16 +71,52 @@ def describe(profile) -> list:
             for p in profile.planes for ln in p.lines]
 
 
+def _operation(name: str) -> tuple:
+    """``(result, opcode)`` of an HLO operation's event name."""
+    result, rest = name.split(" = ", 1)
+    opcode = _OPCODE.search(" " + rest)
+    return result, opcode.group(1) if opcode else ""
+
+
 def short_name(name: str, limit: int = 96) -> str:
     """A program's name without its fingerprint, or an operation's
     result name and opcode without its operand list."""
     if " = " in name:                       # an HLO operation
-        result, rest = name.split(" = ", 1)
-        opcode = _OPCODE.search(" " + rest)
-        name = f"{result} {opcode.group(1) if opcode else ''}".strip()
+        name = " ".join(_operation(name)).strip()
     elif name.endswith(")") and "(" in name:  # jit_f(1234567890)
         name = name[:name.rindex("(")]
     return name[:limit]
+
+
+def is_collective(name: str) -> bool:
+    """Whether an ``XLA Ops`` event is a collective operation."""
+    if " = " not in name:
+        return False
+    result, opcode = _operation(name)
+    return result.lstrip("%").startswith(COLLECTIVES) \
+        or opcode.startswith(COLLECTIVES)
+
+
+def collective_seconds(ops) -> tuple:
+    """``(inside, exposed)`` seconds of one device's ``XLA Ops`` events:
+    the union of its collective operations' intervals, and the part of
+    it in which no other operation ran on that device.  An operation
+    that spans a whole collective interval is what holds it (a loop, a
+    call), not work that overlaps it, and does not count."""
+    spans = np.array([(s, e) for s, e, _ in ops]).reshape(-1, 2)
+    flags = np.array([is_collective(name) for _, _, name in ops], dtype=bool)
+    inside = union(map(tuple, spans[flags]))
+    if not inside:
+        return 0.0, 0.0
+    starts, ends = spans[~flags, 0], spans[~flags, 1]
+    exposed = 0
+    for cs, ce in inside:
+        beside = (starts < ce) & (ends > cs) \
+            & ~((starts <= cs) & (ends >= ce))
+        covered = union(zip(np.maximum(starts[beside], cs),
+                            np.minimum(ends[beside], ce)))
+        exposed += (ce - cs) - sum(e - s for s, e in covered)
+    return sum(e - s for s, e in inside) / 1e9, float(exposed) / 1e9
 
 
 def reduce(profile, own_spans=("runner.execute", "client.execute"),
@@ -78,33 +124,49 @@ def reduce(profile, own_spans=("runner.execute", "client.execute"),
     """See the module docstring.  Times are seconds.  ``busy_s`` is the
     mean over device planes of the union of their operation intervals;
     with no device plane (a CPU rehearsal) the device keys are None.
+    ``collective_s`` / ``collective_exposed_s`` are the means over device
+    planes of ``collective_seconds``; ``per_device`` has each plane's
+    own ``busy_s``, ``launches`` and ``collective_s``, in the order of
+    the planes' numbers.
 
     ``device_ops`` holds the programs that took most device time (the
     sum of their executions, by name, shapes together) and, as
     ``<program>/<operation>``, the single operations that did; a loop's
-    time includes its body's operations, which are listed too."""
+    time includes its body's operations, which are listed too.  With
+    more than one device its last entry is the workers' skew: the most
+    less the least time a device spent inside collectives.
+    ``idle_gaps``, like ``device_ops``, are means over the devices."""
     device_planes, host_events = [], []
-    for plane in profile.planes:
+    for plane in sorted(profile.planes, key=lambda p: (len(p.name), p.name)):
         if plane.name.startswith(DEVICE_PREFIX):
-            lines = {ln.name: _events(ln) for ln in plane.lines
-                     if ln.name in (OPS_LINE, MODULES_LINE)}
-            device_planes.append(lines)
+            device_planes.append((plane.name, {
+                ln.name: _events(ln) for ln in plane.lines
+                if ln.name in (OPS_LINE, MODULES_LINE)}))
         elif plane.name == HOST_PLANE:
             for ln in plane.lines:
                 host_events.extend(_events(ln))
     out = {"devices": len(device_planes), "busy_s": None, "launches": None,
-           "device_ops": [], "idle_gaps": [], "span_s": None}
+           "device_ops": [], "idle_gaps": [], "span_s": None,
+           "collective_s": None, "collective_exposed_s": None,
+           "per_device": []}
     if not device_planes:
         return out
     busy, launches, span = [], 0, []
+    collective, exposed = [], []
     program_time, op_time = defaultdict(float), defaultdict(float)
     gaps = []
-    for lines in device_planes:
+    for plane_name, lines in device_planes:
         modules = sorted(lines.get(MODULES_LINE, ()))
         ops = lines.get(OPS_LINE) or modules
         merged = union((s, e) for s, e, _ in ops)
         busy.append(sum(e - s for s, e in merged) / 1e9)
         launches += len(modules)
+        inside, alone = collective_seconds(lines.get(OPS_LINE, ()))
+        collective.append(inside)
+        exposed.append(alone)
+        out["per_device"].append({
+            "plane": plane_name, "busy_s": busy[-1],
+            "launches": len(modules), "collective_s": inside})
         for s, e, name in modules:
             program_time[short_name(name)] += (e - s) / 1e9
         starts = [m[0] for m in modules]
@@ -120,11 +182,18 @@ def reduce(profile, own_spans=("runner.execute", "client.execute"),
     out["launches"] = launches / len(device_planes)
     out["span_s"] = max(span) if span else 0.0
     n = len(device_planes)
+    out["collective_s"] = sum(collective) / n
+    out["collective_exposed_s"] = sum(exposed) / n
     ranked = [sorted(d.items(), key=lambda kv: -kv[1])
               for d in (program_time, op_time)]
     out["device_ops"] = [[name, t / n] for name, t in
                          ranked[0][:top - top // 2] + ranked[1][:top // 2]]
-    out["idle_gaps"] = _label_gaps(gaps, host_events, own_spans, top)
+    if n > 1:
+        out["device_ops"][top - 1:] = [[
+            "<collectives: most less least over devices>",
+            max(collective) - min(collective)]]
+    out["idle_gaps"] = [[label, t / n] for label, t in _label_gaps(
+        gaps, host_events, own_spans, top)]
     return out
 
 
