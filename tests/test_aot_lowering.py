@@ -225,6 +225,28 @@ def test_sort_by_compiles_for_v5e(one_chip):
     _compile(fn, one_chip, key_ops, cols, nulls, sds((n,), jnp.bool_))
 
 
+def test_resident_store_programs_compile_for_v5e(one_chip):
+    """The memory connector's write-time programs at SF1 ``lineitem``'s
+    shapes: 8 int64 + 8 int32 columns and their null masks, staged in
+    2 x 262,144 lanes and cut into pages of 262,144."""
+    from trino_tpu.connectors.memory import PAGE_ROWS, _kernels
+
+    k = _kernels()
+
+    def arrays(lanes):
+        return (sds((lanes,), jnp.int64),) * 8 + \
+            (sds((lanes,), jnp.int32),) * 8 + (sds((lanes,), jnp.bool_),) * 16
+
+    valid = sds((PAGE_ROWS,), jnp.bool_)
+    _compile(k.live_prefix, one_chip, valid)
+    _compile(k.compact, one_chip, arrays(PAGE_ROWS), valid)
+    _compile(k.stage_in, one_chip, arrays(2 * PAGE_ROWS),
+             arrays(PAGE_ROWS), sds((), jnp.int32))
+    _compile(lambda stage, fill: k.cut(stage, fill, PAGE_ROWS), one_chip,
+             arrays(2 * PAGE_ROWS), sds((), jnp.int32))
+    _compile(k.shift, one_chip, arrays(2 * PAGE_ROWS))
+
+
 def test_device_exchange_compiles_for_v5e_mesh(mesh4):
     """The count and data collectives over the four described devices
     at ``(4, 65536)`` slabs: the data program must hold an all-to-all."""

@@ -235,41 +235,39 @@ def test_partial_enforcement_residual_refiltered():
     TupleDomain; the engine keeps filtering that column itself and the
     answer stays correct (reference:
     spi/connector/ConstraintApplicationResult.java remainingFilter)."""
-    from trino_tpu.connectors.memory import MemoryConnector, MemoryMetadata
     from trino_tpu.connectors.spi import negotiate_constraint
+    from trino_tpu.connectors.tpch import TpchMetadata
 
-    class OneColumnMetadata(MemoryMetadata):
+    class OneColumnMetadata(TpchMetadata):
         offered_cols = []
 
         def apply_filter(self, table, constraint):
             OneColumnMetadata.offered_cols.append(
                 sorted(constraint.as_dict().keys()))
-            data = self.conn.tables.get((table.schema, table.table))
-            if data is None:
-                return None
-            # the connector only knows how to prune on 'k'
+            # the connector only knows how to prune on the key
             return negotiate_constraint(
-                table, constraint, (c.name for c in data.columns),
-                enforceable={"k"})
+                table, constraint,
+                (c.name for c in self.get_columns(table)),
+                enforceable={"n_nationkey"})
 
-    class OneColumnMemory(MemoryConnector):
+    class OneColumnTpch(TpchConnector):
         def metadata(self):
             return OneColumnMetadata(self)
 
-    mem = OneColumnMemory()
-    on, off = _runners({"mem": mem}, "default", "mem")
-    on.execute("create table t (k bigint, v bigint)")
-    on.execute("insert into t values (1, 10), (2, 20), (3, 30), "
-               "(4, 40), (5, 50)")
-    sql = "select k, v from t where k >= 2 and v <= 40"
+    # (the memory connector declines pushdown since its tables live on
+    # the device, and refuses a handle that carries a constraint)
+    on, off = _runners({"tpch": OneColumnTpch()}, "tiny", "tpch")
+    sql = ("select n_nationkey, n_regionkey from nation "
+           "where n_nationkey >= 2 and n_regionkey <= 0")
     rows_on = sorted(on.execute(sql).rows)
-    assert rows_on == [(2, 20), (3, 30), (4, 40)]
+    assert rows_on == [(5, 0), (14, 0), (15, 0), (16, 0)]
     assert rows_on == sorted(off.execute(sql).rows)
-    # both domains were offered; only k landed on the handle
     # both domains were offered together at least once (the iterative
-    # engine may re-offer the residual alone on later passes)
-    assert ["k", "v"] in OneColumnMetadata.offered_cols
+    # engine may re-offer the residual alone on later passes); only the
+    # key landed on the handle
+    assert ["n_nationkey", "n_regionkey"] in OneColumnMetadata.offered_cols
     plan = on.explain(sql)
-    assert "constraint{k" in plan and "constraint{k, v" not in plan
-    # the residual conjunct (v) stays as an engine-side filter
-    assert "v" in plan.split("TableScan")[0]
+    assert "constraint{n_nationkey" in plan \
+        and "constraint{n_nationkey, n_regionkey" not in plan
+    # the residual conjunct stays as an engine-side filter
+    assert "n_regionkey" in plan.split("TableScan")[0]

@@ -1,59 +1,240 @@
-"""In-memory table connector.
+"""In-memory table connector: tables that live on the chip.
 
 Reference analog: ``plugin/trino-memory`` (``MemoryConnector.java``,
-``MemoryMetadata``, ``MemoryPagesStore``) — the engine's writable test
-fixture and cache connector. Tables live as host Page lists per
-(schema, table); writes append under a lock so scaled/parallel writers
-can share one sink target.
+``MemoryMetadata``, ``MemoryPagesStore``) — "stores all data and
+metadata in RAM on workers", bounded by ``memory.max-data-per-node``.
+For an engine whose workers are TPU chips, RAM on workers is HBM: a
+table's pages are kept as ``DevicePage``s on the device of the task
+that wrote them, cut once at write time to ``page_rows`` lanes, string
+columns as codes into the table's one dictionary (which stays on the
+host, as everywhere in the engine).  A scan takes the pages as they
+lie (``ResidentPageSource``): no host page, no concat, no upload.  A
+reader pinned to another device gets a device-to-device transfer of
+the page, not a host round trip.
+
+The device bytes of every table are reserved in the connector's
+``TableMemoryAccount`` (``exec/memory.py``) when written, stay reserved
+after the writing query ends and fall on ``DROP TABLE``; a write past
+``max_data_per_node`` fails and leaves no half table.  Nothing spills,
+is evicted or falls back to host pages.  Writes append under a lock so
+scaled/parallel writers can share one sink target.
 """
 
 from __future__ import annotations
 
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+import time
+from functools import lru_cache
+from types import SimpleNamespace
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
-from .. import types as T
-from ..block import Page
+import numpy as np
+
+from ..block import Block, DevicePage, Dictionary, Page, padded_size
 from ..types import TrinoError
 from .spi import (ColumnHandle, Connector, ConnectorMetadata,
                   ConnectorPageSink, ConnectorPageSource,
-                  ConnectorSplit, ConnectorSplitManager, FixedPageSource,
+                  ConnectorSplit, ConnectorSplitManager, ResidentPage,
                   TableHandle, TableStatistics)
+
+#: lanes of a stored page: what a scan of the generator's ``lineitem``
+#: hands its pipeline (65,536 orders a connector page, four lines an
+#: order), so programs over resident pages are shaped like today's
+PAGE_ROWS = 1 << 18
+
+
+@lru_cache(maxsize=None)
+def _kernels():
+    """The store's device programs; all run at write time only."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    @jax.jit
+    def live_prefix(valid):
+        """(live lanes, whether they are a dense prefix)."""
+        n = valid.sum(dtype=jnp.int32)
+        dense = (valid == (jnp.arange(valid.shape[0]) < n)).all()
+        return jnp.stack([n, dense.astype(jnp.int32)])
+
+    @jax.jit
+    def compact(arrays, valid):
+        """Live lanes gathered to a dense prefix, in order."""
+        idx = jnp.nonzero(valid, size=valid.shape[0], fill_value=0)[0]
+        return tuple(a[idx] for a in arrays)
+
+    @jax.jit
+    def stage_in(stage, pieces, fill):
+        return tuple(lax.dynamic_update_slice(s, p, (fill,))
+                     for s, p in zip(stage, pieces))
+
+    def cut(stage, fill, cap):
+        """The first ``cap`` staged lanes as a page of ``fill`` live
+        rows: (columns, null masks, valid, which columns hold a NULL)."""
+        half = len(stage) // 2
+        valid = jnp.arange(cap) < fill
+        cols = tuple(s[:cap] for s in stage[:half])
+        nulls = tuple(s[:cap] & valid for s in stage[half:])
+        has_null = jnp.stack([n.any() for n in nulls]) if nulls \
+            else jnp.zeros(0, bool)
+        return cols, nulls, valid, has_null
+
+    @jax.jit
+    def shift(stage):
+        """Drop the first half of every staging buffer."""
+        return tuple(jnp.concatenate([s[s.shape[0] // 2:],
+                                      jnp.zeros(s.shape[0] // 2, s.dtype)])
+                     for s in stage)
+
+    return SimpleNamespace(live_prefix=live_prefix, compact=compact,
+                           stage_in=stage_in, shift=shift,
+                           cut=jax.jit(cut, static_argnums=2))
 
 
 class _TableData:
-    def __init__(self, columns: List[ColumnHandle]):
-        from ..block import Dictionary
+    """One table: columns, the table-wide dictionaries of its string
+    columns, and its pages.  ``pages`` holds ``ResidentPage``s; a host
+    ``Page`` put there from outside (a replica's sync, a test) is taken
+    onto the device the next time the table is read."""
 
+    def __init__(self, columns: List[ColumnHandle],
+                 conn: "MemoryConnector", key: Tuple[str, str]):
         self.columns = columns
-        self.pages: List[Page] = []
-        self.lock = threading.Lock()
+        self.conn = conn
+        self.key = key      # (schema, table): the account's key
+        self._pages: List[Union[ResidentPage, Page]] = []
+        self.lock = threading.RLock()
         # canonical per-column pools: appended pages re-encode into these
         # so scans present stable code spaces (group-by/join correctness)
         self.dicts = [Dictionary() if c.type.is_string else None
                       for c in columns]
+        #: per string column, for the source dictionary it last met:
+        #: (uid, codes of its values in this table's pool, whether that
+        #: mapping is the identity) — grown with the source, so a load
+        #: from one pool re-codes each value once
+        self._remaps: Dict[int, tuple] = {}
+        #: all-False / all-True masks shared by the pages of a capacity
+        self._masks: Dict[Tuple[bool, int], object] = {}
+
+    # -- the page list ---------------------------------------------------
+
+    @property
+    def pages(self) -> List[Union[ResidentPage, Page]]:
+        return self._pages
+
+    @pages.setter
+    def pages(self, new):
+        new = list(new)
+        with self.lock:
+            kept = {id(p) for p in new}
+            self._release(p for p in self._pages if id(p) not in kept)
+            self._pages = new
+
+    def _release(self, pages):
+        freed = sum(p.nbytes for p in pages if isinstance(p, ResidentPage))
+        if freed:
+            self.conn.account.release(self.key, freed)
 
     @property
     def row_count(self) -> int:
-        return sum(p.num_rows for p in self.pages)
+        return sum(p.rows if isinstance(p, ResidentPage) else p.num_rows
+                   for p in self._pages)
+
+    def resident(self) -> List[ResidentPage]:
+        """The table's pages, every one on the device."""
+        with self.lock:
+            for i, p in enumerate(self._pages):
+                if not isinstance(p, ResidentPage):
+                    self._pages[i] = self.adopt(p)
+            return list(self._pages)
+
+    def host_pages(self) -> List[Page]:
+        """Host copies of the pages (replication to other processes)."""
+        with self.lock:
+            return [p.to_page() if isinstance(p, ResidentPage) else p
+                    for p in self._pages]
+
+    def replace(self, pages: Sequence[Page]):
+        """The table's content becomes ``pages`` (host pages: DELETE)."""
+        with self.lock:
+            self.pages = []
+            self._pages = [self.adopt(p) for p in pages if p.num_rows]
+
+    def adopt(self, page: Page) -> ResidentPage:
+        """A host page, re-coded and put on the device as it is."""
+        page = self.canonicalize(page)
+        dp = DevicePage.from_page(page)
+        has_null = [b.nulls is not None and bool(np.any(b.nulls))
+                    for b in page.blocks]
+        return self.stored(dp.cols, dp.nulls, dp.valid, page.num_rows,
+                           has_null)
+
+    # -- string columns ----------------------------------------------------
+
+    def recode(self, i: int, source: Optional[Dictionary]
+               ) -> Optional[np.ndarray]:
+        """Codes of ``source``'s values in column ``i``'s table-wide
+        pool, by source code; None where the codes need no change."""
+        d = self.dicts[i]
+        if d is None or source is None or source is d:
+            return None
+        with self.lock:
+            uid, remap, identity = self._remaps.get(i, (None, None, True))
+            if uid != source.uid:
+                remap, identity = np.empty(0, np.int32), True
+            done = len(remap)
+            if done < len(source):
+                more = d.encode(source.values[done:len(source)])
+                identity = identity and bool(np.array_equal(
+                    more, np.arange(done, done + len(more))))
+                remap = np.concatenate([remap, more])
+            self._remaps[i] = (source.uid, remap, identity)
+            return None if identity else remap
 
     def canonicalize(self, page: Page) -> Page:
-        import numpy as np
-
-        from ..block import Block
-
         blocks = []
         for i, c in enumerate(self.columns):
             b = page.block(i).numpy()
-            if c.type.is_string and b.dictionary is not self.dicts[i]:
-                d = self.dicts[i]
-                remap = d.encode(b.dictionary.values) \
-                    if len(b.dictionary) else np.empty(0, np.int32)
-                data = remap[b.data] if len(remap) else b.data
-                blocks.append(Block(c.type, data, b.nulls, d))
+            remap = self.recode(i, b.dictionary)
+            if self.dicts[i] is not None and \
+                    b.dictionary is not self.dicts[i]:
+                data = b.data if remap is None else remap[b.data]
+                blocks.append(Block(c.type, data, b.nulls, self.dicts[i]))
             else:
                 blocks.append(b)
         return Page(blocks, page.num_rows)
+
+    # -- making a stored page -------------------------------------------------
+
+    def _mask(self, value: bool, cap: int):
+        """The table's shared all-``value`` mask of ``cap`` lanes."""
+        import jax.numpy as jnp
+
+        m = self._masks.get((value, cap))
+        if m is None:
+            self.conn.account.reserve(self.key, cap)
+            m = self._masks[(value, cap)] = jnp.full(cap, value, bool)
+        return m
+
+    def stored(self, cols, nulls, valid, rows: int,
+               has_null: Sequence[bool]) -> ResidentPage:
+        """A ``ResidentPage`` of these arrays, its bytes reserved.  Columns
+        without a NULL share the table's all-False mask and a full page
+        the all-True one, so a page holds little but its columns."""
+        cap = int(valid.shape[0])
+        own = cap * sum(c.dtype.itemsize for c in cols) \
+            + cap * sum(1 for h in has_null if h) \
+            + (cap if rows < cap else 0)
+        with self.lock:
+            nulls = [n if h else self._mask(False, cap)
+                     for n, h in zip(nulls, has_null)]
+            if rows == cap:
+                valid = self._mask(True, cap)
+            self.conn.account.reserve(self.key, own)
+        return ResidentPage([c.type for c in self.columns], list(cols),
+                            list(nulls), valid, list(self.dicts),
+                            rows=rows, nbytes=own,
+                            device=next(iter(valid.devices())))
 
 
 class MemoryMetadata(ConnectorMetadata):
@@ -71,16 +252,10 @@ class MemoryMetadata(ConnectorMetadata):
             return TableHandle(self.conn.catalog_name, schema, table)
         return None
 
-    def apply_filter(self, table: TableHandle, constraint):
-        """Row-level enforcement over the stored pages (reference:
-        ConnectorMetadata.applyFilter)."""
-        from .spi import negotiate_constraint
-
-        data = self.conn.tables.get((table.schema, table.table))
-        if data is None:
-            return None
-        return negotiate_constraint(table, constraint,
-                                    (c.name for c in data.columns))
+    # apply_filter: the SPI's default — declined.  The rows are on the
+    # device; the plan keeps its Filter, which fuses the predicate into
+    # the program that reads the page anyway (no launch of its own, no
+    # host pass over stored rows).
 
     def get_columns(self, table: TableHandle) -> List[ColumnHandle]:
         return self.conn.tables[(table.schema, table.table)].columns
@@ -95,14 +270,17 @@ class MemoryMetadata(ConnectorMetadata):
             if (schema, table) in self.conn.tables:
                 raise TrinoError(f"Table '{schema}.{table}' already exists",
                                  "TABLE_ALREADY_EXISTS")
-            self.conn.tables[(schema, table)] = _TableData(list(columns))
+            self.conn.tables[(schema, table)] = _TableData(
+                list(columns), self.conn, (schema, table))
             self.conn.schemas.add(schema)
             self.conn._version += 1      # DDL invalidates cached plans
         return TableHandle(self.conn.catalog_name, schema, table)
 
     def drop_table(self, table: TableHandle):
         with self.conn.lock:
-            self.conn.tables.pop((table.schema, table.table), None)
+            data = self.conn.tables.pop((table.schema, table.table), None)
+            if data is not None:
+                self.conn.account.release(data.key)
             self.conn._version += 1      # DDL invalidates cached plans
 
 
@@ -119,35 +297,192 @@ class MemorySplitManager(ConnectorSplitManager):
                 for i in range(k)]
 
 
+class ResidentPageSource(ConnectorPageSource):
+    """A split's pages as they lie on the device.  Column selection
+    picks arrays (no copy); a reader whose thread is pinned to another
+    device (``jax.default_device``) gets the page transferred there."""
+
+    provides_device_pages = True
+
+    def __init__(self, pages: Sequence[ResidentPage],
+                 ordinals: Sequence[int]):
+        self._pages = iter(pages)
+        self._ordinals = list(ordinals)
+        self._done = False
+
+    def get_next_device_page(self) -> Optional[ResidentPage]:
+        p = next(self._pages, None)
+        if p is None:
+            self._done = True
+            return None
+        import jax
+
+        from ..exec.memory import device_page_bytes
+
+        o = self._ordinals
+        page = ResidentPage([p.types[i] for i in o], [p.cols[i] for i in o],
+                            [p.nulls[i] for i in o], p.valid,
+                            [p.dictionaries[i] for i in o],
+                            rows=p.rows, device=p.device)
+        here = jax.config.jax_default_device
+        if here is not None and p.device is not None and here != p.device:
+            page.cols, page.nulls, page.valid = jax.device_put(
+                (page.cols, page.nulls, page.valid), here)
+            page.device = here
+        page.nbytes = device_page_bytes(page)
+        return page
+
+    def get_next_page(self) -> Optional[Page]:
+        """A host copy, for a caller that asks for one."""
+        page = self.get_next_device_page()
+        return None if page is None else page.to_page()
+
+    def is_finished(self) -> bool:
+        return self._done
+
+
 class MemoryPageSink(ConnectorPageSink):
+    """Writes pages into a table, on the device: live lanes are staged
+    in arrival order and cut into pages of ``page_rows`` lanes; only the
+    re-coding of string columns into the table's pool runs on the host.
+    A write of a single small page is stored as it came."""
+
+    accepts_device_pages = True
+
     def __init__(self, data: _TableData, conn: "MemoryConnector"):
         self.data = data
-        self.rows = 0
         self.conn = conn
+        self.rows = 0
+        self.host_recode_s = 0.0
+        self._written: List[ResidentPage] = []
+        self._held = None       # the first piece, until a second comes
+        self._stage = None      # 2 * page_rows lanes a column and mask
+        self._fill = 0
 
     def append_page(self, page: Page):
-        page = self.data.canonicalize(page)
+        self.append_device_page(DevicePage.from_page(page))
+
+    def append_device_page(self, page: DevicePage) -> int:
+        from ..telemetry.tracing import host_read
+
+        n, dense = (int(v) for v in host_read(
+            _kernels().live_prefix(page.valid), "table_write"))
+        if n == 0:
+            return 0
+        cols = self._recoded(page)
+        arrays = tuple(cols) + tuple(page.nulls)
+        if not dense:
+            arrays = _kernels().compact(arrays, page.valid)
+        if self._stage is None and self._held is None \
+                and n < self.conn.page_rows:
+            self._held = (arrays, n)
+        else:
+            self._stage_piece(arrays, n)
+        self.rows += n
+        return n
+
+    def _recoded(self, page: DevicePage) -> list:
+        """The page's columns, string codes moved into the table's pools
+        (the mapping is made on the host, applied on the device)."""
+        import jax.numpy as jnp
+
+        t0 = time.perf_counter()
+        cols = list(page.cols)
+        for i, source in enumerate(page.dictionaries):
+            remap = self.data.recode(i, source)
+            if remap is not None and len(remap):
+                table = np.zeros(padded_size(len(remap)), np.int32)
+                table[:len(remap)] = remap
+                cols[i] = jnp.take(jnp.asarray(table), cols[i],
+                                   mode="clip")
+        self.host_recode_s += time.perf_counter() - t0
+        return cols
+
+    def _stage_piece(self, arrays, n: int):
+        import jax.numpy as jnp
+
+        rows = self.conn.page_rows
+        if self._stage is None:
+            self._stage = tuple(jnp.zeros(2 * rows, a.dtype)
+                                for a in arrays)
+            held, self._held = self._held, None
+            if held is not None:
+                self._stage_piece(*held)
+        cap = int(arrays[0].shape[0])
+        for lo in range(0, n, rows):
+            piece = arrays if cap <= rows else \
+                tuple(a[lo:lo + rows] for a in arrays)
+            self._stage = _kernels().stage_in(self._stage, piece,
+                                              np.int32(self._fill))
+            self._fill += min(n - lo, rows)
+            if self._fill >= rows:
+                self._cut(self._stage, rows, rows)
+                self._stage = _kernels().shift(self._stage)
+                self._fill -= rows
+
+    def _cut(self, arrays, fill: int, cap: int):
+        """Store the first ``cap`` lanes of ``arrays`` (columns, then
+        their null masks), ``fill`` of them live, as a page."""
+        from ..telemetry.tracing import host_read
+
+        cols, nulls, valid, has_null = _kernels().cut(
+            arrays, np.int32(fill), cap)
+        self._store(cols, nulls, valid, fill,
+                    host_read(has_null, "table_write"))
+
+    def _store(self, cols, nulls, valid, rows, has_null):
+        page = self.data.stored(cols, nulls, valid, rows, has_null)
         with self.data.lock:
             self.data.pages.append(page)
-            self.rows += page.num_rows
+        self._written.append(page)
         # bump per page, not only at finish: a cached read overlapping a
         # half-complete write must already see a moved snapshot version
         self.conn.bump_version()
 
     def finish(self) -> dict:
-        return {"rows": self.rows}
+        if self._held is not None:
+            (arrays, n), self._held = self._held, None
+            self._cut(arrays, n, min(padded_size(n),
+                                     int(arrays[0].shape[0])))
+        elif self._fill:
+            self._cut(self._stage, self._fill, padded_size(self._fill))
+        self._stage, self._fill = None, 0
+        return {"rows": self.rows, "pages": len(self._written),
+                "device_bytes": sum(p.nbytes for p in self._written),
+                "host_recode_s": self.host_recode_s}
+
+    def abort(self):
+        """Take back what this sink wrote (a failed write leaves no
+        half table)."""
+        with self.data.lock:
+            mine = {id(p) for p in self._written}
+            self.data.pages = [p for p in self.data.pages
+                               if id(p) not in mine]
+        self._written, self._held, self._stage = [], None, None
+        self._fill = 0
+        self.conn.bump_version()
 
 
 class MemoryConnector(Connector):
     name = "memory"
 
     def __init__(self, catalog_name: str = "memory",
-                 schemas: Sequence[str] = ("default",)):
+                 schemas: Sequence[str] = ("default",),
+                 max_data_per_node: Optional[int] = None):
+        from ..exec.memory import TableMemoryAccount
+
         self.catalog_name = catalog_name
         self.schemas = set(schemas)
         self.tables: Dict[Tuple[str, str], _TableData] = {}
         self.lock = threading.Lock()
         self._version = 0
+        #: lanes of a stored page (a power of two; no setting: tests set
+        #: the attribute to cut small tables into several pages)
+        self.page_rows = PAGE_ROWS
+        #: Trino's ``memory.max-data-per-node``: the most device bytes
+        #: this connector's tables may hold; None is the node's memory
+        #: less what a query may take (``query_max_memory_bytes``)
+        self.account = TableMemoryAccount(max_data_per_node)
 
     def data_version(self) -> int:
         """Snapshot version for the plan/result caches: every DDL and
@@ -158,6 +493,11 @@ class MemoryConnector(Connector):
         with self.lock:
             self._version += 1
 
+    def resident_bytes_by_table(self) -> Dict[str, int]:
+        """Device bytes held, by ``schema.table``."""
+        return {f"{s}.{t}": n
+                for (s, t), n in self.account.by_table().items()}
+
     def metadata(self) -> ConnectorMetadata:
         return MemoryMetadata(self)
 
@@ -166,20 +506,17 @@ class MemoryConnector(Connector):
 
     def page_source(self, split: ConnectorSplit,
                     columns: Sequence[ColumnHandle]) -> ConnectorPageSource:
+        if split.table.constraint is not None:
+            # apply_filter declines, so no handle of this connector
+            # carries a constraint; one that does (a subclass accepted
+            # it) must not be answered with rows it rejects
+            raise TrinoError(
+                f"{split.table.qualified_name}: the memory connector "
+                "enforces no pushed-down constraint", "NOT_SUPPORTED")
         data = self.tables[(split.table.schema, split.table.table)]
         stride = (split.info or {}).get("stride", 1)
-        with data.lock:
-            mine = data.pages[split.row_start::stride] if data.pages else []
-        ordinals = [c.ordinal for c in columns]
-        cons = split.table.constraint
-        if cons is not None:
-            from .spi import enforce_constraint_page
-
-            names = [c.name for c in data.columns]
-            return FixedPageSource([
-                enforce_constraint_page(p, names, cons, ordinals)
-                for p in mine])
-        return FixedPageSource([p.select_channels(ordinals) for p in mine])
+        mine = data.resident()[split.row_start::stride]
+        return ResidentPageSource(mine, [c.ordinal for c in columns])
 
     def page_sink(self, table: TableHandle,
                   columns: Sequence[ColumnHandle]) -> ConnectorPageSink:

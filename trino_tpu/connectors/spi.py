@@ -7,8 +7,11 @@ load-bearing surface: metadata CRUD, split enumeration, page sources with
 column pruning + predicate pushdown hooks, page sinks for writes.
 
 TPU-first notes: page sources yield host ``Page``s (numpy + dictionaries);
-the scan operator moves them on device. Splits carry a deterministic
-row-range so distributed scans are reproducible regardless of split count.
+the scan operator moves them on device. A source whose table already
+lives on the device says so (``provides_device_pages``) and hands its
+pages out as they are (``get_next_device_page``). Splits carry a
+deterministic row-range so distributed scans are reproducible
+regardless of split count.
 """
 
 from __future__ import annotations
@@ -17,7 +20,7 @@ from dataclasses import dataclass, field
 from typing import Iterator, List, Optional, Sequence, Tuple
 
 from .. import types as T
-from ..block import Page
+from ..block import DevicePage, Page
 from ..predicate import TupleDomain
 
 
@@ -144,11 +147,31 @@ class ConnectorSplit:
     info: Optional[dict] = None
 
 
+@dataclass(eq=False)
+class ResidentPage(DevicePage):
+    """A page that lies on the device, with what the host knows of it
+    without asking the device: what a store keeps, and what a source
+    that ``provides_device_pages`` hands out (of the selected columns)."""
+
+    rows: int = 0           # live lanes
+    nbytes: int = 0         # device bytes it is accounted at
+    device: object = None   # where it lies
+
+
 class ConnectorPageSource:
     """Pull-based page iterator for one split (reference:
     spi/connector/ConnectorPageSource.java)."""
 
+    #: True where the table's pages live on the device: the scan then
+    #: pulls ``get_next_device_page`` and uploads nothing
+    provides_device_pages = False
+
     def get_next_page(self) -> Optional[Page]:
+        raise NotImplementedError
+
+    def get_next_device_page(self) -> Optional[ResidentPage]:
+        """The next page as it lies on the device, or None when the
+        source has none left; only where ``provides_device_pages``."""
         raise NotImplementedError
 
     def is_finished(self) -> bool:
@@ -225,7 +248,16 @@ class ConnectorSplitManager:
 class ConnectorPageSink:
     """Write path (reference: spi/connector/ConnectorPageSink.java)."""
 
+    #: True where the sink keeps pages on the device: the writer then
+    #: hands it the pipeline's ``DevicePage``s as they are
+    accepts_device_pages = False
+
     def append_page(self, page: Page):
+        raise NotImplementedError
+
+    def append_device_page(self, page: DevicePage) -> int:
+        """Write a device page's live rows; returns how many there
+        were.  Only where ``accepts_device_pages``."""
         raise NotImplementedError
 
     def finish(self) -> dict:

@@ -93,6 +93,11 @@ class OperatorStats:
                 pl = m["partial_lanes"]
                 base += (f" [partial lanes {pl['in']}->{pl['kept']} over "
                          f"{pl['pages']} pages, merge {pl['merge']}]")
+            if m.get("resident_pages"):
+                # a scan of a table that lives on the device: pages and
+                # bytes taken as they lay, nothing uploaded
+                base += (f" [resident {m['resident_pages']} pages, "
+                         f"{m['resident_bytes'] / 1e9:.2f} GB]")
             extras = " ".join(
                 f"{k}={m[k]}" for k in ("skew_ratio", "lane_skew_ratio",
                                         "per_dest", "a2a_retries",

@@ -635,6 +635,7 @@ class LocalExecutionPlanner:
         from ..ops.operator import TableWriterOperator
 
         ops, layout, types_ = self.visit(node.source)
+        undo = None
         if self.page_sink_factory is not None:
             sink = self.page_sink_factory(node)
         else:
@@ -648,11 +649,14 @@ class LocalExecutionPlanner:
                 # sibling won — use its table
                 handle = create_table_idempotent(
                     conn, node.schema, node.table_name, node.columns)
+
+                def undo(md=conn.metadata(), handle=handle):
+                    md.drop_table(handle)   # a failed CTAS: no half table
             else:
                 handle = conn.metadata().get_table_handle(node.schema,
                                                           node.table_name)
             sink = conn.page_sink(handle, node.columns)
-        ops.append(TableWriterOperator(sink))
+        ops.append(TableWriterOperator(sink, undo))
         return ops, {node.rows_symbol.name: 0}, [T.BIGINT]
 
     def _v_RemoteSourceNode(self, node):

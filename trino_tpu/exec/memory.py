@@ -80,6 +80,22 @@ class NodeMemoryExceededError(TrinoError):
         self.limit = limit
 
 
+class TableMemoryExceededError(TrinoError):
+    """A write would take a connector's resident tables past its
+    ``max_data_per_node`` (reference: ``MemoryPagesStore`` raising
+    MEMORY_LIMIT_EXCEEDED)."""
+
+    def __init__(self, requested: int, reserved: int, limit: int):
+        super().__init__(
+            f"Memory limit [{limit}] for memory connector exceeded "
+            f"(max_data_per_node; its tables hold {reserved} bytes, the "
+            f"write asked for {requested} more)",
+            "MEMORY_LIMIT_EXCEEDED")
+        self.requested = requested
+        self.reserved = reserved
+        self.limit = limit
+
+
 def default_node_memory_bytes(fallback: int = 16 << 30) -> int:
     """Auto default for ``node_max_memory_bytes``: the accelerator's
     own reported capacity (``Device.memory_stats()['bytes_limit']`` on
@@ -99,6 +115,82 @@ def default_node_memory_bytes(fallback: int = 16 << 30) -> int:
     raise RuntimeError(
         f"{dev.platform} device {dev.device_kind!r} reports no "
         f"bytes_limit in memory_stats(); cannot size the node pool")
+
+
+#: every live account of resident tables in this process (the node)
+_TABLE_ACCOUNTS: "weakref.WeakSet" = weakref.WeakSet()
+
+
+def resident_table_bytes() -> int:
+    """Device bytes the node's connectors hold in tables right now."""
+    return sum(a.reserved for a in list(_TABLE_ACCOUNTS))
+
+
+class TableMemoryAccount:
+    """Device bytes a connector holds in tables, by table: the first
+    tenant of a worker's memory, queries get what is left (reference:
+    ``memory.max-data-per-node``).  A reservation outlives the query
+    that wrote the table and falls when the table is dropped; one past
+    the limit fails, it never spills or evicts.  On a worker the bytes
+    are charged to its ``NodeMemoryPool`` too (``attach``), so
+    concurrent queries are admitted against what the tables leave; a
+    runner without one caps each query's pool the same way
+    (``pool_from_session``)."""
+
+    def __init__(self, max_bytes: Optional[int] = None):
+        self._max_bytes = max_bytes
+        self.node_pool: Optional["NodeMemoryPool"] = None
+        self._by_table: Dict[tuple, int] = {}
+        self._lock = threading.Lock()
+        _TABLE_ACCOUNTS.add(self)
+
+    @property
+    def max_bytes(self) -> int:
+        if self._max_bytes is None:
+            # the node's memory less what one query may take by default
+            from .. import session_properties as SP
+
+            self._max_bytes = max(
+                default_node_memory_bytes()
+                - SP.REGISTRY["query_max_memory_bytes"].default, 0)
+        return self._max_bytes
+
+    @property
+    def reserved(self) -> int:
+        return sum(self._by_table.values())
+
+    def by_table(self) -> Dict[tuple, int]:
+        with self._lock:
+            return dict(self._by_table)
+
+    def attach(self, pool: "NodeMemoryPool"):
+        """Charge ``pool`` for what this account holds, now and from
+        now on."""
+        with self._lock:
+            self.node_pool = pool
+            pool.charge_tables(sum(self._by_table.values()))
+
+    def reserve(self, table: tuple, nbytes: int):
+        limit = self.max_bytes
+        with self._lock:
+            held = sum(self._by_table.values())
+            if held + nbytes > limit:
+                raise TableMemoryExceededError(nbytes, held, limit)
+            if self.node_pool is not None:
+                self.node_pool.charge_tables(nbytes)    # may refuse
+            self._by_table[table] = self._by_table.get(table, 0) + nbytes
+
+    def release(self, table: tuple, nbytes: Optional[int] = None):
+        """Give back ``nbytes`` of a table's reservation, or all of it."""
+        with self._lock:
+            held = self._by_table.get(table, 0)
+            freed = held if nbytes is None else min(nbytes, held)
+            if held - freed:
+                self._by_table[table] = held - freed
+            else:
+                self._by_table.pop(table, None)
+            if freed and self.node_pool is not None:
+                self.node_pool.charge_tables(-freed)
 
 
 def device_page_bytes(page) -> int:
@@ -770,6 +862,8 @@ class NodeMemoryPool:
                  host_spill_limit: Optional[int] = None):
         self.max_bytes = int(max_bytes)
         self.reserved = 0
+        #: of ``reserved``, what resident tables hold
+        self.table_bytes = 0
         self.peak_bytes = 0
         self.blocked_events = 0
         self.cross_query_revokes = 0
@@ -863,6 +957,19 @@ class NodeMemoryPool:
         self.reserved += nbytes
         self.peak_bytes = max(self.peak_bytes, self.reserved)
 
+    def charge_tables(self, nbytes: int):
+        """Resident tables' bytes (a ``TableMemoryAccount``; negative
+        to give back): they hold their share of the node whatever the
+        queries do.  They are never revoked and revoke nothing: a table
+        the node has no room for is refused."""
+        with self._lock:
+            if nbytes > 0 and self.reserved + nbytes > self.max_bytes:
+                raise NodeMemoryExceededError(
+                    nbytes, self.reserved, self.max_bytes,
+                    "resident tables")
+            self.table_bytes += nbytes
+            self._admit_locked(nbytes)
+
     def uncharge_for(self, child: QueryMemoryPool, nbytes: int):
         with self._lock:
             self.reserved -= min(self.reserved, nbytes)
@@ -886,6 +993,7 @@ class NodeMemoryPool:
             return {
                 "max_bytes": self.max_bytes,
                 "reserved_bytes": self.reserved,
+                "table_bytes": self.table_bytes,
                 "peak_bytes": self.peak_bytes,
                 "blocked_events": blocked,
                 "cross_query_revokes": self.cross_query_revokes,
@@ -903,8 +1011,16 @@ def pool_from_session(session, parent: NodeMemoryPool = None,
             query_id, SP.value(session, "query_max_memory_bytes"),
             SP.value(session, "spill_enabled"),
             SP.value(session, "spill_to_disk_enabled"))
+    limit = SP.value(session, "query_max_memory_bytes")
+    tables = resident_table_bytes()
+    if tables:
+        # no node pool to charge: the node's resident tables come off
+        # what a query may take all the same
+        node = SP.value(session, "node_max_memory_bytes") \
+            or default_node_memory_bytes()
+        limit = min(limit, max(node - tables, 0))
     return QueryMemoryPool(
-        SP.value(session, "query_max_memory_bytes"),
+        limit,
         SP.value(session, "spill_enabled"),
         SP.value(session, "spill_to_disk_enabled"),
         host_spill_limit=SP.value(session, "spill_host_memory_bytes"),

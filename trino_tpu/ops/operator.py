@@ -12,6 +12,7 @@ output (device -> numpy).
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import List, Optional, Sequence
 
@@ -76,7 +77,11 @@ class TableScanOperator(SourceOperator):
     ``coalesce_rows`` before the upload, so downstream kernels see one
     full device batch instead of one launch per fragment (reference:
     ``operator/MergePages.java`` — the min-page-size rewindow in front
-    of expensive operators)."""
+    of expensive operators).
+
+    A source whose table lives on the device
+    (``provides_device_pages``) is not uploaded from: its pages pass
+    through as they lie, dynamic filters applied, nothing buffered."""
 
     def __init__(self, connector: Connector, columns: Sequence[ColumnHandle],
                  dynamic_filters: Sequence = (),
@@ -102,7 +107,8 @@ class TableScanOperator(SourceOperator):
         #: seconds in the connector's page generation and the coalescing
         #: concat, seconds of the host-to-device upload — the host's
         #: share of a scan, which overlaps device work and so shows in
-        #: no idle gap
+        #: no idle gap; and, from page metadata, the pages and bytes
+        #: taken as they lay on the device against the bytes uploaded
         self._counters: Optional[dict] = None
         self._counters_known = False
 
@@ -134,12 +140,35 @@ class TableScanOperator(SourceOperator):
 
     def _upload_page(self, page: Page) -> DevicePage:
         dp = DevicePage.from_page(page)
+        if self._counters is not None:
+            from ..exec.memory import device_page_bytes
+
+            self._counters["uploaded_bytes"] += device_page_bytes(dp)
+        return self._filtered(dp)
+
+    def _filtered(self, dp: DevicePage) -> DevicePage:
         for ch, df in self.dynamic_filters:
             dp = DevicePage(dp.types, dp.cols, dp.nulls,
                             df.apply(dp.cols[ch], dp.nulls[ch],
                                      dp.valid),
                             dp.dictionaries)
         return dp
+
+    def _next_resident(self) -> Optional[DevicePage]:
+        """The source's next page that holds a row, as it lies on the
+        device; row and byte counts come from its metadata (no sync)."""
+        while True:
+            page = self._source.get_next_device_page()
+            if page is None:
+                return None
+            if page.rows == 0:
+                continue
+            if self.progress is not None:
+                self.progress.add_rows(page.rows)
+            if self._counters is not None:
+                self._counters["resident_pages"] += 1
+                self._counters["resident_bytes"] += page.nbytes
+            return self._filtered(page)
 
     def _flush(self) -> DevicePage:
         pages, self._buffer = self._buffer, []
@@ -154,7 +183,9 @@ class TableScanOperator(SourceOperator):
             # first call: the statement's span, if any, is current now
             self._counters_known = True
             if tracing.current_span() is not None:
-                self._counters = {"generate_s": 0.0, "upload_s": 0.0}
+                self._counters = {"generate_s": 0.0, "upload_s": 0.0,
+                                  "resident_pages": 0, "resident_bytes": 0,
+                                  "uploaded_bytes": 0}
         while True:
             if self._source is None:
                 if self._splits:
@@ -168,6 +199,13 @@ class TableScanOperator(SourceOperator):
                     return None
                 else:
                     return self._flush() if self._buffer else None
+            if self._source.provides_device_pages:
+                dp = self._next_resident()
+                if dp is not None:
+                    return dp
+                self._source.close()
+                self._source = None
+                continue
             page = self._timed("scan.generate", "generate_s",
                                self._source.get_next_page)
             if page is None:
@@ -445,26 +483,61 @@ class DeferredPagesSourceOperator(SourceOperator):
 class TableWriterOperator(Operator):
     """Feeds pages to a ConnectorPageSink; at finish emits one row with
     the written count (reference: operator/TableWriterOperator.java +
-    TableFinishOperator.java — commit folded into sink.finish())."""
+    TableFinishOperator.java — commit folded into sink.finish()).
 
-    def __init__(self, sink):
+    A sink that keeps its pages on the device
+    (``accepts_device_pages``) is handed the pipeline's pages as they
+    are; any other gets host pages.  A write that fails is taken back
+    (``sink.abort()``, then ``undo``: the CTAS target is dropped).  The
+    write is the statement's ``table_write`` span, with what the sink's
+    ``finish()`` reports."""
+
+    def __init__(self, sink, undo=None):
         self.sink = sink
+        self.undo = undo
         self.rows = 0
+        self._span = None
         self._emitted = False
         self._done = False
 
     def add_input(self, page: DevicePage):
-        host = page.to_page()
-        if host.num_rows:
-            self.rows += host.num_rows
-            self.sink.append_page(host)
+        if self._span is None:
+            self._span = tracing.span("table_write")
+        with self._taken_back_on_failure():
+            if self.sink.accepts_device_pages:
+                self.rows += self.sink.append_device_page(page)
+                return
+            host = page.to_page()
+            if host.num_rows:
+                self.rows += host.num_rows
+                self.sink.append_page(host)
+
+    @contextlib.contextmanager
+    def _taken_back_on_failure(self):
+        """Around every call of the sink that can store a page (the
+        tail page is stored by ``finish()``)."""
+        try:
+            yield
+        except Exception:
+            self.sink.abort()
+            if self.undo is not None:
+                self.undo()
+            if self._span:
+                self._span.finish()
+            raise
 
     def get_output(self) -> Optional[DevicePage]:
         if not self._finishing or self._emitted:
             return None
         self._emitted = True
         self._done = True
-        self.sink.finish()
+        with self._taken_back_on_failure():
+            written = self.sink.finish()
+        if self._span:
+            for key in ("rows", "pages", "device_bytes", "host_recode_s"):
+                if key in (written or {}):
+                    self._span.set(key, written[key])
+            self._span.finish()
         from .. import types as T
 
         return DevicePage.from_page(

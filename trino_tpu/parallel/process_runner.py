@@ -668,8 +668,7 @@ class ProcessQueryRunner:
                         w.alive = False
             return
         data = conn.tables[(schema, table)]
-        with data.lock:
-            pages = list(data.pages)
+        pages = data.host_pages()
         for w in self._worker_snapshot():
             if not w.alive:
                 continue
@@ -707,8 +706,7 @@ class ProcessQueryRunner:
         for catalog in sorted(self._replicated):
             conn = self.connectors[catalog]
             for (schema, table), data in list(conn.tables.items()):
-                with data.lock:
-                    pages = list(data.pages)
+                pages = data.host_pages()
                 self._sync_worker_table(w, catalog, schema, table,
                                         data.columns, pages, full=True)
 

@@ -21,6 +21,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from ..connectors.spi import ConnectorPageSink
 from ..exec.serde import PageDeserializer, PageSerializer
 
 
@@ -391,7 +392,7 @@ class RemoteExchangeChannel:
         return out
 
 
-class RemotePageSink:
+class RemotePageSink(ConnectorPageSink):
     """Worker-side write sink that ships written pages to the
     coordinator's catalog over RPC (reference: the page-sink half of
     ``operator/TableWriterOperator.java`` against a remote metastore —

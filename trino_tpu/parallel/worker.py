@@ -195,6 +195,10 @@ class WorkerServer:
                 or default_node_memory_bytes(),
                 host_spill_limit=SP.prop_value(
                     self.properties, "spill_host_memory_bytes"))
+            for conn in self.connectors.values():
+                # resident tables are the node pool's first tenant
+                if getattr(conn, "account", None) is not None:
+                    conn.account.attach(self.node_pool)
             seeded = 0
             if req.get("hbo_seed"):
                 # coordinator history piggybacks on configure: worker-

@@ -1180,12 +1180,17 @@ class LocalQueryRunner:
         lines = plan_tree_str(root).splitlines()
         lines.append("")
         lines.append(f"Query: {wall * 1e3:.1f}ms, {out_rows} rows")
+        from .exec.memory import resident_table_bytes
+
+        # what the node's resident tables hold beside this query
+        tables = resident_table_bytes()
         lines.append(
             f"Memory: peak {m['peak_bytes']} bytes, "
             f"{m['spill_events']} spills ({m['spilled_bytes']} bytes)"
             + (f", disk {m['disk_spill_events']} files "
                f"({m['disk_spilled_bytes']} bytes)"
-               if m.get("disk_spill_events") is not None else ""))
+               if m.get("disk_spill_events") is not None else "")
+            + (f", resident tables {tables} bytes" if tables else ""))
         for i, d in enumerate(plan.drivers):
             d.collect_operator_metrics()
             lines.append(f"Pipeline {i}:")
@@ -1329,9 +1334,8 @@ class LocalQueryRunner:
             where=keep))
         root = self.plan_statement(ast.QueryStatement(query))
         plan = self._make_local_planner().plan(root)
-        res_pages = [data.canonicalize(p) for p in plan.execute()]
-        with data.lock:
-            data.pages = res_pages
+        res_pages = plan.execute()
+        data.replace(res_pages)   # re-coded and put on the device
         conn.bump_version()       # cached plans/results over t are stale
         return QueryResult(["rows"], [T.BIGINT],
                            [(before - sum(p.num_rows
