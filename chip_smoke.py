@@ -52,6 +52,15 @@ GROUPED_SQL = (
     "from lineitem group by 1, 2")
 
 
+#: the same int32 states over more groups than a page reduces densely
+#: (``ops/hashtable.DENSE_GROUPS``): 1,024 at SF1 and on ``tiny``, so
+#: every page takes the scatter branch, whose int32 states are the
+#: Pallas kernel's on a TPU
+MANY_GROUPS_SQL = (
+    "select l_partkey % 1024, min(l_shipdate), max(l_shipdate), count(*) "
+    "from lineitem group by 1")
+
+
 def say(**doc):
     print(json.dumps(doc), flush=True)
 
@@ -182,6 +191,37 @@ def host_group_reference(conn, schema):
                   for (f, s), (lo, hi, m, n) in groups.items())
 
 
+def host_many_groups_reference(conn, schema):
+    """``MANY_GROUPS_SQL`` by plain numpy over the connector's host
+    pages: no JAX, no engine operator."""
+    import datetime
+
+    import numpy as np
+
+    meta = conn.metadata()
+    handle = meta.get_table_handle(schema, "lineitem")
+    cols = [c for n in ("l_partkey", "l_shipdate")
+            for c in meta.get_columns(handle) if c.name == n]
+    lo = np.full(1024, np.iinfo(np.int64).max)
+    hi = np.full(1024, np.iinfo(np.int64).min)
+    cnt = np.zeros(1024, dtype=np.int64)
+    for split in conn.split_manager().get_splits(handle, 1):
+        src = conn.page_source(split, cols)
+        while (page := src.get_next_page()) is not None:
+            part, ship = (b.numpy().data for b in page.blocks)
+            key = part % 1024
+            np.minimum.at(lo, key, ship)
+            np.maximum.at(hi, key, ship)
+            cnt += np.bincount(key, minlength=1024)
+    epoch = datetime.date(1970, 1, 1)
+
+    def iso(days):
+        return (epoch + datetime.timedelta(days=int(days))).isoformat()
+
+    return sorted((int(k), iso(lo[k]), iso(hi[k]), int(cnt[k]))
+                  for k in np.flatnonzero(cnt))
+
+
 def grouped_phase(client, conn, schema, counter):
     """The int32-state grouped query: checked against the host
     reference, and the kernel's use asserted for the backend in use."""
@@ -199,6 +239,18 @@ def grouped_phase(client, conn, schema, counter):
     check(got == want, f"grouped query: engine={got}\nhost={want}")
     query_line("grouped_int32_states", res, runs, paths)
 
+    # that query's four groups reduce densely; this one's pages have too
+    # many, so the scatter branch — the kernel on a TPU — executes
+    paths = grouping_path_totals()
+    res, runs = timed_twice(client, MANY_GROUPS_SQL, counter)
+    got = sorted(tuple(r) for r in res.rows)
+    want = host_many_groups_reference(conn, schema)
+    check(got == want, "many-group query differs from the host reference: "
+          f"{[p for p in zip(got, want) if p[0] != p[1]][:3]}")
+    query_line("many_groups_int32_states", res, runs, paths)
+    dense = grouping_path_totals()["dense"] - paths["dense"]
+    check(dense == 0, f"{dense} many-group pages reduced densely")
+
     # a profiled run keeps each compiled program: look for the kernel
     profiler.reset()
     with profiler.profiling(True):
@@ -210,7 +262,8 @@ def grouped_phase(client, conn, schema, counter):
     say(phase="pallas_segment_reduce", pallas_mode=mode or "off",
         kernel_calls_traced=kernel_calls,
         programs_with_custom_call=with_kernel,
-        note=("TPU-only sort + Pallas kernel branch ran on the chip"
+        note=("TPU-only sort + Pallas kernel branch ran on the chip "
+              "(in the many-group query's scatter branch)"
               if mode == "tpu" else
               "kernel NOT in the chip path: this backend is not a TPU"))
     if mode == "tpu":

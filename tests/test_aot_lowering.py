@@ -153,7 +153,22 @@ def test_hash_grouping_compiles_for_v5e(one_chip):
     i32 = sds((PAGE,), jnp.int32)
     compiled = _compile(reduce, one_chip, i32, i32, sds((), jnp.int32),
                         key_raws, (valid,) * 2, states)
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    # one program, both reductions: the scatter branch keeps the kernel,
+    # the page's group count picks the branch on the device
+    assert "tpu_custom_call" in text and "conditional(" in text
+
+
+def test_keyless_group_ids_compile_for_v5e_without_a_table(one_chip):
+    """A global aggregate's group ids at a resident page's width: no
+    probe loop and no scatter into a table."""
+    from trino_tpu.ops.hashtable import hash_group_ids
+
+    lanes = 1 << 18
+    compiled = _compile(lambda v: hash_group_ids.jit((), v, exact=True),
+                        one_chip, sds((lanes,), jnp.bool_))
+    text = compiled.as_text()
+    assert "while(" not in text and "scatter(" not in text
 
 
 def test_sort_group_reduce_compiles_for_v5e(one_chip):

@@ -43,7 +43,13 @@ def test_chip_smoke_cpu_rehearsal_at_tiny():
     assert '"platform": "tpu"' not in proc.stdout
     served = [l["query"] for l in lines if "query" in l]
     assert served == ["q6", "q1", "q3", "q18", "q13",
-                      "grouped_int32_states"]
+                      "grouped_int32_states", "many_groups_int32_states"]
+    # four groups reduce densely; 1,024 a page take the scatter branch
+    paths = {l["query"]: l["grouping_path_counts"] for l in lines
+             if "query" in l}
+    assert paths["grouped_int32_states"]["dense"] == \
+        paths["grouped_int32_states"]["hash"]
+    assert "dense" not in paths["many_groups_int32_states"]
 
 
 def test_compile_cache_dir_is_placed_from_outside(monkeypatch, tmp_path):

@@ -50,6 +50,53 @@ def test_agg_same_shape_pages_do_not_retrace(hash_grouping):
     assert op.get_output() is not None
 
 
+def test_pages_on_either_side_of_the_dense_limit_share_one_program():
+    """The reduce picks its branch on the device from the page's group
+    count: a few-group page and a many-group page of one shape are one
+    trace of each grouping program (the ``cond`` adds no cache key)."""
+    from trino_tpu.ops.hashtable import DENSE_GROUPS
+
+    rng = np.random.default_rng(4)
+    types, few = _page(rng, 1000, nkeys=3)
+    op = HashAggregationOperator(types, [0], AGGS, "single")
+    op.add_input(few)
+    names = ("hash_group_ids", "hash_segment_reduce")
+    before = jit_stats.total_for(*names)
+    for nkeys in (4 * DENSE_GROUPS, 2, 10**6):
+        _, page = _page(rng, 1000, nkeys=nkeys)
+        op.add_input(page)
+    assert jit_stats.total_for(*names) == before, jit_stats.counts()
+    assert op.path_counts["hash"] == 4 and op.path_counts["dense"] == 2
+    op.finish()
+    assert op.get_output() is not None
+
+
+def test_keyless_aggregate_traces_its_own_entry_once():
+    """A global aggregate builds no table: its group ids trace under the
+    name ``keyless_group_ids``, once per page shape, and never touch the
+    keyed entry's counter."""
+    rng = np.random.default_rng(5)
+    types, warm = _page(rng, 1000)
+
+    def fresh():
+        return HashAggregationOperator(types, [], AGGS, "single")
+
+    op = fresh()
+    op.add_input(warm)
+    assert jit_stats.counts().get("keyless_group_ids", 0) >= 1
+    before = jit_stats.counts()
+    for op in (op, fresh()):
+        for _ in range(3):
+            _, page = _page(rng, 1000)
+            op.add_input(page)
+    after = jit_stats.counts()
+    for name in ("keyless_group_ids", "hash_group_ids",
+                 "hash_segment_reduce"):
+        assert after.get(name, 0) == before.get(name, 0), name
+    op.finish()
+    assert op.get_output() is not None
+
+
 def test_partial_passthrough_does_not_retrace():
     """The adaptive pass-through layout conversion is sort/jit-free; it
     must add zero traces once tripped."""
@@ -103,8 +150,9 @@ def test_query_repeat_keeps_kernel_traces_flat():
         return op.get_output()
 
     run_once()  # warmup
-    grouping = ("hash_group_ids", "hash_segment_reduce",
-                "sort_group_reduce", "segment_reduce_pallas")
+    grouping = ("hash_group_ids", "keyless_group_ids",
+                "hash_segment_reduce", "sort_group_reduce",
+                "segment_reduce_pallas")
     before = {k: v for k, v in jit_stats.counts().items() if k in grouping}
     run_once()
     after = {k: v for k, v in jit_stats.counts().items() if k in grouping}

@@ -16,7 +16,9 @@ from trino_tpu import types as T
 from trino_tpu.block import DevicePage, Dictionary, Page, padded_size
 from trino_tpu.ops.aggregation import AggCall, HashAggregationOperator, \
     resolve_agg_type
-from trino_tpu.ops.hashtable import hash_group_ids, hashable_key_types
+from trino_tpu.ops.hashtable import (DENSE_GROUPS, _hash_segment_reduce_impl,
+                                     hash_group_ids, hash_segment_reduce,
+                                     hashable_key_types)
 from trino_tpu.ops.sortkeys import group_operands
 
 
@@ -106,6 +108,154 @@ def test_hashable_key_types_gate():
     assert not hashable_key_types([T.BIGINT, T.DOUBLE])
     assert not hashable_key_types([T.REAL])
     assert hashable_key_types([])
+
+
+@pytest.mark.parametrize("live", ["some", "all", "none"])
+def test_keyless_gids_in_closed_form(live):
+    """No key columns: every valid row is group 0, the first valid row
+    represents it, an empty page has no group — the keyed contract
+    without a table."""
+    cap = 64
+    valid = {"some": np.arange(cap) % 5 == 3, "all": np.ones(cap, bool),
+             "none": np.zeros(cap, bool)}[live]
+    gid, group_rows, ngroups, overflow = hash_group_ids(
+        (), jnp.asarray(valid))
+    assert not bool(overflow)
+    assert int(ngroups) == int(valid.any())
+    assert gid.dtype == jnp.int32 and group_rows.dtype == jnp.int32
+    assert np.asarray(gid).tolist() == np.where(valid, 0, cap).tolist()
+    first = int(np.argmax(valid)) if valid.any() else 0
+    assert np.asarray(group_rows).tolist() == [first] + [0] * (cap - 1)
+
+
+_SEGMENT_OPS = {"sum": "segment_sum", "min": "segment_min",
+                "max": "segment_max"}
+
+
+def _gid_page(rng, cap, ngroups, dtype):
+    """A page of ``cap`` lanes with exactly ``ngroups`` groups, a fifth
+    of its lanes invalid (gid == cap); none valid when ``ngroups`` is 0."""
+    if ngroups == 0:
+        valid = np.zeros(cap, dtype=bool)
+        gid = np.full(cap, cap)
+    else:
+        valid = rng.random(cap) < 0.8
+        gid = rng.integers(0, ngroups, size=cap)
+        at = rng.permutation(cap)[:ngroups]
+        gid[at], valid[at] = np.arange(ngroups), True
+        gid = np.where(valid, gid, cap)
+    col = rng.integers(-10**6, 10**6, size=cap).astype(dtype)
+    return jnp.asarray(gid.astype(np.int32)), jnp.asarray(col)
+
+
+@pytest.mark.parametrize("dtype", [np.int64, np.int32])
+@pytest.mark.parametrize("kind", ["sum", "min", "max"])
+@pytest.mark.parametrize("ngroups", [0, 1, 4, DENSE_GROUPS,
+                                     DENSE_GROUPS + 1, 4 * DENSE_GROUPS])
+def test_segment_reduce_branches_equal_segment_ops(ngroups, kind, dtype):
+    """Both branches of the reduce and the boundary between them: the
+    states equal ``jax.ops.segment_*`` lane for lane — groups, empty
+    lanes (the kind's identity) and the all-invalid page."""
+    import jax
+
+    cap = 8 * DENSE_GROUPS
+    rng = np.random.default_rng(ngroups * 7 + len(kind))
+    gid, col = _gid_page(rng, cap, ngroups, dtype)
+    key = jnp.arange(cap, dtype=jnp.int64) * 3
+    rows = jnp.asarray(rng.permutation(cap).astype(np.int32))
+    key_null = jnp.asarray(rng.random(cap) < 0.3)
+    keys, key_nulls, (got,), out_valid = hash_segment_reduce(
+        gid, rows, jnp.int32(ngroups), (key,), (key_null,), (col,), (kind,))
+    want = getattr(jax.ops, _SEGMENT_OPS[kind])(
+        col, gid, num_segments=cap + 1)[:cap]
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    live = np.arange(cap) < ngroups
+    assert np.array_equal(np.asarray(out_valid), live)
+    # group keys: the representative row's, row 0's past the groups
+    at = np.where(live, np.asarray(rows), 0)
+    assert np.array_equal(np.asarray(keys[0]), np.asarray(key)[at])
+    assert np.array_equal(np.asarray(key_nulls[0]),
+                          np.asarray(key_null)[at] & live)
+
+
+def _reduce_jaxpr(*args):
+    import jax
+
+    return str(jax.make_jaxpr(
+        lambda *a: _hash_segment_reduce_impl(*a[:-1], kinds=a[-1]),
+        static_argnums=6)(*args))
+
+
+def test_segment_reduce_is_dense_by_shape_on_a_narrow_page():
+    """A page no wider than the limit cannot have more groups than it:
+    no ``cond`` is traced, and the states still equal the scatter's."""
+    import jax
+
+    cap = DENSE_GROUPS // 2
+    rng = np.random.default_rng(9)
+    gid, col = _gid_page(rng, cap, cap, np.int64)
+    none = jnp.zeros(cap, dtype=bool)
+    args = (gid, jnp.zeros(cap, jnp.int32), jnp.int32(cap), (gid,),
+            (none,), (col, col), ("sum", "max"))
+    jaxpr = _reduce_jaxpr(*args)
+    assert "cond" not in jaxpr and "scatter" not in jaxpr
+    got = hash_segment_reduce(*args)[2]
+    assert np.array_equal(got[0], jax.ops.segment_sum(
+        col, gid, num_segments=cap + 1)[:cap])
+    assert np.array_equal(got[1], jax.ops.segment_max(
+        col, gid, num_segments=cap + 1)[:cap])
+
+
+@pytest.mark.parametrize("ngroups", [0, 1])
+def test_keyless_reduce_is_one_masked_reduction_with_no_branch(ngroups):
+    """No key column, so one group at most, known at trace time: no
+    ``cond``, no scatter, whatever the page's width; lane 0 holds the
+    reduction, the other lanes the kind's identity."""
+    import jax
+
+    cap = 8 * DENSE_GROUPS
+    rng = np.random.default_rng(ngroups)
+    gid, col = _gid_page(rng, cap, ngroups, np.int64)
+    args = (gid, jnp.zeros(cap, jnp.int32), jnp.int32(ngroups), (), (),
+            (col, col, col), ("sum", "min", "max"))
+    jaxpr = _reduce_jaxpr(*args)
+    assert "cond" not in jaxpr and "scatter" not in jaxpr
+    keys, key_nulls, got, out_valid = hash_segment_reduce(*args)
+    assert keys == () and key_nulls == ()
+    assert int(np.asarray(out_valid).sum()) == ngroups
+    for kind, r in zip(("sum", "min", "max"), got):
+        want = getattr(jax.ops, _SEGMENT_OPS[kind])(
+            col, gid, num_segments=cap + 1)[:cap]
+        assert np.array_equal(np.asarray(r), np.asarray(want)), kind
+
+
+def test_segment_reduce_under_vmap_with_lanes_on_both_sides():
+    """The admission batcher's ``jit(vmap(lane))``: the batched group
+    count turns the ``cond`` into a select, and a batch holding a
+    few-group lane and a many-group lane answers both as the scatter."""
+    import jax
+
+    cap = 8 * DENSE_GROUPS
+    rng = np.random.default_rng(13)
+    counts = [3, DENSE_GROUPS + 5, DENSE_GROUPS, 0]
+    pages = [_gid_page(rng, cap, n, np.int64) for n in counts]
+    gids = jnp.stack([g for g, _ in pages])
+    cols = jnp.stack([c for _, c in pages])
+    rows = jnp.zeros((len(counts), cap), dtype=jnp.int32)
+
+    def lane(gid, group_rows, ngroups, col):
+        return _hash_segment_reduce_impl(
+            gid, group_rows, ngroups, (col,), (col < 0,), (col, col),
+            ("sum", "min"), pallas="")[2]
+
+    sums, mins = jax.jit(jax.vmap(lane))(
+        gids, rows, jnp.asarray(counts, dtype=jnp.int32), cols)
+    for i, (gid, col) in enumerate(pages):
+        assert np.array_equal(sums[i], jax.ops.segment_sum(
+            col, gid, num_segments=cap + 1)[:cap]), counts[i]
+        assert np.array_equal(mins[i], jax.ops.segment_min(
+            col, gid, num_segments=cap + 1)[:cap]), counts[i]
 
 
 # ---------------------------------------------------------- operator oracle
@@ -259,6 +409,70 @@ def test_overflow_falls_back_to_sort_oracle(monkeypatch):
     got = _run_single(AGG_TYPES, cols, [0], AGG_SUITE, True, 256)
     monkeypatch.undo()
     want = _run_single(AGG_TYPES, cols, [0], AGG_SUITE, False, 256)
+    _assert_rows_equal(got, want)
+
+
+def _paged_op(types, group, aggs, pages, hash_grouping):
+    """A ``single`` aggregation fed ``pages`` of (columns, live mask or
+    None); returns the operator, not yet drained."""
+    op = HashAggregationOperator(types, group, aggs, "single",
+                                 hash_grouping=hash_grouping)
+    for columns, live in pages:
+        dp = DevicePage.from_page(Page.from_pylists(types, columns))
+        if live is not None:
+            mask = np.zeros(dp.capacity, dtype=bool)
+            mask[:len(live)] = live
+            dp = DevicePage(dp.types, dp.cols, dp.nulls,
+                            jnp.asarray(mask), dp.dictionaries)
+        op.add_input(dp)
+    return op
+
+
+def test_keyless_aggregate_reduces_densely_and_equals_sort_path():
+    """A global aggregate over several pages, one of them empty: every
+    page and the merge count as ``dense`` (they are ``hash`` pages whose
+    states took no scatter) and the answer is the sort path's."""
+    rng = np.random.default_rng(29)
+    types = [T.BIGINT, T.REAL]
+    aggs = [AggCall("count_star", None, None, T.BIGINT),
+            AggCall("sum", 0, T.BIGINT, resolve_agg_type("sum", T.BIGINT)),
+            AggCall("min", 0, T.BIGINT, T.BIGINT),
+            AggCall("max", 1, T.REAL, T.REAL)]
+
+    def columns(n):
+        return [[int(v) if rng.random() > 0.1 else None
+                 for v in rng.integers(-500, 500, size=n)],
+                [float(np.float32(v)) for v in rng.normal(size=n)]]
+
+    pages = [(columns(300), None), (columns(200), np.zeros(200, bool)),
+             (columns(700), None), (columns(90), None)]
+    op = _paged_op(types, [], aggs, pages, True)
+    paths = op.metrics()["grouping_paths"]
+    assert paths == {"hash": len(pages), "dense": len(pages)}
+    got = _drain(op)
+    assert op.metrics()["grouping_paths"] == {
+        "hash": len(pages) + 1, "dense": len(pages) + 1}
+    want = _drain(_paged_op(types, [], aggs, pages, False))
+    assert len(got) == 1
+    _assert_rows_equal(got, want)
+
+
+def test_many_group_stream_reports_no_dense_page():
+    """More groups than the limit on every page and in the merge: the
+    scatter branch, and ``grouping_paths`` has no ``dense`` entry."""
+    rng = np.random.default_rng(31)
+    types = [T.BIGINT, T.BIGINT]
+    aggs = [AggCall("sum", 1, T.BIGINT, resolve_agg_type("sum", T.BIGINT)),
+            AggCall("count_star", None, None, T.BIGINT)]
+    ngroups = 3 * DENSE_GROUPS
+    pages = [([[int(k) for k in rng.permutation(ngroups * 2) % ngroups],
+               [int(v) for v in rng.integers(-99, 99, size=ngroups * 2)]],
+              None) for _ in range(3)]
+    op = _paged_op(types, [0], aggs, pages, True)
+    got = _drain(op)
+    assert op.metrics()["grouping_paths"] == {"hash": len(pages) + 1}
+    want = _drain(_paged_op(types, [0], aggs, pages, False))
+    assert len(got) == ngroups
     _assert_rows_equal(got, want)
 
 
@@ -474,7 +688,9 @@ def test_partials_are_as_wide_as_their_groups(case):
         answers[hashed] = _drain(op)
         if not hashed:
             continue
-        assert op.path_counts == {"hash": len(pages) + 1, "sort": 0,
+        # every page and the merge have few groups: all reduce densely
+        assert op.path_counts == {"hash": len(pages) + 1,
+                                  "dense": len(pages) + 1, "sort": 0,
                                   "passthrough": 0, "range_split": 0}
         want = [padded_size(_page_groups(c, live, group))
                 for c, live in pages]
@@ -564,3 +780,34 @@ def test_q1_sync_contract_has_no_page_trim():
     by_why = root["attrs"]["host_sync_by_why"]
     assert "page_trim" not in by_why
     assert by_why["agg_overflow"][0] == lanes[0]["pages"] + 1
+
+
+@pytest.mark.parametrize("sql,dense", [
+    ("select l_returnflag, l_linestatus, sum(l_quantity), count(*) "
+     "from lineitem group by l_returnflag, l_linestatus", True),
+    ("select sum(l_extendedprice * l_discount) from lineitem "
+     "where l_quantity < 24", True),
+    ("select l_orderkey, sum(l_quantity) from lineitem "
+     "group by l_orderkey", False),
+])
+def test_explain_analyze_says_how_many_pages_reduced_densely(sql, dense):
+    """EXPLAIN ANALYZE's aggregation line carries ``grouping_paths``:
+    every page (and the merge) of a few-group or keyless aggregation is
+    ``dense``, none of a 15,000-group one."""
+    import re
+
+    from trino_tpu.connectors.tpch import TpchConnector
+    from trino_tpu.runner import LocalQueryRunner
+    from trino_tpu.sql.analyzer import Session
+
+    runner = LocalQueryRunner(
+        {"tpch": TpchConnector(page_rows=4096)},
+        Session(catalog="tpch", schema="tiny"), desired_splits=8)
+    text = "\n".join(str(r[0]) for r in
+                     runner.execute("explain analyze " + sql).rows)
+    line, = [ln for ln in text.splitlines()
+             if "HashAggregationOperator" in ln]
+    paths = dict(kv.split("=") for kv in
+                 re.search(r"\[grouping ([^\]]+)\]", line).group(1).split())
+    assert int(paths["hash"]) >= 2
+    assert paths.get("dense") == (paths["hash"] if dense else None), line

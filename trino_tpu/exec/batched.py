@@ -234,7 +234,10 @@ def _agg_group_lane(aggs: Tuple, key_channels: Tuple, key_types: Tuple,
 
     The segment reduce always runs the lax segment-op path
     (``pallas=""``): it is vmap-safe everywhere and byte-identical to
-    the host path on CPU, where the batch-equality oracle runs."""
+    the host path on CPU, where the batch-equality oracle runs. Its
+    choice between the dense and the scatter reduction is per lane, so
+    under ``vmap`` it is a select and a lane pays for both (the dense
+    one is the small part)."""
     nkeys = len(key_channels)
 
     def lane(batched, shared):

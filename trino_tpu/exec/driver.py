@@ -87,6 +87,11 @@ class OperatorStats:
                 # the adaptive partial-agg decision (pass-through or
                 # per-key-range split) — no 'strategy' key on agg ops
                 base += f" [adaptive {m['adaptive']}]"
+            if m.get("grouping_paths"):
+                # pages by grouping path; ``dense`` are the ``hash``
+                # pages few enough in groups to reduce without a scatter
+                base += " [grouping " + " ".join(
+                    f"{k}={v}" for k, v in m["grouping_paths"].items()) + "]"
             if m.get("partial_lanes", {}).get("pages"):
                 # aggregation partials: lanes in, lanes kept for the
                 # merge, and the width the last merge ran at

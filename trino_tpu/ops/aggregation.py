@@ -10,10 +10,18 @@ Grouping runs one of two paths:
 - **hash** (default): the vectorized open-addressing table of
   ``ops/hashtable.py`` assigns each row a dense group id via bounded
   linear-probe rounds of masked scatter/gather — no sort, and state
-  columns never ride through comparator operands. The segment reduce
-  then runs over the hash-assigned gids (one cheap gid-only sort first
-  when the Pallas TPU kernel — which requires sorted segments — is
-  active). Float grouping keys and probe-budget overflow fall back to:
+  columns never ride through comparator operands. The reduce then
+  runs over the hash-assigned gids, and the page's own group count
+  picks its way on the device: up to ``hashtable.DENSE_GROUPS`` groups
+  (q1's four, a global aggregate's one) each state is compared with
+  those ids and reduced under the mask — **dense**, no scatter; beyond
+  that, the segment scatter (one cheap gid-only sort first when the
+  Pallas TPU kernel — which requires sorted segments — is active). A
+  global aggregate (no key columns) builds no table at all: its group
+  ids are known in closed form. ``path_counts["dense"]`` counts the
+  ``hash`` pages that took the dense way, where the step reads the
+  group count (``single``/``final``). Float grouping keys and
+  probe-budget overflow fall back to:
 - **sort** (oracle/fallback): normalize key columns to (null-bit,
   uint64) operand pairs, ``lax.sort`` the batch lexicographically,
   detect group boundaries by adjacent-row comparison, cumsum dense
@@ -65,7 +73,7 @@ from ..block import DevicePage, padded_size
 from ..telemetry.profiler import instrument
 from ..telemetry.tracing import host_read, host_sync
 from ..types import TypeError_
-from .hashtable import (_mix_operands, hash_group_ids,
+from .hashtable import (DENSE_GROUPS, _mix_operands, hash_group_ids,
                         hash_segment_reduce, hashable_key_types)
 from .operator import Operator
 from .sortkeys import group_operands, sort_carrying
@@ -510,7 +518,8 @@ _narrow_lanes = instrument("agg_narrow_partial", _narrow_lanes,
 
 #: process-wide pages per grouping path (the per-operator
 #: ``path_counts`` summed; chip_smoke / test observability)
-_path_totals = {"hash": 0, "sort": 0, "passthrough": 0, "range_split": 0}
+_path_totals = {"hash": 0, "dense": 0, "sort": 0, "passthrough": 0,
+                "range_split": 0}
 _path_totals_lock = threading.Lock()
 
 
@@ -562,8 +571,8 @@ class HashAggregationOperator(Operator):
         self._pass_buckets = None
         self._pending: List[DevicePage] = []  # pass-through output queue
         #: pages grouped per path, for EXPLAIN/observability
-        self.path_counts = {"hash": 0, "sort": 0, "passthrough": 0,
-                            "range_split": 0}
+        self.path_counts = {"hash": 0, "dense": 0, "sort": 0,
+                            "passthrough": 0, "range_split": 0}
         self._partials: List = []  # DevicePage | SpilledPage entries
         #: group count of the newest ``_aggregate_page`` result, where the
         #: page's ``agg_overflow`` read brought one (exact hash path)
@@ -832,6 +841,10 @@ class HashAggregationOperator(Operator):
             if overflow:
                 return None, None
             count = int(count)
+            if count <= DENSE_GROUPS:
+                # the reduce's own branch on the same scalar: a "hash"
+                # page whose states took no scatter
+                self._count_path("dense")
         elif observe and self.adaptive_partial \
                 and not self._adaptive_decided:
             self._observe_reduction(key_ops, page.valid, group_rows,

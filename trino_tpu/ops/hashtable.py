@@ -29,6 +29,26 @@ mode: the caller falls back to the sort-based oracle) or become
 singleton groups (partial aggregation tolerates duplicate groups — the
 final step re-groups, per "Partial Partial Aggregates", PAPERS.md).
 
+Which way a page's states are reduced (``hash_segment_reduce``) is
+decided on the device, by the page's own group count:
+  - at most ``DENSE_GROUPS`` groups (q1's four, a global aggregate's
+    one): **dense** — each state column is compared with the group ids
+    ``[0, DENSE_GROUPS)`` and reduced under the mask, one fused read of
+    ``gid`` and the column, no scatter and no table; exact in int64 in
+    any order (a float ``sum`` adds in another order, as between any two
+    reductions); the group keys are gathered for those ids alone;
+  - more: **scatter** — ``jax.ops.segment_*`` into ``cap + 1``
+    segments (the Pallas kernel for the 32-bit states on a TPU), whose
+    cost is by the lane whatever the number of groups, and a key
+    gather as wide as the page.
+``ngroups`` is an operand of the program, so the branch is a
+``lax.cond`` inside it: no knob, no history, one cache key. A page no
+wider than ``DENSE_GROUPS`` lanes is dense by its shape. With no key
+columns (a global aggregate) there is one group at most, which is known
+when the program is traced: the group ids are written in closed form,
+no table is built (``_keyless_group_ids``), and the reduce is one
+masked reduction a state with no branch in it.
+
 Float keys are NOT hashed here: the TPU x64 rewriter cannot bitcast
 f64<->u64 (see ops/sortkeys.py), so float grouping keys keep the
 sort-based path. ``hashable_key_types`` is the gate.
@@ -51,6 +71,12 @@ from ..telemetry.profiler import instrument
 #: mixed hash, an unresolved row after 32 probes is astronomically rare
 #: for non-adversarial input; adversarial input falls back / singles out.
 PROBE_ROUNDS = 32
+
+#: a page with at most this many groups has its states reduced by
+#: compare-and-sum instead of a scatter (module docstring). The dense
+#: cost grows with it and the scatter's does not; chosen on a v5e from a
+#: sweep at 262,144 lanes and 15 int64 columns (PERF.md §5, PR 29).
+DENSE_GROUPS = 128
 
 _M1 = np.uint64(0xBF58476D1CE4E5B9)
 _M2 = np.uint64(0x94D049BB133111EB)
@@ -106,6 +132,8 @@ def _hash_group_ids_impl(key_ops: Tuple, valid,
                                 mode always False: unresolved rows become
                                 their own singleton groups.
     """
+    if not key_ops:
+        return _keyless_group_ids(valid)
     jit_stats.bump("hash_group_ids")
     cap = valid.shape[0]
     # 2x capacity rounded up to a power of two (pages are pow2-padded
@@ -173,6 +201,22 @@ def _hash_group_ids_impl(key_ops: Tuple, valid,
     return gid, group_rows[:cap], ngroups, overflow
 
 
+def _keyless_group_ids(valid):
+    """``_hash_group_ids_impl`` with no key columns, in closed form:
+    every valid row is group 0, so there is nothing to hash, probe or
+    install. Same outputs, the empty page's included."""
+    jit_stats.bump("keyless_group_ids")
+    cap = valid.shape[0]
+    row_idx = jnp.arange(cap, dtype=jnp.int32)
+    gid = jnp.where(valid, 0, cap).astype(jnp.int32)
+    first = jnp.min(jnp.where(valid, row_idx, cap))
+    some = first < cap
+    group_rows = jnp.zeros((cap,), dtype=jnp.int32).at[0].set(
+        jnp.where(some, first, 0))
+    return (gid, group_rows, some.astype(jnp.int32),
+            jnp.zeros((), dtype=bool))
+
+
 # profiled entry points (telemetry.profiler): cost/compile
 # attribution under EXPLAIN ANALYZE VERBOSE; plain calls when off
 hash_group_ids = instrument(
@@ -182,13 +226,8 @@ hash_group_ids = instrument(
     static_argnames=("rounds", "exact"))
 
 
-def _hash_segment_reduce_impl(gid, group_rows, ngroups, key_raws: Tuple,
-                              key_nulls: Tuple, state_cols: Tuple,
-                              kinds: Tuple, pallas: str = ""):
-    """Reduce state columns by hash-assigned gid and gather group keys.
-
-    Raw implementation (see ``_hash_group_ids_impl`` for why); host
-    callers use the jitted+instrumented ``hash_segment_reduce`` below.
+def _scatter_reduce(gid, state_cols: Tuple, kinds: Tuple, pallas: str):
+    """State columns reduced by gid into ``cap + 1`` segments.
 
     The Pallas segment kernel requires non-decreasing gids (steps <= 1),
     so the states it takes (int32/float32 on a TPU backend) are sorted
@@ -196,11 +235,7 @@ def _hash_segment_reduce_impl(gid, group_rows, ngroups, key_raws: Tuple,
     The other states reduce by the unsorted gid in
     ``jax.ops.segment_*`` and are never sorted — a carried column is
     what makes a sort slow to compile for the TPU.
-
-    Returns (group_key_raws, group_key_nulls, reduced_states, out_valid)
-    in the exact shape contract of ``aggregation._group_reduce``.
     """
-    jit_stats.bump("hash_segment_reduce")
     from .pallas_kernels import segment_reduce, takes_kernel
     from .sortkeys import sort_carrying
 
@@ -221,12 +256,98 @@ def _hash_segment_reduce_impl(gid, group_rows, ngroups, key_raws: Tuple,
             r = segment_reduce(col, gid, num_segments=cap + 1, kind=kind,
                                mode="")
         reduced.append(r[:cap])
+    return tuple(reduced)
 
+
+# a sum accumulates in its column's own dtype, as ``segment_sum`` does
+_DENSE_OPS = {"sum": partial(jnp.sum, promote_integers=False),
+              "min": jnp.min, "max": jnp.max}
+
+
+def _identity(kind: str, dtype):
+    """What ``jax.ops.segment_<kind>`` leaves in an empty segment."""
+    if kind == "sum":
+        return 0
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.inf if kind == "min" else -jnp.inf
+    info = jnp.iinfo(dtype)
+    return info.max if kind == "min" else info.min
+
+
+def _group_keys(key_raws: Tuple, key_nulls: Tuple, safe_idx, out_valid):
+    """Each group's key columns, read at its representative row."""
+    return (tuple(kr[safe_idx] for kr in key_raws),
+            tuple(kn[safe_idx] & out_valid for kn in key_nulls))
+
+
+def _widened(head, fill, cap: int):
+    """``head`` followed by ``fill`` up to ``cap`` lanes."""
+    tail = jnp.full((cap - head.shape[0],), fill, head.dtype)
+    return jnp.concatenate([head, tail])
+
+
+def _dense_reduce(gid, safe_idx, out_valid, key_raws: Tuple,
+                  key_nulls: Tuple, state_cols: Tuple, kinds: Tuple,
+                  k: int):
+    """``_scatter_reduce`` and ``_group_keys`` for a page whose gids all
+    lie below ``k``, with nothing as wide as the page but the reads:
+    compare ``gid`` with each of those ids and reduce the column under
+    the mask; gather the keys of those ids alone. The results fill lanes
+    ``[0, k)`` of outputs as wide as the scatter's; past them a state
+    holds its kind's identity (what ``jax.ops.segment_*`` leaves in an
+    empty segment) and a key row 0's (what index 0 gathers). Invalid
+    lanes carry ``gid == cap`` and match no id."""
+    cap = gid.shape[0]
+    hit = gid[None, :] == jnp.arange(k, dtype=gid.dtype)[:, None]
+    reduced = []
+    for kind, col in zip(kinds, state_cols):
+        ident = jnp.asarray(_identity(kind, col.dtype), col.dtype)
+        r = _DENSE_OPS[kind](jnp.where(hit, col[None, :], ident), axis=1)
+        reduced.append(_widened(r, ident, cap))
+    raws, nulls = _group_keys(key_raws, key_nulls, safe_idx[:k],
+                              out_valid[:k])
+    return (tuple(_widened(r, kr[0], cap) for r, kr in zip(raws, key_raws)),
+            tuple(_widened(n, False, cap) for n in nulls), tuple(reduced))
+
+
+def _hash_segment_reduce_impl(gid, group_rows, ngroups, key_raws: Tuple,
+                              key_nulls: Tuple, state_cols: Tuple,
+                              kinds: Tuple, pallas: str = ""):
+    """Reduce state columns by hash-assigned gid and gather group keys.
+
+    Raw implementation (see ``_hash_group_ids_impl`` for why); host
+    callers use the jitted+instrumented ``hash_segment_reduce`` below.
+
+    The page's group count picks the way on the device (module
+    docstring): ``_dense_reduce`` up to ``DENSE_GROUPS`` groups,
+    ``_scatter_reduce`` and a page-wide key gather beyond. Under ``vmap``
+    the predicate is batched, the ``cond`` becomes a select and a lane
+    pays for both. Rows cannot differ on no key column, so a keyless
+    page has at most one group, known when the program is traced: it
+    is one masked reduction a state, with no branch.
+
+    Returns (group_key_raws, group_key_nulls, reduced_states, out_valid)
+    in the exact shape contract of ``aggregation._group_reduce``.
+    """
+    jit_stats.bump("hash_segment_reduce")
+    cap = gid.shape[0]
+    limit = min(DENSE_GROUPS if key_raws else 1, cap)
     out_valid = jnp.arange(cap, dtype=jnp.int32) < ngroups
     safe_idx = jnp.where(out_valid, group_rows, 0)
-    out_key_raws = tuple(kr[safe_idx] for kr in key_raws)
-    out_key_nulls = tuple(kn[safe_idx] & out_valid for kn in key_nulls)
-    return out_key_raws, out_key_nulls, tuple(reduced), out_valid
+
+    def dense():
+        return _dense_reduce(gid, safe_idx, out_valid, key_raws, key_nulls,
+                             state_cols, kinds, limit)
+
+    def scatter():
+        return _group_keys(key_raws, key_nulls, safe_idx, out_valid) \
+            + (_scatter_reduce(gid, state_cols, kinds, pallas),)
+
+    if limit == cap or not key_raws:
+        out = dense()
+    else:
+        out = jax.lax.cond(ngroups <= limit, dense, scatter)
+    return (*out, out_valid)
 
 
 hash_segment_reduce = instrument(
