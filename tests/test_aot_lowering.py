@@ -189,7 +189,7 @@ def test_sort_group_reduce_compiles_for_v5e(one_chip):
 def test_q1_device_step_compiles_for_v5e(one_chip):
     """The fused q1 step at a full 65,536-row page (int64 states, x64
     on): no custom call, plain XLA."""
-    from trino_tpu.benchmarks import q1_example_args
+    from __graft_entry__ import q1_example_args
 
     step, args = q1_example_args()
     cols, nulls, valid, luts = jax.eval_shape(lambda: args)
@@ -380,7 +380,7 @@ def test_global_hash_agg_program_lowers_for_tpu():
 def test_q1_device_step_lowers_for_tpu():
     """The flagship fused filter+project+group-aggregate step — the
     program ``__graft_entry__.entry`` compiles on the real chip."""
-    from trino_tpu.benchmarks import q1_example_args
+    from __graft_entry__ import q1_example_args
 
     step, args = q1_example_args()
     ex = _export_tpu(jax.jit(step), *jax.eval_shape(lambda: args))

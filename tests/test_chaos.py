@@ -895,3 +895,72 @@ def test_system_runtime_nodes_reflects_ledger(elastic_cluster):
     fams = {f["name"] for f in c.metrics_families()}
     assert {"trino_cluster_size", "trino_nodes_total",
             "trino_autoscaler_target_workers"} <= fams
+
+
+# ------------------------------------- the autoscaler on a live cluster ----
+
+
+def test_autoscaler_grows_a_live_cluster_under_a_burst_and_drains_it():
+    """Queue depth against a resource group of two makes the autoscaler
+    grow the membership 2 -> 4 while the burst runs; every answer of
+    the burst is the clean one with no query retry; a statement planned
+    at the grown width places tasks on the joiners; idle drains the
+    cluster back to its floor one worker at a time and the answer is
+    still the clean one."""
+    from trino_tpu.resource_groups import ResourceGroupManager
+
+    rg = ResourceGroupManager.from_config({"groups": [
+        {"name": "global", "max_concurrency": 2, "max_queued": 10_000}]})
+    s = _mk_session(retry_policy="QUERY", partial_stage_retry=True,
+                    autoscale_enabled=True, autoscale_min_workers=2,
+                    autoscale_max_workers=4, autoscale_cooldown_s=0.5,
+                    autoscale_up_queue_depth=1,
+                    autoscale_down_idle_ticks=4)
+    with ProcessQueryRunner(CATALOGS, s, n_workers=2, desired_splits=4,
+                            heartbeat_interval=0.25,
+                            resource_groups=rg) as c:
+        clean = sorted(c.execute(Q1).rows)
+        lock = threading.Lock()
+        burst, failures = [], []
+        # the burst keeps the queue pressed until the membership has
+        # grown: spawning a worker takes seconds
+        grown = threading.Event()
+
+        def one():
+            for _ in range(40):
+                if grown.is_set():
+                    return
+                try:
+                    r = c.execute(Q1)
+                except Exception as e:  # reported below
+                    with lock:
+                        failures.append(repr(e))
+                    return
+                with lock:
+                    burst.append((sorted(r.rows) == clean,
+                                  r.stats["recovery"]["query_retries"]))
+
+        threads = [threading.Thread(target=one) for _ in range(8)]
+        for t in threads:
+            t.start()
+        deadline = time.time() + 90
+        while any(t.is_alive() for t in threads):
+            if len(c.workers) >= 4 or time.time() > deadline:
+                grown.set()
+            time.sleep(0.05)
+        for t in threads:
+            t.join()
+        assert failures == []
+        assert len(c.workers) == 4
+        assert burst and all(same for same, _ in burst)
+        assert all(retries == 0 for _, retries in burst)
+        mark = len(c.task_launches)
+        assert sorted(c.execute(Q1).rows) == clean
+        assert any(".t2" in t for t in c.task_launches[mark:])
+        deadline = time.time() + 120
+        while time.time() < deadline and len(c.workers) > 2:
+            time.sleep(0.2)
+        assert len(c.workers) == 2
+        assert sorted(c.execute(Q1).rows) == clean
+        snap = c.autoscaler.snapshot()
+        assert snap["scale_ups"] >= 1 and snap["scale_downs"] >= 2

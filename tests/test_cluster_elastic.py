@@ -4,8 +4,8 @@ Pure-python units (no worker processes): the ClusterLedger's generation
 monotonicity, topology-aware placement determinism (including exact
 degeneration to the historical round-robin when topology carries no
 signal), and the autoscaler's hysteresis/cooldown/bounds behavior.
-Process-level elasticity (add/retire mid-query, chaos) lives in
-test_chaos.py and the BENCH_ROLE=elastic smoke.
+Process-level elasticity (add/retire mid-query, chaos, the autoscaler
+growing a live cluster under a burst) lives in test_chaos.py.
 """
 
 from trino_tpu.parallel.autoscaler import Autoscaler

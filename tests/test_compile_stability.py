@@ -6,7 +6,7 @@ interpreter speed. Every hot-path kernel bumps a named counter in
 ``trino_tpu.jit_stats`` at TRACE time only, so after a warmup page the
 total must stay flat across same-shape pages. The driver attributes
 per-operator deltas into OperatorStats, surfacing them through EXPLAIN
-ANALYZE and the bench output.
+ANALYZE.
 """
 
 import numpy as np

@@ -129,3 +129,88 @@ def test_fewer_devices_than_partitions(conn, monkeypatch, sql, n_devices):
     hrows = sorted(host.execute(sql).rows, key=_key)
     assert drows == hrows
     assert any(ran), "device exchange fell back to host path"
+
+
+# -------------------- q1 through the planner over 2, 4 and 8 devices ----
+
+Q1ISH_EXPLAIN = ("explain analyze select l_returnflag, l_linestatus, "
+                 "count(*), sum(l_quantity) from lineitem "
+                 "group by l_returnflag, l_linestatus")
+
+
+def _device_lines(res):
+    return [row[0].strip() for row in res.rows
+            if "exchange [device]" in row[0]]
+
+
+def _local(conn):
+    from trino_tpu.runner import LocalQueryRunner
+
+    return LocalQueryRunner({"tpch": conn},
+                            Session(catalog="tpch", schema="micro"))
+
+
+@pytest.fixture
+def fresh_history():
+    from trino_tpu.parallel.device_exchange import SIZING_HISTORY
+
+    SIZING_HISTORY.reset()
+    yield
+    SIZING_HISTORY.reset()
+
+
+@pytest.mark.parametrize("n_workers", [2, 8])
+def test_q1_over_two_and_eight_workers_equals_local(conn, n_workers):
+    """TPC-H q1 planned and run over 2 and over all 8 virtual devices,
+    its partial groups crossing the mesh in one ``all_to_all``, equals
+    single-process execution."""
+    from trino_tpu.resources.tpch_queries import TPCH_QUERIES
+
+    before = DeviceExchange.total_collectives
+    got = _runner(conn, True, n_workers).execute(TPCH_QUERIES[1]).rows
+    assert DeviceExchange.total_collectives > before
+    want = _local(conn).execute(TPCH_QUERIES[1]).rows
+    assert len(got) == len(want) == 4
+    for g, w in zip(got, want):
+        assert g[:2] == w[:2] and g[-1] == w[-1]
+        assert [float(v) for v in g[2:-1]] == \
+            pytest.approx([float(v) for v in w[2:-1]], rel=1e-9)
+
+
+def test_count_first_sizing_plans_run_with_zero_retries(conn,
+                                                        fresh_history):
+    """On the planner path a shape not seen before is sized by the
+    counting collective: one count, one data ``all_to_all``, no
+    doubling retry."""
+    counts = DeviceExchange.total_count_collectives
+    lines = _device_lines(_runner(conn, True, 4).execute(Q1ISH_EXPLAIN))
+    assert lines
+    for line in lines:
+        assert "retries=0" in line and "collectives=1+1" in line, line
+    assert DeviceExchange.total_count_collectives - counts == len(lines)
+
+
+def test_partition_overflow_is_retried_and_answers_the_same(conn,
+                                                            fresh_history):
+    """A statement whose exchange shape the history has seen only small
+    is presized too small: the lanes overflow, the collective is re-run
+    at doubled capacity until it fits, the answer equals single-process
+    execution, and the shape is re-learned (no retry the next time)."""
+    import re
+
+    sql = ("select l_orderkey, count(*) c from lineitem "
+           "where l_orderkey < {} group by l_orderkey")
+    r = _runner(conn, True, 4)
+
+    def retries(bound):
+        line, = _device_lines(r.execute("explain analyze "
+                                        + sql.format(bound)))
+        return int(re.search(r"retries=(\d+)", line).group(1))
+
+    assert retries(40) == 0          # counted: fits
+    assert retries(40) == 0          # presized from history: fits
+    assert retries(4_000_000) >= 1   # presized from 39 rows: overflows
+    got = sorted(r.execute(sql.format(4_000_000)).rows)
+    want = sorted(_local(conn).execute(sql.format(4_000_000)).rows)
+    assert got == want and len(got) > 1000
+    assert retries(4_000_000) == 0   # re-learned

@@ -2,8 +2,7 @@
 replicated-table kernel against host oracles, its overflow contract,
 the key packing, and the kernel sizing history.
 
-The mesh-level byte-equality against the exchange+merge-final shape
-(and the 'auto' cost-rule pick) lives in test_mesh_query.py; here the
+No planned statement runs this kernel (ROADMAP debt G); here the
 kernel itself is pinned down on one device and on the 8-virtual-device
 mesh with every reduce kind.
 """

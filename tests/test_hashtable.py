@@ -811,3 +811,40 @@ def test_explain_analyze_says_how_many_pages_reduced_densely(sql, dense):
                  re.search(r"\[grouping ([^\]]+)\]", line).group(1).split())
     assert int(paths["hash"]) >= 2
     assert paths.get("dense") == (paths["hash"] if dense else None), line
+
+
+# ------------------------------------------- hash against sort, as SQL ----
+
+#: q18's grouping subquery without its HAVING (which leaves nothing at
+#: micro): the aggregation itself, one group an order
+Q18_GROUPS = ("select l_orderkey, sum(l_quantity) from lineitem "
+              "group by l_orderkey")
+
+
+@pytest.mark.parametrize("qid,witness,min_groups", [
+    (1, None, 4),
+    (18, Q18_GROUPS, 1001),
+], ids=["q1", "q18"])
+def test_sql_answers_the_same_hash_grouped_or_sorted(qid, witness,
+                                                     min_groups):
+    """TPC-H q1 (4 groups) and q18 (an aggregation over more than 1,000
+    groups under a semijoin) through the planner give the same rows
+    whether ``hash_grouping_enabled`` is on (the table) or off (the
+    sort-based oracle)."""
+    from trino_tpu.connectors.tpch import TpchConnector
+    from trino_tpu.resources.tpch_queries import TPCH_QUERIES
+    from trino_tpu.runner import LocalQueryRunner
+    from trino_tpu.sql.analyzer import Session
+
+    answers, groups = {}, {}
+    for hashed in (True, False):
+        session = Session(catalog="tpch", schema="micro")
+        session.properties["hash_grouping_enabled"] = hashed
+        runner = LocalQueryRunner({"tpch": TpchConnector(page_rows=4096)},
+                                  session, desired_splits=4)
+        answers[hashed] = runner.execute(TPCH_QUERIES[qid]).rows
+        groups[hashed] = sorted(runner.execute(witness).rows) \
+            if witness else answers[hashed]
+    assert answers[True] == answers[False]
+    assert groups[True] == groups[False]
+    assert len(groups[True]) >= min_groups

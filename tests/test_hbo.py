@@ -541,16 +541,6 @@ def test_hbo_metric_families_and_prometheus_roundtrip():
     assert sum(parsed["trino_hbo_qerror_count"].values()) >= 1
 
 
-def test_qerror_quantiles_for_bench():
-    st = RuntimeStatsStore()
-    st.record_query("s1", "snap", [
-        {"fp": f"n{i}", "name": "Scan", "rows": 10.0,
-         "est_rows": 10.0 * (2 ** i)} for i in range(4)])
-    assert st.qerror_quantile(0.5) is not None
-    assert st.qerror_quantile(0.9) >= st.qerror_quantile(0.5)
-    assert RuntimeStatsStore().qerror_quantile(0.5) is None
-
-
 def test_store_bounded_lru():
     st = RuntimeStatsStore(max_statements=4)
     for i in range(10):

@@ -67,8 +67,8 @@ def _int_cols(rng, n, ndv, null_frac=0.0, skew=False):
 
 @pytest.mark.parametrize("join_type,ndv,null_frac,skew", [
     # every join type on the adversarial middle (skew + nulls) runs
-    # tier-1; the dense/sparse NDV extremes ride the slow mark (the
-    # BENCH_ROLE=kernels child sweeps them too) — tier-1 budget
+    # tier-1; the dense/sparse NDV extremes ride the slow mark —
+    # tier-1 budget
     ("inner", 150, 0.1, True),
     ("semi", 150, 0.1, True),
     ("anti", 150, 0.1, True),

@@ -857,3 +857,39 @@ def test_hybrid_spill_record_hbo_roundtrip():
     assert h.spill == rec2
     back = NodeHistory.from_dict(h.to_dict())
     assert back.spill == rec2 and back.runs == 3
+
+
+# ---------------------------- the join under a shrinking share of its peak ----
+
+#: no aggregation above the join (its finish-merge has a cliff of its
+#: own); ORDER BY + LIMIT pin the rows
+LADDER_SQL = ("select o_orderdate, o_shippriority, l_extendedprice "
+              "from orders o, lineitem l "
+              "where o.o_orderkey = l.l_orderkey "
+              "order by l_extendedprice desc, o_orderdate limit 10")
+
+
+@pytest.fixture(scope="module")
+def ladder_baseline():
+    res = make_runner(256, hbo_enabled=False).execute(LADDER_SQL)
+    return res.rows, res.stats["memory"]["peak_bytes"]
+
+
+@pytest.mark.parametrize("pct", [100, 50, 25])
+def test_join_answers_the_same_at_a_share_of_its_own_peak(
+        pct, ladder_baseline):
+    """A join given 100 %, 50 % and 25 % of the memory its own
+    unconstrained run peaked at degrades instead of dying: nothing is
+    killed, the rows are those of the unconstrained run, the peak stays
+    under the cap, and at a quarter it runs partitioned (demotions
+    happened), not by the luck of a roomy plan."""
+    rows, peak = ladder_baseline
+    cap = max(1, peak * pct // 100)
+    res = make_runner(256, hbo_enabled=False, query_max_memory_bytes=cap,
+                      spill_enabled=True,
+                      spill_to_disk_enabled=True).execute(LADDER_SQL)
+    mem = res.stats["memory"]
+    assert res.rows == rows
+    assert mem["peak_bytes"] <= cap
+    if pct == 25:
+        assert mem["partition_spills"] > 0, mem

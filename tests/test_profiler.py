@@ -1,6 +1,6 @@
 """Compiled-program profiler: registry core, EXPLAIN ANALYZE VERBOSE,
-system.runtime.kernels, query progress, the flight-recorder differ,
-OTLP export, and the slow-query log."""
+system.runtime.kernels, query progress, OTLP export, and the slow-query
+log."""
 
 import json
 import threading
@@ -15,8 +15,7 @@ from trino_tpu.connectors.tpch import TpchConnector
 from trino_tpu.runner import LocalQueryRunner, QueryResult
 from trino_tpu.sql.analyzer import Session
 from trino_tpu.telemetry import profiler
-from trino_tpu.telemetry.profiler import (diff_profiles, instrument,
-                                          validate_profile)
+from trino_tpu.telemetry.profiler import instrument
 
 
 @pytest.fixture(autouse=True)
@@ -310,81 +309,6 @@ def test_protocol_live_query_info_serves_partial_stats():
     finally:
         release.set()
         server.stop()
-
-
-# -- flight recorder -------------------------------------------------------
-
-
-def _profile_doc(kernels):
-    compiles = sum(k.get("compiles", 0) for k in kernels)
-    compile_ms = sum(k.get("compile_ms", 0.0) for k in kernels)
-    return {"version": 1, "role": "test", "kernels": kernels,
-            "totals": {"programs": len(kernels), "compiles": compiles,
-                       "compile_ms": compile_ms}}
-
-
-def _kernel(name, key="k0", compiles=1, compile_ms=10.0, flops=100.0,
-            bytes_accessed=1000.0):
-    return {"name": name, "key": key, "compiles": compiles,
-            "calls": 3, "trace_ms": 1.0, "compile_ms": compile_ms,
-            "execute_ms": 1.0, "flops": flops,
-            "bytes_accessed": bytes_accessed, "output_bytes": 0,
-            "temp_bytes": 0, "argument_bytes": 0, "code_bytes": 0,
-            "fallbacks": 0}
-
-
-def test_differ_names_the_kernel_that_moved():
-    old = _profile_doc([_kernel("join_probe"), _kernel("agg")])
-    # synthetic regression: agg's bytes double AND it recompiled a new
-    # shape; join untouched
-    new = _profile_doc([
-        _kernel("join_probe"),
-        _kernel("agg", key="k0"),
-        _kernel("agg", key="k1", bytes_accessed=3000.0),
-    ])
-    moved = diff_profiles(old, new)
-    assert moved, "regression not detected"
-    assert all(m["kernel"] == "agg" for m in moved), moved
-    changes = {m["change"] for m in moved}
-    assert "recompiled" in changes
-    assert "bytes_accessed-grew" in changes
-    # identical artifacts: clean diff
-    assert diff_profiles(old, old) == []
-
-
-def test_differ_flags_new_and_vanished_kernels():
-    old = _profile_doc([_kernel("a")])
-    new = _profile_doc([_kernel("b")])
-    changes = {(m["kernel"], m["change"])
-               for m in diff_profiles(old, new)}
-    assert ("a", "vanished") in changes
-    assert ("b", "new-kernel") in changes
-
-
-def test_validate_profile_rejects_empty_and_disconnected():
-    assert validate_profile({}) != []
-    assert validate_profile({"kernels": []}) != []
-    assert validate_profile(
-        {"kernels": [_kernel("x", compiles=0, compile_ms=0.0)],
-         "totals": {"compiles": 0, "compile_ms": 0.0}}) != []
-    good = _profile_doc([_kernel("x")])
-    assert validate_profile(good) == []
-    # round-trips through JSON (the artifact is a file)
-    assert validate_profile(json.loads(json.dumps(good))) == []
-
-
-def test_profile_document_shape():
-    f = _fresh_kernel("t_doc")
-    profiler.enable()
-    try:
-        f(jnp.arange(8, dtype=jnp.float32),
-          jnp.ones(8, dtype=jnp.float32), n=2)
-    finally:
-        profiler.enable(False)
-    doc = profiler.profile_document("unit")
-    assert validate_profile(doc) == []
-    assert doc["role"] == "unit"
-    assert any(k["name"] == "t_doc" for k in doc["kernels"])
 
 
 # -- OTLP export -----------------------------------------------------------

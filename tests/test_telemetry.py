@@ -23,7 +23,6 @@ from trino_tpu.telemetry.metrics import (ClusterMetrics, MetricsRegistry,
                                          parse_prometheus,
                                          render_prometheus)
 from trino_tpu.telemetry.tracing import (NULL_TRACER, Tracer, span_tree,
-                                         stage_overlap, to_chrome_trace,
                                          trace_line)
 
 CATALOGS = {"tpch": {"connector": "tpch", "page_rows": 4096}}
@@ -73,21 +72,6 @@ def test_cross_process_parenting():
     roots, children, orphans = span_tree(t.finished())
     assert len(roots) == 1 and not orphans
     assert children[root.span_id][0]["name"] == "task x"
-
-
-def test_stage_overlap_from_timelines():
-    def task(frag, start, end):
-        return {"trace_id": "t", "span_id": f"{frag}{start}",
-                "parent_id": None, "name": "task", "process": "w",
-                "start": start, "end": end,
-                "attrs": {"span_kind": "task", "fragment": frag}}
-
-    # frag1 active [0,2], frag2 [1,3]: busy union 3s, overlap [1,2]
-    spans = [task(1, 0.0, 2.0), task(2, 1.0, 3.0)]
-    assert abs(stage_overlap(spans) - 1 / 3) < 1e-9
-    # barrier shape: no concurrency across fragments
-    assert stage_overlap([task(1, 0.0, 1.0), task(2, 1.0, 2.0)]) == 0.0
-    assert stage_overlap([task(1, 0.0, 1.0)]) == 0.0
 
 
 def test_metrics_exposition_roundtrip():
@@ -150,15 +134,13 @@ def test_q3_distributed_trace_tree(cluster):
                if s["process"].startswith("worker-")}
     assert len(workers) >= 2, workers
     # worker task spans exist for every non-output fragment and carry
-    # their fragment id (the stage_overlap input)
+    # their fragment id
     tasks = [s for s in spans
              if s["attrs"].get("span_kind") == "task"
              and s["process"].startswith("worker-")]
     assert tasks and all(s["attrs"].get("fragment") is not None
                          for s in tasks)
     assert trace_line(spans).startswith("Trace: ")
-    # streaming execution: upstream fragments overlap the output stage
-    assert stage_overlap(spans) > 0.0
 
 
 def test_barrier_operator_spans_account_for_task_wall(cluster):
@@ -184,30 +166,6 @@ def test_barrier_operator_spans_account_for_task_wall(cluster):
     assert wall > 0
     assert busy >= 0.9 * wall, \
         f"operator spans {busy * 1e3:.1f}ms vs exec {wall * 1e3:.1f}ms"
-
-
-def test_chrome_trace_artifact_schema(cluster):
-    res = cluster.execute("select count(*) from lineitem")
-    doc = to_chrome_trace(res.stats["trace"])
-    blob = json.loads(json.dumps(doc))  # JSON-serializable end to end
-    events = blob["traceEvents"]
-    assert events
-    pids = set()
-    for e in events:
-        # the trace-event schema: phase, name, pid/tid always; complete
-        # ("X") events add microsecond ts + dur
-        assert e["ph"] in ("X", "M")
-        assert isinstance(e["name"], str)
-        assert isinstance(e["pid"], int) and isinstance(e["tid"], int)
-        if e["ph"] == "X":
-            assert isinstance(e["ts"], (int, float)) and e["ts"] >= 0
-            assert isinstance(e["dur"], (int, float)) and e["dur"] >= 0
-            pids.add(e["pid"])
-        else:
-            assert e["name"] in ("process_name", "thread_name")
-    named = {e["pid"] for e in events
-             if e["ph"] == "M" and e["name"] == "process_name"}
-    assert pids <= named  # every used pid lane is named for Perfetto
 
 
 def test_explain_analyze_trace_line(cluster):

@@ -4,9 +4,8 @@ Exit codes: 0 clean (every finding baselined), 1 non-baselined
 findings OR stale baseline entries (the baseline may only shrink),
 2 usage error. The analysis package itself is pure stdlib ``ast``
 (never imports the analyzed code or JAX); note that ``-m`` entry
-pays the PARENT package's ``import jax`` — a context where that
-could hang (the bench parent) must load this package by file path
-instead (see bench.py ``_load_qlint``).
+pays the PARENT package's ``import jax`` — a process that must stay
+off JAX loads this package by file path instead.
 
 ``--changed-since <rev>`` is the pre-commit gate shape: the FULL
 index is still built (call graphs are whole-program — a pass run on a

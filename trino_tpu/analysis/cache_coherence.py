@@ -3,7 +3,7 @@ must be represented in its cache key.
 
 The bug class that bit ``min_collectives`` in PR 5 and forced PR 10's
 session fingerprint: a memoized builder (an ``lru_cache``'d program
-builder, a get-or-build memo dict like ``mesh_query._PROGRAM_CACHE``,
+builder, a get-or-build memo dict like ``exec/batched._KERNEL_CACHE``,
 ``ProcessorCache.get``, ``QueryCache.parse``, the sizing histories)
 reads state that can CHANGE between calls — a session property, an
 environment variable, a rebindable module global — without that state

@@ -4,8 +4,8 @@ Entry points are functions that jax stages out: ``@jax.jit`` /
 ``@partial(jax.jit, ...)`` / ``@partial(shard_map, ...)`` decorated
 defs, and local functions passed into ``jax.jit(f)`` /
 ``shard_map(f, ...)`` / ``pl.pallas_call(kernel, ...)`` call forms
-(the builder idiom of ``device_exchange._exchange_program`` and the
-``mesh_query`` programs). From every entry the pass walks resolved
+(the builder idiom of ``device_exchange._exchange_program``). From
+every entry the pass walks resolved
 call-graph edges and flags host effects at any reachable function:
 span/metrics calls, lock acquisition, ``time.*``, file/socket/
 subprocess IO, ``print``, host-RNG, and subscript stores into traced
@@ -82,8 +82,8 @@ def profiled_entries(index: ProjectIndex) -> Dict[str, List[str]]:
     by name with the registering module(s) as values — the not-blind
     witness that the cost registry actually covers the engine's jit
     entry points (a renamed wrapper or dropped instrument() call would
-    silently blind EXPLAIN ANALYZE VERBOSE and the bench flight
-    recorder)."""
+    silently blind EXPLAIN ANALYZE VERBOSE and
+    ``system.runtime.kernels``)."""
     out: Dict[str, List[str]] = {}
     # registration FACADES (round 17): a function whose body forwards
     # its own parameter as instrument()'s name — e.g. exec/batched.py

@@ -46,7 +46,7 @@ class OperatorStats:
     #: XLA cost attribution (telemetry.profiler thread deltas): flops /
     #: bytes accessed by this operator's compiled programs per
     #: execution, and the compile wall it paid — all zero unless the
-    #: profiler was enabled (EXPLAIN ANALYZE VERBOSE, bench trace role)
+    #: profiler was enabled (EXPLAIN ANALYZE VERBOSE)
     flops: float = 0.0
     device_bytes: float = 0.0
     compile_ms: float = 0.0

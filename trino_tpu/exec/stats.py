@@ -117,7 +117,7 @@ class QueryStatsTree:
     #: self-healing counters for this query (fault.RecoveryStats dict):
     #: attempts, retries by error type, backoff wall-time, workers
     #: replaced, speculative launches/wins — attached by the process
-    #: runner so EXPLAIN ANALYZE and the bench surface recovery
+    #: runner so EXPLAIN ANALYZE and the protocol's stats surface recovery
     recovery: Optional[Dict] = None
     #: finished distributed-trace spans (telemetry.tracing dicts):
     #: coordinator root/plan/fragment/attempt spans + the worker

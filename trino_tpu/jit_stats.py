@@ -9,7 +9,7 @@ once per cache miss (trace) and never on a cache hit.
 
 The driver snapshots ``total()`` around each operator call and
 attributes the delta to that operator's stats, which flow into EXPLAIN
-ANALYZE and the bench output (reference analog: the per-operator
+ANALYZE (reference analog: the per-operator
 ``*CompilerStats`` / planner bytecode-compilation counters that
 Trino exposes through OperatorStats metrics).
 """

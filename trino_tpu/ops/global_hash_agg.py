@@ -4,11 +4,11 @@ Reference analog: "Global Hash Tables Strike Back!" (PAPERS.md,
 arXiv 2505.04153) — a single shared hash table updated by every thread
 beats partition-then-aggregate for GROUP BY across a wide NDV range.
 On a TPU mesh the translation is: instead of the exchange+merge-final
-shape (all_to_all of partial groups, then per-device re-grouping —
-``parallel/mesh_query.q1_exchange_final_fn``), every device owns a
-REPLICATED open-addressing table and updates it with collective
-scatter-adds: local scatter into the table, one ``psum``/``pmin``/
-``pmax`` per state column to merge the replicas.  For low-NDV grouping
+shape the planner builds (all_to_all of partial groups, then per-device
+re-grouping — ``parallel/device_exchange`` under a FINAL aggregation),
+every device owns a REPLICATED open-addressing table and updates it
+with collective scatter-adds: local scatter into the table, one
+``psum``/``pmin``/``pmax`` per state column to merge the replicas.  For low-NDV grouping
 the table is tiny, so the collectives move O(table) bytes instead of
 O(partial groups) rows — and no re-grouping kernel runs at all.
 

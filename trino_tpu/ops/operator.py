@@ -348,7 +348,7 @@ class LimitOperator(Operator):
 class ValuesOperator(SourceOperator):
     """Inline literal rows (reference: operator/ValuesOperator.java).
     ``coalesce_rows`` applies the scan's small-page coalescing to
-    pre-materialized host pages (the bench's values-fed pipelines)."""
+    pre-materialized host pages."""
 
     def __init__(self, pages: Sequence[Page],
                  coalesce_rows: Optional[int] = None):

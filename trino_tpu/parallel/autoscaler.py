@@ -6,7 +6,7 @@ cluster memory utilization — while the engine itself only exposes the
 signals. Here the policy is IN the engine but deliberately mechanical:
 no wall-clock sampling, no randomness — every decision is a pure
 function of the tick inputs and the controller's counters, so chaos
-tests and the bench role replay identically.
+tests replay identically.
 
 Signals per tick (the monitor thread calls ``tick`` once per heartbeat
 interval):
@@ -38,7 +38,7 @@ class Autoscaler:
 
     #: consecutive pressure ticks required before a scale-up fires
     UP_TICKS = 2
-    #: bounded decision history for the bench result line / debugging
+    #: bounded decision history (``snapshot()``; tests and debugging)
     MAX_DECISIONS = 64
 
     def __init__(self, clock=time.monotonic):

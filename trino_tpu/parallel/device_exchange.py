@@ -59,7 +59,7 @@ Every collective records skew observability into ``self.stats``:
 per-partition row counts, max/mean skew ratio, per-receiver lane loads,
 hot partitions split and the receiver lanes they spread across,
 per_dest chosen, retries, collective count and bytes moved — surfaced
-through OperatorStats / EXPLAIN ANALYZE and the bench output.
+through OperatorStats / EXPLAIN ANALYZE.
 """
 
 from __future__ import annotations
@@ -258,7 +258,7 @@ class DeviceExchange:
         #: skew observability of the last collective (per-partition row
         #: counts, skew ratio, per_dest chosen, retries, bytes moved) —
         #: populated by _collect, surfaced via OperatorStats / EXPLAIN
-        #: ANALYZE / bench
+        #: ANALYZE
         self.stats: Optional[Dict] = None
         # streaming-scheduler support: the collective is a barrier — it
         # needs every producer's rows — so consumers park on a listen
@@ -297,7 +297,7 @@ class DeviceExchange:
     #: hits skip them — assertable)
     total_count_collectives = 0
     #: process-wide count of hot partitions split across receivers
-    #: (bench SKEW_RESULT / test observability)
+    #: (test observability)
     total_splits = 0
     _total_lock = threading.Lock()
 

@@ -22,7 +22,7 @@ collective (``partition_histogram`` + psum/pmax over the mesh — O(n*d)
 scalars, negligible vs the payload) to learn the exact max
 (sender, destination) load and size the data ``all_to_all`` exactly;
 the overflow retry then remains only as a bug backstop. See
-``parallel/device_exchange._count_program`` and ``mesh_query``.
+``parallel/device_exchange._count_program``.
 """
 
 from __future__ import annotations
@@ -83,23 +83,6 @@ def hash_partition_ids(keys_u64: Sequence, num_partitions: int):
         acc = acc * np.uint64(31) + z
     acc = acc ^ (acc >> np.uint64(33))
     return (acc % np.uint64(num_partitions)).astype(jnp.int32)
-
-
-def subbucket_ids(keys_u64: Sequence, num_sub: int):
-    """Second, INDEPENDENT hash of pre-normalized key columns into
-    ``num_sub`` sub-buckets — the hot-partition SPLIT salt for
-    aggregation/join inputs: equal keys land in equal sub-buckets (so a
-    group's rows stay co-located on one receiver), while the distinct
-    keys of a hot partition spread across receivers. Uses the murmur3
-    finalizer with different constants than ``hash_partition_ids`` so
-    the sub-bucket is uncorrelated with the partition id."""
-    acc = jnp.zeros(keys_u64[0].shape, dtype=jnp.uint64)
-    for k in keys_u64:
-        z = (k ^ np.uint64(0x94D049BB133111EB)) * np.uint64(0xFF51AFD7ED558CCD)
-        z = z ^ (z >> np.uint64(29))
-        acc = acc * np.uint64(37) + z
-    acc = acc ^ (acc >> np.uint64(32))
-    return (acc % np.uint64(num_sub)).astype(jnp.int32)
 
 
 def partition_histogram(part_ids, valid, num_partitions: int):

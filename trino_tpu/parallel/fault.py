@@ -257,8 +257,8 @@ class DecayingFailureStats:
 @dataclass
 class RecoveryStats:
     """What self-healing actually did, per query and cumulatively
-    (surfaced through QueryResult.stats['recovery'], EXPLAIN ANALYZE and
-    the bench output). Counters are bumped from parallel task threads,
+    (surfaced through QueryResult.stats['recovery'] and EXPLAIN
+    ANALYZE). Counters are bumped from parallel task threads,
     transport-retry callbacks and the monitor thread — mutate through
     the locked methods, not bare `+=`."""
 

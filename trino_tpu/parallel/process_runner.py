@@ -1185,10 +1185,10 @@ class ProcessQueryRunner:
                            [(line,) for line in lines])
 
     def profile_snapshot(self) -> dict:
-        """Cluster-wide flight-recorder table: the coordinator's
-        program registry merged with every live worker's (the
-        ``profile`` RPC), each row stamped with its process — the
-        BENCH_PROFILE.json body."""
+        """Cluster-wide kernel table: the coordinator's program
+        registry merged with every live worker's (the ``profile``
+        RPC), each row stamped with its process — EXPLAIN ANALYZE
+        VERBOSE's ``Kernels:`` line."""
         from ..telemetry import profiler
 
         kernels = [dict(k, process="coordinator")

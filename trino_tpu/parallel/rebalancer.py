@@ -85,7 +85,7 @@ class UniformPartitionRebalancer:
     """Logical-partition -> writer-lane assignment, adapted from
     observed per-partition row counts."""
 
-    #: process-wide count of assignment changes (bench/test
+    #: process-wide count of assignment changes (test
     #: observability, mirrors DeviceExchange.total_collectives)
     total_rebalances = 0
     _total_lock = threading.Lock()

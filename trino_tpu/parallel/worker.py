@@ -679,7 +679,7 @@ class WorkerServer:
                           memory_pool=None, tracer=None,
                           task_span=None) -> int:
         """Profiling envelope: SCOPED to this fragment execution (the
-        refcounted ``profiling`` context), so one VERBOSE/bench query
+        refcounted ``profiling`` context), so one VERBOSE query
         cannot leave the per-call profiled path enabled for every later
         query on this worker — the session property's zero-cost-when-
         off claim holds per task."""

@@ -304,10 +304,10 @@ class Optimizer:
 # kernel-strategy cost rules: MXU matmul join + global-hash aggregation
 # ("Density-optimized ... Matrix Multiplication for Join-Project" and
 # "Global Hash Tables Strike Back!", PAPERS.md).  ONE decision path for
-# the planner annotation, the session-property overrides, and the
-# device-mesh runtime (parallel/mesh_query consults choose_agg_strategy
-# with its observed group count), so the estimate EXPLAIN shows is the
-# estimate that executed.
+# the planner annotation and the session-property overrides.  The join
+# strategy reaches the operators (exec/local_planner hands it to the
+# joins); the aggregation strategy is an EXPLAIN annotation only: no
+# operator reads it and no planned statement runs ops/global_hash_agg.
 
 
 def _matmul_max_build_rows() -> int:

@@ -2,9 +2,10 @@
 process.
 
 Reference analog: ``core/trino-main/.../testing/LocalQueryRunner.java:254``
-— the single-node, no-HTTP engine used for fast correctness tests and
-operator benchmarks. The distributed runner builds on the same planner
-with exchanges between fragments (parallel/ package).
+— the single-node, no-HTTP engine used for fast correctness tests; here
+it is also what ``ProtocolServer`` serves on one chip. The distributed
+runner builds on the same planner with exchanges between fragments
+(parallel/ package).
 """
 
 from __future__ import annotations

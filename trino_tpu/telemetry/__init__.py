@@ -6,8 +6,8 @@ exposition surface, and the ``system.runtime`` introspection tables.
 Three integrated pieces, all dependency-free:
 
 - ``tracing``: Tracer/Span core with W3C-traceparent-style dict
-  context, Chrome-trace-event export (Perfetto-loadable) and
-  span-timeline analysis (critical path, stage overlap);
+  context, OTLP export and span-timeline analysis (span tree,
+  critical path);
 - ``metrics``: process-local counter/gauge/histogram registry with
   Prometheus text exposition and coordinator-side aggregation of
   heartbeat-piggybacked worker snapshots;
@@ -18,12 +18,11 @@ Three integrated pieces, all dependency-free:
 from .metrics import (ClusterMetrics, MetricsRegistry, merge_families,
                       process_families, relabel, render_prometheus)
 from .tracing import (NULL_TRACER, Span, Tracer, critical_path,
-                      span_tree, stage_overlap, to_chrome_trace,
-                      trace_line)
+                      span_tree, trace_line)
 
 __all__ = [
     "ClusterMetrics", "MetricsRegistry", "merge_families",
     "process_families", "relabel", "render_prometheus",
     "NULL_TRACER", "Span", "Tracer", "critical_path", "span_tree",
-    "stage_overlap", "to_chrome_trace", "trace_line",
+    "trace_line",
 ]

@@ -43,7 +43,7 @@ from typing import Dict, List, Optional, Tuple
 __all__ = [
     "enabled", "enable", "profiling", "instrument", "snapshot",
     "compiled_programs", "totals", "thread_totals", "reset", "device_memory_stats",
-    "diff_profiles", "validate_profile", "ProfiledFunction",
+    "ProfiledFunction",
 ]
 
 
@@ -133,7 +133,7 @@ def enable(on: bool = True):
 
 class profiling:
     """Context manager enabling the profiler for a scope (EXPLAIN
-    ANALYZE VERBOSE, bench flight-recorder runs).  Scopes REFCOUNT:
+    ANALYZE VERBOSE, the workers' ``profile`` RPC).  Scopes REFCOUNT:
     concurrent queries on different threads each hold a count, and the
     profiler only switches off when the last scope exits (a plain
     query's no-op scope can never clobber a profiled neighbor)."""
@@ -427,7 +427,7 @@ def instrument(name: str, jitted, key=None,
 
 def snapshot() -> List[dict]:
     """Every registry entry as a JSON-able dict, stable order (by name,
-    then key) — the system.runtime.kernels / BENCH_PROFILE.json rows."""
+    then key) — the system.runtime.kernels rows."""
     with _STATE.lock:
         entries = list(_STATE.entries.values())
     return sorted((e.to_dict() for e in entries),
@@ -485,116 +485,3 @@ def device_memory_stats() -> Optional[dict]:
                 "limit_bytes": limit}
     except Exception:  # qlint: ignore[taxonomy] device memory_stats is best-effort per backend; None = not reported
         return None
-
-
-# -- flight recorder -------------------------------------------------------
-
-
-def profile_document(role: str, extra: Optional[dict] = None,
-                     kernels: Optional[List[dict]] = None,
-                     table_totals: Optional[dict] = None) -> dict:
-    """The BENCH_PROFILE.json artifact body: per-kernel cost/compile/
-    trace table + totals + provenance.  ``kernels``/``table_totals``
-    override the local registry (the bench trace role installs the
-    cluster-merged table — the local registry would miss every
-    worker-compiled program)."""
-    import jax
-
-    doc = {
-        "version": 1,
-        "role": role,
-        "backend": jax.default_backend(),
-        "kernels": snapshot() if kernels is None else kernels,
-        "totals": totals() if table_totals is None else table_totals,
-    }
-    if extra:
-        doc.update(extra)
-    return doc
-
-
-def validate_profile(doc: dict) -> List[str]:
-    """Problems that make a profile artifact unusable (empty table,
-    zero recorded compile work, malformed rows) — the bench trace role
-    maps a non-empty list to its distinct rc."""
-    problems: List[str] = []
-    if not isinstance(doc, dict):
-        return ["artifact is not a JSON object"]
-    kernels = doc.get("kernels")
-    if not kernels:
-        problems.append("empty kernel table (profiler never engaged?)")
-        return problems
-    required = ("name", "compiles", "compile_ms", "flops",
-                "bytes_accessed")
-    for i, row in enumerate(kernels):
-        for f in required:
-            if f not in row:
-                problems.append(f"kernel[{i}] missing field {f!r}")
-                break
-    tot = doc.get("totals") or {}
-    if not tot.get("compiles"):
-        problems.append("totals.compiles == 0: disconnected profile")
-    if tot.get("compile_ms", 0.0) <= 0.0:
-        problems.append("totals.compile_ms == 0: no compile wall "
-                        "recorded")
-    return problems
-
-
-def _by_name(doc: dict) -> Dict[str, dict]:
-    agg: Dict[str, dict] = {}
-    for row in doc.get("kernels") or ():
-        a = agg.setdefault(row["name"], {
-            "compiles": 0, "calls": 0, "compile_ms": 0.0,
-            "trace_ms": 0.0, "flops": 0.0, "bytes_accessed": 0.0,
-            "programs": 0})
-        a["programs"] += 1
-        a["compiles"] += row.get("compiles", 0)
-        a["calls"] += row.get("calls", 0)
-        a["compile_ms"] += row.get("compile_ms", 0.0)
-        a["trace_ms"] += row.get("trace_ms", 0.0)
-        a["flops"] += row.get("flops", 0.0)
-        a["bytes_accessed"] += row.get("bytes_accessed", 0.0)
-    return agg
-
-
-def diff_profiles(old: dict, new: dict, cost_ratio: float = 1.5,
-                  compile_ratio: float = 2.0) -> List[dict]:
-    """Name the kernels that MOVED between two flight-recorder
-    artifacts: new/vanished kernels, extra compiled programs (a shape
-    or literal started recompiling), and per-kernel flops/bytes/compile
-    growth past the ratios.  Sorted worst-first by compile growth then
-    cost growth — the regression-attribution answer to 'the bench got
-    slower'."""
-    a, b = _by_name(old), _by_name(new)
-    moved: List[dict] = []
-    for name in sorted(set(a) | set(b)):
-        oa, nb = a.get(name), b.get(name)
-        if oa is None:
-            moved.append({"kernel": name, "change": "new-kernel",
-                          "detail": f"{nb['programs']} program(s), "
-                                    f"{nb['compile_ms']:.1f}ms compile"})
-            continue
-        if nb is None:
-            moved.append({"kernel": name, "change": "vanished"})
-            continue
-        if nb["programs"] > oa["programs"]:
-            moved.append({
-                "kernel": name, "change": "recompiled",
-                "detail": f"programs {oa['programs']} -> "
-                          f"{nb['programs']} (new shape/cache key)"})
-        for field, ratio in (("flops", cost_ratio),
-                             ("bytes_accessed", cost_ratio),
-                             ("compile_ms", compile_ratio)):
-            if oa[field] > 0 and nb[field] > oa[field] * ratio:
-                moved.append({
-                    "kernel": name, "change": f"{field}-grew",
-                    "detail": f"{oa[field]:.6g} -> {nb[field]:.6g} "
-                              f"({nb[field] / oa[field]:.2f}x)"})
-
-    def rank(m):
-        order = {"recompiled": 0, "compile_ms-grew": 1, "flops-grew": 2,
-                 "bytes_accessed-grew": 3, "new-kernel": 4,
-                 "vanished": 5}
-        return order.get(m["change"], 9)
-
-    moved.sort(key=rank)
-    return moved

@@ -438,30 +438,6 @@ class RuntimeStatsStore:
              "samples": [[{}, qerr]]},
         ]
 
-    def qerror_quantile(self, q: float) -> Optional[float]:
-        """Q-error quantile for bench reporting, linearly interpolated
-        WITHIN the landing bucket from the cumulative counts — a
-        regression that stays inside one bucket still moves the
-        reported value (the ratchet must see it).  The open-ended
-        bucket clamps to its lower bound."""
-        with self._lock:
-            count = self._qerr["count"]
-            buckets = [list(b) for b in self._qerr["buckets"]]
-        if not count:
-            return None
-        target = q * count
-        lo, prev_cum = 1.0, 0
-        for le, cum in buckets:
-            if cum >= target:
-                if le == float("inf"):
-                    return lo
-                in_bucket = cum - prev_cum
-                frac = (target - prev_cum) / in_bucket if in_bucket \
-                    else 1.0
-                return lo + frac * (le - lo)
-            lo, prev_cum = le, cum
-        return lo
-
     # -- persistence -------------------------------------------------------
 
     def save(self, path: str):

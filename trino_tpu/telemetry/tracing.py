@@ -1,4 +1,4 @@
-"""Distributed tracing core: spans, context propagation, exporters.
+"""Distributed tracing core: spans, context propagation, OTLP export.
 
 Reference analog: the reference engine's OpenTelemetry instrumentation —
 ``io.opentelemetry.api.trace.Span`` opened per query/stage/task/operator
@@ -6,12 +6,11 @@ with ``TrinoAttributes``, context propagated to workers in task requests
 (W3C ``traceparent``), and the resulting timeline viewable in any trace
 UI.  Here the core is dependency-free: spans are plain dicts once
 finished, context is a small dict riding the task RPC envelope, and the
-export target is the Chrome trace-event JSON format (loadable in
-Perfetto / chrome://tracing, one pid lane per process).
+one exporter is OTLP JSON over HTTP (``to_otlp`` / ``export_otlp``).
 
 Cost model: tracing must be zero-cost when off — ``NULL_TRACER.span()``
 returns a shared no-op span, and spans are NEVER opened inside jit'd
-code (host-side boundaries only), so the bench ratchet is untouched.
+code (host-side boundaries only), so no compiled program changes.
 In-memory spans are per statement, stage and operator (tens per
 statement), never per page: per-page detail is a profiler annotation
 (``annotation``: one flag test unless a profile is being taken) plus a
@@ -550,102 +549,6 @@ def slow_query_record(spans: Optional[List[dict]], wall_ms: float,
              "compile_ms": s["attrs"].get("compile_ms", 0.0)}
             for s in ops[:3]]
     return record
-
-
-def stage_overlap(spans: List[dict]) -> float:
-    """Fraction of busy task time during which tasks of >= 2 DIFFERENT
-    fragments ran concurrently — the streaming-pipeline metric (a
-    barrier execution scores ~0; a fully pipelined one approaches 1).
-    Computed over worker task-execution spans (span_kind=task)."""
-    tasks = [s for s in spans
-             if s.get("attrs", {}).get("span_kind") == "task"
-             and s.get("attrs", {}).get("fragment") is not None]
-    if len(tasks) < 2:
-        return 0.0
-    events = []
-    for s in tasks:
-        frag = s["attrs"]["fragment"]
-        events.append((s["start"], 1, frag))
-        events.append((s["end"], -1, frag))
-    events.sort(key=lambda e: (e[0], -e[1]))
-    active: Dict[object, int] = {}
-    busy = overlap = 0.0
-    prev = events[0][0]
-    for t, delta, frag in events:
-        if active:
-            busy += t - prev
-            if len(active) >= 2:
-                overlap += t - prev
-        prev = t
-        cnt = active.get(frag, 0) + delta
-        if cnt <= 0:
-            active.pop(frag, None)
-        else:
-            active[frag] = cnt
-    return overlap / busy if busy > 0 else 0.0
-
-
-# -- Chrome trace-event export --------------------------------------------
-
-
-def to_chrome_trace(spans: List[dict]) -> dict:
-    """Chrome trace-event JSON (Perfetto-loadable): one complete ("X")
-    event per span, one pid lane per process (coordinator, worker-NNN),
-    tids grouping operator spans under their task. Timestamps are
-    microseconds relative to the earliest span so the viewer opens at
-    t=0."""
-    if not spans:
-        return {"traceEvents": [], "displayTimeUnit": "ms"}
-    t0 = min(s["start"] for s in spans)
-    pids: Dict[str, int] = {}
-    tids: Dict[Tuple[int, str], int] = {}
-    events: List[dict] = []
-
-    def pid_for(process: str) -> int:
-        if process not in pids:
-            pids[process] = len(pids) + 1
-            events.append({"name": "process_name", "ph": "M",
-                           "pid": pids[process], "tid": 0,
-                           "args": {"name": process}})
-        return pids[process]
-
-    by_id = {s["span_id"]: s for s in spans}
-
-    def lane_for(s: dict) -> str:
-        # operator/exec spans share their owning task's lane; everything
-        # else gets a lane per span name (plan/fragment/attempt rows)
-        cur = s
-        seen = 0
-        while cur is not None and seen < 16:
-            task = cur.get("attrs", {}).get("task_id")
-            if task:
-                return str(task)
-            cur = by_id.get(cur.get("parent_id"))
-            seen += 1
-        return s["name"]
-
-    for s in spans:
-        pid = pid_for(s.get("process") or "?")
-        lane = lane_for(s)
-        key = (pid, lane)
-        if key not in tids:
-            tids[key] = len(tids) + 1
-            events.append({"name": "thread_name", "ph": "M",
-                           "pid": pid, "tid": tids[key],
-                           "args": {"name": lane}})
-        args = {k: v for k, v in s.get("attrs", {}).items()
-                if isinstance(v, (str, int, float, bool))}
-        args["span_id"] = s["span_id"]
-        if s.get("parent_id"):
-            args["parent_id"] = s["parent_id"]
-        events.append({
-            "name": s["name"], "cat": "span", "ph": "X",
-            "ts": round((s["start"] - t0) * 1e6, 3),
-            "dur": round(max(0.0, s["end"] - s["start"]) * 1e6, 3),
-            "pid": pid, "tid": tids[key], "args": args,
-        })
-    return {"traceEvents": events, "displayTimeUnit": "ms",
-            "otherData": {"trace_id": spans[0].get("trace_id")}}
 
 
 # -- OTLP JSON-over-HTTP export --------------------------------------------
