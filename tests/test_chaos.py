@@ -531,11 +531,14 @@ def test_barrier_driver_observes_abort_at_page_boundaries():
                 self._abort.set()
             return self._finish_at == self.quanta
 
+        def close(self):
+            self.closed = True
+
     # pre-set abort: not a single page moves
     d = Driver()
     with pytest.raises(RemoteTaskError):
         run_barrier_driver(d, _set_event())
-    assert d.quanta == 0
+    assert d.quanta == 0 and d.closed  # its scan reads ahead no more
     # abort lands mid-run: observed at the NEXT page boundary
     ev = threading.Event()
     d = Driver(abort=ev, abort_at=7)

@@ -20,7 +20,7 @@ from trino_tpu.exec.dynamic_filter import DynamicFilter
 from trino_tpu.exec.memory import (NodeMemoryExceededError, NodeMemoryPool,
                                    TableMemoryAccount, pool_from_session,
                                    resident_table_bytes)
-from trino_tpu.ops.operator import TableScanOperator
+from trino_tpu.ops.operator import TableScanOperator, _ScanPages
 from trino_tpu.resources.tpch_queries import TPCH_QUERIES
 from trino_tpu.runner import LocalQueryRunner
 from trino_tpu.server.protocol import ProtocolServer
@@ -110,8 +110,8 @@ def test_scan_of_a_resident_table_uploads_nothing(qid, loaded,
         "enforce", spi.enforce_constraint_page))
     monkeypatch.setattr(Page, "concat", staticmethod(
         counting("concat", Page.concat)))
-    monkeypatch.setattr(TableScanOperator, "_upload_page", counting(
-        "upload", TableScanOperator._upload_page))
+    monkeypatch.setattr(_ScanPages, "_upload", counting(
+        "upload", _ScanPages._upload))
     res = runner.execute(TPCH_QUERIES[qid])
     assert res.rows
     assert calls == {"from_page": 0, "enforce": 0, "concat": 0,

@@ -148,6 +148,46 @@ def test_runner_opens_its_own_root_with_no_current_span(runner, template):
         assert a["generate_s"] > 0 and a["upload_s"] > 0
 
 
+def test_scan_spans_carry_the_read_ahead_counters_and_their_reader():
+    """A traced q1 whose scan has sixteen pages: all but the first come
+    from the producer, and ``scan_wait_s_per_query`` reads the mean of
+    the scans' ``wait_s``; a resident scan reads ahead nothing and
+    waits 0.0."""
+    from benchmark.layer_metrics import scan_wait_s_per_query
+    from benchmark.run import RunFacts
+    from trino_tpu.connectors.memory import MemoryConnector
+
+    mem = MemoryConnector(schemas=["tiny"])
+    mem.page_rows = 8192
+    r = LocalQueryRunner(
+        {"tpch": TpchConnector(page_rows=1024), "memory": mem},
+        Session(catalog="tpch", schema="tiny"), desired_splits=4)
+    r.execute("create table memory.tiny.lineitem as select * from lineitem")
+
+    def scans_of(sqls):
+        t_open = time.perf_counter()
+        traces = [r.execute(sql).stats["trace"] for sql in sqls]
+        facts = RunFacts(window_open=t_open,
+                         window_close=time.perf_counter())
+        return [s["attrs"] for spans in traces for s in spans
+                if s["name"] == "TableScanOperator"], facts
+
+    scans, facts = scans_of(SQL["q1"][:2])
+    assert len(scans) == 2
+    for a in scans:
+        assert a["pages"] == 16 and a["readahead_pages"] == 15
+        assert 0 <= a["readahead_ready"] <= 15
+        assert a["wait_s"] >= 0.0 and a["generate_s"] > 0
+    assert scan_wait_s_per_query.read(facts) == pytest.approx(
+        sum(a["wait_s"] for a in scans) / 2)
+
+    scans, facts = scans_of(
+        ["select sum(l_quantity) from memory.tiny.lineitem"])
+    assert scans[0]["resident_pages"] > 1
+    assert scans[0]["readahead_pages"] == 0 and scans[0]["wait_s"] == 0.0
+    assert scan_wait_s_per_query.read(facts) == 0.0
+
+
 @pytest.mark.parametrize("template", TEMPLATES)
 def test_plan_attributes_flip_between_executions(template, monkeypatch):
     from trino_tpu import cache

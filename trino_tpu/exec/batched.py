@@ -720,18 +720,21 @@ def execute_batched(plan, param_types, bindings: Sequence[Tuple],
             raise BatchIneligible("params_unconsumed")
         final.append(page)
 
-    while True:
-        dpage = scan.get_output()
-        if dpage is None:
-            if scan.is_finished():
-                break
-            continue
-        cnt = jnp.sum(dpage.valid)
-        scan_rows_acc = cnt if scan_rows_acc is None \
-            else scan_rows_acc + cnt
-        run_from(0, _BatchPage(list(dpage.types), tuple(dpage.cols),
-                               tuple(dpage.nulls), dpage.valid,
-                               list(dpage.dictionaries), False))
+    try:
+        while True:
+            dpage = scan.get_output()
+            if dpage is None:
+                if scan.is_finished():
+                    break
+                continue
+            cnt = jnp.sum(dpage.valid)
+            scan_rows_acc = cnt if scan_rows_acc is None \
+                else scan_rows_acc + cnt
+            run_from(0, _BatchPage(list(dpage.types), tuple(dpage.cols),
+                                   tuple(dpage.nulls), dpage.valid,
+                                   list(dpage.dictionaries), False))
+    finally:
+        scan.close()    # a stage that raised leaves no scan reading ahead
     # the shared scan's host-side counters, on the span around this call
     # (no driver ran it, so no operator span carries them)
     for key, value in (getattr(scan, "metrics", dict)() or {}).items():

@@ -203,7 +203,27 @@ class Driver:
 
     def process(self) -> bool:
         """One scheduling quantum: move pages between adjacent operators.
-        Returns True if the driver is fully finished."""
+        Returns True if the driver is fully finished.  A driver that has
+        finished, or whose operator raised, has closed its operators."""
+        try:
+            done = self._move_pages()
+        except BaseException:
+            self.close()
+            raise
+        if done:
+            self.close()
+        return done
+
+    def close(self):
+        """Nothing more will be asked of the operators: the source
+        releases what outlives a call (a scan stopped short of its end —
+        LIMIT, a failure downstream, an aborted task — stops its
+        producer thread)."""
+        src = self.source
+        if src is not None:
+            src.close()
+
+    def _move_pages(self) -> bool:
         ops = self.operators
         moved = False
         for i in range(len(ops) - 1):

@@ -13,7 +13,10 @@ output (device -> numpy).
 from __future__ import annotations
 
 import contextlib
+import queue
+import threading
 import time
+import weakref
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -61,6 +64,10 @@ class SourceOperator(Operator):
     def no_more_splits(self):
         pass
 
+    def close(self):
+        """The driver is done with this source, finished or not:
+        release what outlives a call (a scan's producer thread)."""
+
     def add_input(self, page):
         raise AssertionError("source operators take splits, not pages")
 
@@ -68,146 +75,128 @@ class SourceOperator(Operator):
         return False
 
 
-class TableScanOperator(SourceOperator):
-    """Pulls pages from connector page sources and uploads them to device
-    (reference: operator/TableScanOperator.java).
+#: device pages the producer of a host scan may hold ahead of the driver
+READAHEAD_PAGES = 2
 
-    Small pages (split tails: a table cut into many splits yields pages
-    far below the connector's page size) COALESCE on host up to
-    ``coalesce_rows`` before the upload, so downstream kernels see one
-    full device batch instead of one launch per fragment (reference:
-    ``operator/MergePages.java`` — the min-page-size rewindow in front
-    of expensive operators).
 
-    A source whose table lives on the device
-    (``provides_device_pages``) is not uploaded from: its pages pass
-    through as they lie, dynamic filters applied, nothing buffered."""
+def _timed(counters: Optional[dict], name: str, key: str, fn, *args):
+    """``fn(*args)``; in a traced statement (``counters`` is its scan's)
+    its wall is added to ``counters[key]`` and the call is the profiler
+    annotation ``name`` on the calling thread."""
+    if counters is None:
+        return fn(*args)
+    with tracing.annotation(name):
+        t0 = time.perf_counter()
+        try:
+            return fn(*args)
+        finally:
+            counters[key] += time.perf_counter() - t0
 
-    def __init__(self, connector: Connector, columns: Sequence[ColumnHandle],
-                 dynamic_filters: Sequence = (),
-                 coalesce_rows: Optional[int] = None,
-                 progress=None):
+
+class _ScanPages:
+    """A scan's device pages in the order its splits were added, before
+    dynamic filters: the connector's host pages generated, coalesced and
+    uploaded, or a resident source's pages as they lie.  One walk,
+    whichever thread calls ``next_page`` (one at a time): the driver's,
+    or the producer's of a ``_ReadAhead``.  It knows its connector and
+    not its operator, so an operator dropped unfinished can be collected
+    and its producer stopped."""
+
+    def __init__(self, connector: Connector,
+                 columns: Sequence[ColumnHandle],
+                 coalesce_rows: Optional[int]):
         self.connector = connector
-        self.columns = list(columns)
-        #: telemetry.progress.QueryProgress fed host-page row counts as
-        #: splits are read — a plain int add, never a device sync
-        self.progress = progress
-        # [(channel, DynamicFilter)] — join build-side domains applied to
-        # every scanned page as a lane-mask update (reference analog:
-        # dynamic-filter TupleDomains pushed into ConnectorPageSource)
-        self.dynamic_filters = list(dynamic_filters)
+        self.columns = columns
         self.coalesce_rows = coalesce_rows
+        self.splits: List[ConnectorSplit] = []
+        self.no_more_splits = False
+        #: the walk has given its last page
+        self.done = False
+        #: the operator's counters in a traced statement
+        self.counters: Optional[dict] = None
+        self._source = None
+        #: the source opened last yields host pages
+        self._on_host = False
         self._buffer: List[Page] = []
         self._buffered_rows = 0
-        self._splits: List[ConnectorSplit] = []
-        self._source = None
-        self._no_more_splits = False
-        self._done = False
-        #: host-side counters of a traced statement (None: tracing off):
-        #: seconds in the connector's page generation and the coalescing
-        #: concat, seconds of the host-to-device upload — the host's
-        #: share of a scan, which overlaps device work and so shows in
-        #: no idle gap; and, from page metadata, the pages and bytes
-        #: taken as they lay on the device against the bytes uploaded
-        self._counters: Optional[dict] = None
-        self._counters_known = False
+        #: one walker at a time: the driver's thread hands the walk to
+        #: the producer's and takes it back in ``close()``
+        self._lock = threading.Lock()
 
-    def add_split(self, split: ConnectorSplit):
-        self._splits.append(split)
+    def may_have_more_on_host(self) -> bool:
+        """After a page: another may follow it, made on the host, and
+        every split is known — what a producer thread is started on."""
+        with self._lock:
+            return self._on_host and self.no_more_splits and (
+                bool(self.splits) or (self._source is not None
+                                      and not self._source.is_finished()))
 
-    def no_more_splits(self):
-        self._no_more_splits = True
+    def close(self):
+        with self._lock:
+            if self._source is not None:
+                self._source.close()
+                self._source = None
+            self._buffer = []
+            self.done = True
 
-    def metrics(self) -> Optional[dict]:
-        return self._counters
-
-    def _timed(self, name: str, key: str, fn, *args):
-        """``fn(*args)``; in a traced statement its wall is added to the
-        counter ``key`` and the call is the annotation ``name``."""
-        c = self._counters
-        if c is None:
-            return fn(*args)
-        with tracing.annotation(name):
-            t0 = time.perf_counter()
-            try:
-                return fn(*args)
-            finally:
-                c[key] += time.perf_counter() - t0
-
-    def _upload(self, page: Page) -> DevicePage:
-        return self._timed("scan.upload", "upload_s",
-                           self._upload_page, page)
-
-    def _upload_page(self, page: Page) -> DevicePage:
-        dp = DevicePage.from_page(page)
-        if self._counters is not None:
+    def _upload(self, page: Page):
+        dp = _timed(self.counters, "scan.upload", "upload_s",
+                    DevicePage.from_page, page)
+        if self.counters is not None:
             from ..exec.memory import device_page_bytes
 
-            self._counters["uploaded_bytes"] += device_page_bytes(dp)
-        return self._filtered(dp)
+            self.counters["uploaded_bytes"] += device_page_bytes(dp)
+        return dp, page.num_rows
 
-    def _filtered(self, dp: DevicePage) -> DevicePage:
-        for ch, df in self.dynamic_filters:
-            dp = DevicePage(dp.types, dp.cols, dp.nulls,
-                            df.apply(dp.cols[ch], dp.nulls[ch],
-                                     dp.valid),
-                            dp.dictionaries)
-        return dp
+    def _flush(self):
+        pages, self._buffer = self._buffer, []
+        self._buffered_rows = 0
+        return self._upload(pages[0] if len(pages) == 1
+                            else _timed(self.counters, "scan.generate",
+                                        "generate_s", Page.concat, pages))
 
-    def _next_resident(self) -> Optional[DevicePage]:
+    def _next_resident(self):
         """The source's next page that holds a row, as it lies on the
         device; row and byte counts come from its metadata (no sync)."""
         while True:
             page = self._source.get_next_device_page()
-            if page is None:
-                return None
-            if page.rows == 0:
-                continue
-            if self.progress is not None:
-                self.progress.add_rows(page.rows)
-            if self._counters is not None:
-                self._counters["resident_pages"] += 1
-                self._counters["resident_bytes"] += page.nbytes
-            return self._filtered(page)
+            if page is None or page.rows:
+                break
+        if page is None:
+            return None
+        if self.counters is not None:
+            self.counters["resident_pages"] += 1
+            self.counters["resident_bytes"] += page.nbytes
+        return page, page.rows
 
-    def _flush(self) -> DevicePage:
-        pages, self._buffer = self._buffer, []
-        self._buffered_rows = 0
-        return self._upload(pages[0] if len(pages) == 1
-                            else self._timed("scan.generate",
-                                             "generate_s",
-                                             Page.concat, pages))
+    def next_page(self):
+        """``(device page, its rows)``, or None: at the end (``done``),
+        while splits are still to come, or where the source stalled."""
+        with self._lock:
+            return self._next_page()
 
-    def get_output(self) -> Optional[DevicePage]:
-        if not self._counters_known:
-            # first call: the statement's span, if any, is current now
-            self._counters_known = True
-            if tracing.current_span() is not None:
-                self._counters = {"generate_s": 0.0, "upload_s": 0.0,
-                                  "resident_pages": 0, "resident_bytes": 0,
-                                  "uploaded_bytes": 0}
+    def _next_page(self):
         while True:
             if self._source is None:
-                if self._splits:
-                    split = self._splits.pop(0)
+                if self.splits:
                     self._source = self.connector.page_source(
-                        split, self.columns)
-                elif self._no_more_splits or self._finishing:
-                    if self._buffer:
-                        return self._flush()
-                    self._done = True
-                    return None
+                        self.splits.pop(0), self.columns)
+                    self._on_host = \
+                        not self._source.provides_device_pages
+                elif self._buffer:
+                    return self._flush()
                 else:
-                    return self._flush() if self._buffer else None
-            if self._source.provides_device_pages:
-                dp = self._next_resident()
-                if dp is not None:
-                    return dp
+                    self.done = self.no_more_splits
+                    return None
+            if not self._on_host:
+                got = self._next_resident()
+                if got is not None:
+                    return got
                 self._source.close()
                 self._source = None
                 continue
-            page = self._timed("scan.generate", "generate_s",
-                               self._source.get_next_page)
+            page = _timed(self.counters, "scan.generate", "generate_s",
+                          self._source.get_next_page)
             if page is None:
                 if self._source.is_finished():
                     self._source.close()
@@ -217,8 +206,6 @@ class TableScanOperator(SourceOperator):
                 return self._flush() if self._buffer else None
             if page.num_rows == 0:
                 continue
-            if self.progress is not None:
-                self.progress.add_rows(page.num_rows)
             target = self.coalesce_rows
             if target and page.num_rows < target:
                 self._buffer.append(page)
@@ -231,6 +218,214 @@ class TableScanOperator(SourceOperator):
                 self._buffered_rows += page.num_rows
                 return self._flush()
             return self._upload(page)
+
+
+class _ReadAhead:
+    """The producer of a host scan: a thread that walks ``pages`` at
+    most ``READAHEAD_PAGES`` ahead of ``take()``, the page it is making
+    included, uploading to ``device`` (``jax.default_device`` is
+    thread-local: the driver's thread says where its task runs)."""
+
+    def __init__(self, pages: _ScanPages, device):
+        self._pages = pages
+        self._device = device
+        self._room = threading.Semaphore(READAHEAD_PAGES)
+        #: ``(page and rows | None at the end, exception | None)``
+        self._made: queue.SimpleQueue = queue.SimpleQueue()
+        self._last = None
+        self._stopped = threading.Event()
+        self.thread = threading.Thread(target=self._run, daemon=True,
+                                       name="scan-readahead")
+        self.thread.start()
+
+    def _run(self):
+        import jax
+
+        try:
+            with jax.default_device(self._device):
+                while True:
+                    self._room.acquire()
+                    if self._stopped.is_set():
+                        return
+                    got = self._pages.next_page()
+                    if got is not None:
+                        self._made.put((got, None))
+                    elif self._pages.done:
+                        self._made.put((None, None))
+                        return
+                    else:
+                        # the source stalled: ask again shortly
+                        self._room.release()
+                        if self._stopped.wait(0.001):
+                            return
+        except BaseException as e:  # noqa: BLE001 - raised by take()
+            self._made.put((None, e))
+
+    def ready(self) -> bool:
+        return self._last is not None or not self._made.empty()
+
+    def take(self):
+        """The next page and its rows, waiting for it if need be; None
+        at the end; the producer's exception where it failed."""
+        if self._last is None:
+            got, error = self._made.get()
+            if got is not None:
+                self._room.release()
+                return got
+            self._last = (None, error)
+        if self._last[1] is not None:
+            raise self._last[1]
+        return None
+
+    def stop(self):
+        """No page is started after this; the thread ends once the one
+        it is making is made."""
+        self._stopped.set()
+        self._room.release()
+
+    def close(self):
+        """``stop()``, wait for the thread's end, drop what it made."""
+        self.stop()
+        self.thread.join()
+        self._made = queue.SimpleQueue()
+        self._last = (None, None)
+
+
+class TableScanOperator(SourceOperator):
+    """Hands a connector's pages to its pipeline as device pages
+    (reference: operator/TableScanOperator.java).
+
+    Small pages (split tails: a table cut into many splits yields pages
+    far below the connector's page size) COALESCE on host up to
+    ``coalesce_rows`` before the upload, so downstream kernels see one
+    full device batch instead of one launch per fragment (reference:
+    ``operator/MergePages.java`` — the min-page-size rewindow in front
+    of expensive operators).
+
+    A host source is READ AHEAD.  The first page is generated and
+    uploaded by ``get_output`` itself; if another may follow it, the
+    rest of the walk (``_ScanPages``: the same splits in the same
+    order, the same coalescing) moves to a producer thread that stays at
+    most ``READAHEAD_PAGES`` pages ahead, and ``get_output`` takes a
+    finished page from it, blocking until one is there.  So the host's
+    page generation runs while the driver's thread waits for the device
+    (an aggregation's per-page ``host_sync``) instead of after it.  A
+    scan whose first page is its only one starts no thread.  Dynamic
+    filters, the progress counter and the end of the scan stay on the
+    driver's thread: a filter that arrives late applies to every page
+    taken after it.  ``close()`` — from ``finish()``, or from the
+    driver when its pipeline ends or fails — stops the producer and
+    drops what it held.
+
+    A source whose table lives on the device
+    (``provides_device_pages``) is not uploaded from and not read
+    ahead: its pages pass through as they lie, dynamic filters applied,
+    nothing buffered."""
+
+    def __init__(self, connector: Connector, columns: Sequence[ColumnHandle],
+                 dynamic_filters: Sequence = (),
+                 coalesce_rows: Optional[int] = None,
+                 progress=None):
+        #: telemetry.progress.QueryProgress fed each page's row count as
+        #: it is handed out — a plain int add, never a device sync
+        self.progress = progress
+        # [(channel, DynamicFilter)] — join build-side domains applied to
+        # every scanned page as a lane-mask update (reference analog:
+        # dynamic-filter TupleDomains pushed into ConnectorPageSource)
+        self.dynamic_filters = list(dynamic_filters)
+        self._pages = _ScanPages(connector, list(columns), coalesce_rows)
+        self._ahead: Optional[_ReadAhead] = None
+        self._done = False
+        #: host-side counters of a traced statement (None: tracing off).
+        #: ``generate_s`` (the connector's page generation and the
+        #: coalescing concat) and ``upload_s`` (pad + host-to-device
+        #: copy) are the host's share of the scan: on the driver's
+        #: thread for the first page — where they are bare on the
+        #: statement's path, the device idle — and on the producer's
+        #: for the rest, where they overlap whatever the driver does
+        #: or waits for.  ``wait_s`` is what stayed on the path of that
+        #: rest: seconds ``get_output`` waited for a page the producer
+        #: had not finished; ``readahead_pages`` counts the pages taken
+        #: from the producer and ``readahead_ready`` those that were
+        #: ready when asked for.  From page metadata: the pages and
+        #: bytes taken as they lay on the device against the bytes
+        #: uploaded
+        self._counters: Optional[dict] = None
+        self._counters_known = False
+
+    def add_split(self, split: ConnectorSplit):
+        self._pages.splits.append(split)
+
+    def no_more_splits(self):
+        self._pages.no_more_splits = True
+
+    def metrics(self) -> Optional[dict]:
+        return self._counters
+
+    def _filtered(self, dp: DevicePage) -> DevicePage:
+        for ch, df in self.dynamic_filters:
+            dp = DevicePage(dp.types, dp.cols, dp.nulls,
+                            df.apply(dp.cols[ch], dp.nulls[ch],
+                                     dp.valid),
+                            dp.dictionaries)
+        return dp
+
+    def _take_ahead(self):
+        """The producer's next page (None at its end)."""
+        ready = self._ahead.ready()
+        c = self._counters
+        got = self._ahead.take() if ready or c is None else \
+            _timed(c, "scan.wait", "wait_s", self._ahead.take)
+        if c is not None and got is not None:
+            c["readahead_pages"] += 1
+            c["readahead_ready"] += ready
+        return got
+
+    def get_output(self) -> Optional[DevicePage]:
+        if self._done:
+            return None
+        if not self._counters_known:
+            # first call: the statement's span, if any, is current now
+            self._counters_known = True
+            if tracing.current_span() is not None:
+                self._counters = self._pages.counters = {
+                    "generate_s": 0.0, "upload_s": 0.0, "wait_s": 0.0,
+                    "readahead_pages": 0, "readahead_ready": 0,
+                    "resident_pages": 0, "resident_bytes": 0,
+                    "uploaded_bytes": 0}
+        if self._ahead is not None:
+            got = self._take_ahead()
+            if got is None:
+                self.close()
+        else:
+            got = self._pages.next_page()
+            self._done = self._pages.done
+            if got is not None and self._pages.may_have_more_on_host():
+                import jax
+
+                self._ahead = _ReadAhead(self._pages,
+                                         jax.config.jax_default_device)
+                # an operator dropped unfinished takes its producer along
+                weakref.finalize(self, self._ahead.stop)
+        if got is None:
+            return None
+        page, rows = got
+        if self.progress is not None:
+            self.progress.add_rows(rows)
+        return self._filtered(page)
+
+    def finish(self):
+        super().finish()
+        self.close()
+
+    def close(self):
+        """The scan gives nothing more: no producer is left running and
+        no page it made is held."""
+        self._done = True
+        ahead, self._ahead = self._ahead, None
+        if ahead is not None:
+            ahead.close()
+        self._pages.close()
 
     def is_finished(self) -> bool:
         return self._done

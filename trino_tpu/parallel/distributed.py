@@ -551,20 +551,25 @@ class DistributedQueryRunner:
             pipelines = planner.pipelines
         for p in pipelines:
             d = Driver(p.operators, collect_stats=collect)
-            for _ in range(10_000_000):
-                if d.process():
-                    break
-                if streaming:
-                    # park only after a NO-PROGRESS quantum: a blocked
-                    # source with runnable downstream work must keep
-                    # running
-                    toks = [] if d.last_moved else d.blocked_tokens()
-                    yield Blocked(toks) if toks else None
+            try:
+                for _ in range(10_000_000):
+                    if d.process():
+                        break
+                    if streaming:
+                        # park only after a NO-PROGRESS quantum: a
+                        # blocked source with runnable downstream work
+                        # must keep running
+                        toks = [] if d.last_moved else d.blocked_tokens()
+                        yield Blocked(toks) if toks else None
+                    else:
+                        yield  # quantum boundary: hand the thread back
                 else:
-                    yield  # quantum boundary: hand the thread back
-            else:
-                raise T.TrinoError("driver did not finish",
-                                   "GENERIC_INTERNAL_ERROR")
+                    raise T.TrinoError("driver did not finish",
+                                       "GENERIC_INTERNAL_ERROR")
+            finally:
+                # a task dropped between quanta leaves no scan reading
+                # ahead
+                d.close()
             if collect:
                 d.collect_operator_metrics()
                 task.operators.extend(d.stats)

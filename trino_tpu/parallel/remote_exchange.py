@@ -461,14 +461,17 @@ def run_barrier_driver(driver, abort: threading.Event,
     next page boundary."""
     from .fault import INTERNAL, RemoteTaskError
 
-    for _ in range(max_quanta):
-        if abort.is_set():
-            raise RemoteTaskError("task aborted", INTERNAL)
-        if driver.process():
-            return
-    raise RemoteTaskError(
-        f"driver did not finish within {max_quanta} quanta "
-        "(stuck pipeline?)", INTERNAL)
+    try:
+        for _ in range(max_quanta):
+            if abort.is_set():
+                raise RemoteTaskError("task aborted", INTERNAL)
+            if driver.process():
+                return
+        raise RemoteTaskError(
+            f"driver did not finish within {max_quanta} quanta "
+            "(stuck pipeline?)", INTERNAL)
+    finally:
+        driver.close()      # an aborted task's scan stops reading ahead
 
 
 def run_driver_blocking(driver, abort: threading.Event,
@@ -479,26 +482,29 @@ def run_driver_blocking(driver, abort: threading.Event,
     from .fault import INTERNAL, RemoteTaskError
 
     idle_since = None
-    while True:
-        if abort.is_set():
-            raise RemoteTaskError("task aborted", INTERNAL)
-        if driver.process():
-            return
-        if driver.last_moved:
-            idle_since = None
-            continue
-        toks = driver.blocked_tokens()
-        if toks:
-            wait_tokens(toks, timeout=0.25)
-            idle_since = None
-        else:
-            # runnable but idle quantum (e.g. operator waiting on an
-            # internal condition): spin gently, bounded
-            now = time.monotonic()
-            if idle_since is None:
-                idle_since = now
-            elif now - idle_since > max_idle_s:
-                raise RemoteTaskError("driver made no progress for "
-                                      f"{max_idle_s}s (stuck "
-                                      f"pipeline?)", INTERNAL)
-            time.sleep(0.002)
+    try:
+        while True:
+            if abort.is_set():
+                raise RemoteTaskError("task aborted", INTERNAL)
+            if driver.process():
+                return
+            if driver.last_moved:
+                idle_since = None
+                continue
+            toks = driver.blocked_tokens()
+            if toks:
+                wait_tokens(toks, timeout=0.25)
+                idle_since = None
+            else:
+                # runnable but idle quantum (e.g. operator waiting on an
+                # internal condition): spin gently, bounded
+                now = time.monotonic()
+                if idle_since is None:
+                    idle_since = now
+                elif now - idle_since > max_idle_s:
+                    raise RemoteTaskError("driver made no progress for "
+                                          f"{max_idle_s}s (stuck "
+                                          f"pipeline?)", INTERNAL)
+                time.sleep(0.002)
+    finally:
+        driver.close()

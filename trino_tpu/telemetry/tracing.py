@@ -417,9 +417,10 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
         if st.metrics:
             # the scan operator's host-side counters and the
             # aggregation's partial widths, under their names
-            for key in ("generate_s", "upload_s", "resident_pages",
-                        "resident_bytes", "uploaded_bytes",
-                        "partial_lanes"):
+            for key in ("generate_s", "upload_s", "wait_s",
+                        "readahead_pages", "readahead_ready",
+                        "resident_pages", "resident_bytes",
+                        "uploaded_bytes", "partial_lanes"):
                 if st.metrics.get(key) is not None:
                     span["attrs"][key] = st.metrics[key]
             for key in ("kind", "first_page_ms", "reconnects",
