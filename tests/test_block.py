@@ -1,4 +1,5 @@
 import numpy as np
+import pytest
 
 from trino_tpu import types as T
 from trino_tpu.block import Block, Dictionary, Page, padded_size
@@ -72,3 +73,60 @@ def test_page_concat_with_nulls():
     p2 = Page.from_pylists([T.BIGINT], [[3]])
     out = Page.concat([p1, p2])
     assert out.block(0).to_pylist() == [1, None, 3]
+
+
+def _encode_one_by_one(d, strings, null_value=""):
+    """``encode`` as it was: ``code()`` a value, in order."""
+    out = []
+    for s in strings:
+        if s is None:
+            if not d.values:
+                d.code(null_value)
+            out.append(0)
+        else:
+            out.append(d.code(s))
+    return out
+
+
+@pytest.mark.parametrize("pool, strings, null_value", [
+    ([], ["a", "b", "a", "c"], ""),
+    ([], [None, "a", None, "b", "a"], ""),       # NULL first: "" is code 0
+    ([], ["a", None, "b"], ""),                  # NULL later: code 0 is "a"
+    ([], [], ""),
+    ([], [None], ""),
+    (["p", "q"], ["q", "p", None, "q"], ""),      # nothing new: no lock taken
+    (["p", "q"], ["r", "q", "r", "s"], ""),
+    ([], [(1, 2), None, (3,), (1, 2)], ()),
+], ids=["new", "null_first", "null_later", "empty", "only_null",
+        "all_known", "some_new", "tuples"])
+def test_encode_a_batch_equals_code_a_value(pool, strings, null_value):
+    batch, single = Dictionary(pool), Dictionary(pool)
+    got = batch.encode(strings, null_value=null_value)
+    assert got.dtype == np.int32 and got.shape == (len(strings),)
+    assert got.tolist() == _encode_one_by_one(single, strings, null_value)
+    assert list(batch.values) == list(single.values)
+    assert batch.decode(got[[s is not None for s in strings]]) == \
+        [s for s in strings if s is not None]
+
+
+def test_concurrent_encoders_grow_one_pool_consistently():
+    """Four scan tasks growing one connector pool (a distributed CTAS):
+    every value gets one code, whichever thread met it first."""
+    import threading
+
+    d = Dictionary()
+    batches = [[f"v{(7 * t + i) % 5000}" for i in range(20000)]
+               for t in range(4)]
+    codes = [None] * 4
+
+    def run(t):
+        codes[t] = d.encode(batches[t])
+
+    threads = [threading.Thread(target=run, args=(t,)) for t in range(4)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    assert len(d.values) == len(set(d.values)) == 5000
+    for t in range(4):
+        assert d.decode(codes[t]) == batches[t]

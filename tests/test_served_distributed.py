@@ -105,6 +105,44 @@ def test_q3_runs_five_collectives_and_finishes_its_root(client):
     assert root["t1"] >= root["t0"]
 
 
+def test_q3_tree_has_plan_execute_and_a_task_span_a_task(client):
+    """A distributed statement's tree is the local runner's — ``parse``,
+    ``plan``, ``execute`` under ``statement.run`` — with one ``task``
+    span a task under ``execute``, each on its device, the operators'
+    spans under their task, and the tasks' blocking reads counted on
+    the root."""
+    t0 = time.perf_counter()
+    client.execute(template_sql("q3"))
+    root, = statement_roots(t0)
+    traces, _ = tracing.RING.since(t0)
+    tree, = [spans for spans in traces if root in spans]
+    by_name = {}
+    for s in tree:
+        by_name.setdefault(s["name"], []).append(s)
+    assert [len(by_name[n]) for n in ("parse", "plan", "execute")] == \
+        [1, 1, 1]
+    run, = by_name["statement.run"]
+    execute, = by_name["execute"]
+    assert execute["parent_id"] == run["span_id"]
+    tasks = by_name["task"]
+    assert all(t["parent_id"] == execute["span_id"] for t in tasks)
+    by_fragment = {}
+    for t in tasks:
+        by_fragment.setdefault(t["attrs"]["fragment"], []).append(
+            (t["attrs"]["task"], t["attrs"]["device"]))
+    assert len(by_fragment) >= 6        # five hash boundaries
+    for placed in by_fragment.values():
+        # worker i runs on device i; a single-task fragment on device 0
+        assert sorted(placed) in ([(0, 0)], [(i, i) for i in range(4)])
+    task_ids = {t["span_id"] for t in tasks}
+    operators = [s for s in tree
+                 if s["attrs"].get("span_kind") == "operator"]
+    assert operators and all(s["parent_id"] in task_ids for s in operators)
+    assert not tracing.span_tree(tree)[2]       # no orphan
+    assert root["attrs"]["host_syncs"] > 0
+    assert root["attrs"]["host_sync_s"] > 0
+
+
 def test_failing_statement_reaches_the_client_with_its_root_closed(client):
     t0 = time.perf_counter()
     with pytest.raises(TrinoError) as err:

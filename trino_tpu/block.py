@@ -124,15 +124,29 @@ class Dictionary:
         ``null_value`` is the pool placeholder kept type-homogeneous
         ("" for strings, () for arrays) so rank sorting never compares
         across types."""
-        out = np.empty(len(strings), dtype=np.int32)
-        for i, s in enumerate(strings):
-            if s is None:
-                if not self.values:
-                    self.code(null_value)  # keep code 0 decodable
-                out[i] = 0
-            else:
-                out[i] = self.code(s)
-        return out
+        get = self._index.get
+        codes = [0 if s is None else get(s) for s in strings]
+        if None in codes or not self.values:
+            # values to add: under the lock once for the batch, in the
+            # order they come.  (Once a value, the tasks that load one
+            # table from a shared pool convoy on the lock: a distributed
+            # CTAS of SF1 ``lineitem`` by four tasks took five times a
+            # local one's time, nearly all of it here.)
+            with self._lock:
+                index, values = self._index, self.values
+                for i, s in enumerate(strings):
+                    if s is None:
+                        if not values:      # keep code 0 decodable
+                            index[null_value] = 0
+                            values.append(null_value)
+                    elif codes[i] is None:
+                        c = index.get(s)
+                        if c is None:
+                            c = index[s] = len(values)
+                            values.append(s)
+                        codes[i] = c
+                self._sort_rank = None
+        return np.asarray(codes, dtype=np.int32)
 
     def decode(self, codes: np.ndarray) -> list:
         vals = self.values

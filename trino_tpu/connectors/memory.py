@@ -8,16 +8,28 @@ table's pages are kept as ``DevicePage``s on the device of the task
 that wrote them, cut once at write time to ``page_rows`` lanes, string
 columns as codes into the table's one dictionary (which stays on the
 host, as everywhere in the engine).  A scan takes the pages as they
-lie (``ResidentPageSource``): no host page, no concat, no upload.  A
-reader pinned to another device gets a device-to-device transfer of
-the page, not a host round trip.
+lie (``ResidentPageSource``): no host page, no concat, no upload.
+
+A table written by the tasks of several workers lies on several
+devices, each writer's pages on its own (reference: every worker keeps
+what its writer tasks wrote).  Its splits then cover the pages of one
+device each and name it (``ConnectorSplit.device``: the reference's
+``MemorySplit`` carries its worker's address and is not remotely
+accessible), and the scheduler gives a split to the task on that
+device, so a scan reads only what lies where it runs.  A reader with
+no task there (a ``LocalQueryRunner`` over a table four workers wrote,
+a cluster of fewer workers than devices hold pages) still reads every
+page: it gets a device-to-device copy, never a host round trip, and
+the scan counts its bytes as ``transferred_bytes`` beside
+``local_bytes``.
 
 The device bytes of every table are reserved in the connector's
-``TableMemoryAccount`` (``exec/memory.py``) when written, stay reserved
-after the writing query ends and fall on ``DROP TABLE``; a write past
-``max_data_per_node`` fails and leaves no half table.  Nothing spills,
-is evicted or falls back to host pages.  Writes append under a lock so
-scaled/parallel writers can share one sink target.
+``TableMemoryAccount`` (``exec/memory.py``) by table and by device when
+written, stay reserved after the writing query ends and fall on ``DROP
+TABLE``; a write that takes one device's share past
+``max_data_per_node`` fails and leaves no half table on any device.
+Nothing spills, is evicted or falls back to host pages.  Writes append
+under a lock so scaled/parallel writers can share one sink target.
 """
 
 from __future__ import annotations
@@ -41,6 +53,12 @@ from .spi import (ColumnHandle, Connector, ConnectorMetadata,
 #: hands its pipeline (65,536 orders a connector page, four lines an
 #: order), so programs over resident pages are shaped like today's
 PAGE_ROWS = 1 << 18
+
+
+def _device_id(device) -> Optional[int]:
+    """A jax device's id: what a split's address and the account's
+    shares are keyed by."""
+    return getattr(device, "id", None)
 
 
 @lru_cache(maxsize=None)
@@ -114,7 +132,15 @@ class _TableData:
         #: from one pool re-codes each value once
         self._remaps: Dict[int, tuple] = {}
         #: all-False / all-True masks shared by the pages of a capacity
-        self._masks: Dict[Tuple[bool, int], object] = {}
+        #: on a device
+        self._masks: Dict[Tuple[bool, int, object], object] = {}
+        #: DROP TABLE took the table away: a sink that still writes
+        #: into it fails instead of reserving bytes nothing releases —
+        #: with ``write_failure``, the error a sibling sink of the same
+        #: write met last, where that is why the table went (a CTAS
+        #: taken back): the statement's tasks then all fail alike
+        self.dropped = False
+        self.write_failure: Optional[TrinoError] = None
 
     # -- the page list ---------------------------------------------------
 
@@ -131,9 +157,10 @@ class _TableData:
             self._pages = new
 
     def _release(self, pages):
-        freed = sum(p.nbytes for p in pages if isinstance(p, ResidentPage))
-        if freed:
-            self.conn.account.release(self.key, freed)
+        for p in pages:
+            if isinstance(p, ResidentPage) and p.nbytes:
+                self.conn.account.release(self.key, p.nbytes,
+                                          _device_id(p.device))
 
     @property
     def row_count(self) -> int:
@@ -147,6 +174,14 @@ class _TableData:
                 if not isinstance(p, ResidentPage):
                     self._pages[i] = self.adopt(p)
             return list(self._pages)
+
+    def by_device(self) -> Dict[Optional[int], List[ResidentPage]]:
+        """The table's pages by the id of the device they lie on, each
+        device's in the order they were stored."""
+        groups: Dict[Optional[int], List[ResidentPage]] = {}
+        for p in self.resident():
+            groups.setdefault(_device_id(p.device), []).append(p)
+        return groups
 
     def host_pages(self) -> List[Page]:
         """Host copies of the pages (replication to other processes)."""
@@ -206,14 +241,18 @@ class _TableData:
 
     # -- making a stored page -------------------------------------------------
 
-    def _mask(self, value: bool, cap: int):
-        """The table's shared all-``value`` mask of ``cap`` lanes."""
+    def _mask(self, value: bool, cap: int, device):
+        """The table's shared all-``value`` mask of ``cap`` lanes on
+        ``device``: a page's arrays all lie where its columns do."""
+        import jax
         import jax.numpy as jnp
 
-        m = self._masks.get((value, cap))
+        m = self._masks.get((value, cap, device))
         if m is None:
-            self.conn.account.reserve(self.key, cap)
-            m = self._masks[(value, cap)] = jnp.full(cap, value, bool)
+            self.conn.account.reserve(self.key, cap, _device_id(device))
+            with jax.default_device(device):
+                m = self._masks[(value, cap, device)] = \
+                    jnp.full(cap, value, bool)
         return m
 
     def stored(self, cols, nulls, valid, rows: int,
@@ -225,16 +264,20 @@ class _TableData:
         own = cap * sum(c.dtype.itemsize for c in cols) \
             + cap * sum(1 for h in has_null if h) \
             + (cap if rows < cap else 0)
+        device = next(iter((cols[0] if cols else valid).devices()))
         with self.lock:
-            nulls = [n if h else self._mask(False, cap)
+            if self.dropped:
+                raise self.write_failure or TrinoError(
+                    f"Table '{'.'.join(self.key)}' was dropped while "
+                    "it was being written", "TABLE_NOT_FOUND")
+            nulls = [n if h else self._mask(False, cap, device)
                      for n, h in zip(nulls, has_null)]
             if rows == cap:
-                valid = self._mask(True, cap)
-            self.conn.account.reserve(self.key, own)
+                valid = self._mask(True, cap, device)
+            self.conn.account.reserve(self.key, own, _device_id(device))
         return ResidentPage([c.type for c in self.columns], list(cols),
                             list(nulls), valid, list(self.dicts),
-                            rows=rows, nbytes=own,
-                            device=next(iter(valid.devices())))
+                            rows=rows, nbytes=own, device=device)
 
 
 class MemoryMetadata(ConnectorMetadata):
@@ -280,7 +323,9 @@ class MemoryMetadata(ConnectorMetadata):
         with self.conn.lock:
             data = self.conn.tables.pop((table.schema, table.table), None)
             if data is not None:
-                self.conn.account.release(data.key)
+                with data.lock:     # no page is stored past this point
+                    data.dropped = True
+                    self.conn.account.release(data.key)
             self.conn._version += 1      # DDL invalidates cached plans
 
 
@@ -290,17 +335,32 @@ class MemorySplitManager(ConnectorSplitManager):
 
     def get_splits(self, table: TableHandle,
                    desired_splits: int) -> List[ConnectorSplit]:
+        """A table on one device: ``desired_splits`` strides over its
+        pages, readable from anywhere.  A table spread over several:
+        each device's share of the splits strides over that device's
+        pages and carries its address."""
         data = self.conn.tables[(table.schema, table.table)]
-        n = len(data.pages)
-        k = max(1, min(desired_splits, n)) if n else 1
-        return [ConnectorSplit(table, i, k, i, n, info={"stride": k})
-                for i in range(k)]
+        groups = data.by_device()
+        if len(groups) <= 1:
+            groups = {None: data.pages}
+        share = max(1, desired_splits // len(groups))
+        cuts = []
+        for device, pages in sorted(groups.items(),
+                                    key=lambda g: -1 if g[0] is None
+                                    else g[0]):    # not by who wrote first
+            k = max(1, min(share, len(pages)))
+            cuts += [(device, i, k, len(pages)) for i in range(k)]
+        return [ConnectorSplit(table, split_id, len(cuts), i, n,
+                               info={"stride": k}, device=device)
+                for split_id, (device, i, k, n) in enumerate(cuts)]
 
 
 class ResidentPageSource(ConnectorPageSource):
     """A split's pages as they lie on the device.  Column selection
-    picks arrays (no copy); a reader whose thread is pinned to another
-    device (``jax.default_device``) gets the page transferred there."""
+    picks arrays (no copy); a reader that runs on another device (the
+    one its thread is pinned to by ``jax.default_device``, else the
+    process's first) gets the page copied there, marked
+    ``transferred``."""
 
     provides_device_pages = True
 
@@ -324,11 +384,11 @@ class ResidentPageSource(ConnectorPageSource):
                             [p.nulls[i] for i in o], p.valid,
                             [p.dictionaries[i] for i in o],
                             rows=p.rows, device=p.device)
-        here = jax.config.jax_default_device
-        if here is not None and p.device is not None and here != p.device:
+        here = jax.config.jax_default_device or jax.local_devices()[0]
+        if p.device is not None and here != p.device:
             page.cols, page.nulls, page.valid = jax.device_put(
                 (page.cols, page.nulls, page.valid), here)
-            page.device = here
+            page.device, page.transferred = here, True
         page.nbytes = device_page_bytes(page)
         return page
 
@@ -352,6 +412,7 @@ class MemoryPageSink(ConnectorPageSink):
     def __init__(self, data: _TableData, conn: "MemoryConnector"):
         self.data = data
         self.conn = conn
+        data.write_failure = None       # a new write
         self.rows = 0
         self.host_recode_s = 0.0
         self._written: List[ResidentPage] = []
@@ -431,7 +492,11 @@ class MemoryPageSink(ConnectorPageSink):
                     host_read(has_null, "table_write"))
 
     def _store(self, cols, nulls, valid, rows, has_null):
-        page = self.data.stored(cols, nulls, valid, rows, has_null)
+        try:
+            page = self.data.stored(cols, nulls, valid, rows, has_null)
+        except TrinoError as e:
+            self.data.write_failure = e
+            raise
         with self.data.lock:
             self.data.pages.append(page)
         self._written.append(page)
@@ -515,8 +580,10 @@ class MemoryConnector(Connector):
                 "enforces no pushed-down constraint", "NOT_SUPPORTED")
         data = self.tables[(split.table.schema, split.table.table)]
         stride = (split.info or {}).get("stride", 1)
-        mine = data.resident()[split.row_start::stride]
-        return ResidentPageSource(mine, [c.ordinal for c in columns])
+        pages = data.resident() if split.device is None else \
+            data.by_device().get(split.device, [])
+        return ResidentPageSource(pages[split.row_start::stride],
+                                  [c.ordinal for c in columns])
 
     def page_sink(self, table: TableHandle,
                   columns: Sequence[ColumnHandle]) -> ConnectorPageSink:

@@ -11,7 +11,8 @@ the scan operator moves them on device. A source whose table already
 lives on the device says so (``provides_device_pages``) and hands its
 pages out as they are (``get_next_device_page``). Splits carry a
 deterministic row-range so distributed scans are reproducible
-regardless of split count.
+regardless of split count, and the device that holds their pages
+where a table is spread over several (``ConnectorSplit.device``).
 """
 
 from __future__ import annotations
@@ -137,7 +138,14 @@ def enforce_constraint_page(page: Page, names: Sequence[str], constraint,
 class ConnectorSplit:
     """A unit of scan parallelism (reference: spi/connector/ConnectorSplit).
     ``row_start``/``row_end`` give deterministic slicing for generators;
-    file-backed connectors may carry opaque ``info`` instead."""
+    file-backed connectors may carry opaque ``info`` instead.
+
+    ``device`` is the split's address (reference:
+    ``ConnectorSplit.getAddresses()`` of a split that is not remotely
+    accessible): the id of the device that holds its pages.  The
+    scheduler gives such a split to a task on that device where there
+    is one (``exec.local_planner.splits_of_task``); None is a split
+    that reads the same from anywhere."""
 
     table: TableHandle
     split_id: int
@@ -145,6 +153,7 @@ class ConnectorSplit:
     row_start: int = 0
     row_end: int = 0
     info: Optional[dict] = None
+    device: Optional[int] = None
 
 
 @dataclass(eq=False)
@@ -156,6 +165,9 @@ class ResidentPage(DevicePage):
     rows: int = 0           # live lanes
     nbytes: int = 0         # device bytes it is accounted at
     device: object = None   # where it lies
+    #: a source's page that was copied from the device it is stored on
+    #: to its reader's (the scan counts its bytes as transferred)
+    transferred: bool = False
 
 
 class ConnectorPageSource:

@@ -101,8 +101,13 @@ class OperatorStats:
             if m.get("resident_pages"):
                 # a scan of a table that lives on the device: pages and
                 # bytes taken as they lay, nothing uploaded
+                # (of them ``transferred`` from another device's share)
                 base += (f" [resident {m['resident_pages']} pages, "
-                         f"{m['resident_bytes'] / 1e9:.2f} GB]")
+                         f"{m['resident_bytes'] / 1e9:.2f} GB")
+                if m.get("transferred_bytes"):
+                    base += (f", {m['transferred_bytes'] / 1e9:.2f} GB "
+                             "transferred")
+                base += "]"
             extras = " ".join(
                 f"{k}={m[k]}" for k in ("skew_ratio", "lane_skew_ratio",
                                         "per_dest", "a2a_retries",

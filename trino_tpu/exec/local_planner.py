@@ -119,14 +119,38 @@ class LocalExecutionPlan:
         return self.sink.pages
 
 
+def splits_of_task(splits, task_id: int, task_count: int,
+                   task_devices: Optional[Sequence[int]] = None) -> list:
+    """The splits of one scan that task ``task_id`` of ``task_count``
+    reads (reference: ``NodeScheduler`` placing a split on the nodes
+    its addresses name).  A split goes round robin over the tasks that
+    run on the device it names (``task_devices``: each task's device
+    id), and over all the tasks where it names none or no task runs
+    there — so unaddressed splits go by stride, and every split is
+    read by exactly one task."""
+    everyone = tuple(range(task_count))
+    homes: Dict[int, tuple] = {}
+    for task, device in zip(everyone, task_devices or ()):
+        homes[device] = homes.get(device, ()) + (task,)
+    mine, turns = [], {}
+    for split in splits:
+        among = homes.get(split.device) or everyone
+        turn = turns[among] = turns.get(among, -1) + 1
+        if among[turn % len(among)] == task_id:
+            mine.append(split)
+    return mine
+
+
 class LocalExecutionPlanner:
     """``task_id``/``task_count`` assign a subset of table splits to this
-    task (reference: split assignment in SqlTaskExecution);
-    ``exchange_reader(fragment_id, kind) -> thunk`` resolves
-    RemoteSourceNodes to upstream fragment output pages."""
+    task, and ``task_devices`` says where each task runs
+    (``splits_of_task``; reference: split assignment in
+    SqlTaskExecution); ``exchange_reader(fragment_id, kind) -> thunk``
+    resolves RemoteSourceNodes to upstream fragment output pages."""
 
     def __init__(self, metadata: Metadata, desired_splits: int = 4,
                  task_id: int = 0, task_count: int = 1,
+                 task_devices: Optional[Sequence[int]] = None,
                  exchange_reader=None, memory_pool=None,
                  join_max_lanes: Optional[int] = None,
                  dynamic_filtering: bool = True,
@@ -147,6 +171,7 @@ class LocalExecutionPlanner:
         self.desired_splits = desired_splits
         self.task_id = task_id
         self.task_count = task_count
+        self.task_devices = task_devices
         self.exchange_reader = exchange_reader
         self.memory_pool = memory_pool
         #: coalesce split-tail scan pages up to the connector page size
@@ -283,9 +308,9 @@ class LocalExecutionPlanner:
                                  progress=self.progress)
         splits = conn.split_manager().get_splits(node.table,
                                                  self.desired_splits)
-        for i, split in enumerate(splits):
-            if i % self.task_count == self.task_id:
-                scan.add_split(split)
+        for split in splits_of_task(splits, self.task_id, self.task_count,
+                                    self.task_devices):
+            scan.add_split(split)
         scan.no_more_splits()
         layout = {s.name: i for i, (s, _) in enumerate(node.assignments)}
         types_ = [s.type for s, _ in node.assignments]

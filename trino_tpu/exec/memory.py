@@ -126,21 +126,35 @@ def resident_table_bytes() -> int:
     return sum(a.reserved for a in list(_TABLE_ACCOUNTS))
 
 
+def resident_table_bytes_by_device() -> Dict[Optional[int], int]:
+    """The same bytes by the id of the device that holds them (None:
+    bytes reserved without a device); they add up to
+    ``resident_table_bytes()``."""
+    out: Dict[Optional[int], int] = {}
+    for account in list(_TABLE_ACCOUNTS):
+        for device, n in account.by_device().items():
+            out[device] = out.get(device, 0) + n
+    return out
+
+
 class TableMemoryAccount:
-    """Device bytes a connector holds in tables, by table: the first
-    tenant of a worker's memory, queries get what is left (reference:
-    ``memory.max-data-per-node``).  A reservation outlives the query
-    that wrote the table and falls when the table is dropped; one past
-    the limit fails, it never spills or evicts.  On a worker the bytes
-    are charged to its ``NodeMemoryPool`` too (``attach``), so
-    concurrent queries are admitted against what the tables leave; a
-    runner without one caps each query's pool the same way
-    (``pool_from_session``)."""
+    """Device bytes a connector holds in tables, by table and by the
+    device they lie on: the first tenant of a worker's memory, queries
+    get what is left (reference: ``memory.max-data-per-node``).  A node
+    is a chip: the limit bounds each device's share, not the sum over
+    the devices of a process that runs several workers.  A reservation
+    outlives the query that wrote the table and falls when the table is
+    dropped; one past the limit fails, it never spills or evicts.  On a
+    worker the bytes are charged to its ``NodeMemoryPool`` too
+    (``attach``), so concurrent queries are admitted against what the
+    tables leave; a runner without one caps each query's pool the same
+    way (``pool_from_session``)."""
 
     def __init__(self, max_bytes: Optional[int] = None):
         self._max_bytes = max_bytes
         self.node_pool: Optional["NodeMemoryPool"] = None
-        self._by_table: Dict[tuple, int] = {}
+        #: bytes by (table, device id)
+        self._held: Dict[tuple, int] = {}
         self._lock = threading.Lock()
         _TABLE_ACCOUNTS.add(self)
 
@@ -157,38 +171,57 @@ class TableMemoryAccount:
 
     @property
     def reserved(self) -> int:
-        return sum(self._by_table.values())
+        return sum(self._held.values())
+
+    def _summed(self, part: int) -> dict:
+        out: dict = {}
+        with self._lock:
+            for key, n in self._held.items():
+                out[key[part]] = out.get(key[part], 0) + n
+        return out
 
     def by_table(self) -> Dict[tuple, int]:
-        with self._lock:
-            return dict(self._by_table)
+        return self._summed(0)
+
+    def by_device(self) -> Dict[Optional[int], int]:
+        return self._summed(1)
 
     def attach(self, pool: "NodeMemoryPool"):
         """Charge ``pool`` for what this account holds, now and from
         now on."""
         with self._lock:
             self.node_pool = pool
-            pool.charge_tables(sum(self._by_table.values()))
+            pool.charge_tables(sum(self._held.values()))
 
-    def reserve(self, table: tuple, nbytes: int):
+    def reserve(self, table: tuple, nbytes: int,
+                device: Optional[int] = None):
         limit = self.max_bytes
         with self._lock:
-            held = sum(self._by_table.values())
+            held = sum(n for (_, d), n in self._held.items()
+                       if d == device)
             if held + nbytes > limit:
                 raise TableMemoryExceededError(nbytes, held, limit)
             if self.node_pool is not None:
                 self.node_pool.charge_tables(nbytes)    # may refuse
-            self._by_table[table] = self._by_table.get(table, 0) + nbytes
+            key = (table, device)
+            self._held[key] = self._held.get(key, 0) + nbytes
 
-    def release(self, table: tuple, nbytes: Optional[int] = None):
-        """Give back ``nbytes`` of a table's reservation, or all of it."""
+    def release(self, table: tuple, nbytes: Optional[int] = None,
+                device: Optional[int] = None):
+        """Give back ``nbytes`` of a table's reservation on ``device``,
+        or all of the table's on every device."""
         with self._lock:
-            held = self._by_table.get(table, 0)
-            freed = held if nbytes is None else min(nbytes, held)
-            if held - freed:
-                self._by_table[table] = held - freed
-            else:
-                self._by_table.pop(table, None)
+            keys = [k for k in self._held if k[0] == table] \
+                if nbytes is None else [(table, device)]
+            freed = 0
+            for key in keys:
+                held = self._held.get(key, 0)
+                part = held if nbytes is None else min(nbytes, held)
+                if held - part:
+                    self._held[key] = held - part
+                else:
+                    self._held.pop(key, None)
+                freed += part
             if freed and self.node_pool is not None:
                 self.node_pool.charge_tables(-freed)
 

@@ -167,6 +167,8 @@ class _ScanPages:
         if self.counters is not None:
             self.counters["resident_pages"] += 1
             self.counters["resident_bytes"] += page.nbytes
+            self.counters["transferred_bytes" if page.transferred
+                          else "local_bytes"] += page.nbytes
         return page, page.rows
 
     def next_page(self):
@@ -349,7 +351,9 @@ class TableScanOperator(SourceOperator):
         #: from the producer and ``readahead_ready`` those that were
         #: ready when asked for.  From page metadata: the pages and
         #: bytes taken as they lay on the device against the bytes
-        #: uploaded
+        #: uploaded, and of the resident bytes those that lay on the
+        #: scan's own device (``local_bytes``) against those copied
+        #: from another (``transferred_bytes``)
         self._counters: Optional[dict] = None
         self._counters_known = False
 
@@ -392,6 +396,7 @@ class TableScanOperator(SourceOperator):
                     "generate_s": 0.0, "upload_s": 0.0, "wait_s": 0.0,
                     "readahead_pages": 0, "readahead_ready": 0,
                     "resident_pages": 0, "resident_bytes": 0,
+                    "local_bytes": 0, "transferred_bytes": 0,
                     "uploaded_bytes": 0}
         if self._ahead is not None:
             got = self._take_ahead()

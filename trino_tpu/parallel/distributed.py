@@ -126,7 +126,29 @@ class DistributedQueryRunner:
         return text + ("\n" + "\n".join(prov) if prov else "")
 
     def execute(self, sql: str) -> QueryResult:
-        stmt = parse_statement(sql)
+        """One statement.  Its spans hang under the caller's current
+        span (``ProtocolServer`` enters ``statement.run``), or under a
+        ``statement`` root of the call's own, as the local runner's do:
+        ``parse``, ``plan``, ``execute`` and under that one ``task`` a
+        task, inside which the task's operators run."""
+        from ..telemetry import tracing
+
+        with tracing.root_scope(
+                "statement", SP.value(self.session,
+                                      "query_tracing_enabled"),
+                served_by="solo", batch_size=1):
+            res = self._execute_sql(sql)
+            cur = tracing.current_span()
+            if cur is not None:
+                res.stats = dict(res.stats or {},
+                                 trace=cur.tracer.finished())
+            return res
+
+    def _execute_sql(self, sql: str) -> QueryResult:
+        from ..telemetry import tracing
+
+        with tracing.span("parse"):
+            stmt = parse_statement(sql)
         if isinstance(stmt, ast.Explain) and stmt.analyze and \
                 isinstance(stmt.statement, (ast.QueryStatement,
                                             ast.Insert,
@@ -203,18 +225,23 @@ class DistributedQueryRunner:
 
         from ..exec.stats import QueryStatsTree, StageStatsTree
 
-        self._hbo = hbo_ctx = self._hbo_context(stmt)
-        key = self._plan_cache_key(stmt)
-        cached = self.plan_cache.lookup(key) if key is not None else None
-        plan_hit = cached is not None
-        if cached is not None:
-            self._root, self._fragments = cached
-            fragments = self._fragments
-        else:
-            fragments = self.create_fragments(stmt, hbo=hbo_ctx)
-            if key is not None:
-                self.plan_cache.store(key, (self._root, self._fragments),
-                                      128)
+        from ..telemetry import tracing
+
+        with tracing.span("plan") as plan_span:
+            self._hbo = hbo_ctx = self._hbo_context(stmt)
+            key = self._plan_cache_key(stmt)
+            cached = self.plan_cache.lookup(key) \
+                if key is not None else None
+            plan_hit = cached is not None
+            plan_span.set("plan_cache", "hit" if plan_hit else "miss")
+            if cached is not None:
+                self._root, self._fragments = cached
+                fragments = self._fragments
+            else:
+                fragments = self.create_fragments(stmt, hbo=hbo_ctx)
+                if key is not None:
+                    self.plan_cache.store(
+                        key, (self._root, self._fragments), 128)
         self._plan_shape = key[0] if key is not None else None
         root: OutputNode = self._root
         buffers: Dict[int, OutputBuffer] = {}
@@ -240,28 +267,34 @@ class DistributedQueryRunner:
         executor = shared_executor()
         streaming = SP.value(self.session, "streaming_execution")
         try:
-            if streaming:
-                result_pages = self._execute_streaming(
-                    executor, fragments, root, buffers)
-            else:
-                for frag in fragments:
-                    ntasks = 1 if frag.partitioning == "single" \
-                        else self.n_workers
-                    if frag.output_kind == "output":
-                        collected = self._run_output_fragment(
-                            executor, frag, root, ntasks, buffers)
-                        result_pages = collected
-                    else:
-                        buffers[frag.fragment_id] = self._run_fragment(
-                            executor, frag, ntasks, buffers)
+            # the parent of the tasks' spans: they run on the
+            # executor's threads, where no span is current
+            with tracing.span("execute") as self._exec_span:
+                if streaming:
+                    result_pages = self._execute_streaming(
+                        executor, fragments, root, buffers)
+                else:
+                    for frag in fragments:
+                        ntasks = 1 if frag.partitioning == "single" \
+                            else self.n_workers
+                        if frag.output_kind == "output":
+                            collected = self._run_output_fragment(
+                                executor, frag, root, ntasks, buffers)
+                            result_pages = collected
+                        else:
+                            buffers[frag.fragment_id] = \
+                                self._run_fragment(executor, frag, ntasks,
+                                                   buffers)
 
-            rows: List[tuple] = []
-            for p in result_pages:
-                rows.extend(p.to_rows())
+            with tracing.span("fetch_rows"):
+                rows: List[tuple] = []
+                for p in result_pages:
+                    rows.extend(p.to_rows())
             stats = {"memory": self._memory_pool.stats()}
         except BaseException:
             # reap spill files + free residue even when the query dies
             self._memory_pool.close()
+            self._take_back_created(root)
             raise
         names = root.column_names
         types_ = [s.type for s in root.outputs]
@@ -296,6 +329,24 @@ class DistributedQueryRunner:
             stats["query_stats"] = tree
         self._memory_pool.close()  # reap spill files, free residue
         return QueryResult(names, types_, rows, stats=stats)
+
+    def _take_back_created(self, root):
+        """A failed CTAS leaves no table.  The writer task that failed
+        dropped the target (``TableWriterOperator``'s undo), but every
+        task creates it where it finds none, so one that started after
+        that drop has made it again (the analyzer rejected a target
+        that was there before the statement)."""
+        from ..planner.plan import TableWriterNode
+
+        stack = [root]
+        while stack:
+            node = stack.pop()
+            stack.extend(node.sources)
+            if isinstance(node, TableWriterNode) and node.create:
+                md = self.metadata.connectors[node.catalog].metadata()
+                handle = md.get_table_handle(node.schema, node.table_name)
+                if handle is not None:
+                    md.drop_table(handle)
 
     def _plan_cache_key(self, stmt) -> Optional[tuple]:
         """Fragment-plan cache key, or None when uncacheable: mirrors
@@ -481,39 +532,57 @@ class DistributedQueryRunner:
         collective reads them and its partition arrives where it runs."""
         import jax
 
+        from ..telemetry import tracing
         from .device_exchange import task_device
 
-        steps = self._task_steps(frag, ntasks, t, out, buffers, stage,
-                                 root, results, streaming)
         device = task_device(t, self.n_workers, jax.devices())
-        # jax.default_device is thread-local and the executor may resume
-        # a task on another thread: enter it around each quantum, never
-        # across a yield
-        while True:
-            with jax.default_device(device):
-                try:
-                    item = next(steps)
-                except StopIteration:
-                    return
-            yield item
+        parent = getattr(self, "_exec_span", None)
+        span = parent.tracer.span(
+            "task", parent=parent, fragment=frag.fragment_id, task=t,
+            device=device.id) if parent else tracing.NULL_SPAN
+        steps = self._task_steps(frag, ntasks, t, out, buffers, stage,
+                                 root, results, streaming, span)
+        # jax.default_device and the current span are the thread's and
+        # the executor may resume a task on another thread: enter them
+        # around each quantum, never across a yield
+        try:
+            while True:
+                with jax.default_device(device), tracing.use_span(span):
+                    try:
+                        item = next(steps)
+                    except StopIteration:
+                        return
+                yield item
+        finally:
+            span.finish()
 
     def _task_steps(self, frag: PlanFragment, ntasks: int, t: int, out,
                     buffers, stage, root: Optional[OutputNode],
                     results: Optional[List[List[Page]]],
-                    streaming: bool = False):
+                    streaming: bool = False, span=None):
         """One task of one fragment as a cooperative generator. ``out``
         is the fragment's output (OutputBuffer | DeviceExchange | None
         for the output fragment, which collects into ``results[t]``).
         In streaming mode a no-progress quantum yields Blocked(tokens)
-        so the executor parks the task."""
+        so the executor parks the task.  ``span`` is the task's span:
+        its drivers' operator spans hang under it."""
+        import jax
+
         from ..exec.driver import Driver
         from ..exec.local_planner import project_to_wire_layout
         from ..exec.stats import TaskStatsTree
         from ..exec.task_executor import Blocked
+        from ..telemetry import tracing
+        from .device_exchange import task_device
 
+        devices = jax.devices()
         planner = LocalExecutionPlanner(
             self.metadata, self.desired_splits, task_id=t,
             task_count=ntasks,
+            # where each task of this fragment runs: an addressed split
+            # goes to the task on its device
+            task_devices=[task_device(i, self.n_workers, devices).id
+                          for i in range(ntasks)],
             exchange_reader=self._make_reader(buffers, t, streaming),
             memory_pool=self._memory_pool,
             join_max_lanes=SP.value(self.session,
@@ -573,6 +642,8 @@ class DistributedQueryRunner:
             if collect:
                 d.collect_operator_metrics()
                 task.operators.extend(d.stats)
+                if span:
+                    tracing.add_driver_spans(span.tracer, d, span)
         if root is not None and results is not None:
             results[t] = plan.sink.pages
         if collect:

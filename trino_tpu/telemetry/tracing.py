@@ -28,8 +28,11 @@ Linkage inside a process: a ``ContextVar`` holds the span a ``with``
 entered; ``span(name)`` opens a child of it.  ``ProtocolServer`` enters a
 statement's ``statement.run`` on the executor thread, so the runner below
 needs no argument; a runner called with no current span opens its own
-root (``root_scope``).  Finished trees of served statements go to
-``RING``.
+root (``root_scope``).  A runner whose tasks run on other threads
+(``DistributedQueryRunner``) opens a ``task`` span a task under its
+``execute`` and enters it (``use_span``) around each quantum, so what a
+task's operators count lands in the statement's tree.  Finished trees of
+served statements go to ``RING``.
 """
 
 from __future__ import annotations
@@ -47,6 +50,11 @@ import numpy as np
 #: the span entered by the innermost ``with`` on this thread / context
 _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
     "trino_tpu_current_span", default=None)
+
+
+#: the root's counters are added to by every thread that runs a part of
+#: the statement
+_ROOT_COUNTERS = threading.Lock()
 
 
 def annotation(name: str):
@@ -356,11 +364,12 @@ def host_sync(why: str):
         finally:
             dt = time.perf_counter() - t0
             attrs = cur.root.attrs
-            attrs["host_syncs"] = attrs.get("host_syncs", 0) + 1
-            attrs["host_sync_s"] = attrs.get("host_sync_s", 0.0) + dt
-            by_why = attrs.setdefault("host_sync_by_why", {})
-            n, s = by_why.get(why, (0, 0.0))
-            by_why[why] = (n + 1, s + dt)
+            with _ROOT_COUNTERS:    # a distributed statement's tasks
+                attrs["host_syncs"] = attrs.get("host_syncs", 0) + 1
+                attrs["host_sync_s"] = attrs.get("host_sync_s", 0.0) + dt
+                by_why = attrs.setdefault("host_sync_by_why", {})
+                n, s = by_why.get(why, (0, 0.0))
+                by_why[why] = (n + 1, s + dt)
 
 
 def host_read(x, why: str) -> np.ndarray:
@@ -420,6 +429,7 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
             for key in ("generate_s", "upload_s", "wait_s",
                         "readahead_pages", "readahead_ready",
                         "resident_pages", "resident_bytes",
+                        "local_bytes", "transferred_bytes",
                         "uploaded_bytes", "partial_lanes"):
                 if st.metrics.get(key) is not None:
                     span["attrs"][key] = st.metrics[key]
