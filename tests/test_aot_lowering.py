@@ -222,6 +222,24 @@ def test_join_kernels_compile_for_v5e(one_chip):
              one_chip, sds((PAGE,), jnp.int64), sds((PAGE,), jnp.int64))
 
 
+def test_direct_probe_compiles_for_v5e(one_chip):
+    """The direct-address probe at q3-SF1 sizes: the ``orderkey`` build
+    (2^20 sorted rows, 6.0 M codes: a table of 2^23 int32 offsets) and
+    one coalesced probe page of 524,288 rows."""
+    from trino_tpu.ops.join import (_build_direct_offsets, _key_span,
+                                    _probe_direct_counts)
+
+    build, kp, probe = 1 << 20, 1 << 23, 1 << 19
+    u64 = sds((build,), jnp.uint64)
+    flag = sds((build,), jnp.bool_)
+    span = sds((3,), jnp.uint64)
+    _compile(_key_span, one_chip, u64, flag)
+    _compile(lambda k, u, s: _build_direct_offsets.jit(k, u, s, kp=kp),
+             one_chip, u64, flag, span)
+    _compile(_probe_direct_counts.jit, one_chip, sds((kp,), jnp.int32),
+             span, sds((probe,), jnp.uint64), sds((probe,), jnp.bool_))
+
+
 def test_sort_by_compiles_for_v5e(one_chip):
     """ORDER BY / TopN over a trimmed aggregation output (q3 at SF1
     sorts ~11,600 groups: 16,384 lanes) — two keys, chained single-key

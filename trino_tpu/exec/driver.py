@@ -83,6 +83,16 @@ class OperatorStats:
                     if m.get(k):
                         base += f" {k}={m[k]!r}"
                 base += "]"
+            if m.get("probe_pages"):
+                # the join's candidate lookup: pages answered from the
+                # build's direct-address table, or why it has none
+                base += (f" [probe direct {m['direct_probe_pages']}/"
+                         f"{m['probe_pages']} pages")
+                if m.get("direct_table_bytes"):
+                    base += f", table {m['direct_table_bytes'] / 1e6:.1f} MB"
+                if m.get("probe_fallback"):
+                    base += f", sorted index: {m['probe_fallback']}"
+                base += "]"
             if m.get("adaptive"):
                 # the adaptive partial-agg decision (pass-through or
                 # per-key-range split) — no 'strategy' key on agg ops

@@ -8,12 +8,23 @@ semi joins.
 TPU redesign: open-addressing probes are scatter/gather-chase loops that
 map poorly to XLA. Instead the build side becomes a **sorted index**: key
 columns normalize to uint64 (exact for single keys; packed or hashed for
-multi-key), ``lax.sort`` orders the build rows, and probing is two
-``searchsorted`` calls (XLA-native vectorized binary search) giving each
-probe row its candidate range. Matches expand via cumsum offsets into a
-static-capacity output whose size is GUESSED from a running expansion
-ratio (jit shapes are static, so some host value must pick the
-capacity); the exact total rides along as an unread device scalar and is
+multi-key), ``lax.sort`` orders the build rows, and a probe looks up each
+probe row's candidate range ``(lo, count)`` in that index. Which lookup
+runs is read off the build, once, when it is published
+(``_attach_direct_table``): a build whose keys are exact and span a range
+the chip can hold a table over gets a **direct-address table** of offsets
+over ``key - klo`` (one scatter-add and one cumsum over the sorted rows),
+and a probe page then costs two gathers (``_probe_direct_counts``); any
+other build (hashed or float keys, a key at the u64 sentinel, a range past
+``DIRECT_TABLE_MAX_BYTES``, no memory for the table, hybrid partitions)
+keeps the two ``searchsorted`` calls (``_probe_counts``: XLA-native
+vectorized binary search, log2(build) dependent gathers each) and says
+why in the operator's metrics. Both give the same ``(lo, count)`` bit for
+bit, so everything downstream is one path. Matches expand via cumsum
+offsets into a static-capacity output whose size is GUESSED from a
+running expansion ratio (jit shapes are static, so some host value must
+pick the capacity); the exact total rides along as an unread device
+scalar and is
 checked only when the probe pipeline is already ``pipeline_depth`` pages
 deep — the host never blocks on the page it just enqueued, and an
 overflowing guess (rare) re-expands at the exact size. Candidates are
@@ -99,6 +110,10 @@ def _hash_combine(ops):
     return acc
 
 
+#: where the build's dead lanes (invalid or null-key rows) sort to
+_U64_SENTINEL = np.uint64(0xFFFFFFFFFFFFFFFF)
+
+
 @jax.jit
 def _build_sorted(key_u64, anynull, cols, nulls, valid):
     """Sort the build rows by key; null-key or invalid lanes sort last.
@@ -108,7 +123,7 @@ def _build_sorted(key_u64, anynull, cols, nulls, valid):
 
     jit_stats.bump("join_build_sorted")
     usable = valid & ~anynull if anynull is not None else valid
-    sort_key = jnp.where(usable, key_u64, np.uint64(0xFFFFFFFFFFFFFFFF))
+    sort_key = jnp.where(usable, key_u64, _U64_SENTINEL)
     (s_key,), s = sort_carrying(
         [sort_key], [usable, valid] + list(cols) + list(nulls))
     n = len(cols)
@@ -163,6 +178,94 @@ _expand_matches = instrument(
     static_argnames=("out_cap",))
 
 
+# -- the direct-address probe ------------------------------------------------
+#
+# For a build whose u64 keys are exact and span [klo, khi], ``offsets[c]``
+# is the number of usable build rows with key < klo + c: exactly what
+# ``searchsorted(side="left")`` answers for key klo + c (usable rows sort
+# first, unusable ones to the sentinel, past every usable key), and
+# ``offsets[c + 1]`` is what ``side="right"`` answers.
+
+#: the most a direct-address table may take (int32 offsets, padded to a
+#: power of two): 64 Mi codes — TPC-H's order keys up to SF10. A build
+#: whose key range needs more keeps the sorted-index probe.
+DIRECT_TABLE_MAX_BYTES = 256 << 20
+
+
+@jax.jit
+def _key_span(key_sorted, usable_sorted):
+    """u64[3]: the usable build rows' number, least and greatest key
+    (usable rows sort first, so they are the ends of that prefix)."""
+    n = jnp.sum(usable_sorted, dtype=jnp.int32)
+    return jnp.stack([n.astype(jnp.uint64), key_sorted[0],
+                      key_sorted[jnp.maximum(n - 1, 0)]])
+
+
+def _span_range(span):
+    """(n_usable, klo, number of codes) from ``_key_span``'s result,
+    inside a traced program; no codes for an empty build."""
+    n, klo = span[0], span[1]
+    return n, klo, jnp.where(n == 0, np.uint64(0),
+                             span[2] - klo + np.uint64(1))
+
+
+@partial(jax.jit, static_argnames=("kp",))
+def _build_direct_offsets(key_sorted, usable_sorted, span, kp: int):
+    """int32[kp] offsets over ``key - klo`` in ONE pass over the sorted
+    build rows: a scatter-add of ones (indices ascending: the rows are
+    sorted, dead lanes go past the end and are dropped) and a cumsum.
+    ``kp`` > the number of codes, so ``offsets[range]`` = usable rows."""
+    from .. import jit_stats
+
+    jit_stats.bump("join_direct_table")
+    _, klo, krange = _span_range(span)
+    off = key_sorted - klo
+    live = usable_sorted & (off < krange)
+    idx = jnp.where(live, off, np.uint64(kp)).astype(jnp.int32)
+    cnt = jnp.zeros(kp, dtype=jnp.int32).at[idx].add(
+        1, mode="drop", indices_are_sorted=True)
+    return jnp.cumsum(cnt) - cnt
+
+
+_build_direct_offsets = instrument("join_direct_table",
+                                   _build_direct_offsets,
+                                   static_argnames=("kp",))
+
+
+@jax.jit
+def _probe_direct_counts(offsets, span, probe_keys, probe_usable):
+    """``_probe_counts``' (lo, count) by two gathers: bit-identical for
+    every usable probe row below the u64 sentinel (at the sentinel the
+    searches count the build's dead lanes as candidates, which the
+    raw-key verification then drops; the table counts none)."""
+    from .. import jit_stats
+
+    jit_stats.bump("join_probe_direct")
+    n, klo, krange = _span_range(span)
+    off = probe_keys - klo  # u64: wraps below klo -> out of range
+    in_range = probe_usable & (off < krange)
+    code = jnp.where(in_range, off, np.uint64(0)).astype(jnp.int32)
+    first = offsets[code]
+    count = jnp.where(in_range, offsets[code + 1] - first, 0)
+    lo = jnp.where(in_range, first,
+                   jnp.where(probe_keys < klo, 0, n.astype(jnp.int32)))
+    return lo, count
+
+
+_probe_direct_counts = instrument("join_probe_direct",
+                                  _probe_direct_counts)
+
+
+@dataclass
+class DirectTable:
+    offsets: "jax.Array"   # int32[kp], kp = padded_size(codes + 1)
+    span: "jax.Array"      # u64[3] on the device: n_usable, klo, khi
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.offsets.nbytes)
+
+
 @dataclass
 class BuildSide:
     key_sorted: "jax.Array"
@@ -174,6 +277,56 @@ class BuildSide:
     dictionaries: List
     key_channels: List
     key_mode: str = "single"
+    #: the direct-address table over the build's keys, or None and why
+    #: not (``_attach_direct_table``); every operator that probes this
+    #: build shares it
+    direct: Optional[DirectTable] = None
+    direct_fallback: Optional[str] = None
+
+
+def _attach_direct_table(b: BuildSide, ctx=None) -> None:
+    """Give ``b`` its direct-address table if what the build shows
+    allows one — exact keys, an observed key range whose table fits
+    ``DIRECT_TABLE_MAX_BYTES`` and the operator's memory — else the
+    reason. One blocking read (three scalars) a build."""
+    from ..exec.memory import MemoryExceededError, NodeMemoryExceededError
+
+    if b.key_mode == "hashed":
+        b.direct_fallback = "hashed key mode"
+        return
+    if any(b.types[c] in (T.DOUBLE, T.REAL) for c in b.key_channels):
+        b.direct_fallback = "float key"
+        return
+    span = _key_span(b.key_sorted, b.usable_sorted)
+    n, klo, khi = (int(v) for v in host_read(span, "join_key_range"))
+    if n and khi == int(_U64_SENTINEL):
+        b.direct_fallback = "key at the u64 sentinel"
+        return
+    key_range = khi - klo + 1 if n else 0
+    kp = padded_size(key_range + 1)
+    nbytes = 4 * kp
+    if nbytes > DIRECT_TABLE_MAX_BYTES:
+        b.direct_fallback = (f"key range {key_range} past the table's "
+                             f"bound ({DIRECT_TABLE_MAX_BYTES >> 20} MiB)")
+        return
+    if ctx is not None:
+        # the table is an optional index: it takes what is free and
+        # never makes another operator spill for it. The counts and
+        # their cumsum are both alive while it is built
+        pool = ctx.pool
+        if pool.reserved + 2 * nbytes > pool.max_bytes:
+            b.direct_fallback = "memory reservation refused"
+            return
+        try:
+            ctx.reserve(2 * nbytes, revocable=False)
+        except (MemoryExceededError, NodeMemoryExceededError):
+            b.direct_fallback = "memory reservation refused"
+            return
+    offsets = _build_direct_offsets(b.key_sorted, b.usable_sorted, span,
+                                    kp=kp)
+    if ctx is not None:
+        ctx.free(nbytes, revocable=False)
+    b.direct = DirectTable(offsets, span)
 
 
 class JoinBridge:
@@ -882,9 +1035,9 @@ class HashBuilderOperator(Operator):
             dicts = [Dictionary() if t.is_pooled else None
                      for t in self.input_types]
         self._collect_dynamic_filters(cols, nulls, valid)
-        self.bridge.set_build(_assemble_build_side(
+        build = _assemble_build_side(
             self.input_types, self.key_channels, cols, nulls, valid,
-            cap, dicts))
+            cap, dicts)
         self._pages = []  # release the input pages; only the index remains
         if self._ctx is not None:
             # retain only the published index: sorted key (8B) + usable
@@ -893,6 +1046,13 @@ class HashBuilderOperator(Operator):
             self._ctx.close()
             self._ctx.reserve(retained, revocable=False)
             self.bridge.release = self._ctx.close
+        if self._hstate is not None and self._hstate.spilled_build:
+            # memory is short and the cold partitions' passes probe
+            # indexes of their own: the resident part keeps the searches
+            build.direct_fallback = "hybrid partitions"
+        else:
+            _attach_direct_table(build, self._ctx)
+        self.bridge.set_build(build)
 
     def _collect_dynamic_filters(self, cols, nulls, valid):
         """Fill the join's dynamic filters over ALL build rows — the
@@ -1000,6 +1160,24 @@ class LookupJoinOperator(Operator):
         self._emitted_unmatched = False
         # probe-dict -> build-dict code remap LUTs for pooled join keys
         self._remap_cache: dict = {}
+        #: probe pages looked up, those of them looked up in the
+        #: build's direct-address table, and the build's table or why
+        #: it has none (kept here: the bridge drops the build at finish)
+        self._probe_pages = 0
+        self._direct_pages = 0
+        self._direct_table_bytes = 0
+        self._probe_fallback: Optional[str] = None
+
+    def metrics(self) -> dict:
+        """Which probe ran: pages by lookup, the table's size or why
+        the build has none (EXPLAIN ANALYZE, the operator span)."""
+        out = {"probe_pages": self._probe_pages,
+               "direct_probe_pages": self._direct_pages}
+        if self._direct_table_bytes:
+            out["direct_table_bytes"] = self._direct_table_bytes
+        elif self._probe_fallback:
+            out["probe_fallback"] = self._probe_fallback
+        return out
 
     @property
     def output_types(self) -> List[T.Type]:
@@ -1037,6 +1215,9 @@ class LookupJoinOperator(Operator):
                                   key_types, b.key_mode)
         pusable = page.valid & ~panynull if panynull is not None \
             else page.valid
+        self._probe_pages += 1
+        self._direct_table_bytes = b.direct.nbytes if b.direct else 0
+        self._probe_fallback = b.direct_fallback
         direct = self._probe_direct(page, b, pkey, pusable)
         if direct is not None:
             self._ready.append(direct)
@@ -1111,9 +1292,14 @@ class LookupJoinOperator(Operator):
 
     def _probe_lo_count(self, b: "BuildSide", pkey, pusable):
         """Strategy seam: each probe row's candidate range (lo, count)
-        against the sorted build index — here two XLA-native vectorized
-        binary searches; the matmul strategy overrides with the blocked
-        one-hot matmul probe."""
+        against the sorted build index — two gathers from the build's
+        direct-address table where it has one, else two XLA-native
+        vectorized binary searches; the matmul strategy overrides with
+        the blocked one-hot matmul probe."""
+        if b.direct is not None:
+            self._direct_pages += 1
+            return _probe_direct_counts(b.direct.offsets, b.direct.span,
+                                        pkey, pusable)
         return _probe_counts(b.key_sorted, b.usable_sorted, pkey,
                              pusable)
 
@@ -1327,6 +1513,7 @@ class LookupJoinOperator(Operator):
                                   key_types, b.key_mode)
         pusable = page.valid & ~panynull if panynull is not None \
             else page.valid
+        self._probe_pages += 1
         lo, count = _probe_counts(b.key_sorted, b.usable_sorted, pkey,
                                   pusable)
         tot = int(host_read(jnp.sum(count), "join_expand_total"))

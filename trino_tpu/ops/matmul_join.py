@@ -59,7 +59,8 @@ from .. import jit_stats
 from .. import types as T
 from ..block import DevicePage
 from ..telemetry.profiler import instrument
-from .join import BuildSide, JoinBridge, LookupJoinOperator
+from .join import (_U64_SENTINEL, BuildSide, JoinBridge,
+                   LookupJoinOperator)
 from .kernel_sizing import KERNEL_SIZING
 
 #: default cap on the dense key domain (``matmul_join_max_key_range``):
@@ -76,8 +77,6 @@ MAX_BUILD_ROWS = 1 << 24
 #: they divide every padded page capacity and table width)
 _MB = 1024
 _KB = 512
-
-_U64_SENTINEL = np.uint64(0xFFFFFFFFFFFFFFFF)
 
 
 @partial(jax.jit, static_argnames=("kp",))
@@ -196,8 +195,9 @@ class MatmulJoinOperator(LookupJoinOperator):
         self._fallback_reason: Optional[str] = None
 
     def metrics(self) -> dict:
-        out = {"strategy": "matmul" if self._fallback_reason is None
-               else "matmul->sorted-index"}
+        out = super().metrics()
+        out["strategy"] = "matmul" if self._fallback_reason is None \
+            else "matmul->sorted-index"
         if self._fallback_reason is not None:
             out["fallback"] = self._fallback_reason
         elif self._mm is not None:

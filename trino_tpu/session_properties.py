@@ -358,7 +358,8 @@ register(SessionProperty(
 register(SessionProperty(
     "join_strategy", "varchar", "AUTOMATIC",
     "Join probe kernel: AUTOMATIC (cost model picks from build NDV/"
-    "range stats) | SORTED_INDEX (searchsorted binary-search probe) | "
+    "range stats) | SORTED_INDEX (sorted build index, probed by direct "
+    "address or binary search as the build's key range allows) | "
     "MATMUL (blocked one-hot matmul over the dense key domain — the "
     "MXU-native low-NDV path; infeasible builds fall back per build, "
     "reason in EXPLAIN ANALYZE)",

@@ -424,13 +424,16 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
             span["attrs"]["device_bytes"] = st.device_bytes
             span["attrs"]["compile_ms"] = round(st.compile_ms, 3)
         if st.metrics:
-            # the scan operator's host-side counters and the
-            # aggregation's partial widths, under their names
+            # the scan operator's host-side counters, the aggregation's
+            # partial widths and the join's probe counters, under their
+            # names
             for key in ("generate_s", "upload_s", "wait_s",
                         "readahead_pages", "readahead_ready",
                         "resident_pages", "resident_bytes",
                         "local_bytes", "transferred_bytes",
-                        "uploaded_bytes", "partial_lanes"):
+                        "uploaded_bytes", "partial_lanes",
+                        "probe_pages", "direct_probe_pages",
+                        "direct_table_bytes", "probe_fallback"):
                 if st.metrics.get(key) is not None:
                     span["attrs"][key] = st.metrics[key]
             for key in ("kind", "first_page_ms", "reconnects",
