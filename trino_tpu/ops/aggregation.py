@@ -580,6 +580,12 @@ class HashAggregationOperator(Operator):
         #: pages aggregated, their summed capacity, the summed capacity of
         #: the partials kept for them, the capacity of the last merge call
         self._lanes = {"pages": 0, "in": 0, "kept": 0, "merge": 0}
+        #: merges of kept partials over the operator's life and their
+        #: summed capacity: the lanes grouped a second time
+        self._merge_calls = 0
+        self._merge_lanes = 0
+        #: group count of the output page, where the last grouping read one
+        self._groups_out: Optional[int] = None
         self._emitted = False
         self._done = False
         self._group_dicts: List = [None] * len(group_channels)
@@ -928,6 +934,7 @@ class HashAggregationOperator(Operator):
         self._emitted = True
         self._done = True
         merged, ngroups = self._merge_partials()
+        self._groups_out = ngroups
         self._partials = []
         if self.step in ("single", "final"):
             merged = self._finalize(merged)
@@ -1029,6 +1036,8 @@ class HashAggregationOperator(Operator):
         else:
             cap = padded_size(sum(p.capacity for p in dev))
             self._lanes["merge"] = cap
+            self._merge_calls += 1
+            self._merge_lanes += cap
             cols, nulls = [], []
             for i in range(len(types)):
                 c = jnp.concatenate([p.cols[i] for p in dev])
@@ -1090,7 +1099,11 @@ class HashAggregationOperator(Operator):
         (whole-stream pass-through vs the per-key-range split)."""
         out = {"grouping_paths": {k: v for k, v in
                                   self.path_counts.items() if v},
-               "partial_lanes": dict(self._lanes)}
+               "partial_lanes": dict(self._lanes),
+               "merge_calls": self._merge_calls,
+               "merge_lanes": self._merge_lanes}
+        if self._groups_out is not None:
+            out["groups_out"] = self._groups_out
         seeded = " (seeded by hbo)" \
             if self._adaptive_source == "hbo" else ""
         if self.passthrough:

@@ -1171,7 +1171,8 @@ class LookupJoinOperator(Operator):
     def metrics(self) -> dict:
         """Which probe ran: pages by lookup, the table's size or why
         the build has none (EXPLAIN ANALYZE, the operator span)."""
-        out = {"probe_pages": self._probe_pages,
+        out = {"join_type": self.join_type,
+               "probe_pages": self._probe_pages,
                "direct_probe_pages": self._direct_pages}
         if self._direct_table_bytes:
             out["direct_table_bytes"] = self._direct_table_bytes

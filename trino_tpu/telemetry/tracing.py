@@ -398,7 +398,7 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
     parent_id = parent.span_id if isinstance(parent, Span) else \
         parse_context(parent)[1]
     n = 0
-    for st in driver.stats:
+    for i, st in enumerate(driver.stats):
         if st.first_ns == 0:
             continue  # operator never ran a quantum
         start = epoch0 + (st.first_ns - pc0) / 1e9
@@ -423,16 +423,20 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
             span["attrs"]["flops"] = st.flops
             span["attrs"]["device_bytes"] = st.device_bytes
             span["attrs"]["compile_ms"] = round(st.compile_ms, 3)
+        if i:
+            # what the operator before it in the chain handed it
+            span["attrs"]["input_rows"] = driver.stats[i - 1].output_rows
         if st.metrics:
             # the scan operator's host-side counters, the aggregation's
-            # partial widths and the join's probe counters, under their
-            # names
+            # partial widths, merges and groups, and the join's type and
+            # probe counters, under their names
             for key in ("generate_s", "upload_s", "wait_s",
                         "readahead_pages", "readahead_ready",
                         "resident_pages", "resident_bytes",
                         "local_bytes", "transferred_bytes",
                         "uploaded_bytes", "partial_lanes",
-                        "probe_pages", "direct_probe_pages",
+                        "merge_calls", "merge_lanes", "groups_out",
+                        "join_type", "probe_pages", "direct_probe_pages",
                         "direct_table_bytes", "probe_fallback"):
                 if st.metrics.get(key) is not None:
                     span["attrs"][key] = st.metrics[key]
