@@ -222,6 +222,36 @@ def test_join_kernels_compile_for_v5e(one_chip):
              one_chip, sds((PAGE,), jnp.int64), sds((PAGE,), jnp.int64))
 
 
+def test_join_expansion_compiles_without_a_loop_for_v5e(one_chip):
+    """What the chip runs for a probe page's expansion (PR 38): the
+    histogram's scatter and two prefix sums, no ``while`` — at a
+    generated page of 65,536 rows and at a resident page of 262,144 rows
+    expanded into 2^20 lanes. The semi join's narrow late pages (16
+    lanes against 262,144 rows) keep the search, with no scatter over
+    the page's rows."""
+    from functools import partial
+
+    from trino_tpu.ops.join import _expand_verified, _semi_matched
+
+    build = sds((1 << 21,), jnp.int64)
+
+    def expand(lo, count, pk, bk, out_cap):
+        return _expand_verified(lo, count, (pk,), (bk,), out_cap=out_cap)
+
+    def semi(lo, count, pk, bk, out_cap):
+        return _semi_matched(lo, count, (pk,), (bk,), lo.shape[0],
+                             out_cap=out_cap)
+
+    for fn, rows, out_cap in ((expand, PAGE, PAGE),
+                              (expand, 1 << 18, 1 << 20),
+                              (semi, 1 << 18, 1 << 18),
+                              (semi, 1 << 18, 16)):
+        idx = sds((rows,), jnp.int32)
+        text = _compile(partial(fn, out_cap=out_cap), one_chip, idx, idx,
+                        sds((rows,), jnp.int64), build).as_text()
+        assert (" while(" in text) == (out_cap == 16)
+
+
 def test_direct_probe_compiles_for_v5e(one_chip):
     """The direct-address probe at q3-SF1 sizes: the ``orderkey`` build
     (2^20 sorted rows, 6.0 M codes: a table of 2^23 int32 offsets) and

@@ -20,8 +20,13 @@ other build (hashed or float keys, a key at the u64 sentinel, a range past
 keeps the two ``searchsorted`` calls (``_probe_counts``: XLA-native
 vectorized binary search, log2(build) dependent gathers each) and says
 why in the operator's metrics. Both give the same ``(lo, count)`` bit for
-bit, so everything downstream is one path. Matches expand via cumsum
-offsets into a static-capacity output whose size is GUESSED from a
+bit, so everything downstream is one path. Matches expand into a
+static-capacity output: ``cumsum(count)`` gives every probe row's end
+lane, and each output lane finds its row as the number of rows that end
+at or before it — a histogram of those ends over the lanes and its
+prefix sum (``_lane_rows``: one scatter-add at ascending indices, no
+loop), or a binary search a lane where the expansion is far narrower
+than the page (a selective join). The capacity is GUESSED from a
 running expansion ratio (jit shapes are static, so some host value must
 pick the capacity); the exact total rides along as an unread device
 scalar and is
@@ -155,21 +160,53 @@ _probe_counts = instrument("join_probe_counts",
                            jax.jit(_probe_counts_impl))
 
 
+#: an expansion at least this many times narrower than its probe page
+#: keeps the search a lane: the histogram's scatter touches every probe
+#: row (9 ns a row on a v5e), the search only its ``out_cap`` lanes
+#: (0.2 us a lane), and they cross between rows / 32 and rows / 16 (the
+#: expansion sweep, PERF.md section 5). A selective join's pages are of
+#: that shape.
+_SEARCH_WHEN_NARROWER = 32
+
+
+def _lane_rows(off_end, out_cap: int):
+    """int32[out_cap]: the probe row of every output lane, given the
+    rows' ascending ends ``off_end = cumsum(count)``.
+
+    Lane j belongs to the row p with ``off_end[p-1] <= j < off_end[p]``,
+    i.e. p is the number of rows whose ``off_end <= j``. The ends ascend
+    and so do the lanes, so that number is a merge, not a search a lane:
+    a histogram of ``off_end`` over the lanes (one scatter-add at
+    ascending indices; rows ending at or past ``out_cap`` fall off the
+    end, no lane reaches them) and its prefix sum. Dead lanes
+    (j >= total) read the last row either way."""
+    rows = off_end.shape[0]
+    if out_cap * _SEARCH_WHEN_NARROWER <= rows:
+        j = jnp.arange(out_cap, dtype=off_end.dtype)
+        ended = jnp.searchsorted(off_end, j, side="right")
+    else:
+        hist = jnp.zeros(out_cap, dtype=jnp.int32).at[off_end].add(
+            1, mode="drop", indices_are_sorted=True)
+        ended = jnp.cumsum(hist)
+    return jnp.minimum(ended, rows - 1).astype(jnp.int32)
+
+
 def _expand_matches_impl(lo, count, out_cap: int):
-    """Candidate pairs: output lane j -> (probe_row, build_row)."""
+    """Candidate pairs: output lane j -> (probe_row, build_row). The
+    row's build offset comes by one gather of ``lo - (off_end - count)``;
+    all lane arithmetic is int32 (``out_cap`` is bounded by
+    ``max_lanes``, the build by the device)."""
     from .. import jit_stats
 
     jit_stats.bump("join_expand_matches")
     off_end = jnp.cumsum(count)
     total = off_end[-1]
-    j = jnp.arange(out_cap, dtype=jnp.int64)
-    probe_idx = jnp.searchsorted(off_end, j, side="right")
-    probe_idx = jnp.clip(probe_idx, 0, count.shape[0] - 1)
-    start = off_end[probe_idx] - count[probe_idx]
-    build_idx = lo[probe_idx] + (j - start)
+    j = jnp.arange(out_cap, dtype=jnp.int32)
+    probe_idx = _lane_rows(off_end, out_cap)
+    delta = (lo - (off_end - count)).astype(jnp.int32)
+    build_idx = j + delta[probe_idx]
     lane_valid = j < total
-    return (probe_idx.astype(jnp.int32),
-            jnp.clip(build_idx, 0, None).astype(jnp.int32), lane_valid)
+    return probe_idx, jnp.maximum(build_idx, 0), lane_valid
 
 
 _expand_matches = instrument(
