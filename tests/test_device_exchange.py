@@ -62,7 +62,7 @@ def test_collective_actually_runs(conn, monkeypatch):
 
     def spying_collect(self):
         out = orig(self)
-        ran.append(self.collective_ran)
+        ran.append(self.data_collectives > 0)
         return out
 
     monkeypatch.setattr(DeviceExchange, "_collect", spying_collect)
@@ -118,7 +118,7 @@ def test_fewer_devices_than_partitions(conn, monkeypatch, sql, n_devices):
     def spying_collect(self):
         assert self.d == min(n_devices, self.n)
         out = orig(self)
-        ran.append(self.collective_ran)
+        ran.append(self.data_collectives > 0)
         return out
 
     monkeypatch.setattr(DeviceExchange, "_collect", spying_collect)

@@ -75,7 +75,6 @@ def _partition_rows(ex: DeviceExchange, n: int):
 def test_exact_sizing_zero_retries_single_data_collective():
     before = DeviceExchange.total_collectives
     ex = _skewed_exchange("exact")
-    assert ex.collective_ran
     assert ex.a2a_retries == 0
     assert ex.data_collectives == 1
     assert ex.count_collectives == 1
@@ -484,3 +483,35 @@ def test_scaled_writer_ctas_correct_and_rebalances():
     assert count_on == count_off == [(8000,)]
     assert rows_on == rows_off
     assert UniformPartitionRebalancer.total_rebalances > before
+
+
+def test_stale_history_overflow_shows_on_the_exchange_span():
+    """A forced ``per_dest`` overflow inside a traced statement: the
+    ``exchange`` span says the collective ran again (``a2a_retries``,
+    every attempt's lanes in ``bytes_moved``) and that the doubled
+    ``per_dest`` was a program to lower (``lowered``)."""
+    from trino_tpu.parallel import device_exchange
+    from trino_tpu.telemetry import tracing
+
+    SIZING_HISTORY.reset()
+    device_exchange._exchange_program.cache_clear()
+    _skewed_exchange("history", rows_per_task=40, hot_frac=0.0, seed=2)
+    tracer = tracing.Tracer()
+    with tracer.span("statement") as root:
+        ex = _skewed_exchange("history", rows_per_task=4000, seed=3)
+    span, = [s for s in tracer.finished() if s["name"] == "exchange"]
+    a = span["attrs"]
+    assert span["parent_id"] == root.span_id
+    assert a["a2a_retries"] == ex.a2a_retries >= 1
+    assert a["data_collectives"] == a["a2a_retries"] + 1
+    assert a["sizing_used"] == "history" and a["count_collectives"] == 0
+    assert a["lowered"] >= a["a2a_retries"]
+    # the attempts doubled up to the last one's per_dest (or the cap)
+    attempts = [a["per_dest"] >> k for k in range(a["data_collectives"])]
+    assert a["bytes_moved"] == sum(attempts) * 4 * 4 * a["lane_bytes"]
+    by_why = root.attrs["host_sync_by_why"]
+    assert by_why["exchange_overflow"][0] == a["data_collectives"]
+    assert by_why["exchange_ready"][0] == a["data_collectives"]
+    assert by_why["exchange_readback"][0] == 1
+    assert root.attrs["lowerings_by_program"]["exchanged"][0] \
+        >= a["a2a_retries"]

@@ -213,10 +213,22 @@ def test_explain_analyze_verbose_distributed(dist_runner, qid):
     assert "compile" in text
     assert "Kernels:" in text
     before = profiler.totals()
-    text2 = _explain_text(dist_runner.execute(sql))
+    res2 = dist_runner.execute(sql)
+    text2 = _explain_text(res2)
     after = profiler.totals()
-    assert after["compiles"] == before["compiles"], \
-        "repeat-shape VERBOSE run recompiled"
+    # what the second run lowered, by program name, and how each
+    # exchange was sized: the statement's own record of what it built
+    spans = res2.stats["trace"]
+    root, = [s for s in spans if s["parent_id"] is None]
+    lowered = {name: row[0] for name, row in root["attrs"].get(
+        "lowerings_by_program", {}).items() if row[2] or row[3]}
+    sized = [(s["attrs"]["fragment"], s["attrs"]["cap"],
+              s["attrs"]["per_dest"], s["attrs"]["sizing_used"],
+              s["attrs"]["lowered"])
+             for s in spans if s["name"] == "exchange"]
+    assert after["compiles"] == before["compiles"], (
+        f"repeat-shape VERBOSE run recompiled: lowered {lowered}; "
+        f"exchanges (fragment, cap, per_dest, sizing, lowered) {sized}")
     assert "0 new, 0 compiles this run" in text2, text2
 
 

@@ -60,11 +60,25 @@ per-partition row counts, max/mean skew ratio, per-receiver lane loads,
 hot partitions split and the receiver lanes they spread across,
 per_dest chosen, retries, collective count and bytes moved — surfaced
 through OperatorStats / EXPLAIN ANALYZE.
+
+In a traced statement every collective is one ``exchange`` span under
+the ``task`` span of the consumer that triggered it
+(``telemetry/tracing.py``): what moved (``rows``, ``bytes_moved``,
+``lane_bytes``), how it was sized (``cap``, ``per_dest``,
+``sizing_used``, ``lowered``: programs the statement lowered while the
+exchange's program ran, 0 when jit's cache had it), and the barrier's
+five phases in seconds — ``assemble_s``, ``size_s``, ``run_s``,
+``readback_s``, ``slice_s``, each also the annotation
+``exchange.<phase>``.  Its blocking reads are ``host_sync`` sites
+(``exchange_count``, ``exchange_ready``, ``exchange_overflow``,
+``exchange_readback``), and a consumer that waited for the barrier
+adds its wait to its own ``task`` span as ``exchange_wait_s``.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 from functools import lru_cache, partial
 from typing import Dict, List, Optional, Sequence
 
@@ -77,6 +91,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import jit_stats
 from .. import types as T
 from ..block import DevicePage, Dictionary, padded_size
+from ..telemetry import tracing
 from ..telemetry.profiler import instrument
 from .exchange import (hash_partition_ids, key_to_u64, partition_histogram,
                        repartition_a2a, shard_map, string_hash_lut)
@@ -227,7 +242,8 @@ class DeviceExchange:
     def __init__(self, n_partitions: int, devices: Sequence,
                  sizing: str = "history",
                  history_key: Optional[tuple] = None,
-                 hot_split_threshold: float = 0.5):
+                 hot_split_threshold: float = 0.5,
+                 fragment_id: Optional[int] = None):
         # p-partitions-on-d-devices layout: with fewer devices than
         # partitions (a single real chip being the important case),
         # partition p lives on device p % d; partition ids are carried
@@ -246,6 +262,8 @@ class DeviceExchange:
         #: exchange's rows is split across all d receiver devices
         #: (>= 1.0 disables splitting; single-device meshes never split)
         self.hot_split_threshold = hot_split_threshold
+        #: the producing fragment, for the ``exchange`` span
+        self.fragment_id = fragment_id
         self.types: Optional[List[T.Type]] = None
         self.key_channels: Optional[List[int]] = None
         self._by_task: Dict[int, List[DevicePage]] = {}
@@ -254,7 +272,6 @@ class DeviceExchange:
         self.a2a_retries = 0
         self.count_collectives = 0
         self.data_collectives = 0
-        self.collective_ran = False  # test observability
         #: skew observability of the last collective (per-partition row
         #: counts, skew ratio, per_dest chosen, retries, bytes moved) —
         #: populated by _collect, surfaced via OperatorStats / EXPLAIN
@@ -321,9 +338,16 @@ class DeviceExchange:
     # -- consumer side --------------------------------------------------
 
     def pages(self, partition: int) -> List[DevicePage]:
-        with self._lock:
-            if self._result is None:
-                self._result = self._collect()
+        if self._result is None:
+            t0 = time.perf_counter()
+            with self._lock:
+                waited = self._result is not None
+                if not waited:
+                    self._result = self._collect()
+            if waited:
+                # the barrier held this consumer's thread meanwhile
+                tracing.span_add("exchange_wait_s",
+                                 time.perf_counter() - t0)
         return self._result[partition]
 
     @property
@@ -335,11 +359,31 @@ class DeviceExchange:
     # -- the collective -------------------------------------------------
 
     def _collect(self) -> List[List[DevicePage]]:
+        if self.types is None or not self._by_task:
+            return [[] for _ in range(self.n)]
+        task = tracing.current_span()
+        # jit's cache key holds the thread's default device, and the
+        # consumer that triggers the collective is whichever task comes
+        # first: under one device the exchange's programs and the eager
+        # operations around them are lowered once a shape, not once a
+        # shape and triggering task
+        with tracing.span(
+                "exchange", fragment=self.fragment_id,
+                device=task.attrs.get("device") if task else None) as span, \
+                jax.default_device(self.devices[0]):
+            phase = _Phases(span)
+            try:
+                return self._collect_phases(span, phase)
+            finally:
+                phase(None)
+
+    def _collect_phases(self, span, phase) -> List[List[DevicePage]]:
+        """The collective.  ``phase(name)`` marks where each of the five
+        phases the ``exchange`` span times begins."""
         n, d, types_ = self.n, self.d, self.types
-        if types_ is None or not self._by_task:
-            return [[] for _ in range(n)]
         nch = len(types_)
 
+        phase("assemble")
         # unify string pools: remap every divergent pool's codes into the
         # first pool seen per channel (device gather through a host LUT)
         target: List[Optional[Dictionary]] = [None] * nch
@@ -403,6 +447,7 @@ class DeviceExchange:
                     s_valid.append(jnp.zeros((cap,), dtype=bool))
 
         if total_rows == 0:
+            span.set("rows", 0)
             return [[] for _ in range(n)]
 
         mesh = Mesh(np.asarray(self.devices), ("x",))
@@ -421,6 +466,7 @@ class DeviceExchange:
         luts = tuple(jnp.asarray(string_hash_lut(target[c]))
                      for c in self.key_channels if types_[c].is_string)
 
+        phase("size")
         tkey = tuple(types_)
         kkey = tuple(self.key_channels)
         hkey = self.history_key or (
@@ -448,8 +494,9 @@ class DeviceExchange:
             # count-first pass: the exact max (sender, dest) load from a
             # tiny counting collective; per_dest needs no retry headroom
             cprog = _count_program(mesh, tkey, kkey, n, d)
-            hist, need, pair_max = cprog(cols, nulls, valid, luts)
-            hist = np.asarray(hist)[0]
+            counted = cprog(cols, nulls, valid, luts)
+            with tracing.host_sync("exchange_count"):
+                hist, need, pair_max = (np.asarray(x)[0] for x in counted)
             self.count_collectives += 1
             with DeviceExchange._total_lock:
                 DeviceExchange.total_count_collectives += 1
@@ -458,11 +505,10 @@ class DeviceExchange:
                 hot = {p for p in range(n)
                        if hist[p] / total > self.hot_split_threshold}
             if hot:
-                pair_np = np.asarray(pair_max)[0].reshape(n, d)
                 per_dest = padded_size(max(_salted_need_bound(
-                    pair_np, hot, n, d), 16))
+                    pair_max.reshape(n, d), hot, n, d), 16))
             else:
-                per_dest = padded_size(max(int(np.asarray(need)[0]), 16))
+                per_dest = padded_size(max(int(need), 16))
         elif sizing == "legacy":
             per_dest = padded_size(max(32, (2 * cap) // d))
         per_dest = min(per_dest, cap)
@@ -472,15 +518,20 @@ class DeviceExchange:
         for p in hot:
             hot_mask[p] = 1
         hot_mask = jnp.asarray(hot_mask)
+
+        phase("run")
+        lowerings = _lowerings(span)
         lanes_moved = 0
         while True:
             prog = _exchange_program(mesh, tkey, kkey, n, d, per_dest)
             out_cols, out_nulls, out_valid, out_part, overflow = prog(
                 cols, nulls, valid, luts, hot_mask)
-            jax.block_until_ready(out_valid)
+            with tracing.host_sync("exchange_ready"):
+                jax.block_until_ready(out_valid)
             self.data_collectives += 1
             lanes_moved += d * d * per_dest  # at THIS attempt's capacity
-            if int(np.asarray(overflow).sum()) == 0:
+            if int(tracing.host_read(
+                    overflow, "exchange_overflow").sum()) == 0:
                 break
             if per_dest >= cap:
                 raise T.TrinoError(
@@ -493,17 +544,19 @@ class DeviceExchange:
             per_dest = min(per_dest * 2, cap)
             self.a2a_retries += 1
 
-        self.collective_ran = True
+        span.set("lowered", _lowerings(span) - lowerings)
         with DeviceExchange._total_lock:
             DeviceExchange.total_collectives += 1
 
+        phase("readback")
         # skew observability + history feedback, from the RESULT (costs
         # one host transfer of the valid/partition lanes, no extra
         # collective in any mode): receiver r's lanes [s*per_dest,
         # (s+1)*per_dest) came from sender s, so per-(receiver, sender)
         # valid counts give the exact max pair load actually observed
-        ov = np.asarray(out_valid)
-        op_ids = np.asarray(out_part)
+        with tracing.host_sync("exchange_readback"):
+            ov = np.asarray(out_valid)
+            op_ids = np.asarray(out_part)
         pair_rows = ov.reshape(d, d, per_dest).sum(axis=2)
         observed_max = int(pair_rows.max()) if pair_rows.size else 0
         partition_rows = np.bincount(op_ids[ov], minlength=n)[:n]
@@ -554,6 +607,12 @@ class DeviceExchange:
             "hot_spread": hot_spread,
             "bytes_moved": lanes_moved * lane_bytes,
         }
+        if span:
+            span.attrs.update(
+                {key: self.stats[key] for key in _SPAN_STATS},
+                cap=cap, lane_bytes=lane_bytes)
+
+        phase("slice")
         # release producer-side inputs: without this the exchange pins
         # ~2x the exchanged bytes in HBM for the rest of the query
         self._by_task.clear()
@@ -597,6 +656,43 @@ class DeviceExchange:
                                         page_nulls, pv, out_dicts))
             result.append(pages)
         return result
+
+
+#: what of ``DeviceExchange.stats`` the ``exchange`` span carries
+_SPAN_STATS = ("rows", "bytes_moved", "per_dest", "sizing_used",
+               "count_collectives", "data_collectives", "a2a_retries",
+               "skew_ratio", "lane_skew_ratio", "splits")
+
+
+class _Phases:
+    """The phases of one traced collective, end to end: ``phase(name)``
+    ends the phase before and begins ``name`` at one reading of the
+    clock (the first began with the span), so the five tile the
+    ``exchange`` span.  A phase is the annotation ``exchange.<name>``
+    while it lasts and the span's ``<name>_s`` after."""
+
+    def __init__(self, span):
+        self.span, self.name, self.note = span, None, None
+        self.t0 = span.t0 if span else 0.0
+
+    def __call__(self, name: Optional[str]):
+        if not self.span:
+            return
+        now = time.perf_counter()
+        if self.name is not None:
+            self.note.__exit__(None, None, None)
+            self.span.set(self.name + "_s", now - self.t0)
+            self.t0 = now
+        self.name = name
+        if name is not None:
+            self.note = tracing.annotation("exchange." + name)
+            self.note.__enter__()
+
+
+def _lowerings(span) -> int:
+    """Programs the span's statement has lowered so far (its tasks on
+    other threads included)."""
+    return span.root.attrs.get("lowerings", 0) if span else 0
 
 
 def _salted_need_bound(pair_max: np.ndarray, hot: set, n: int,

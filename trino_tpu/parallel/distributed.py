@@ -191,6 +191,13 @@ class DistributedQueryRunner:
             from ..runner import _kernels_line
 
             lines.append(_kernels_line(before, profiler.totals()))
+        from ..telemetry import tracing
+
+        spans = tracing.snapshot()
+        for line in (tracing.sync_line(spans),
+                     tracing.lowering_line(spans)):
+            if line:
+                lines.append(line)
         return QueryResult(["Query Plan"], [T.VARCHAR],
                            [(line,) for line in lines],
                            stats={"query_stats": tree.to_dict()})
@@ -540,6 +547,8 @@ class DistributedQueryRunner:
         span = parent.tracer.span(
             "task", parent=parent, fragment=frag.fragment_id, task=t,
             device=device.id) if parent else tracing.NULL_SPAN
+        # a thread's line in a profile says whose quantum it ran
+        label = f"task:f{frag.fragment_id}.t{t}"
         steps = self._task_steps(frag, ntasks, t, out, buffers, stage,
                                  root, results, streaming, span)
         # jax.default_device and the current span are the thread's and
@@ -547,7 +556,8 @@ class DistributedQueryRunner:
         # around each quantum, never across a yield
         try:
             while True:
-                with jax.default_device(device), tracing.use_span(span):
+                with jax.default_device(device), \
+                        tracing.use_span(span, label):
                     try:
                         item = next(steps)
                     except StopIteration:
@@ -694,7 +704,8 @@ class DistributedQueryRunner:
             self.n_workers, devices,
             sizing=SP.value(self.session, "device_exchange_sizing"),
             hot_split_threshold=SP.value(
-                self.session, "hot_partition_split_threshold"))
+                self.session, "hot_partition_split_threshold"),
+            fragment_id=frag.fragment_id)
 
     def _run_fragment(self, executor, frag: PlanFragment, ntasks: int,
                       buffers: Dict[int, OutputBuffer]):

@@ -1214,7 +1214,8 @@ class LocalQueryRunner:
         if verbose:
             lines.append(_kernels_line(before, profiler.totals()))
         spans = tracing.snapshot()
-        for line in (tracing.sync_line(spans), tracing.trace_line(spans)):
+        for line in (tracing.sync_line(spans), tracing.lowering_line(spans),
+                     tracing.trace_line(spans)):
             if line:
                 lines.append(line)
         return QueryResult(["Query Plan"], [T.VARCHAR],

@@ -31,8 +31,16 @@ needs no argument; a runner called with no current span opens its own
 root (``root_scope``).  A runner whose tasks run on other threads
 (``DistributedQueryRunner``) opens a ``task`` span a task under its
 ``execute`` and enters it (``use_span``) around each quantum, so what a
-task's operators count lands in the statement's tree.  Finished trees of
+task's operators count lands in the statement's tree; a device
+exchange opens an ``exchange`` span a collective under the task that
+triggered it (``parallel/device_exchange.py``).  Finished trees of
 served statements go to ``RING``.
+
+Counters on the root: a statement's blocking device-to-host reads
+(``host_sync``) and the programs JAX traced, lowered or compiled for it
+(``lowerings``, fed by one ``jax.monitoring`` listener) are plain adds
+on the root span's attributes, from whichever thread ran that part of
+the statement.
 """
 
 from __future__ import annotations
@@ -230,6 +238,8 @@ class Tracer:
             tid, parent_id = parse_context(parent)
             if tid:
                 self.trace_id = tid
+        if local_parent is None and not _listening:
+            _listen_for_lowerings()
         return Span(self, name, parent_id, local_parent, **attrs)
 
     def _record(self, span_dict: dict):
@@ -311,14 +321,15 @@ def snapshot() -> List[dict]:
 
 
 @contextlib.contextmanager
-def use_span(span):
+def use_span(span, label: Optional[str] = None):
     """``span`` is the context's current span (and a profiler
-    annotation) inside the block, and is NOT ended by it: for a span
-    that somebody else ends (a batch member's ``statement.run``)."""
+    annotation, ``label`` or the span's name) inside the block, and is
+    NOT ended by it: for a span that somebody else ends (a batch
+    member's ``statement.run``, a task's span around each quantum)."""
     if not span:
         yield span
         return
-    with annotation(span.name):
+    with annotation(label or span.name):
         token = _CURRENT.set(span)
         try:
             yield span
@@ -331,6 +342,14 @@ def span_set(key: str, value):
     cur = _CURRENT.get()
     if cur is not None:
         cur.attrs[key] = value
+
+
+def span_add(key: str, value):
+    """Add ``value`` to a counter on the context's current span, if
+    any."""
+    cur = _CURRENT.get()
+    if cur is not None:
+        cur.attrs[key] = cur.attrs.get(key, 0) + value
 
 
 @contextlib.contextmanager
@@ -378,6 +397,68 @@ def host_read(x, why: str) -> np.ndarray:
         return np.asarray(x)
 
 
+#: ``jax.monitoring`` duration events of a program on its way to the
+#: device, and the slot of ``lowerings_by_program``'s row each adds to
+_LOWERING_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": 1,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": 2,
+    "/jax/core/compile/backend_compile_duration": 3,
+}
+_listening = False
+
+
+def _on_lowering(event: str, seconds: float, fun_name: str = "?", **_):
+    """The process's one ``jax.monitoring`` duration listener.  JAX
+    calls it on the thread that traces, lowers or compiles, whose
+    current span is the statement's (a task's, an exchange's): the
+    statement's root gets ``lowerings`` (programs lowered to an MLIR
+    module, each one request to the backend), ``lowering_s`` (seconds
+    of the three events together) and ``lowerings_by_program`` =
+    ``{name: [traces, trace_s, lower_s, compile_s]}``.
+
+    What fires when (JAX 0.9, checked on the CPU backend): a call that
+    hits jit's in-memory cache fires nothing; a program not seen by
+    this process fires all three, also when the persistent compile
+    cache answers — ``compile_s`` is then the retrieval.  The trace
+    event fires for every jitted function traced on the way, jnp's own
+    inside a program's body too (under their own names, with no lower
+    or compile seconds, and inside the outer program's ``trace_s``), so
+    ``lowering_s`` bounds the thread-seconds from above and a name's
+    ``traces`` is not a count of programs.  With no current span
+    (tracing off, or outside a statement) it returns at once."""
+    cur = _CURRENT.get()
+    if cur is None:
+        return
+    slot = _LOWERING_EVENTS.get(event)
+    if slot is None:
+        return
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        fun_name = fun_name[4:-1]       # lower / compile say jit(<name>)
+    attrs = cur.root.attrs
+    with _ROOT_COUNTERS:
+        if slot == 2:
+            attrs["lowerings"] = attrs.get("lowerings", 0) + 1
+        attrs["lowering_s"] = attrs.get("lowering_s", 0.0) + seconds
+        row = attrs.setdefault("lowerings_by_program", {}).setdefault(
+            fun_name, [0, 0.0, 0.0, 0.0])
+        if slot == 1:
+            row[0] += 1
+        row[slot] += seconds
+
+
+def _listen_for_lowerings():
+    """Install ``_on_lowering``, once a process (when its first root
+    opens: a process that never traces a statement never listens)."""
+    global _listening
+    with _ROOT_COUNTERS:
+        if _listening:
+            return
+        _listening = True
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_lowering)
+
+
 def add_driver_spans(tracer: Tracer, driver, parent) -> int:
     """Emit one span per operator of a finished Driver from its
     collected stats (the driver records first/last activity timestamps;
@@ -388,9 +469,9 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
     anchor = getattr(driver, "epoch_anchor", None)
     if anchor is None:
         return 0
-    # pull operator-reported metrics (exchange flow/replay counters)
-    # into the stats entries so the spans carry them — streaming output
-    # drivers have no other stats-rendering path
+    # pull operator-reported metrics (the scans', aggregations' and
+    # joins' counters below) into the stats entries so the spans carry
+    # them — streaming output drivers have no other stats-rendering path
     collect = getattr(driver, "collect_operator_metrics", None)
     if collect is not None:
         collect()
@@ -440,12 +521,6 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
                         "direct_table_bytes", "probe_fallback"):
                 if st.metrics.get(key) is not None:
                     span["attrs"][key] = st.metrics[key]
-            for key in ("kind", "first_page_ms", "reconnects",
-                        "replayed_frames", "skew_ratio",
-                        "lane_skew_ratio", "splits", "rebalances",
-                        "source_fragment"):
-                if st.metrics.get(key) is not None:
-                    span["attrs"][f"exchange_{key}"] = st.metrics[key]
         tracer._record(span)
         n += 1
     return n
@@ -536,6 +611,29 @@ def sync_line(spans: List[dict]) -> Optional[str]:
             "waiting (" + ", ".join(
                 f"{why} {n}x {t * 1e3:.1f}ms" for why, (n, t) in by_wait)
             + ")")
+
+
+def lowering_line(spans: List[dict], top: int = 4) -> Optional[str]:
+    """One EXPLAIN ANALYZE line: the programs the statement lowered
+    (``_on_lowering``), those that took longest first."""
+    programs: Dict[str, list] = {}
+    count, seconds = 0, 0.0
+    for s in spans:
+        if s.get("parent_id") is None:
+            attrs = s.get("attrs", {})
+            count += attrs.get("lowerings", 0)
+            seconds += attrs.get("lowering_s", 0.0)
+            programs.update(attrs.get("lowerings_by_program", {}))
+    if not programs:
+        return None
+    by_seconds = sorted(((name, row) for name, row in programs.items()
+                         if row[2] or row[3]),
+                        key=lambda kv: -sum(kv[1][1:]))
+    shown = ", ".join(f"{name} {row[0]}x {sum(row[1:]) * 1e3:.1f}ms"
+                      for name, row in by_seconds[:top])
+    more = ", …" if len(by_seconds) > top else ""
+    return (f"Lowerings: {count} programs, {seconds * 1e3:.1f}ms"
+            + (f" ({shown}{more})" if shown else ""))
 
 
 def slow_query_record(spans: Optional[List[dict]], wall_ms: float,
