@@ -16,7 +16,10 @@ from trino_tpu import types as T
 from trino_tpu.block import DevicePage, Dictionary, Page, padded_size
 from trino_tpu.ops.aggregation import AggCall, HashAggregationOperator, \
     resolve_agg_type
-from trino_tpu.ops.hashtable import (DENSE_GROUPS, _hash_segment_reduce_impl,
+from trino_tpu.ops.hashtable import (DENSE_GROUPS, PROBE_ROUNDS,
+                                     _hash_group_ids_impl,
+                                     _hash_segment_reduce_impl,
+                                     _mix_operands, _probe_widths,
                                      hash_group_ids, hash_segment_reduce,
                                      hashable_key_types)
 from trino_tpu.ops.sortkeys import group_operands
@@ -50,7 +53,7 @@ def test_hash_gids_match_reference(nvals, n, cap):
     valid = np.zeros(cap, dtype=bool)
     valid[:n] = True
     ops = group_operands(jnp.asarray(keys), jnp.asarray(nulls), T.BIGINT)
-    gid, group_rows, ngroups, overflow = hash_group_ids(
+    gid, group_rows, ngroups, overflow, *_ = hash_group_ids(
         tuple(ops), jnp.asarray(valid))
     gid, group_rows = np.asarray(gid), np.asarray(group_rows)
     assert not bool(overflow)
@@ -75,7 +78,7 @@ def test_hash_gids_multi_key_and_all_null():
     valid = np.arange(cap) < n
     ops = group_operands(jnp.asarray(k1), jnp.asarray(n1), T.BIGINT) \
         + group_operands(jnp.asarray(k2), jnp.asarray(n2), T.BIGINT)
-    gid, _rows, ngroups, overflow = hash_group_ids(
+    gid, _rows, ngroups, overflow, *_ = hash_group_ids(
         tuple(ops), jnp.asarray(valid))
     assert not bool(overflow)
     ref, nref = _reference_gids([k1, k2], [n1, n2], n)
@@ -91,10 +94,10 @@ def test_probe_budget_overflow_is_flagged():
     keys = np.arange(cap, dtype=np.int64) * 7919
     valid = np.ones(cap, dtype=bool)
     ops = group_operands(jnp.asarray(keys), None, T.BIGINT)
-    _gid, _rows, _ng, overflow = hash_group_ids(
+    _gid, _rows, _ng, overflow, *_ = hash_group_ids(
         tuple(ops), jnp.asarray(valid), rounds=1, exact=True)
     assert bool(overflow)
-    gid, _rows, ngroups, overflow = hash_group_ids(
+    gid, _rows, ngroups, overflow, *_ = hash_group_ids(
         tuple(ops), jnp.asarray(valid), rounds=1, exact=False)
     assert not bool(overflow)
     # every row got SOME group; duplicates allowed, coverage is dense
@@ -118,7 +121,7 @@ def test_keyless_gids_in_closed_form(live):
     cap = 64
     valid = {"some": np.arange(cap) % 5 == 3, "all": np.ones(cap, bool),
              "none": np.zeros(cap, bool)}[live]
-    gid, group_rows, ngroups, overflow = hash_group_ids(
+    gid, group_rows, ngroups, overflow, *_ = hash_group_ids(
         (), jnp.asarray(valid))
     assert not bool(overflow)
     assert int(ngroups) == int(valid.any())
@@ -126,6 +129,266 @@ def test_keyless_gids_in_closed_form(live):
     assert np.asarray(gid).tolist() == np.where(valid, 0, cap).tolist()
     first = int(np.argmax(valid)) if valid.any() else 0
     assert np.asarray(group_rows).tolist() == [first] + [0] * (cap - 1)
+
+
+# ------------------------------------------- the probe's narrow buffer
+
+
+def _parent_loop(key_ops, valid, rounds=PROBE_ROUNDS, exact=True):
+    """The probe as it ran before the narrowing, plainly: every round
+    over every lane still unresolved, an empty slot to the smallest row
+    probing it. Returns the four outputs and the rounds run."""
+    ops = [np.asarray(op) for op in key_ops]
+    valid = np.asarray(valid)
+    cap = len(valid)
+    tsize = 1 << max(2 * cap - 1, 1).bit_length()
+    h = np.asarray(_mix_operands(tuple(key_ops), cap))
+    slot0 = (h & np.uint64(tsize - 1)).astype(np.int64)
+    row = np.arange(cap)
+    table = np.full(tsize, cap)
+    rep = np.where(valid, cap, row)
+    r = 0
+    while r < rounds and (rep == cap).any():
+        act = np.flatnonzero(rep == cap)
+        slot = (slot0[act] + r) & (tsize - 1)
+        empty = table[slot] == cap
+        claim = np.full(tsize, cap)
+        np.minimum.at(claim, slot[empty], act[empty])
+        winner = empty & (claim[slot] == act)
+        table[slot[winner]] = act[winner]
+        owner = table[slot]
+        eq = np.ones(len(act), dtype=bool)
+        for op in ops:
+            eq &= op[act] == op[owner]
+        rep[act[eq]] = owner[eq]
+        r += 1
+    unresolved = rep == cap
+    overflow = bool(exact and unresolved.any())
+    if not exact:
+        rep = np.where(unresolved, row, rep)
+    leader = valid & (rep == row)
+    prefix = np.cumsum(leader) - 1
+    gid = np.where(valid & (rep < cap), prefix[np.minimum(rep, cap - 1)],
+                   cap)
+    group_rows = np.zeros(cap + 1, dtype=np.int64)
+    group_rows[np.where(leader, prefix, cap)] = row
+    return (gid, group_rows[:cap], int(leader.sum()), overflow), r
+
+
+def _assert_equals_parent_loop(got, key_ops, valid, **kwargs):
+    """All four outputs of ``got`` bit for bit, and its two counters'
+    sum; returns (rounds_full, rounds_narrow)."""
+    gid, group_rows, ngroups, overflow, full, narrow = got
+    want, rounds = _parent_loop(key_ops, valid, **kwargs)
+    assert gid.dtype == jnp.int32 and group_rows.dtype == jnp.int32
+    assert overflow.dtype == jnp.bool_ and full.dtype == narrow.dtype
+    assert np.array_equal(np.asarray(gid), want[0])
+    assert np.array_equal(np.asarray(group_rows), want[1])
+    assert (int(ngroups), bool(overflow)) == want[2:]
+    assert int(full) + int(narrow) == rounds
+    return int(full), int(narrow)
+
+
+def _keys_sharing_slots(cap, chains, spacing, seed):
+    """Distinct int64 keys in chains: ``chains`` = [(how many chains,
+    keys a chain)], the keys of a chain all hashing to one first slot of
+    a ``cap``-lane page's table, the chains' slots ``spacing`` apart."""
+    tsize = 2 * cap
+    candidates = np.arange(60 * cap, dtype=np.int64)
+    ops = group_operands(jnp.asarray(candidates), None, T.BIGINT)
+    h = np.asarray(_mix_operands(tuple(ops), len(candidates)))
+    slot0 = (h & np.uint64(tsize - 1)).astype(np.int64)
+    order = np.argsort(slot0, kind="stable")
+    starts = np.searchsorted(slot0[order], np.arange(tsize))
+    keys, slot = [], 0
+    for count, length in chains:
+        for _ in range(count):
+            at = starts[slot]
+            assert slot0[order[at + length - 1]] == slot
+            keys.extend(candidates[order[at:at + length]])
+            slot += spacing
+    assert slot <= tsize
+    keys = np.asarray(keys, dtype=np.int64)
+    return np.random.default_rng(seed).permutation(keys)
+
+
+def _page_of(keys, cap):
+    """Key operands and valid mask of ``keys`` in the first lanes of a
+    ``cap``-lane page."""
+    padded = np.zeros(cap, dtype=np.int64)
+    padded[:len(keys)] = keys
+    ops = group_operands(jnp.asarray(padded), None, T.BIGINT)
+    return tuple(ops), np.arange(cap) < len(keys)
+
+
+def _late_narrowing_page(cap=8192):
+    """1,150 chains of six keys and 100 of ten: over ``cap // 8`` lanes
+    stay unresolved through round 5, 400 go on from round 7."""
+    assert _probe_widths(cap)[1] == cap // 8 == 1024
+    return _page_of(_keys_sharing_slots(
+        cap, [(1150, 6), (100, 10)], spacing=13, seed=cap), cap)
+
+
+def _narrow_page(cap, shape):
+    rng = np.random.default_rng(cap + len(shape))
+    valid = np.ones(cap, dtype=bool)
+    nulls = [None]
+    if shape == "all_distinct":
+        keys = [rng.permutation(cap).astype(np.int64) * 7919]
+    elif shape == "four_groups":
+        keys = [rng.integers(0, 4, size=cap).astype(np.int64)]
+    elif shape == "quarter_groups_scattered_invalid":
+        keys = [rng.integers(0, cap // 4, size=cap).astype(np.int64)]
+        valid = rng.random(cap) >= 0.15
+    else:
+        assert shape == "two_columns_with_nulls"
+        keys = [rng.integers(0, 300, size=cap).astype(np.int64),
+                rng.integers(0, 40, size=cap).astype(np.int64)]
+        nulls = [jnp.asarray(rng.random(cap) < 0.1),
+                 jnp.asarray(rng.random(cap) < 0.3)]
+        valid = rng.random(cap) >= 0.05
+    ops = []
+    for k, n in zip(keys, nulls):
+        ops.extend(group_operands(jnp.asarray(k), n, T.BIGINT))
+    null_cols = [np.zeros(cap, dtype=bool) if n is None else np.asarray(n)
+                 for n in nulls]
+    return tuple(ops), valid, keys, null_cols
+
+
+@pytest.mark.parametrize("exact", [True, False])
+@pytest.mark.parametrize("shape", [
+    "all_distinct", "four_groups", "quarter_groups_scattered_invalid",
+    "two_columns_with_nulls"])
+@pytest.mark.parametrize("cap", [8192, 65536])
+def test_narrowed_probe_equals_the_full_width_loop(cap, shape, exact):
+    """Pages wide enough to narrow: every output equal to the loop that
+    ran every round over the whole page, and the gids to first
+    occurrence."""
+    assert len(_probe_widths(cap)) > 1
+    ops, valid, keys, nulls = _narrow_page(cap, shape)
+    got = hash_group_ids(ops, jnp.asarray(valid), exact=exact)
+    full, narrow = _assert_equals_parent_loop(got, ops, valid, exact=exact)
+    assert not bool(got[3])
+    assert (full, narrow) == (1, 0) if shape == "four_groups" \
+        else narrow > 0
+    if cap == 8192:
+        live = np.flatnonzero(valid)
+        ref, nref = _reference_gids([k[live] for k in keys],
+                                    [n[live] for n in nulls], len(live))
+        assert int(got[2]) == nref
+        assert np.asarray(got[0])[live].tolist() == ref
+
+
+@pytest.mark.parametrize("exact", [True, False])
+@pytest.mark.parametrize("live,groups", [(7, 7), (900, 4), (3000, 3000)])
+def test_sparse_page_probes_in_the_narrow_buffer_alone(live, groups, exact):
+    """Few valid rows scattered over a wide page (a selective join's
+    output): they fit the buffer before any round, so none runs at the
+    page's width — and 7 rows skip the first buffer's width too."""
+    cap = 65536
+    assert _probe_widths(cap) == (65536, 8192, 1024)
+    rng = np.random.default_rng(live)
+    valid = np.zeros(cap, dtype=bool)
+    valid[rng.choice(cap, live, replace=False)] = True
+    keys = rng.integers(0, 1 << 40, size=groups)[
+        rng.integers(0, groups, size=cap)].astype(np.int64)
+    ops = tuple(group_operands(jnp.asarray(keys), None, T.BIGINT)
+                + group_operands(jnp.asarray(keys % 11), None, T.BIGINT))
+    got = hash_group_ids(ops, jnp.asarray(valid), exact=exact)
+    full, narrow = _assert_equals_parent_loop(got, ops, valid, exact=exact)
+    assert full == 0 and narrow >= 1 and not bool(got[3])
+
+
+def test_probe_stays_wide_while_many_rows_are_unresolved():
+    """Keys built to share first slots: the count on the device keeps
+    the page's own width for six rounds, and the narrow buffer takes the
+    long chains' last rounds."""
+    ops, valid = _late_narrowing_page()
+    got = hash_group_ids(ops, jnp.asarray(valid))
+    full, narrow = _assert_equals_parent_loop(got, ops, valid)
+    assert full >= 6 and narrow >= 3 and not bool(got[3])
+    assert int(got[2]) == int(valid.sum())
+
+
+def _chain_of_three_page(cap=8192):
+    """Four keys over most lanes and one chain of three: round 1 leaves
+    two rows, a budget of two rounds ends in the narrow buffer."""
+    rng = np.random.default_rng(3)
+    chain = _keys_sharing_slots(cap, [(1, 3)], spacing=1, seed=3)
+    keys = rng.integers(-4, 0, size=cap - 100).astype(np.int64)
+    keys[rng.choice(len(keys), 3, replace=False)] = chain
+    return _page_of(keys, cap)
+
+
+@pytest.mark.parametrize("exact", [True, False])
+@pytest.mark.parametrize("ends_in", ["narrow_buffer", "full_width"])
+def test_probe_budget_is_one_budget_across_widths(ends_in, exact):
+    """``rounds=2`` against chains of three and more: ``overflow`` in
+    exact mode, singleton groups in non-exact mode, on the lanes the
+    full-width loop leaves — also where the budget ends with more rows
+    unresolved than the narrow buffer holds."""
+    ops, valid = _chain_of_three_page() if ends_in == "narrow_buffer" \
+        else _late_narrowing_page()
+    got = hash_group_ids(ops, jnp.asarray(valid), rounds=2, exact=exact)
+    full, narrow = _assert_equals_parent_loop(got, ops, valid, rounds=2,
+                                              exact=exact)
+    assert (full, narrow) == ((1, 1) if ends_in == "narrow_buffer"
+                              else (2, 0))
+    assert bool(got[3]) == exact
+    if not exact:
+        # every row has a group; the chain's last rows lead their own
+        assert (np.asarray(got[0])[valid] < int(got[2])).all()
+
+
+@pytest.mark.parametrize("exact", [True, False])
+def test_narrowed_probe_under_vmap_with_a_page_that_needs_no_buffer(exact):
+    """Two pages batched, one resolved in its first round: the batched
+    predicate makes the ``cond`` a select and the loops run to the
+    slower page — each page's outputs are still its own."""
+    import jax
+
+    cap = 8192
+    pages = [_narrow_page(cap, shape)[:2]
+             for shape in ("four_groups", "all_distinct")]
+    ops = tuple(jnp.stack(cols) for cols in zip(*(p[0] for p in pages)))
+    valid = jnp.stack([jnp.asarray(p[1]) for p in pages])
+    got = jax.jit(jax.vmap(
+        lambda o, v: _hash_group_ids_impl(o, v, exact=exact)))(ops, valid)
+    rounds = [_assert_equals_parent_loop(
+        tuple(out[i] for out in got), *pages[i], exact=exact)
+        for i in range(2)]
+    assert rounds[0] == (1, 0) and rounds[1][1] > 0
+
+
+def test_probe_rounds_reach_the_operator_with_no_read_of_their_own():
+    """A many-group aggregation over pages wide enough to narrow: the
+    span carries the rounds of every page and merge whose flags the step
+    read, most of them narrow, and the reads are one a page and one a
+    merge as before."""
+    from trino_tpu.connectors.tpch import TpchConnector
+    from trino_tpu.runner import LocalQueryRunner
+    from trino_tpu.sql.analyzer import Session
+
+    runner = LocalQueryRunner(
+        {"tpch": TpchConnector(page_rows=8192)},
+        Session(catalog="tpch", schema="tiny"), desired_splits=1)
+    res = runner.execute("select l_orderkey, sum(l_quantity) from lineitem "
+                         "group by l_orderkey")
+    assert len(res.rows) == 15000
+    spans = res.stats["trace"]
+    root, = [s for s in spans if s["name"] == "statement"]
+    agg, = [s["attrs"] for s in spans if "probe_rounds" in s["attrs"]]
+    calls = agg["partial_lanes"]["pages"] + agg["merge_calls"]
+    assert agg["partial_lanes"]["pages"] > 1 and agg["merge_calls"] >= 1
+    assert root["attrs"]["host_sync_by_why"]["agg_overflow"][0] == calls
+    assert agg["probe_rounds"] > 2 * calls
+    assert 0 < agg["probe_rounds_narrow"] <= agg["probe_rounds"] - calls
+    text = runner.execute(
+        "explain analyze select l_orderkey, sum(l_quantity) from lineitem "
+        "group by l_orderkey").rows
+    assert f"[probe rounds {agg['probe_rounds']}, " \
+        f"{agg['probe_rounds_narrow']} narrow]" in \
+        "\n".join(r[0] for r in text)
 
 
 _SEGMENT_OPS = {"sum": "segment_sum", "min": "segment_min",

@@ -273,8 +273,8 @@ def _agg_group_lane(aggs: Tuple, key_channels: Tuple, key_types: Tuple,
             key_raws.append(col)
         key_nulls = tuple(nulls[c] for c in key_channels)
         if hash_path:
-            gid, group_rows, ngroups, overflow = _hash_group_ids_impl(
-                tuple(key_ops), valid, exact=True)
+            gid, group_rows, ngroups, overflow, *_rounds = \
+                _hash_group_ids_impl(tuple(key_ops), valid, exact=True)
             out_keys, out_key_nulls, reduced, out_valid = \
                 _hash_segment_reduce_impl(
                     gid, group_rows, ngroups, tuple(key_raws), key_nulls,

@@ -108,6 +108,11 @@ class OperatorStats:
                 pl = m["partial_lanes"]
                 base += (f" [partial lanes {pl['in']}->{pl['kept']} over "
                          f"{pl['pages']} pages, merge {pl['merge']}]")
+            if m.get("probe_rounds"):
+                # the grouping's probe rounds over the pages whose flags
+                # the step read, and those run over the unresolved alone
+                base += (f" [probe rounds {m['probe_rounds']}, "
+                         f"{m['probe_rounds_narrow']} narrow]")
             if m.get("resident_pages"):
                 # a scan of a table that lives on the device: pages and
                 # bytes taken as they lay, nothing uploaded

@@ -584,6 +584,11 @@ class HashAggregationOperator(Operator):
         #: summed capacity: the lanes grouped a second time
         self._merge_calls = 0
         self._merge_lanes = 0
+        #: ``hash_group_ids``' probe rounds over the pages whose flags the
+        #: step read, and those of them run over a narrow buffer of the
+        #: rows still unresolved
+        self._probe_rounds = 0
+        self._probe_rounds_narrow = 0
         #: group count of the output page, where the last grouping read one
         self._groups_out: Optional[int] = None
         self._emitted = False
@@ -829,7 +834,7 @@ class HashAggregationOperator(Operator):
         overflow); ngroups is the page's group count on the host where
         the step reads the page's flags anyway (exact), else None."""
         exact = self.step != "partial"
-        gid, group_rows, ngroups, overflow = hash_group_ids(
+        gid, group_rows, ngroups, overflow, full, narrow = hash_group_ids(
             tuple(key_ops), page.valid, exact=exact)
         key_nulls = tuple(page.nulls[c] for c in key_channels)
         # dispatch the reduce SPECULATIVELY, before the overflow sync:
@@ -841,9 +846,12 @@ class HashAggregationOperator(Operator):
                                      pallas=mode)
         count = None
         if exact:
-            # one wait for two scalars of the one program
+            # one wait for four scalars of the one program
             with host_sync("agg_overflow"):
-                overflow, count = jax.device_get((overflow, ngroups))
+                overflow, count, full, narrow = jax.device_get(
+                    (overflow, ngroups, full, narrow))
+            self._probe_rounds += int(full) + int(narrow)
+            self._probe_rounds_narrow += int(narrow)
             if overflow:
                 return None, None
             count = int(count)
@@ -1101,7 +1109,9 @@ class HashAggregationOperator(Operator):
                                   self.path_counts.items() if v},
                "partial_lanes": dict(self._lanes),
                "merge_calls": self._merge_calls,
-               "merge_lanes": self._merge_lanes}
+               "merge_lanes": self._merge_lanes,
+               "probe_rounds": self._probe_rounds,
+               "probe_rounds_narrow": self._probe_rounds_narrow}
         if self._groups_out is not None:
             out["groups_out"] = self._groups_out
         seeded = " (seeded by hbo)" \

@@ -15,11 +15,36 @@ Design:
     each slot (``capacity`` = empty sentinel), plus one dummy slot that
     absorbs masked scatters;
   - insert-or-lookup runs a bounded number of linear-probe ROUNDS, each
-    round fully vectorized over the page: every unresolved row probes
+    round fully vectorized over its lanes: every unresolved row probes
     ``(h + round) & mask``, empty slots are claimed by scatter-min on
     row index, claimants re-gather the installed owner and compare full
     keys by gathering the owner row's operands — equal keys join the
-    owner's group, colliders advance to the next probe;
+    owner's group, colliders advance to the next probe. A round is five
+    gathers / scatters on the table and a gather a key operand, and on
+    a TPU each costs by the lanes it is given; the loop runs as many
+    rounds as the page's LONGEST collision chain, while the mean chain
+    is a little over one: a q1 page (4 groups) takes 1 round, a q3 page
+    4, a q13 or first-level q18 page (65-100 k groups in 262,144 lanes)
+    9, q18's merge (1.5 M groups in 2,097,152 lanes) 16 — and after the
+    first round 5-11 % of the lanes are still looking, after the second
+    1-3 %;
+  - so the probe NARROWS: the page's own width probes only while more
+    rows are unresolved than an eighth of its lanes (``_NARROW_BY``; the
+    count is taken on the device each round, nothing is assumed of the
+    data: a full page runs its first round wide, a page of few valid
+    rows — a selective join's output — none); then the unresolved
+    rows' indices, first slots and key operands are gathered once into
+    a buffer that wide, in row order (``_first_lanes``), the same round
+    runs over the buffer against the same table — the row index that
+    claims a slot is the original one, so the smallest probing row
+    still wins, and the round counter and its budget carry on — and
+    one scatter puts the owners found back. The buffer narrows again
+    the same way (``_NARROW_LEVELS``); a page that resolved at its own
+    width (every q1 page) skips all of it under a ``lax.cond``. In round ``r`` the same rows probe the same
+    slots as in a loop that ran every round over the whole page, so the
+    outputs are equal lane for lane; ``rounds_full`` / ``rounds_narrow``
+    say how the rounds were run (``probe_rounds`` on the aggregation's
+    span);
   - dense group ids are a cumsum over "row owns itself" leaders, so gid
     order is first-occurrence order (matching the reference's
     putIfAbsent numbering), with no sort anywhere.
@@ -78,6 +103,14 @@ PROBE_ROUNDS = 32
 #: sweep at 262,144 lanes and 15 int64 columns (PERF.md §5, PR 29).
 DENSE_GROUPS = 128
 
+#: the probe goes on over the rows still unresolved alone once they fit a
+#: buffer this many times narrower than the lanes probing, and does so
+#: again, ``_NARROW_LEVELS`` times at most. Pages of any width: on a v5e
+#: it pays from 1,024 lanes up and is even at 256 (the probe-round
+#: sweep, PERF.md section 5, PR 40)
+_NARROW_BY = 8
+_NARROW_LEVELS = 2
+
 _M1 = np.uint64(0xBF58476D1CE4E5B9)
 _M2 = np.uint64(0x94D049BB133111EB)
 _M3 = np.uint64(0x9E3779B97F4A7C15)  # golden-ratio increment
@@ -107,6 +140,28 @@ def _mix_operands(key_ops: Tuple, n: int):
     return h
 
 
+def _first_lanes(mask, size: int):
+    """int32[size]: the positions of ``mask``'s first ``size`` set lanes,
+    ascending, and ``mask.shape[0]`` in the lanes past them. A prefix
+    count gives every set lane its place and one scatter at those
+    (ascending) places puts it there; unset lanes and the set ones past
+    ``size`` go to a dummy lane."""
+    width = mask.shape[0]
+    place = jnp.cumsum(mask, dtype=jnp.int32) - 1
+    place = jnp.where(mask, jnp.minimum(place, size), size)
+    lanes = jnp.full((size + 1,), width, dtype=jnp.int32)
+    lanes = lanes.at[place].set(jnp.arange(width, dtype=jnp.int32))
+    return lanes[:size]
+
+
+def _probe_widths(cap: int) -> Tuple[int, ...]:
+    """The lane widths a page's probe may run at, the page's own first."""
+    widths = [cap]
+    while len(widths) <= _NARROW_LEVELS and widths[-1] >= _NARROW_BY:
+        widths.append(widths[-1] // _NARROW_BY)
+    return tuple(widths)
+
+
 def _hash_group_ids_impl(key_ops: Tuple, valid,
                          rounds: int = PROBE_ROUNDS,
                          exact: bool = True):
@@ -121,7 +176,8 @@ def _hash_group_ids_impl(key_ops: Tuple, valid,
     key_ops: flattened (tag_u8, u64) grouping operands (integer dtypes).
     valid:   bool lane mask; invalid lanes get the dump gid ``capacity``.
 
-    Returns (gid, group_rows, ngroups, overflow):
+    Returns (gid, group_rows, ngroups, overflow, rounds_full,
+    rounds_narrow):
       gid        int32 (cap,)   dense group id per row, first-occurrence
                                 order; invalid lanes get ``cap``
       group_rows int32 (cap,)   representative row index per group id
@@ -131,6 +187,10 @@ def _hash_group_ids_impl(key_ops: Tuple, valid,
                                 (caller must fall back). In non-exact
                                 mode always False: unresolved rows become
                                 their own singleton groups.
+      rounds_full, rounds_narrow
+                 int32 scalars  probe rounds run over the page's own
+                                lanes, and over a narrow buffer of the
+                                rows still unresolved (module docstring)
     """
     if not key_ops:
         return _keyless_group_ids(valid)
@@ -148,41 +208,72 @@ def _hash_group_ids_impl(key_ops: Tuple, valid,
     # slot -> owning row index; ``cap`` = empty; slot ``tsize`` is the
     # dummy that absorbs scatters from masked-off lanes
     table0 = jnp.full((tsize + 1,), cap, dtype=jnp.int32)
-    rep0 = jnp.where(valid, cap, row_idx)  # resolved rows' owner row
-    resolved0 = ~valid
+    # a lane's owner row; ``cap`` = still looking (invalid lanes never do)
+    rep0 = jnp.where(valid, cap, row_idx)
 
-    def probe_round(carry):
-        r, table, rep, resolved = carry
-        active = ~resolved
-        slot = jnp.where(active, (slot0 + r) & (tsize - 1), tsize)
-        owner = table[slot]
-        empty = active & (owner == cap)
-        # claim empty slots: smallest probing row index wins the install
-        claim = jnp.full((tsize + 1,), cap, dtype=jnp.int32)
-        claim = claim.at[jnp.where(empty, slot, tsize)].min(row_idx)
-        winner = empty & (claim[slot] == row_idx)
-        table = table.at[jnp.where(winner, slot, tsize)].set(row_idx)
-        owner = table[slot]
-        # full-key compare against the (possibly just-installed) owner
-        owner_safe = jnp.clip(owner, 0, cap - 1)
-        eq = active & (owner < cap)
-        for op in key_ops:
-            eq = eq & (op == op[owner_safe])
-        rep = jnp.where(eq, owner, rep)
-        return r + 1, table, rep, resolved | eq
+    def probe(rows, slot0, own, widths, r, table, rep, left):
+        """Probe rounds over one set of lanes — the page's, or a buffer
+        of the rows still unresolved: ``rows`` their row indices,
+        ``slot0`` and ``own`` their first slots and key operands — until
+        the ``left`` unresolved ones fit ``widths[1]`` lanes, then over
+        those alone. Returns (rep, rounds run at each of ``widths``)."""
+        narrow = widths[1] if len(widths) > 1 else 0
 
-    def keep_probing(carry):
-        r, _table, _rep, resolved = carry
-        return (r < rounds) & jnp.any(~resolved)
+        def probe_round(carry):
+            r, table, rep, _left = carry
+            active = rep == cap
+            slot = jnp.where(active, (slot0 + r) & (tsize - 1), tsize)
+            owner = table[slot]
+            empty = active & (owner == cap)
+            # claim empty slots: smallest probing row index wins the install
+            claim = jnp.full((tsize + 1,), cap, dtype=jnp.int32)
+            claim = claim.at[jnp.where(empty, slot, tsize)].min(rows)
+            winner = empty & (claim[slot] == rows)
+            table = table.at[jnp.where(winner, slot, tsize)].set(rows)
+            owner = table[slot]
+            # full-key compare against the (possibly just-installed) owner
+            owner_safe = jnp.clip(owner, 0, cap - 1)
+            eq = active & (owner < cap)
+            for mine, op in zip(own, key_ops):
+                eq = eq & (mine == op[owner_safe])
+            rep = jnp.where(eq, owner, rep)
+            return r + 1, table, rep, jnp.sum(rep == cap, dtype=jnp.int32)
 
-    # typical pages resolve in 1-3 rounds; the loop exits as soon as
-    # every row found its group, paying the full budget only under
-    # adversarial collision chains
-    _, _, rep, resolved = jax.lax.while_loop(
-        keep_probing, probe_round,
-        (jnp.zeros((), dtype=jnp.int32), table0, rep0, resolved0))
+        def keep_probing(carry):
+            r, _table, _rep, left = carry
+            return (r < rounds) & (left > narrow)
 
-    unresolved = ~resolved
+        r_in = r
+        r, table, rep, left = jax.lax.while_loop(
+            keep_probing, probe_round, (r, table, rep, left))
+        ran = (r - r_in,)
+        if not narrow:
+            return rep, ran
+
+        def narrowed(r, table, rep):
+            # the budget can end the loop with more left than fit: the
+            # rounds are spent then, and what the buffer holds goes back
+            # as it came
+            at = _first_lanes(rep == cap, narrow)
+            live = at < rows.shape[0]
+            safe = jnp.where(live, at, 0)
+            rep_n, ran_n = probe(
+                jnp.where(live, rows[safe], cap), slot0[safe],
+                tuple(op[safe] for op in own), widths[1:], r, table,
+                jnp.where(live, cap, 0), jnp.minimum(left, narrow))
+            return rep.at[at].set(rep_n, mode="drop"), ran_n
+
+        def done(r, table, rep):
+            return rep, (jnp.zeros((), jnp.int32),) * (len(widths) - 1)
+
+        rep, ran_n = jax.lax.cond(left > 0, narrowed, done, r, table, rep)
+        return rep, ran + ran_n
+
+    rep, ran = probe(row_idx, slot0, key_ops, _probe_widths(cap),
+                     jnp.zeros((), dtype=jnp.int32), table0, rep0,
+                     jnp.sum(valid, dtype=jnp.int32))
+
+    unresolved = rep == cap
     if exact:
         overflow = jnp.any(unresolved)
     else:
@@ -198,7 +289,8 @@ def _hash_group_ids_impl(key_ops: Tuple, valid,
     ngroups = jnp.sum(leader.astype(jnp.int32))
     group_rows = jnp.zeros((cap + 1,), dtype=jnp.int32)
     group_rows = group_rows.at[jnp.where(leader, prefix, cap)].set(row_idx)
-    return gid, group_rows[:cap], ngroups, overflow
+    return (gid, group_rows[:cap], ngroups, overflow, ran[0],
+            sum(ran[1:], jnp.zeros((), jnp.int32)))
 
 
 def _keyless_group_ids(valid):
@@ -213,8 +305,9 @@ def _keyless_group_ids(valid):
     some = first < cap
     group_rows = jnp.zeros((cap,), dtype=jnp.int32).at[0].set(
         jnp.where(some, first, 0))
+    none = jnp.zeros((), dtype=jnp.int32)
     return (gid, group_rows, some.astype(jnp.int32),
-            jnp.zeros((), dtype=bool))
+            jnp.zeros((), dtype=bool), none, none)
 
 
 # profiled entry points (telemetry.profiler): cost/compile

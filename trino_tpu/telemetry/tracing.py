@@ -509,14 +509,15 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
             span["attrs"]["input_rows"] = driver.stats[i - 1].output_rows
         if st.metrics:
             # the scan operator's host-side counters, the aggregation's
-            # partial widths, merges and groups, and the join's type and
-            # probe counters, under their names
+            # partial widths, merges, groups and probe rounds, and the
+            # join's type and probe counters, under their names
             for key in ("generate_s", "upload_s", "wait_s",
                         "readahead_pages", "readahead_ready",
                         "resident_pages", "resident_bytes",
                         "local_bytes", "transferred_bytes",
                         "uploaded_bytes", "partial_lanes",
                         "merge_calls", "merge_lanes", "groups_out",
+                        "probe_rounds", "probe_rounds_narrow",
                         "join_type", "probe_pages", "direct_probe_pages",
                         "direct_table_bytes", "probe_fallback"):
                 if st.metrics.get(key) is not None:
