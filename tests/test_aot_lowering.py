@@ -252,6 +252,35 @@ def test_join_expansion_compiles_without_a_loop_for_v5e(one_chip):
         assert (" while(" in text) == (out_cap == 16)
 
 
+def test_two_column_key_join_compiles_for_v5e(one_chip):
+    """q9's ``partsupp`` join at SF1 (``sf1_q9_join6``): the key is two
+    bigints, so the build is ``hashed`` and has no direct-address table.
+    The 323,326 joined rows with their ten columns are sorted at 2^21
+    lanes, a resident ``partsupp`` page of 262,144 rows probes the
+    sorted index by two binary searches, and the expansion verifies
+    both raw key columns."""
+    from functools import partial
+
+    from trino_tpu.ops.join import (_build_sorted, _expand_verified,
+                                    _probe_counts)
+
+    build, rows = 1 << 21, 1 << 18
+    u64 = sds((build,), jnp.uint64)
+    flag = sds((build,), jnp.bool_)
+    cols = (sds((build,), jnp.int64),) * 8 + (sds((build,), jnp.int32),) * 2
+    _compile(_build_sorted.jit, one_chip, u64, flag, cols,
+             (flag,) * 10, flag)
+    _compile(_probe_counts.jit, one_chip, u64, flag,
+             sds((rows,), jnp.uint64), sds((rows,), jnp.bool_))
+    idx = sds((rows,), jnp.int32)
+    key, bkey = sds((rows,), jnp.int64), sds((build,), jnp.int64)
+    text = _compile(
+        partial(lambda lo, count, p0, p1, b0, b1, out_cap: _expand_verified(
+            lo, count, (p0, p1), (b0, b1), out_cap=out_cap), out_cap=rows),
+        one_chip, idx, idx, key, key, bkey, bkey).as_text()
+    assert " while(" not in text
+
+
 def test_direct_probe_compiles_for_v5e(one_chip):
     """The direct-address probe at q3-SF1 sizes: the ``orderkey`` build
     (2^20 sorted rows, 6.0 M codes: a table of 2^23 int32 offsets) and

@@ -46,6 +46,30 @@ def test_q9_rows_unchanged_by_reorder(runner):
     assert rows == sorted(rows, key=lambda r: (r[0], -r[1]))
 
 
+def test_q9_plan_settles_within_four_statements():
+    """History re-plans a shape from what its last run read; the fourth
+    statement's physical plan is the third's, and so are its rows (q9
+    ran under four plans in five statements while a scan's history was
+    what a dynamic filter had left of it and a join's history was served
+    to the same criteria over other relations:
+    ``tests/test_q9_deep_join.py`` holds the served path to it)."""
+    from trino_tpu.telemetry import stats_store
+
+    stats_store.store().clear()
+    fresh = LocalQueryRunner({"tpch": TpchConnector(page_rows=2048)},
+                             Session(catalog="tpch", schema="micro"))
+    runs = [fresh.execute(TPCH_QUERIES[9]) for _ in range(4)]
+    plans = [[s for s in r.stats["trace"] if s["parent_id"] is None][0]
+             ["attrs"]["plan_fp"] for r in runs]
+    assert plans[3] == plans[2]
+    assert all(r.rows == runs[0].rows for r in runs)
+    line, = [ln for ln in fresh.explain(TPCH_QUERIES[9]).splitlines()
+             if "TableScan tpch.micro.lineitem" in ln]
+    assert "[source=hbo]" in line
+    rows = fresh.execute("select count(*) from lineitem").rows[0][0]
+    assert f"est~{rows} rows" in line
+
+
 def test_provenance_in_explain(runner):
     plan = runner.explain(
         "select n_name from nation where n_regionkey = 2 "

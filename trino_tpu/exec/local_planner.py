@@ -99,6 +99,33 @@ class LocalExecutionPlan:
         #: telemetry.progress.QueryProgress fed live task counts
         self.progress = progress
 
+    def fingerprint(self) -> str:
+        """A hash of the physical plan as it will run: every pipeline's
+        operators in order, a scan's columns, a builder's key channels
+        and the joins that probe its build, a join's type and key
+        channels.  No literal and no estimate: two statements of one
+        shape differ here only if the planner ordered them apart (the
+        statement root's ``plan_fp``)."""
+        import hashlib
+
+        bridges: dict = {}
+        parts = []
+        for p in self.pipelines:
+            for op in p.operators:
+                part = [type(op).__name__]
+                scan = getattr(op, "_pages", None)
+                if hasattr(scan, "columns"):
+                    part.append([c.name for c in scan.columns])
+                for attr in ("key_channels", "probe_keys", "join_type"):
+                    if hasattr(op, attr):
+                        part.append(getattr(op, attr))
+                if hasattr(op, "bridge"):
+                    part.append(bridges.setdefault(id(op.bridge),
+                                                   len(bridges)))
+                parts.append(part)
+            parts.append("|")
+        return hashlib.sha1(repr(parts).encode()).hexdigest()[:16]
+
     def execute(self, collect_stats: bool = False) -> List[Page]:
         from .driver import Driver
 

@@ -663,6 +663,7 @@ class HashBuilderOperator(Operator):
         #: each device page, or -1 for a not-yet-split mixed page
         self._page_pid: List[int] = []
         self._pages: List = []  # DevicePage | SpilledPage
+        self._published: dict = {}
         self._done = False
         self._ctx = memory_context
         if self._ctx is not None:
@@ -947,11 +948,15 @@ class HashBuilderOperator(Operator):
                 self._page_pid.append(-1)
 
     def metrics(self) -> dict:
+        """What was published: how the keys were assembled (``single``
+        / ``packed`` / ``hashed`` - the last has no direct-address
+        table) and the index's width in lanes."""
+        out = dict(self._published)
         hs = self._hstate
         if hs is None:
-            return {}
+            return out
         with hs._lock:
-            return {"hybrid_spill": {
+            return {**out, "hybrid_spill": {
                 "fanout": hs.fanout,
                 "source": hs.source,
                 "fraction": round(hs.spilled_build_rows
@@ -1089,6 +1094,8 @@ class HashBuilderOperator(Operator):
             build.direct_fallback = "hybrid partitions"
         else:
             _attach_direct_table(build, self._ctx)
+        self._published = {"key_mode": build.key_mode,
+                           "build_lanes": int(build.key_sorted.shape[0])}
         self.bridge.set_build(build)
 
     def _collect_dynamic_filters(self, cols, nulls, valid):
@@ -1201,6 +1208,9 @@ class LookupJoinOperator(Operator):
         #: build's direct-address table, and the build's table or why
         #: it has none (kept here: the bridge drops the build at finish)
         self._probe_pages = 0
+        #: the probe pages' widths summed: what every kernel of the
+        #: probe ran over, whatever share of the lanes held a row
+        self._probe_lanes = 0
         self._direct_pages = 0
         self._direct_table_bytes = 0
         self._probe_fallback: Optional[str] = None
@@ -1210,6 +1220,7 @@ class LookupJoinOperator(Operator):
         the build has none (EXPLAIN ANALYZE, the operator span)."""
         out = {"join_type": self.join_type,
                "probe_pages": self._probe_pages,
+               "probe_lanes": self._probe_lanes,
                "direct_probe_pages": self._direct_pages}
         if self._direct_table_bytes:
             out["direct_table_bytes"] = self._direct_table_bytes
@@ -1254,6 +1265,7 @@ class LookupJoinOperator(Operator):
         pusable = page.valid & ~panynull if panynull is not None \
             else page.valid
         self._probe_pages += 1
+        self._probe_lanes += int(page.valid.shape[0])
         self._direct_table_bytes = b.direct.nbytes if b.direct else 0
         self._probe_fallback = b.direct_fallback
         direct = self._probe_direct(page, b, pkey, pusable)
@@ -1552,6 +1564,7 @@ class LookupJoinOperator(Operator):
         pusable = page.valid & ~panynull if panynull is not None \
             else page.valid
         self._probe_pages += 1
+        self._probe_lanes += int(page.valid.shape[0])
         lo, count = _probe_counts(b.key_sorted, b.usable_sorted, pkey,
                                   pusable)
         tot = int(host_read(jnp.sum(count), "join_expand_total"))

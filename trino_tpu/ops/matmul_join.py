@@ -59,8 +59,9 @@ from .. import jit_stats
 from .. import types as T
 from ..block import DevicePage
 from ..telemetry.profiler import instrument
+from ..telemetry.tracing import host_read
 from .join import (_U64_SENTINEL, BuildSide, JoinBridge,
-                   LookupJoinOperator)
+                   LookupJoinOperator, _key_span)
 from .kernel_sizing import KERNEL_SIZING
 
 #: default cap on the dense key domain (``matmul_join_max_key_range``):
@@ -219,20 +220,21 @@ class MatmulJoinOperator(LookupJoinOperator):
         if b.key_mode != "single":
             reason = f"{b.key_mode} key mode (needs one equi key)"
         else:
-            n_usable = int(jnp.sum(b.usable_sorted))
+            # the build's own span where its direct-address table
+            # left one on the device: one blocking read either way
+            span = b.direct.span if b.direct is not None \
+                else _key_span(b.key_sorted, b.usable_sorted)
+            n_usable, klo, khi = host_read(span, "matmul_join_key_range")
+            n_usable = int(n_usable)
             if n_usable == 0:
                 reason = "empty build"
             elif n_usable > MAX_BUILD_ROWS:
                 reason = f"build {n_usable} rows > f32-exact bound"
-            else:
-                # usable rows sort first: [0, n_usable) spans the range
-                klo = np.uint64(b.key_sorted[0])
-                khi = np.uint64(b.key_sorted[n_usable - 1])
-                if khi == _U64_SENTINEL:
-                    reason = "key at the u64 sentinel"
-                elif int(khi - klo) + 1 > self.max_key_range:
-                    reason = (f"key range {int(khi - klo) + 1} > "
-                              f"max {self.max_key_range}")
+            elif khi == _U64_SENTINEL:
+                reason = "key at the u64 sentinel"
+            elif int(khi - klo) + 1 > self.max_key_range:
+                reason = (f"key range {int(khi - klo) + 1} > "
+                          f"max {self.max_key_range}")
         if reason is not None:
             self._fallback_reason = reason
             return False

@@ -338,6 +338,11 @@ class TableScanOperator(SourceOperator):
         self._pages = _ScanPages(connector, list(columns), coalesce_rows)
         self._ahead: Optional[_ReadAhead] = None
         self._done = False
+        #: rows the scan read, before any dynamic filter's mask: what
+        #: history-based statistics file under the scan node (a filter
+        #: belongs to the plan that hung it there, its effect to the
+        #: join above, whose own history holds it)
+        self._rows_read = 0
         #: host-side counters of a traced statement (None: tracing off).
         #: ``generate_s`` (the connector's page generation and the
         #: coalescing concat) and ``upload_s`` (pad + host-to-device
@@ -364,7 +369,7 @@ class TableScanOperator(SourceOperator):
         self._pages.no_more_splits = True
 
     def metrics(self) -> Optional[dict]:
-        return self._counters
+        return dict(self._counters or {}, rows_read=self._rows_read)
 
     def _filtered(self, dp: DevicePage) -> DevicePage:
         for ch, df in self.dynamic_filters:
@@ -415,6 +420,7 @@ class TableScanOperator(SourceOperator):
         if got is None:
             return None
         page, rows = got
+        self._rows_read += rows
         if self.progress is not None:
             self.progress.add_rows(rows)
         return self._filtered(page)

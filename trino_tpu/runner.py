@@ -1020,8 +1020,20 @@ class LocalQueryRunner:
         with profiling(SP.value(self.session,
                                 "query_profiling_enabled")):
             try:
-                with tracing.span("local_plan"):
+                with tracing.span("local_plan") as local_span:
                     plan = local.plan(root)
+                    if local_span:
+                        # which physical plan ran, and of which
+                        # statement shape: a shape whose plan_fp moves
+                        # between statements was re-ordered
+                        local_span.root.attrs["plan_fp"] = \
+                            plan.fingerprint()
+                        if pq.shape is not None:
+                            from .telemetry.stats_store import \
+                                statement_fingerprint
+
+                            local_span.root.attrs["shape_fp"] = \
+                                statement_fingerprint(pq.shape)
                 # per-node actuals need per-operator row counts: the
                 # stats-collecting driver path runs exactly when HBO
                 # records (off = the byte-identical pre-HBO hot path);

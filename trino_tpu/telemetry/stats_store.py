@@ -182,10 +182,26 @@ class NodeHistory:
     #: sizes its partition fan-out from it (source=hbo) and the
     #: optimizer learns the build will spill
     spill: Optional[dict] = None
+    #: the base relations a JOIN node stood over when it was recorded
+    #: (``relations_under``).  A join's fingerprint is its criteria, and
+    #: a re-ordered plan hangs the same criteria over other relations
+    #: (``s_nationkey = n_nationkey`` over supplier x nation is 10,000
+    #: rows, over lineitem x supplier x nation 6 M): its rows are
+    #: served only to a node over the same relations, and a record
+    #: from over others starts the history anew
+    under: Optional[str] = None
 
     _EWMA_FIELDS = ("rows", "bytes", "wall_ms", "flops", "peak_bytes")
 
+    def stands_over(self, under: Optional[str]) -> bool:
+        """Whether this history was recorded over the relations
+        ``under`` (either side unknown: yes, as before it was kept)."""
+        return under is None or self.under is None or under == self.under
+
     def merge(self, upd: dict, alpha: float):
+        if not self.stands_over(upd.get("under")):
+            self.runs = 0
+        self.under = upd.get("under") or self.under
         self.runs += 1
         for k in self._EWMA_FIELDS:
             v = float(upd.get(k) or 0.0)
@@ -204,7 +220,7 @@ class NodeHistory:
                 "bytes": self.bytes, "wall_ms": self.wall_ms,
                 "flops": self.flops, "peak_bytes": self.peak_bytes,
                 "runs": self.runs, "adaptive": self.adaptive,
-                "spill": self.spill}
+                "spill": self.spill, "under": self.under}
 
     @classmethod
     def from_dict(cls, d: dict) -> "NodeHistory":
@@ -214,7 +230,7 @@ class NodeHistory:
                    float(d.get("flops", 0.0)),
                    float(d.get("peak_bytes", 0.0)),
                    int(d.get("runs", 0)), d.get("adaptive"),
-                   d.get("spill"))
+                   d.get("spill"), d.get("under"))
 
 
 def _dump_statement(fp: str, st: dict) -> dict:
@@ -354,7 +370,8 @@ class RuntimeStatsStore:
                 rows = float(upd.get("rows") or 0.0)
                 if upd.get("decision"):
                     # what would the NEXT plan see without this record?
-                    prior = h.rows if h is not None and h.runs else \
+                    prior = h.rows if h is not None and h.runs \
+                        and h.stands_over(upd.get("under")) else \
                         upd.get("est_rows")
                     if prior is not None \
                             and q_error(prior, rows) >= MATERIAL_QERROR:
@@ -579,6 +596,7 @@ class HboContext:
         # node identity survives only while the node object does: the
         # cached NODE rides in the value (the StatsCalculator pattern)
         self._fps: Dict[int, tuple] = {}
+        self._under: Dict[int, tuple] = {}
 
     @classmethod
     def for_statement(cls, stmt, session, metadata,
@@ -618,7 +636,38 @@ class HboContext:
 
     def rows_for(self, node) -> Optional[float]:
         h = self.history(node)
-        return h.rows if h is not None and h.runs else None
+        if h is None or not h.runs \
+                or not h.stands_over(self.relations_under(node)):
+            return None
+        return h.rows
+
+    def relations_under(self, node) -> Optional[str]:
+        """The base relations beneath a join node, sorted and joined
+        (exchanges and join order do not move it), or None for any
+        other node and where a leaf is not a scan (a memo group, a
+        remote source: the relations cannot be told from here)."""
+        from ..planner.plan import JoinNode, TableScanNode, ValuesNode
+
+        if not isinstance(node, JoinNode):
+            return None
+        hit = self._under.get(id(node))
+        if hit is not None and hit[0] is node:
+            return hit[1]
+        names, stack = [], [node]
+        while stack:
+            n = stack.pop()
+            if isinstance(n, TableScanNode):
+                names.append(n.table.qualified_name)
+            elif isinstance(n, ValuesNode):
+                names.append("values")
+            elif not n.sources:
+                names = None
+                break
+            else:
+                stack.extend(n.sources)
+        under = None if names is None else ",".join(sorted(names))
+        self._under[id(node)] = (node, under)
+        return under
 
     def adaptive_seed(self, node_fp: str) -> Optional[dict]:
         if self.store is None:
@@ -657,7 +706,11 @@ class HboContext:
                     "fp": fp, "name": st.name, "rows": 0.0,
                     "bytes": 0.0, "wall_ms": 0.0, "flops": 0.0,
                     "peak_bytes": 0.0}
-            cur["rows"] += st.output_rows
+            # a scan's actual is what it read, not what a dynamic
+            # filter's mask left of it: the node's fingerprint knows
+            # nothing of the filters one plan hung on it
+            cur["rows"] += (getattr(st, "metrics", None) or {}).get(
+                "rows_read", st.output_rows)
             cur["bytes"] += getattr(st, "device_bytes", 0.0) or 0.0
             cur["wall_ms"] += st.wall_ns / 1e6
             cur["flops"] += getattr(st, "flops", 0.0) or 0.0
@@ -706,8 +759,17 @@ class HboContext:
             return None
         est_map, decision_fps = estimates if estimates is not None \
             else self.estimates(root, metadata)
+        under = {}
+        stack = [root]
+        while stack:
+            n = stack.pop()
+            stack.extend(n.sources)
+            if (relations := self.relations_under(n)) is not None:
+                under[self.fp(n)] = relations
         worst = None
         for a in actuals:
+            if a["fp"] in under:
+                a["under"] = under[a["fp"]]
             est = est_map.get(a["fp"])
             if est is None:
                 continue
