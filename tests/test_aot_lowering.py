@@ -299,6 +299,38 @@ def test_direct_probe_compiles_for_v5e(one_chip):
              span, sds((probe,), jnp.uint64), sds((probe,), jnp.bool_))
 
 
+@pytest.mark.parametrize("form", ["table", "sorted", None])
+def test_dynamic_filter_programs_compile_for_v5e(form, one_chip):
+    """The dynamic filter at q9-SF1 sizes: a 2^21-lane build column
+    whose keys span ``l_orderkey``'s 6.0 M codes (a table of 2^23
+    bytes) and the mask of one resident ``lineitem`` page of 262,144
+    lanes under each form of the value set."""
+    from trino_tpu.exec.dynamic_filter import (_dynamic_filter_mask,
+                                               _dynamic_filter_span,
+                                               _dynamic_filter_table)
+
+    build, kp, page = 1 << 21, 1 << 23, 1 << 18
+    i64, i8 = jnp.int64, jnp.int8
+    scalar = sds((), i64)
+    if form == "table":
+        flag = sds((build,), jnp.bool_)
+        _compile(_dynamic_filter_span.jit, one_chip, sds((build,), i64),
+                 flag, flag)
+        _compile(lambda c, n, v, lo: _dynamic_filter_table.jit(
+            c, n, v, lo, kp=kp), one_chip, sds((build,), i64), flag, flag,
+            scalar)
+    members = {"table": sds((kp,), i8), "sorted": sds((1 << 14,), i64),
+               None: None}[form]
+    compiled = _compile(
+        lambda c, n, v, lo, hi, m, nan, p, s: _dynamic_filter_mask.jit(
+            c, n, v, lo, hi, m, nan, p, s, form=form),
+        one_chip, sds((page,), i64), sds((page,), jnp.bool_),
+        sds((page,), jnp.bool_), scalar, scalar, members,
+        sds((), jnp.bool_), scalar, scalar)
+    # only the search loops: the table's test is a gather
+    assert ("while" in compiled.as_text()) == (form == "sorted")
+
+
 def test_sort_by_compiles_for_v5e(one_chip):
     """ORDER BY / TopN over a trimmed aggregation output (q3 at SF1
     sorts ~11,600 groups: 16,384 lanes) — two keys, chained single-key

@@ -153,10 +153,13 @@ def test_semi_join_span_says_its_type_and_its_input(client, tables):
     assert semi["attrs"]["rows"] < semi["attrs"]["input_rows"]
 
 
-@pytest.mark.parametrize("name,syncs", [("q3", 24), ("q13", 14)])
+@pytest.mark.parametrize("name,syncs", [("q3", 26), ("q13", 14)])
 def test_counters_add_no_device_read(name, syncs, client):
     """The warm ``host_syncs`` of the q3 and q13 templates over the same
-    runner are what they were before the counters came."""
+    runner are what they were before the counters came (q3: 24 until
+    PR 42, whose table-backed dynamic filters read their number of
+    distinct keys as a second scalar — one more read for each of q3's
+    two filters, and no key column crossing to the host)."""
     template = traffic.load_template(name)
     served(client, template, **template.meta["validation"])
     _, trace = served(client, template, **template.meta["validation"])

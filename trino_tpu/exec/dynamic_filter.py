@@ -10,10 +10,29 @@ that the probe-side TableScan applies to every page BEFORE rows enter
 the pipeline.
 
 TPU-first details: the scan applies the domain as a lane-mask update (no
-compaction, no host sync — pruned-row counts accumulate in a device
-scalar read once at query end), and the value-set membership test is a
-``searchsorted`` + equality over a padded sorted array, the same
-XLA-native binary-search idiom the join probe uses.
+compaction, no host sync — pruned-row counts accumulate in device
+scalars read once at query end), one jitted program a page and filter
+(``jit__dynamic_filter_mask``: the range compares, the membership test, the
+NaN pass-through and both counts; ``lo`` / ``hi`` are traced, so another
+statement's bounds compile nothing).  The value set takes the form the
+build shows it can:
+
+* a **membership table** where the build's keys are signed integers
+  (bigint, integer, date) on the device and their observed range
+  ``hi - lo + 1`` fits ``TABLE_MAX_CODES``: ``table[c] = 1`` iff key
+  ``lo + c`` is in the build, one byte a code, made on the device by one
+  scatter; a page's test is ``in_range & table[col - lo]`` — one gather.
+  ``collect`` then reads scalars only (live rows, min, max; then the
+  table's distinct count) and the key column never crosses to the host;
+* a **sorted set** and a binary search (``searchsorted`` + equality over
+  a padded sorted array, inside the same program) for everything else:
+  float keys (NaN handling below), a key range past the bound, a table
+  the builder's memory context refuses, and build arrays handed over on
+  the host (the spilled-partition path).  That path pulls the column and
+  runs ``np.unique`` on the host, as every filter did before.
+
+Either way a value set exists exactly when the build has at most
+``MAX_VALUE_SET`` distinct keys; above that the filter is min / max only.
 
 Scheduling guarantee: pipelines of a task run build-before-probe (the
 physical planner sequences them), so the filter is complete before the
@@ -23,16 +42,95 @@ first probe page is scanned — the engine-level analog of Trino's
 
 from __future__ import annotations
 
+from functools import partial
 from typing import List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
+from .. import jit_stats
 from ..block import padded_size
-from ..telemetry.tracing import host_read, host_sync
+from ..telemetry import tracing
+from ..telemetry.profiler import instrument
+from .memory import MemoryExceededError, NodeMemoryExceededError
 
 #: value sets larger than this keep only min/max (reference analog:
 #: dynamic-filtering.small.max-distinct-values-per-driver)
 MAX_VALUE_SET = 1 << 17
+
+#: the widest key range a membership table may cover (one byte a code,
+#: padded to a power of two): 16 Mi codes — every TPC-H SF1 key,
+#: ``l_orderkey``'s 6.0 M included.  A wider build keeps the sorted set.
+TABLE_MAX_CODES = 1 << 24
+
+
+@jax.jit
+def _dynamic_filter_span(col, nulls, valid):
+    """int64[3]: the live (valid, non-null) lanes' number, least and
+    greatest key of a signed-integer column."""
+    jit_stats.bump("dynamic_filter_span")
+    live = valid & ~nulls
+    info = jnp.iinfo(col.dtype)
+    return jnp.stack([
+        jnp.sum(live, dtype=jnp.int64),
+        jnp.min(jnp.where(live, col, info.max)).astype(jnp.int64),
+        jnp.max(jnp.where(live, col, info.min)).astype(jnp.int64)])
+
+
+_dynamic_filter_span = instrument("dynamic_filter_span",
+                                  _dynamic_filter_span)
+
+
+@partial(jax.jit, static_argnames=("kp",))
+def _dynamic_filter_table(col, nulls, valid, lo, kp: int):
+    """(int8[kp] membership table over ``key - lo``, its number of
+    distinct keys) in one scatter: dead and null lanes go past the end
+    and are dropped.  ``kp`` >= the number of codes."""
+    jit_stats.bump("dynamic_filter_table")
+    live = valid & ~nulls
+    idx = jnp.where(live, col.astype(jnp.int64) - lo, kp).astype(jnp.int32)
+    table = jnp.zeros(kp, dtype=jnp.int8).at[idx].set(1, mode="drop")
+    return table, jnp.sum(table, dtype=jnp.int32)
+
+
+_dynamic_filter_table = instrument("dynamic_filter_table",
+                                   _dynamic_filter_table,
+                                   static_argnames=("kp",))
+
+
+@partial(jax.jit, static_argnames=("form",))
+def _dynamic_filter_mask(col, nulls, valid, lo, hi, members, allow_nan,
+                         pruned, seen, form: Optional[str]):
+    """One page under one filter: the new valid mask and the running
+    ``pruned`` / ``seen`` counts.  ``form`` says what ``members`` is: a
+    membership table over ``col - lo`` (``"table"``), a padded sorted
+    array (``"sorted"``) or nothing (min / max only).  An empty build
+    has ``lo`` > ``hi`` and keeps no lane but, for a float key, the NaN
+    lanes ``allow_nan`` lets through."""
+    jit_stats.bump("dynamic_filter_mask")
+    live = valid & ~nulls
+    keep = live & (col >= lo) & (col <= hi)
+    if form == "table":
+        # in range, ``col - lo`` < TABLE_MAX_CODES; elsewhere it may
+        # wrap, and the ``where`` discards it
+        code = jnp.where(keep, col - lo, 0).astype(jnp.int32)
+        keep = keep & (members[code] != 0)
+    elif form == "sorted":
+        members = members.astype(col.dtype)
+        idx = jnp.clip(jnp.searchsorted(members, col), 0,
+                       members.shape[0] - 1)
+        keep = keep & (members[idx] == col)
+    if jnp.issubdtype(col.dtype, jnp.floating):
+        keep = keep | (live & allow_nan & jnp.isnan(col))
+    return (keep,
+            pruned + jnp.sum(valid & ~keep, dtype=jnp.int64),
+            seen + jnp.sum(valid, dtype=jnp.int64))
+
+
+_dynamic_filter_mask = instrument("dynamic_filter_mask",
+                                  _dynamic_filter_mask,
+                                  static_argnames=("form",))
 
 
 class DynamicFilter:
@@ -44,21 +142,82 @@ class DynamicFilter:
         self.allow_nan = False     # build side had NaN float keys
         self.lo = None             # numpy scalar in the key's storage dtype
         self.hi = None
-        self._values: Optional[np.ndarray] = None  # sorted unique, padded
-        self._values_dev = None
-        self._pruned_dev = None    # lazy device accumulator (no hot sync)
-        self._seen_dev = None
+        #: the value set's form — ``"table"``, ``"sorted"`` or None (min /
+        #: max only) — and its device array: int8[kp] over ``key - lo``,
+        #: or the sorted distinct keys padded with the greatest
+        self.set_form: Optional[str] = None
+        self._members = None
+        self.n_values = 0          # distinct keys of the value set
+        #: bytes of the membership table, which the builder's memory
+        #: context keeps reserved (the sorted set goes unaccounted)
+        self.table_bytes = 0
+        #: (pruned, seen) device accumulators once a page was seen (no
+        #: hot sync)
+        self._counts = None
         self.build_rows = 0
 
     # -- build side -----------------------------------------------------
 
-    def collect(self, col, nulls, valid):
-        """Collect the domain from build-side device arrays (called once
-        at HashBuilder publish; one device->host transfer)."""
-        import jax.numpy as jnp
+    def collect(self, col, nulls, valid, ctx=None):
+        """Collect the domain from the build side's arrays (called once
+        at HashBuilder publish).  Device arrays of a signed-integer key
+        stay on the device: two scalar reads.  ``ctx`` is the builder's
+        memory context, asked for the table's bytes."""
+        if isinstance(col, jax.Array) \
+                and jnp.issubdtype(col.dtype, jnp.signedinteger) \
+                and self._collect_on_device(col, nulls, valid, ctx):
+            return
+        self._collect_on_host(col, nulls, valid)
 
-        with host_sync("dynamic_filter_collect"):
-            live = np.asarray(valid & ~nulls)
+    def _set_empty(self):
+        # no (finite) build keys: the range matches nothing; NaN lanes
+        # still pass when the build had NaN keys
+        self.lo, self.hi = np.int64(1), np.int64(0)
+        self.ready = True
+
+    def _collect_on_device(self, col, nulls, valid, ctx) -> bool:
+        """The domain without the column leaving the device; False
+        where the observed range or the memory context allows no
+        table (nothing is set then)."""
+        n, lo, hi = (int(v) for v in tracing.host_read(
+            _dynamic_filter_span(col, nulls, valid), "dynamic_filter_collect"))
+        self.build_rows = n
+        if n == 0:
+            self._set_empty()
+            return True
+        if hi - lo + 1 > TABLE_MAX_CODES:
+            return False
+        kp = padded_size(hi - lo + 1)
+        if ctx is not None:
+            # an optional index, like the join's direct-address table:
+            # it takes what is free and makes no operator spill for
+            # it.  The zeros and the scattered table are both alive
+            # while it is built
+            pool = ctx.pool
+            if pool.reserved + 2 * kp > pool.max_bytes:
+                return False
+            try:
+                ctx.reserve(2 * kp, revocable=False)
+            except (MemoryExceededError, NodeMemoryExceededError):
+                return False
+        table, count = _dynamic_filter_table(col, nulls, valid,
+                                             np.int64(lo), kp=kp)
+        self.lo, self.hi = col.dtype.type(lo), col.dtype.type(hi)
+        self.n_values = int(tracing.host_read(count,
+                                              "dynamic_filter_collect"))
+        if self.n_values <= MAX_VALUE_SET:
+            self.set_form, self._members, self.table_bytes = \
+                "table", table, kp
+        if ctx is not None:
+            ctx.free(2 * kp - self.table_bytes, revocable=False)
+        self.ready = True
+        return True
+
+    def _collect_on_host(self, col, nulls, valid):
+        """The domain by ``np.unique`` over the live keys on the host:
+        one transfer of the column if it lay on the device."""
+        with tracing.host_sync("dynamic_filter_collect"):
+            live = np.asarray(valid) & ~np.asarray(nulls)
             vals = np.asarray(col)[live]
         self.build_rows = int(vals.shape[0])
         if np.issubdtype(vals.dtype, np.floating):
@@ -71,48 +230,32 @@ class DynamicFilter:
             self.allow_nan = bool(nan_mask.any())
             vals = vals[~nan_mask]
         if vals.shape[0] == 0:
-            # no (finite) build keys: range matches nothing; NaN lanes
-            # still pass when the build had NaN keys
-            self.lo, self.hi = np.int64(1), np.int64(0)
-            self.ready = True
+            self._set_empty()
             return
         uniq = np.unique(vals)
         self.lo, self.hi = uniq[0], uniq[-1]
-        if uniq.shape[0] <= MAX_VALUE_SET:
-            cap = padded_size(int(uniq.shape[0]))
-            padded = np.full(cap, uniq[-1], dtype=uniq.dtype)
-            padded[:uniq.shape[0]] = uniq
-            self._values = padded
-            self._values_dev = jnp.asarray(padded)
+        self.n_values = int(uniq.shape[0])
+        if self.n_values <= MAX_VALUE_SET:
+            padded = np.full(padded_size(self.n_values), uniq[-1],
+                             dtype=uniq.dtype)
+            padded[:self.n_values] = uniq
+            self.set_form, self._members = "sorted", jnp.asarray(padded)
         self.ready = True
 
     # -- probe side -----------------------------------------------------
 
     def apply(self, col, nulls, valid):
-        """valid-mask update for one scanned page (device, no sync)."""
-        import jax.numpy as jnp
-
+        """valid-mask update for one scanned page (device, no sync):
+        one program."""
         if not self.ready:
             return valid
-        if self.lo > self.hi:  # no finite build keys
-            keep = jnp.zeros_like(valid)
-        else:
-            keep = valid & ~nulls & \
-                (col >= jnp.asarray(self.lo, dtype=col.dtype)) & \
-                (col <= jnp.asarray(self.hi, dtype=col.dtype))
-            if self._values_dev is not None:
-                vs = self._values_dev.astype(col.dtype)
-                idx = jnp.clip(jnp.searchsorted(vs, col), 0,
-                               vs.shape[0] - 1)
-                keep = keep & (vs[idx] == col)
-        if self.allow_nan:
-            keep = keep | (valid & ~nulls & jnp.isnan(col))
-        pruned = jnp.sum((valid & ~keep).astype(jnp.int64))
-        seen = jnp.sum(valid.astype(jnp.int64))
-        self._pruned_dev = pruned if self._pruned_dev is None \
-            else self._pruned_dev + pruned
-        self._seen_dev = seen if self._seen_dev is None \
-            else self._seen_dev + seen
+        kind = col.dtype.type
+        pruned, seen = self._counts or (np.int64(0), np.int64(0))
+        keep, pruned, seen = _dynamic_filter_mask(
+            col, nulls, valid, kind(self.lo), kind(self.hi),
+            self._members, np.bool_(self.allow_nan), pruned, seen,
+            form=self.set_form)
+        self._counts = (pruned, seen)
         return keep
 
     def to_domain(self):
@@ -120,15 +263,20 @@ class DynamicFilter:
         — the engine's TupleDomain interop form (reference:
         DynamicFilterService handing TupleDomains to connector scans).
         NaN admission can't be expressed as a range and stays a device-
-        side flag; the device ``apply`` path remains the enforcement."""
+        side flag; the device ``apply`` path remains the enforcement.
+        A small value set is listed — read back from the device here,
+        the only place that asks for it."""
         from ..predicate import Domain, Range, ValueSet
 
         if not self.ready:
             return Domain.all_()
         if self.lo > self.hi:  # no finite build keys
             return Domain.none()
-        if self._values is not None and self._values.shape[0] <= 1024:
-            uniq = np.unique(self._values)
+        if self.set_form is not None \
+                and padded_size(self.n_values) <= 1024:
+            members = np.asarray(self._members)
+            uniq = np.unique(members) if self.set_form == "sorted" \
+                else self.lo.item() + np.flatnonzero(members)
             return Domain(ValueSet.of(*(v.item() for v in uniq)), False)
         return Domain(ValueSet.of_ranges(
             Range(self.lo.item(), True, self.hi.item(), True)), False)
@@ -137,13 +285,13 @@ class DynamicFilter:
 
     @property
     def pruned_rows(self) -> int:
-        return 0 if self._pruned_dev is None else int(
-            host_read(self._pruned_dev, "dynamic_filter_stats"))
+        return 0 if self._counts is None else int(tracing.host_read(
+            self._counts[0], "dynamic_filter_stats"))
 
     @property
     def scanned_rows(self) -> int:
-        return 0 if self._seen_dev is None else int(
-            host_read(self._seen_dev, "dynamic_filter_stats"))
+        return 0 if self._counts is None else int(tracing.host_read(
+            self._counts[1], "dynamic_filter_stats"))
 
     def stats(self) -> dict:
         return {
@@ -152,7 +300,8 @@ class DynamicFilter:
             "build_rows": self.build_rows,
             "scanned_rows": self.scanned_rows,
             "pruned_rows": self.pruned_rows,
-            "has_value_set": self._values is not None,
+            "has_value_set": self.set_form is not None,
+            "set": self.set_form,
         }
 
 

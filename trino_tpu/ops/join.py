@@ -1084,7 +1084,9 @@ class HashBuilderOperator(Operator):
         if self._ctx is not None:
             # retain only the published index: sorted key (8B) + usable
             # + valid (1B each) + per-channel data/null lanes
-            retained = cap * (10 + sum(c.dtype.itemsize + 1 for c in cols))
+            # — and its dynamic filters' membership tables
+            retained = cap * (10 + sum(c.dtype.itemsize + 1 for c in cols)) \
+                + sum(df.table_bytes for _, df in self.dynamic_filters)
             self._ctx.close()
             self._ctx.reserve(retained, revocable=False)
             self.bridge.release = self._ctx.close
@@ -1113,7 +1115,7 @@ class HashBuilderOperator(Operator):
                            for p in ps]
         if not spilled:
             for ch, df in self.dynamic_filters:
-                df.collect(cols[ch], nulls[ch], valid)
+                df.collect(cols[ch], nulls[ch], valid, self._ctx)
             return
         hosts = [p.host() for p in spilled]
         sv = np.concatenate([np.asarray(valid)]

@@ -358,7 +358,10 @@ class TableScanOperator(SourceOperator):
         #: bytes taken as they lay on the device against the bytes
         #: uploaded, and of the resident bytes those that lay on the
         #: scan's own device (``local_bytes``) against those copied
-        #: from another (``transferred_bytes``)
+        #: from another (``transferred_bytes``).  Of its dynamic filters:
+        #: the page·filter applications that tested a value set
+        #: (``df_member_pages``) and those of them answered by the
+        #: filter's membership table (``df_table_pages``)
         self._counters: Optional[dict] = None
         self._counters_known = False
 
@@ -372,11 +375,15 @@ class TableScanOperator(SourceOperator):
         return dict(self._counters or {}, rows_read=self._rows_read)
 
     def _filtered(self, dp: DevicePage) -> DevicePage:
+        c = self._counters
         for ch, df in self.dynamic_filters:
             dp = DevicePage(dp.types, dp.cols, dp.nulls,
                             df.apply(dp.cols[ch], dp.nulls[ch],
                                      dp.valid),
                             dp.dictionaries)
+            if c is not None and df.set_form is not None:
+                c["df_member_pages"] += 1
+                c["df_table_pages"] += df.set_form == "table"
         return dp
 
     def _take_ahead(self):
@@ -402,7 +409,8 @@ class TableScanOperator(SourceOperator):
                     "readahead_pages": 0, "readahead_ready": 0,
                     "resident_pages": 0, "resident_bytes": 0,
                     "local_bytes": 0, "transferred_bytes": 0,
-                    "uploaded_bytes": 0}
+                    "uploaded_bytes": 0,
+                    "df_member_pages": 0, "df_table_pages": 0}
         if self._ahead is not None:
             got = self._take_ahead()
             if got is None:

@@ -515,7 +515,8 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
                         "readahead_pages", "readahead_ready",
                         "resident_pages", "resident_bytes",
                         "local_bytes", "transferred_bytes",
-                        "uploaded_bytes", "partial_lanes",
+                        "uploaded_bytes", "df_member_pages",
+                        "df_table_pages", "partial_lanes",
                         "merge_calls", "merge_lanes", "groups_out",
                         "probe_rounds", "probe_rounds_narrow",
                         "join_type", "probe_pages", "direct_probe_pages",
