@@ -13,7 +13,12 @@ on a TPU backend).
     python chip_smoke.py              one chip (what the driver runs)
     python chip_smoke.py --chips 4    only the four-device phase:
                                       DistributedQueryRunner + DeviceExchange
-                                      on q3 and q18
+                                      on q3 and q18, both over the
+                                      generator's catalog (host pages).  The
+                                      served q18 over tables sharded across
+                                      the four chips' HBM is the benchmark's
+                                      cell now (mesh4_q18_exchange, PR 43),
+                                      not a phase of this script
     python chip_smoke.py --queries 6,1,3,18,13
                                       one chip, q18 included: it passes, but
                                       takes 317 s cold + 149 s warm on a v5e

@@ -5,8 +5,9 @@ costs — two uses of ``benchmark/run.py``'s parts in one process:
 <n> --seconds <s> --trace <0|1>`` runs the cell as ``benchmark/run.py``
 does (same result line) and then writes what ``tracing.RING`` holds of
 the window's statements to OUT.json: each root with its counters
-(``host_sync_by_why``, ``lowerings_by_program``), its ``exchange`` spans
-and the ``task`` spans that waited for one.
+(``host_sync_by_why``, ``lowerings_by_program``, ``plan_fp``), its
+``exchange`` spans, its ``task`` spans (fragment, task, device, what it
+waited for an exchange) and the operators' spans under them.
 
 ``python scripts/trace_probe.py onoff --workload <cell> --seed <n>
 --seconds <s> --windows on,off,off,on,on,off`` sets the cell up once
@@ -37,9 +38,9 @@ from benchmark import run  # noqa: E402
 
 
 def ring_statements(since: float = 0.0) -> list:
-    """The ring's statement trees, cut to what the exchange and the
-    lowering counters say: the root, the ``exchange`` spans and the
-    ``task`` spans that carry an ``exchange_wait_s``."""
+    """The ring's statement trees, cut to what the distributed runner
+    says of a statement: the root, the ``exchange`` and ``task`` spans
+    and the operators' spans (``parent`` is the task's ``span_id``)."""
     from trino_tpu.telemetry import tracing
 
     traces, lost = tracing.RING.since(since)
@@ -48,13 +49,14 @@ def ring_statements(since: float = 0.0) -> list:
         root = next((s for s in spans if s["parent_id"] is None), None)
         if root is None or root["name"] != "statement":
             continue
-        kept = [s for s in spans if s["name"] == "exchange"
-                or (s["name"] == "task"
-                    and "exchange_wait_s" in s["attrs"])]
+        kept = [s for s in spans if s["name"] in ("exchange", "task")
+                or s["attrs"].get("span_kind") == "operator"]
         out.append({"t0": root["t0"], "t1": root["t1"],
                     "root": root["attrs"],
                     "spans": [{"name": s["name"], "t0": s["t0"],
-                               "t1": s["t1"], "attrs": s["attrs"]}
+                               "t1": s["t1"], "id": s["span_id"],
+                               "parent": s["parent_id"],
+                               "attrs": s["attrs"]}
                               for s in sorted(kept, key=lambda s: s["t0"])]})
     return [{"lost": lost}] + out
 

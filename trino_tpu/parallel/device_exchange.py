@@ -64,12 +64,17 @@ through OperatorStats / EXPLAIN ANALYZE.
 In a traced statement every collective is one ``exchange`` span under
 the ``task`` span of the consumer that triggered it
 (``telemetry/tracing.py``): what moved (``rows``, ``bytes_moved``,
-``lane_bytes``), how it was sized (``cap``, ``per_dest``,
-``sizing_used``, ``lowered``: programs the statement lowered while the
-exchange's program ran, 0 when jit's cache had it), and the barrier's
-five phases in seconds — ``assemble_s``, ``size_s``, ``run_s``,
-``readback_s``, ``slice_s``, each also the annotation
-``exchange.<phase>``.  Its blocking reads are ``host_sync`` sites
+``lane_bytes``; ``rows_in``, the live rows the producers handed over
+as counted on the host before the collective, beside ``rows``, the
+rows that arrived: equal where every row was delivered exactly once;
+``rows_stayed``, those of ``rows`` whose receiver was their sender's
+device, a quarter of a uniform hash over four and all of an exchange
+on the key its input was already partitioned on), how it was sized
+(``cap``, ``per_dest``, ``sizing_used``, ``lowered``: programs the
+statement lowered while the exchange's program ran, 0 when jit's cache
+had it), and the barrier's five phases in seconds — ``assemble_s``,
+``size_s``, ``run_s``, ``readback_s``, ``slice_s``, each also the
+annotation ``exchange.<phase>``.  Its blocking reads are ``host_sync`` sites
 (``exchange_count``, ``exchange_ready``, ``exchange_overflow``,
 ``exchange_readback``), and a consumer that waited for the barrier
 adds its wait to its own ``task`` span as ``exchange_wait_s``.
@@ -411,7 +416,7 @@ class DeviceExchange:
             dev_pages[t % d].extend(self._by_task[t])
         dev_caps = [sum(p.capacity for p in ps) for ps in dev_pages]
         cap = padded_size(max(max(dev_caps), 16))
-        total_rows = 0
+        rows_in = 0
         s_cols = [[] for _ in range(nch)]
         s_nulls = [[] for _ in range(nch)]
         s_valid = []
@@ -427,7 +432,7 @@ class DeviceExchange:
         # pages already live there) and becomes that device's shard of
         # the (d, cap) global arrays the collective reads
         for dev, ps in zip(self.devices, dev_pages):
-            total_rows += sum(p.count() for p in ps)
+            rows_in += sum(p.count() for p in ps)
             with jax.default_device(dev):
                 page_cols = [unified_cols(p) for p in ps]
                 for c in range(nch):
@@ -446,7 +451,8 @@ class DeviceExchange:
                 else:
                     s_valid.append(jnp.zeros((cap,), dtype=bool))
 
-        if total_rows == 0:
+        if rows_in == 0:
+            span.set("rows_in", 0)
             span.set("rows", 0)
             return [[] for _ in range(n)]
 
@@ -594,7 +600,12 @@ class DeviceExchange:
             "a2a_retries": self.a2a_retries,
             "count_collectives": self.count_collectives,
             "data_collectives": self.data_collectives,
+            # what the producers handed over (counted before the
+            # collective), what arrived, and of that the rows whose
+            # receiver was their sender's device
+            "rows_in": rows_in,
             "rows": total_rows,
+            "rows_stayed": int(np.trace(pair_rows)),
             "partition_rows": [int(r) for r in partition_rows],
             "skew_ratio": (round(float(partition_rows.max()) / mean_rows, 3)
                            if mean_rows > 0 else 0.0),
@@ -659,9 +670,10 @@ class DeviceExchange:
 
 
 #: what of ``DeviceExchange.stats`` the ``exchange`` span carries
-_SPAN_STATS = ("rows", "bytes_moved", "per_dest", "sizing_used",
-               "count_collectives", "data_collectives", "a2a_retries",
-               "skew_ratio", "lane_skew_ratio", "splits")
+_SPAN_STATS = ("rows_in", "rows", "rows_stayed", "bytes_moved",
+               "per_dest", "sizing_used", "count_collectives",
+               "data_collectives", "a2a_retries", "skew_ratio",
+               "lane_skew_ratio", "splits")
 
 
 class _Phases:

@@ -31,7 +31,8 @@ from ..exec.local_planner import (LocalExecutionPlanner,
                                   PhysicalPipeline, grouping_options)
 from ..ops.output import OutputBuffer, PartitionedOutputOperator
 from ..planner.exchanges import add_exchanges
-from ..planner.fragmenter import PlanFragment, fragment_plan, fragments_str
+from ..planner.fragmenter import (PlanFragment, fragment_plan,
+                                  fragments_fingerprint, fragments_str)
 from ..planner.logical_planner import LogicalPlanner, Metadata
 from ..planner.optimizer import optimize
 from ..planner.plan import OutputNode
@@ -249,6 +250,18 @@ class DistributedQueryRunner:
                 if key is not None:
                     self.plan_cache.store(
                         key, (self._root, self._fragments), 128)
+            if plan_span:
+                # which fragment plan ran, and of which statement
+                # shape, as the local runner's root says: a shape whose
+                # plan_fp moves between statements was re-planned
+                plan_span.root.attrs["plan_fp"] = \
+                    fragments_fingerprint(fragments)
+                if key is not None:
+                    from ..telemetry.stats_store import \
+                        statement_fingerprint
+
+                    plan_span.root.attrs["shape_fp"] = \
+                        statement_fingerprint(key[0])
         self._plan_shape = key[0] if key is not None else None
         root: OutputNode = self._root
         buffers: Dict[int, OutputBuffer] = {}

@@ -10,11 +10,12 @@ the consumer (the PartitioningScheme of the reference).
 
 from __future__ import annotations
 
+import hashlib
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-from .plan import (ExchangeNode, OutputNode, PlanNode, RemoteSourceNode,
-                   TableScanNode)
+from .plan import (AggregationNode, ExchangeNode, JoinNode, OutputNode,
+                   PlanNode, RemoteSourceNode, TableScanNode)
 from .symbols import Symbol
 
 
@@ -100,6 +101,43 @@ class Fragmenter:
 
 def fragment_plan(root: OutputNode) -> List[PlanFragment]:
     return Fragmenter().fragment(root)
+
+
+def fragments_fingerprint(fragments: List[PlanFragment]) -> str:
+    """A hash of the fragment DAG as it will run: every fragment's
+    partitioning, output kind, keys and inputs, and its nodes in tree
+    order, each with its output symbols, a scan's table, an
+    aggregation's step and strategy, a join's type, criteria, strategy
+    and distribution.  No literal and no estimate: two statements of
+    one shape differ here only if the planner ordered or distributed
+    them apart (the statement root's ``plan_fp`` under
+    ``DistributedQueryRunner``, as ``LocalExecutionPlan.fingerprint``
+    is under the local runner)."""
+    parts: list = []
+
+    def walk(node: PlanNode):
+        part = [type(node).__name__,
+                [s.name for s in node.output_symbols]]
+        if isinstance(node, TableScanNode):
+            part.append(node.table.qualified_name)
+        elif isinstance(node, AggregationNode):
+            part += [node.step, node.strategy]
+        elif isinstance(node, JoinNode):
+            part += [node.join_type,
+                     [(l.name, r.name) for l, r in node.criteria],
+                     node.strategy, getattr(node, "distribution", None)]
+        elif isinstance(node, RemoteSourceNode):
+            part.append(node.fragment_id)
+        parts.append(part)
+        for s in node.sources:
+            walk(s)
+        parts.append(")")
+
+    for f in fragments:
+        parts.append([f.fragment_id, f.partitioning, f.output_kind,
+                      [s.name for s in f.output_keys], list(f.inputs)])
+        walk(f.root)
+    return hashlib.sha1(repr(parts).encode()).hexdigest()[:16]
 
 
 def fragments_str(fragments: List[PlanFragment]) -> str:
