@@ -281,6 +281,39 @@ def test_two_column_key_join_compiles_for_v5e(one_chip):
     assert " while(" not in text
 
 
+def test_join_expansion_at_the_matches_width_compiles_for_v5e(one_chip):
+    """q9's chained joins at SF1 since a page is expanded at its
+    matches' width: a resident 262,144-lane page of which a dynamic
+    filter left ~14 k rows expands into 2^14 lanes (the histogram: no
+    loop) against a build sorted at 2^19 lanes, and ``_finalize_join``
+    gathers the page's six and the build's ten columns at that width —
+    2^14 lanes out, also LEFT's 2^14 + 2^18."""
+    from functools import partial
+
+    from trino_tpu.ops.join import _expand_verified, _finalize_join
+
+    rows, out_cap, build = 1 << 18, 1 << 14, 1 << 19
+    idx = sds((rows,), jnp.int32)
+    text = _compile(
+        partial(lambda lo, count, pk, bk, out_cap: _expand_verified(
+            lo, count, (pk,), (bk,), out_cap=out_cap), out_cap=out_cap),
+        one_chip, idx, idx, sds((rows,), jnp.int64),
+        sds((build,), jnp.int64)).as_text()
+    assert " while(" not in text
+    pcols = (sds((rows,), jnp.int64),) * 5 + (sds((rows,), jnp.int32),)
+    bcols = (sds((build,), jnp.int64),) * 8 + (sds((build,), jnp.int32),) * 2
+    lane = sds((out_cap,), jnp.int32)
+    for left in (False, True):
+        compiled = _compile(
+            partial(_finalize_join, left=left), one_chip, pcols,
+            (sds((rows,), jnp.bool_),) * 6, sds((rows,), jnp.bool_), bcols,
+            (sds((build,), jnp.bool_),) * 10, lane, lane,
+            sds((out_cap,), jnp.bool_))
+        out_cols, out_nulls, keep = compiled.out_info
+        assert len(out_cols) == len(out_nulls) == 16
+        assert keep.shape == (out_cap + (rows if left else 0),)
+
+
 def test_direct_probe_compiles_for_v5e(one_chip):
     """The direct-address probe at q3-SF1 sizes: the ``orderkey`` build
     (2^20 sorted rows, 6.0 M codes: a table of 2^23 int32 offsets) and

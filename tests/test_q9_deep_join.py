@@ -197,6 +197,35 @@ def test_the_two_column_key_probes_the_sorted_index(served_run):
         assert j["attrs"]["probe_lanes"] % j["attrs"]["probe_pages"] == 0
 
 
+def test_a_build_behind_a_join_is_as_wide_as_its_rows(served_run):
+    """A join expands a probe page at its matches' width, so a build fed
+    by a chain of joins behind a selective filter is sorted at its rows'
+    width, not at the masked pages': within twice its rows' padded size
+    in every statement (at SF1 the two 323 k-row builds were sorted at
+    2,097,152 and 1,048,576 lanes).  A build fed straight by a filtered
+    scan keeps the scan's width (``part``: 105 rows in 2,048 lanes)."""
+    from trino_tpu.block import padded_size
+
+    for _, _, trace, _ in served_run:
+        ops = [s for s in trace if s["attrs"].get("span_kind") == "operator"]
+        behind_a_join = [
+            b["attrs"] for a, b in zip(ops, ops[1:])
+            if b["name"] == "HashBuilderOperator"
+            and a["name"] == "LookupJoinOperator"]
+        assert behind_a_join
+        for attrs in behind_a_join:
+            assert attrs["build_lanes"] <= \
+                2 * padded_size(attrs["input_rows"]), attrs
+        joins = named(trace, "LookupJoinOperator")
+        assert sum(j["attrs"]["expand_rows"] for j in joins) > 0
+        for j in joins:
+            assert j["attrs"]["expand_rows"] <= j["attrs"]["expand_lanes"] \
+                <= 2 * padded_size(j["attrs"]["expand_rows"])
+    _, _, trace, _ = served_run[-1]
+    assert max(b["attrs"]["build_lanes"] // b["attrs"]["input_rows"]
+               for b in named(trace, "HashBuilderOperator")) > 8
+
+
 def test_matmul_join_reads_through_host_read():
     """Over the generator's catalog, which states key ranges, ``supplier
     x nation`` is the one-hot matmul join: its span counts its lanes and

@@ -404,7 +404,9 @@ class DevicePage:
     TPU-first replacement for positional compaction: filtering flips lanes
     off in ``valid`` instead of gathering survivors, so filter+project+agg
     chains stay on device with static shapes; compaction happens only at
-    host boundaries (``to_page``) or when an operator chooses to densify.
+    host boundaries (``to_page``) or when an operator chooses to densify:
+    a join does — its output page is as wide as the probe page's matches
+    (``ops/join.py::LookupJoinOperator._expand``), not as the page.
 
     - ``cols[i]``: jax array, shape (capacity,), dtype types[i].storage
     - ``nulls[i]``: jax bool array (True = SQL NULL) — always materialized

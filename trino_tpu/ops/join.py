@@ -26,16 +26,17 @@ lane, and each output lane finds its row as the number of rows that end
 at or before it — a histogram of those ends over the lanes and its
 prefix sum (``_lane_rows``: one scatter-add at ascending indices, no
 loop), or a binary search a lane where the expansion is far narrower
-than the page (a selective join). The capacity is GUESSED from a
-running expansion ratio (jit shapes are static, so some host value must
-pick the capacity); the exact total rides along as an unread device
-scalar and is
-checked only when the probe pipeline is already ``pipeline_depth`` pages
-deep — the host never blocks on the page it just enqueued, and an
-overflowing guess (rare) re-expands at the exact size. Candidates are
-verified against the raw key columns, so hash collisions cost only
-capacity, never correctness. Unmatched-probe lanes for LEFT/ANTI come
-from a segment-OR over verified matches.
+than the page (a selective join). The capacity is the page's own match
+total, padded to a power of two (jit shapes are static, so some host
+value must pick it): the lookup is enqueued with the total as an unread
+device scalar, and the total is read — and the expansion enqueued at
+that size — only when the probe pipeline is already ``pipeline_depth``
+pages deep, so the host never blocks on the page it just looked up. An
+output page is thus as wide as its matches whatever the probe page's
+width was: the join is where a page a selective filter masked gets
+dense again. Candidates are verified against the raw key columns, so
+hash collisions cost only capacity, never correctness. Unmatched-probe
+lanes for LEFT/ANTI come from a segment-OR over verified matches.
 
 Two-operator split with a JoinBridge mirrors the reference; the physical
 planner runs the build pipeline to completion before the probe pipeline.
@@ -1157,12 +1158,15 @@ class LookupJoinOperator(Operator):
     #: _expand_matches blows HBM at scale)
     max_lanes = 1 << 20
 
-    #: probe pages whose guessed-capacity outputs are enqueued on device
-    #: but not yet overflow-checked. The oldest is checked — ONE scalar
-    #: read, computed pipeline_depth-1 pages ago and thus long since
-    #: done — only when the pipeline is full or upstream stalls, so the
-    #: host never blocks on kernels it just enqueued (round-3 verdict:
-    #: int(jnp.sum(count)) serialized host and device per probe page)
+    #: probe pages whose lookup (candidate ranges, match total) is
+    #: enqueued on the device and whose expansion waits for that total.
+    #: The oldest page's total is read — ONE scalar, computed
+    #: pipeline_depth-1 pages ago and thus long since done — only when
+    #: the pipeline is full or upstream stalls, so the host never blocks
+    #: on kernels it just enqueued (round-3 verdict:
+    #: int(jnp.sum(count)) serialized host and device per probe page);
+    #: the page is then expanded at ``padded_size(total)`` lanes, its
+    #: matches' own width, whatever the probe page's was
     pipeline_depth = 4
 
     def __init__(self, probe_types: Sequence[T.Type],
@@ -1183,14 +1187,8 @@ class LookupJoinOperator(Operator):
             # the memory manager's reserve/revoke machinery, so keep the
             # pre-pipelining one-page-in-flight footprint
             self.pipeline_depth = 1
-        self._pending: List[dict] = []   # awaiting overflow check
+        self._pending: List[dict] = []   # looked up, awaiting expansion
         self._ready: List[DevicePage] = []
-        # EWMA lanes-per-probe-row for the capacity guess. Starts below
-        # 1 so the first guess lands in the page's own pow2 bucket (N:1
-        # joins then never overflow and never double the page); a
-        # fan-out join overflows once, the ratio learns, later pages
-        # guess right. pow2 padding gives the headroom.
-        self._ratio = 0.75
         self._added_since_get = False
         self._done = False
         #: deferred cold-partition work queue (hybrid join): None until
@@ -1216,6 +1214,10 @@ class LookupJoinOperator(Operator):
         self._direct_pages = 0
         self._direct_table_bytes = 0
         self._probe_fallback: Optional[str] = None
+        #: the expansions' widths summed and the matches they held (each
+        #: page's total, read once to size its expansion): plain adds
+        self._expand_lanes = 0
+        self._expand_rows = 0
 
     def metrics(self) -> dict:
         """Which probe ran: pages by lookup, the table's size or why
@@ -1223,7 +1225,9 @@ class LookupJoinOperator(Operator):
         out = {"join_type": self.join_type,
                "probe_pages": self._probe_pages,
                "probe_lanes": self._probe_lanes,
-               "direct_probe_pages": self._direct_pages}
+               "direct_probe_pages": self._direct_pages,
+               "expand_lanes": self._expand_lanes,
+               "expand_rows": self._expand_rows}
         if self._direct_table_bytes:
             out["direct_table_bytes"] = self._direct_table_bytes
         elif self._probe_fallback:
@@ -1243,10 +1247,11 @@ class LookupJoinOperator(Operator):
                 and not self._finishing)
 
     def add_input(self, page: DevicePage):
-        """Enqueue the whole probe chain for this page — counts,
-        guessed-capacity expansion, finalize — WITHOUT reading anything
-        back; the overflow check happens in get_output once the
-        pipeline is deep enough to have hidden this page's latency."""
+        """Enqueue what needs no size — the page's keys in the build's
+        key space, each row's candidate range and the page's match
+        total — WITHOUT reading anything back; the expansion is sized
+        from that total in get_output, once the pipeline is deep enough
+        to have hidden this page's latency."""
         b = self.bridge.build
         assert b is not None, "probe started before build finished"
         hs = self.bridge.hybrid
@@ -1259,15 +1264,7 @@ class LookupJoinOperator(Operator):
             if page is None:
                 self._added_since_get = True
                 return
-        kc = self.probe_keys
-        pkey_cols, key_types = self._probe_key_cols(page, b)
-        pkey, panynull = _key_u64(pkey_cols,
-                                  [page.nulls[c] for c in kc],
-                                  key_types, b.key_mode)
-        pusable = page.valid & ~panynull if panynull is not None \
-            else page.valid
-        self._probe_pages += 1
-        self._probe_lanes += int(page.valid.shape[0])
+        pkey_cols, pkey, pusable = self._probe_keys_u64(page, b)
         self._direct_table_bytes = b.direct.nbytes if b.direct else 0
         self._probe_fallback = b.direct_fallback
         direct = self._probe_direct(page, b, pkey, pusable)
@@ -1276,18 +1273,8 @@ class LookupJoinOperator(Operator):
             self._added_since_get = True
             return
         lo, count = self._probe_lo_count(b, pkey, pusable)
-        rows = int(page.valid.shape[0])
-        cap = padded_size(max(16, int(rows * self._ratio * 1.1)))
-        while cap > self.max_lanes and cap > 16:
-            cap >>= 1  # budget is checked POST-padding, like every path
-        out, keep, bidx = self._make_out(b, page, pkey_cols, pusable, lo,
-                                         count, cap)
-        self._pending.append({
-            "b": b,
-            "page": page, "pkey_cols": pkey_cols, "pusable": pusable,
-            "lo": lo, "count": count, "rows": rows, "cap": cap,
-            "total": jnp.sum(count), "out": out, "keep": keep,
-            "bidx": bidx})
+        self._pending.append(
+            _looked_up(b, page, pkey_cols, pusable, lo, count))
         self._added_since_get = True
 
     def _route_probe(self, page: DevicePage,
@@ -1356,12 +1343,17 @@ class LookupJoinOperator(Operator):
                              pusable)
 
     def get_output(self):
+        """The next joined page. The oldest looked-up page is expanded
+        (its total read, its expansion and gathers enqueued at that
+        size) once ``pipeline_depth`` pages are looked up, upstream
+        stalls or the input has ended; then the hybrid join's parked
+        partitions, then FULL OUTER's unmatched build rows."""
         if self._ready:
             return self._ready.pop(0)
         if self._pending and (self._finishing
                               or len(self._pending) >= self.pipeline_depth
                               or not self._added_since_get):
-            self._verify_oldest()
+            self._expand(self._pending.pop(0))
             self._added_since_get = False
             if self._ready:
                 return self._ready.pop(0)
@@ -1382,22 +1374,17 @@ class LookupJoinOperator(Operator):
             self._done = True
         return None
 
-    def _verify_oldest(self):
-        """Overflow-check the oldest pending page: the deferred scalar
-        read. Fits the guess (common) -> emit as-is; overflowed (rare)
-        -> re-expand at the now-known exact size, chunked under the
-        lane budget."""
-        rec = self._pending.pop(0)
+    def _expand(self, rec: dict):
+        """Expand one looked-up page at its matches' own width: read the
+        total (the deferred scalar; for a parked page of the hybrid
+        pass, the one just enqueued) and run one expansion at
+        ``padded_size(total)`` lanes, or row chunks under the lane
+        budget where that passes it."""
         tot = int(host_read(rec["total"], "join_expand_total"))
-        self._ratio = 0.75 * self._ratio \
-            + 0.25 * (tot / max(rec["rows"], 1))
-        if tot <= rec["cap"]:
-            self._mark_full(rec["keep"], rec["bidx"],
-                            rec["page"].dictionaries)
-            self._ready.append(rec["out"])
-            return
-        for unit in self._chunk_units(rec, tot):
-            out, keep, bidx = self._make_out(rec["b"], *unit)
+        self._expand_rows += tot
+        for *probe, lane_cap in self._chunk_units(rec, tot):
+            self._expand_lanes += lane_cap
+            out, keep, bidx = self._make_out(rec["b"], *probe, lane_cap)
             self._mark_full(keep, bidx, rec["page"].dictionaries)
             self._ready.append(out)
 
@@ -1558,27 +1545,14 @@ class LookupJoinOperator(Operator):
         bypassed: the matmul strategy caches ONE table from the
         resident build side and must not see per-partition indexes."""
         page = sp.to_device()
-        kc = self.probe_keys
-        pkey_cols, key_types = self._probe_key_cols(page, b)
-        pkey, panynull = _key_u64(pkey_cols,
-                                  [page.nulls[c] for c in kc],
-                                  key_types, b.key_mode)
-        pusable = page.valid & ~panynull if panynull is not None \
-            else page.valid
-        self._probe_pages += 1
-        self._probe_lanes += int(page.valid.shape[0])
+        pkey_cols, pkey, pusable = self._probe_keys_u64(page, b)
         lo, count = _probe_counts(b.key_sorted, b.usable_sorted, pkey,
                                   pusable)
-        tot = int(host_read(jnp.sum(count), "join_expand_total"))
-        rec = {"b": b, "page": page, "pkey_cols": pkey_cols,
-               "pusable": pusable, "lo": lo, "count": count}
-        for unit in self._chunk_units(rec, tot):
-            out, keep, bidx = self._make_out(b, *unit)
-            self._ready.append(out)
+        self._expand(_looked_up(b, page, pkey_cols, pusable, lo, count))
 
     def _mark_full(self, keep, build_idx, pdicts):
-        """FULL OUTER bookkeeping, applied only AFTER the overflow check
-        passed (a truncated expansion must not mark build rows)."""
+        """FULL OUTER bookkeeping: OR an expansion's kept lanes into the
+        per-build-row matched flags."""
         if self.join_type != "full" or keep is None:
             return
         b = self.bridge.build
@@ -1657,13 +1631,25 @@ class LookupJoinOperator(Operator):
                 types_.append(t)
         return out, types_
 
+    def _probe_keys_u64(self, page: DevicePage, b: "BuildSide"):
+        """(key columns in the build's key space, their u64 key, the
+        rows that can match) of one probe page, counted as looked up."""
+        pkey_cols, key_types = self._probe_key_cols(page, b)
+        pkey, panynull = _key_u64(pkey_cols,
+                                  [page.nulls[c] for c in self.probe_keys],
+                                  key_types, b.key_mode)
+        pusable = page.valid & ~panynull if panynull is not None \
+            else page.valid
+        self._probe_pages += 1
+        self._probe_lanes += int(page.valid.shape[0])
+        return pkey_cols, pkey, pusable
+
     def _make_out(self, b: "BuildSide", page: DevicePage, pkey_cols,
                   pusable, lo, count, lane_cap: int) -> Tuple:
         """One expansion at static capacity ``lane_cap`` against build
         side ``b`` (the resident index, or a per-partition index during
         the deferred hybrid pass): returns (out_page, keep, build_idx).
-        keep/build_idx feed the FULL OUTER marker — applied by the
-        caller only after the overflow check — and are None for
+        keep/build_idx feed the FULL OUTER marker and are None for
         semi/anti (no build channels in the output)."""
         if self.join_type in ("semi", "anti"):
             if self.filter_fn is None:
@@ -1710,6 +1696,15 @@ class LookupJoinOperator(Operator):
         dicts = list(page.dictionaries) + list(b.dictionaries)
         return (DevicePage(types, list(out_cols), list(out_nulls),
                            out_valid, dicts), keep, build_idx)
+
+
+def _looked_up(b: "BuildSide", page: DevicePage, pkey_cols, pusable, lo,
+               count) -> dict:
+    """A probe page with its candidate ranges and its match total on the
+    device: what ``LookupJoinOperator._expand`` sizes an expansion from."""
+    return {"b": b, "page": page, "pkey_cols": pkey_cols,
+            "pusable": pusable, "lo": lo, "count": count,
+            "total": jnp.sum(count)}
 
 
 def _finalize_join_impl(pcols, pnulls, pvalid, bcols, bnulls,

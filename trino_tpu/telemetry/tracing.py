@@ -521,7 +521,8 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
                         "probe_rounds", "probe_rounds_narrow",
                         "join_type", "probe_pages", "direct_probe_pages",
                         "direct_table_bytes", "probe_fallback",
-                        "probe_lanes", "key_mode", "build_lanes"):
+                        "probe_lanes", "expand_lanes", "expand_rows",
+                        "key_mode", "build_lanes"):
                 if st.metrics.get(key) is not None:
                     span["attrs"][key] = st.metrics[key]
         tracer._record(span)
