@@ -10,8 +10,10 @@ range leaves no order at ``tiny``, so the thresholds are
 ``test_q18_semijoin.py``'s: 842, 68, 12 and 0 orders pass at 200, 250,
 275 and 300.  Every answer is held to the benchmark's numpy reference
 and to a ``LocalQueryRunner`` over the generator's catalog, exactly; the
-``exchange`` spans to what they were given (``rows`` = ``rows_in``), the
-statement roots to one fragment plan from the second statement on.
+``exchange`` spans to what they were given (``rows`` = ``rows_in``) and to
+the rows the settled plan moves (the semi join on ``orders``, beneath the
+inner joins, since PR 45), the statement roots to one fragment plan from
+the second statement on.
 """
 
 import time
@@ -146,25 +148,51 @@ def test_plan_settles_at_the_second_statement(served):
     roots = [root_of(trace)["attrs"] for _, _, trace in served]
     assert all(r["state"] == "FINISHED" for r in roots)
     assert len({r["shape_fp"] for r in roots}) == 1
-    # no literal in the fingerprint: four thresholds, one plan
+    # no literal in the fingerprint: four thresholds, one plan; the
+    # first statement's is another (connector statistics: lineitem is
+    # the last join's build, from history on its probe)
     assert len({r["plan_fp"] for r in roots[1:]}) == 1
-    counts = [len(exchanges(trace)) for _, _, trace in served]
-    # an empty exchange is a span all the same: QUANTITY 300 leaves
-    # the semi join's build and the last aggregation no row
-    assert len(set(counts[1:])) == 1 and counts[1] >= 7
+    assert roots[0]["plan_fp"] != roots[1]["plan_fp"]
+    # the three scans, the first level's partials, the passing keys to
+    # the semi join, its output to customer's join, that join's to
+    # lineitem's, the last aggregation's partials; an empty exchange
+    # is a span all the same (QUANTITY 300 leaves the semi join's
+    # build and all that follows it no row)
+    assert [len(exchanges(trace)) for _, _, trace in served] \
+        == [8] * len(served)
 
 
-def test_every_exchange_delivers_the_rows_it_was_given(served, tables):
+def first_level_partials(runner) -> int:
+    """Groups the first level's partial aggregations send: the distinct
+    ``l_orderkey`` of each device's share of ``lineitem``."""
+    mem = runner.metadata.connectors[CONFIG["connector"]["catalog"]]
+    data = mem.tables[(SCHEMA, "lineitem")]
+    key, = [i for i, c in enumerate(data.columns)
+            if c.name == "l_orderkey"]
+    return sum(len(np.unique(np.concatenate(
+        [np.asarray(p.to_page().blocks[key].data) for p in pages])))
+        for pages in data.by_device().values())
+
+
+def test_every_exchange_delivers_the_rows_it_was_given(runner, served,
+                                                       tables):
+    """The semi join filters ``orders`` before anything joins it
+    (``PushSemiJoinBelowJoin``): every base row crosses once, the first
+    level's partial groups once, and after the semi join only the
+    passing orders move — its build, its output, the join with
+    ``customer`` and the last aggregation's groups (an order's lines
+    meet on one device).  No joined ``lineitem`` row is sent again."""
     base_rows = sum(tables.row_count(t) for t in TEMPLATE.tables)
-    for statement, (_, _, trace) in enumerate(served):
+    partials = first_level_partials(runner)
+    assert tables.row_count("orders") <= partials \
+        <= tables.row_count("lineitem")
+    for quantity, _, trace in served:
         spans = exchanges(trace)
         assert all(a["rows"] == a["rows_in"] for a in spans)
         assert all(0 <= a.get("rows_stayed", 0) <= a["rows"]
                    for a in spans)
-        # every base row crosses once at the least; in the settled plan
-        # lineitem's rows cross twice more, joined
         moved = sum(a["rows"] for a in spans)
-        assert moved > (2 if statement else 1) * base_rows
+        assert moved == base_rows + partials + 4 * PASSING[quantity]
 
 
 def test_collectives_of_a_statement_are_its_exchange_spans(runner, served):

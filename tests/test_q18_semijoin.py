@@ -9,7 +9,8 @@ and 300 (the first is cut by the 100-row limit, the last is empty).
 The answers are held to the benchmark's numpy reference, exactly, and to
 sqlite; the operators' counters the cell's per-layer metrics read
 (``merge_lanes``, ``groups_out``, ``join_type``, ``input_rows``) to numpy
-counts over the same data.
+counts over the same data.  The semi join runs on ``orders`` itself,
+beneath the inner joins (``PushSemiJoinBelowJoin``, since PR 45).
 """
 
 import json
@@ -36,13 +37,14 @@ TEMPLATE = traffic.load_template("q18")
 PASSING = {200: 842, 250: 68, 275: 12, 300: 0}
 
 
-def serve():
+def serve(**session_properties):
     """The cell's configuration cut to ``tiny`` behind a started server:
     ``(server, client)``."""
     with open(os.path.join(ROOT, "benchmark", "configs",
                            "tpch_sf1_resident_q18_1chip.json")) as f:
         config = json.load(f)
     config["schema"] = "tiny"
+    config["session_properties"].update(session_properties)
     server = ProtocolServer(local_resident.build(config)).start()
     return server, Client(server.uri)
 
@@ -139,18 +141,37 @@ def test_many_partials_merge_and_count_their_groups(monkeypatch, tables):
     assert first_level["rows"] == first_level["groups_out"]
 
 
-def test_semi_join_span_says_its_type_and_its_input(client, tables):
-    _, trace = served(client, TEMPLATE, QUANTITY=250)
-    ops = operators(trace)
-    semi, = [s for s in ops if s["attrs"].get("join_type") == "semi"]
-    upstream = ops[ops.index(semi) - 1]
-    assert upstream["name"] == "LookupJoinOperator"
-    assert upstream["attrs"]["join_type"] == "inner"
-    # the plan keeps the semi join above the joins: every lineitem row
-    # (each has its order and its customer) is probed against the keys
-    assert semi["attrs"]["input_rows"] == upstream["attrs"]["rows"] \
-        == len(tables.column("lineitem", "l_orderkey"))
-    assert semi["attrs"]["rows"] < semi["attrs"]["input_rows"]
+def test_semi_join_span_says_its_type_and_its_input(client, tables,
+                                                    order_sums):
+    """The semi join sits on the scan of ``orders``, beneath the inner
+    joins (``PushSemiJoinBelowJoin``): what it is handed is ``orders``
+    — every row of it where no dynamic filter masks the scan, and the
+    rows its own build's filter left (68 keys, a membership table:
+    exact) where one does — and ``lineitem`` joins the passing orders
+    alone."""
+    lkey = tables.column("lineitem", "l_orderkey")
+    passing_lines = int(np.isin(
+        lkey, np.unique(lkey)[order_sums > 250 * 100]).sum())
+    unfiltered, plain = serve(enable_dynamic_filtering=False)
+    try:
+        _, unmasked = served(plain, TEMPLATE, QUANTITY=250)
+    finally:
+        unfiltered.stop()
+    _, masked = served(client, TEMPLATE, QUANTITY=250)
+    for trace, handed in ((unmasked, tables.row_count("orders")),
+                          (masked, PASSING[250])):
+        ops = operators(trace)
+        semi, = [s for s in ops if s["attrs"].get("join_type") == "semi"]
+        scan = ops[ops.index(semi) - 1]
+        assert scan["name"] == "TableScanOperator"
+        assert semi["attrs"]["input_rows"] == scan["attrs"]["rows"] \
+            == handed
+        assert semi["attrs"]["rows"] == PASSING[250]
+        inner = [s["attrs"] for s in ops[ops.index(semi) + 1:]
+                 if s["attrs"].get("join_type") == "inner"]
+        # orders x customer, then x lineitem, whichever side probes
+        assert [j["rows"] for j in inner] == [PASSING[250], passing_lines]
+    assert scan["attrs"]["df_table_pages"] == scan["attrs"]["pages"] > 0
 
 
 @pytest.mark.parametrize("name,syncs", [("q3", 26), ("q13", 14)])

@@ -81,21 +81,12 @@ def optimize(root: OutputNode, metadata: Metadata,
     return out
 
 
-def template_param_slots(root: PlanNode) -> Tuple[int, ...]:
-    """The sorted ``ParamRef`` slot indices reachable from any
-    expression of the plan (empty for non-template plans).  The
-    optimizer itself never needs this — ParamRef is opaque to every
-    value-reading pass BY CONSTRUCTION (it is not a Literal subclass,
-    and folding/pushdown/domain translation are all
-    ``isinstance(_, Literal)``-gated) — but the runner's batch
-    assembler and EXPLAIN both want to know which slots survived into
-    the optimized plan, and a slot that was optimized AWAY (pruned
-    with its projection) is exactly the "params_unconsumed" batching
-    fallback."""
+def node_param_slots(node: PlanNode) -> Set[int]:
+    """The ``ParamRef`` slot indices in one plan node's own expressions
+    (its sources are not entered)."""
     from ..expr.ir import param_indices
 
     slots: Set[int] = set()
-    seen: Set[int] = set()
     plan_mod = PlanNode.__module__
 
     def walk_value(v):
@@ -107,20 +98,39 @@ def template_param_slots(root: PlanNode) -> Tuple[int, ...]:
         elif isinstance(v, (list, tuple)):
             for x in v:
                 walk_value(x)
-        elif isinstance(v, PlanNode):
-            walk_node(v)
-        elif type(v).__module__ == plan_mod and hasattr(v, "__dict__"):
+        elif not isinstance(v, PlanNode) \
+                and type(v).__module__ == plan_mod \
+                and hasattr(v, "__dict__"):
             # expression-bearing leaf specs (Aggregation, Ordering,
             # WindowFunctionSpec, ...) — same module, not PlanNodes
             for x in vars(v).values():
                 walk_value(x)
 
+    walk_value(list(vars(node).values()))
+    return slots
+
+
+def template_param_slots(root: PlanNode) -> Tuple[int, ...]:
+    """The sorted ``ParamRef`` slot indices reachable from any
+    expression of the plan (empty for non-template plans).  The
+    optimizer itself never needs this — ParamRef is opaque to every
+    value-reading pass BY CONSTRUCTION (it is not a Literal subclass,
+    and folding/pushdown/domain translation are all
+    ``isinstance(_, Literal)``-gated) — but the runner's batch
+    assembler and EXPLAIN both want to know which slots survived into
+    the optimized plan, and a slot that was optimized AWAY (pruned
+    with its projection) is exactly the "params_unconsumed" batching
+    fallback."""
+    slots: Set[int] = set()
+    seen: Set[int] = set()
+
     def walk_node(node):
-        if node is None or id(node) in seen:
+        if id(node) in seen:
             return
         seen.add(id(node))
-        for v in vars(node).values():
-            walk_value(v)
+        slots.update(node_param_slots(node))
+        for source in node.sources:
+            walk_node(source)
 
     walk_node(root)
     return tuple(sorted(slots))

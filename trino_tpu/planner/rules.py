@@ -127,6 +127,49 @@ class PushFilterThroughOuterJoin(Rule):
         return _filter(new_join, stay)
 
 
+class PushSemiJoinBelowJoin(Rule):
+    """Semi([Filter p](A x B), S) -> [Filter p](Semi(A, S) x B) where one
+    input A of the inner or cross join beneath produces every probe
+    symbol the semi / anti join reads (the upstream engine runs q18's
+    semi join on ``orders`` before ``lineitem`` joins it; the logical
+    planner here puts it on the whole FROM relation). Exact row by
+    row: the semi join keeps a left row by that row's own key columns
+    and S alone, the join multiplies it by its own criteria alone and
+    the filter drops it by its predicate alone, duplicates and NULL
+    keys included. One step a firing — the child group's exploration
+    carries it on towards the scan. Nothing crosses an outer join, and
+    a key that an equivalence alone would move to another input stays
+    where its symbol is produced."""
+
+    name = "PushSemiJoinBelowJoin"
+    pattern = Pattern(JoinNode,
+                      where=lambda j: j.join_type in ("semi", "anti"))
+
+    def apply(self, node: JoinNode, ctx: RuleContext):
+        below = ctx.lookup.resolve(node.left)
+        preds: List[RowExpression] = []
+        if isinstance(below, FilterNode):
+            preds = conjuncts(below.predicate)
+            below = ctx.lookup.resolve(below.source)
+        if not (isinstance(below, CrossJoinNode) or
+                (isinstance(below, JoinNode)
+                 and below.join_type == "inner")):
+            return None
+        need = {l.name for l, _r in node.criteria}
+        if node.filter_expr is not None:
+            need |= referenced_symbols(node.filter_expr) \
+                & {s.name for s in node.left.output_symbols}
+        from .optimizer import _replace_sources
+
+        sides = [below.left, below.right]
+        for i, side in enumerate(sides):
+            if need <= {s.name for s in side.output_symbols}:
+                sides[i] = JoinNode(node.join_type, side, node.right,
+                                    node.criteria, node.filter_expr)
+                return _filter(_replace_sources(below, sides), preds)
+        return None
+
+
 class PushFilterIntoTableScan(Rule):
     """The pushdown negotiation as a rule (reference:
     PushPredicateIntoTableScan.java + ConnectorMetadata.applyFilter):
@@ -329,6 +372,23 @@ def negotiate_scan_pushdown(metadata, session, scan: TableScanNode,
                          list(scan.assignments)), kept
 
 
+def _probe_side_param_slots(node: PlanNode) -> Set[int]:
+    """The ``ParamRef`` slots of a relation outside every join's build
+    (right) input.  Invariant the template gate of ``ReorderJoins``
+    rests on: a slot this leaves out is read in a build pipeline
+    wherever the relation is placed, and such a plan is never batched
+    (``exec/batched.py::vmappable_stages`` refuses a parameter in an
+    aux pipeline), so no lane shares that build with another binding."""
+    from .optimizer import node_param_slots
+
+    slots = node_param_slots(node)
+    build = node.right if isinstance(node, JoinNode) else None
+    for source in node.sources:
+        if source is not build:
+            slots |= _probe_side_param_slots(source)
+    return slots
+
+
 class ReorderJoins(Rule):
     """Cost-based join-order exploration over a flattened inner-join
     region (reference: iterative/rule/ReorderJoins.java — bushy
@@ -466,10 +526,12 @@ class ReorderJoins(Rule):
         literal binding, and a literal-poisoned cardinality could flip
         the param-filtered side onto the build — breaking the
         one-build-serves-all-lanes batching invariant for every other
-        binding the template must serve."""
-        from .optimizer import template_param_slots
-
-        if any(template_param_slots(ctx.extract(l)) for l in leaves):
+        binding the template must serve.  A slot under a join's build
+        input inside a relation (the filtering source of a semi join
+        that ``PushSemiJoinBelowJoin`` moved into the region) is in a
+        build pipeline wherever the relation lands, so it does not
+        take history away."""
+        if any(_probe_side_param_slots(ctx.extract(l)) for l in leaves):
             from .stats import StatsCalculator
 
             ordered = self._order_with(ctx, StatsCalculator(ctx.metadata),
@@ -667,6 +729,7 @@ def default_rules() -> List[Rule]:
         PushFilterThroughAggregation(),
         PushFilterThroughExchangeLike(),
         PushFilterThroughOuterJoin(),
+        PushSemiJoinBelowJoin(),
         ReorderJoins(),
         PushFilterIntoTableScan(),
         MergeLimits(),
