@@ -26,18 +26,20 @@ on a TPU backend).
                                       too little room under its 1200 s limit
 
     python chip_smoke.py --queries 9  q9 over the generator's catalog: six
-                                      relations, supplier x nation as the
-                                      matmul join (the Memory connector's
-                                      tables state no key range, so the
-                                      benchmark's q9 cell plans none).
-                                      Not run on a chip through this script
-                                      yet (PR 41 was refused a chip for it;
-                                      the CPU rehearsal passes).  On a v5e
-                                      q9 has run served over resident
-                                      tables (cell sf1_q9_join6, PR 41):
-                                      258 s the first statement, 125 s the
-                                      second (history's new plan compiles),
-                                      10.1 s each from the third on
+                                      relations, every join a lookup join;
+                                      supplier x nation probes nation by
+                                      direct address like every other
+                                      single-key build (partsupp's
+                                      two-column key is hashed and probed
+                                      by search).  Not run on a chip
+                                      through this script yet (PR 41 was
+                                      refused a chip for it; the CPU
+                                      rehearsal passes).  On a v5e q9 has
+                                      run served over resident tables
+                                      (cell sf1_q9_join6, PR 41): 258 s
+                                      the first statement, 125 s the second
+                                      (history's new plan compiles), 10.1 s
+                                      each from the third on
 
 Without a TPU the script exits non-zero.  ``--allow-cpu`` (with
 ``--schema tiny``) is the CPU rehearsal; such a run never prints a

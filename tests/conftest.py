@@ -61,6 +61,21 @@ def _cap_memory_maps():
         gc.collect()
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _collect_dead_tables():
+    """``exec.memory.resident_table_bytes()`` is process-wide and counts
+    a Memory connector's tables until the connector is collected; the
+    resident runner kinds (``benchmark/systems/*_resident.py``) hold
+    each load to the bytes it added.  A connector an earlier module left
+    in a reference cycle, freed by the collector in the middle of a
+    load, makes a 288-byte ``region`` read as no bytes at all: collect
+    before a module builds anything."""
+    import gc
+
+    gc.collect()
+    yield
+
+
 @pytest.fixture(autouse=True)
 def _isolate_template_seeds():
     """The round-17 template-seed store is process-global (like the HBO

@@ -459,66 +459,6 @@ def test_count_program_lowers_for_tpu():
     assert "tpu" in ex.platforms
 
 
-def test_matmul_join_probe_lowers_for_tpu():
-    """The blocked one-hot matmul probe + the build-table construction
-    (ops/matmul_join.py) — the MXU path must pass real TPU lowering
-    including the fori_loop'd dynamic-slice matmul grid."""
-    from functools import partial as _partial
-
-    from trino_tpu.ops.matmul_join import (_build_code_table,
-                                           _matmul_lo_count)
-
-    m, kp = 4096, 1024
-    ex = _export_tpu(
-        _matmul_lo_count.jit,
-        sds((m,), jnp.uint64), sds((m,), jnp.bool_),
-        sds((), jnp.uint64), sds((), jnp.uint64),
-        sds((kp, 2), jnp.float32))
-    assert "tpu" in ex.platforms
-    ex = _export_tpu(
-        jax.jit(_partial(_build_code_table, kp=kp)),
-        sds((8192,), jnp.uint64), sds((), jnp.uint64),
-        sds((), jnp.uint64))
-    assert "tpu" in ex.platforms
-
-
-def test_global_hash_agg_program_lowers_for_tpu():
-    """The global-hash aggregation SPMD program (replicated-table claim
-    loop with pmin-agreed inserts + collective scatter-add reduce)
-    against an 8-device TPU-platform lowering."""
-    from functools import partial as _partial
-
-    from trino_tpu.ops.global_hash_agg import (global_hash_insert,
-                                               global_hash_reduce,
-                                               pack_keys)
-    from trino_tpu.parallel.exchange import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    mesh = Mesh(np.asarray(jax.devices()[:8]), ("x",))
-    ts, n = 256, 8
-
-    @_partial(shard_map, mesh=mesh, in_specs=(P("x"),) * 3,
-              out_specs=(P("x"),) * 3, check_vma=False)
-    def prog(keys, vals, valid):
-        k, v, va = keys[0], vals[0], valid[0]
-        packed = pack_keys([k], [None], (32,))
-        table, slot_of, resolved, unresolved = global_hash_insert(
-            packed, va, ts, axis_name="x")
-        sums, cnts = global_hash_reduce(
-            slot_of, resolved, va, (v, va.astype(jnp.int64)),
-            ("sum", "sum"), ts, axis_name="x")
-        i = jax.lax.axis_index("x")
-        sh = ts // n
-        return (jax.lax.dynamic_slice(table, (i * sh,), (sh,))[None],
-                jax.lax.dynamic_slice(sums, (i * sh,), (sh,))[None],
-                unresolved[None])
-
-    cap = 512
-    ex = _export_tpu(jax.jit(prog), sds((8, cap), jnp.int64),
-                     sds((8, cap), jnp.int64), sds((8, cap), jnp.bool_))
-    assert "tpu" in ex.platforms
-
-
 def test_q1_device_step_lowers_for_tpu():
     """The flagship fused filter+project+group-aggregate step — the
     program ``__graft_entry__.entry`` compiles on the real chip."""

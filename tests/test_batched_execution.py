@@ -577,6 +577,27 @@ def test_batch_lane_overflow_falls_back_alone():
     assert r.query_cache.batched_launches == 2
 
 
+def test_kernel_sizing_history_stabilizes_capacity():
+    """The history behind the unified lane capacities above (and the
+    hybrid join's fan-out): a repeat shape re-lands on one compiled
+    program."""
+    from trino_tpu.ops.kernel_sizing import ShapeSizingHistory
+
+    h = ShapeSizingHistory()
+    key = ("test", "shape")
+    assert h.suggest(key, 1000) == 1024
+    # fast-up: a larger need grows immediately
+    assert h.suggest(key, 5000) == 8192
+    # slow-down: a shrunken need keeps the remembered bucket (EWMA)
+    assert h.suggest(key, 900) >= 2048
+    # the need is a floor even on a cold key
+    assert h.suggest(("other",), 17) == 32
+    # repeated small needs eventually decay the remembered level
+    for _ in range(12):
+        got = h.suggest(key, 900)
+    assert got == 1024
+
+
 def test_batch_agg_failing_member_demuxes_positionally():
     r = _star_runner()
     sqls = [AGG_BURST[0], "select nope from f group by k", AGG_BURST[2]]

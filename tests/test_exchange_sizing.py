@@ -6,9 +6,9 @@ paid the full shuffle twice or more. These tests pin the count-first
 protocol: a 90%-of-rows-in-one-partition exchange completes with ZERO
 doubling retries and exactly one data collective (exact mode), the
 per-shape history pre-sizes repeat shapes without re-counting OR
-recompiling (asserted via jit_stats), legacy mode still shows the cliff
-(the knob works), and the skew stats surface identically on the device
-and host paths through EXPLAIN ANALYZE.
+recompiling (asserted via jit_stats), a stale history still completes
+through the doubling backstop, and the skew stats surface identically on
+the device and host paths through EXPLAIN ANALYZE.
 """
 
 import jax
@@ -103,14 +103,6 @@ def test_history_presizes_repeat_without_count_or_recompile():
     assert ex2.stats["per_dest"] == ex1.stats["per_dest"]
     assert jit_stats.total_for(*SIZING_KERNELS) == traces_before, (
         "history-presized repeat shape recompiled an exchange kernel")
-
-
-def test_legacy_mode_pays_the_doubling_cliff():
-    ex = _skewed_exchange("legacy")
-    assert ex.count_collectives == 0
-    assert ex.a2a_retries >= 1  # the 2x cliff the count pass removes
-    assert ex.data_collectives == ex.a2a_retries + 1
-    assert ex.stats["sizing"] == "legacy"
 
 
 def test_stale_history_recovers_via_backstop_and_relearns():

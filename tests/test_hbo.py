@@ -2,8 +2,8 @@
 that closes the loop into the cost model.
 
 The heart is the acceptance loop: a repeated query whose CONNECTOR
-estimate is wrong must demonstrably flip its join strategy on the
-second run via recorded history (EXPLAIN shows source=hbo), with
+estimate is wrong must demonstrably plan from recorded history on the
+second run (EXPLAIN shows the observed rows with source=hbo), with
 results byte-equal to the first run — and ``hbo_enabled=false`` must
 restore exactly the pre-HBO engine (no store writes, plan-cache key
 unchanged, zero extra jit traces).  Around it: fingerprint canonics
@@ -77,8 +77,7 @@ class LyingMemoryConnector(MemoryConnector):
 
 def _join_runner(**session_props):
     """fact(4 rows) join dim(3 rows), with stats claiming both are in
-    the hundreds of millions: the matmul probe is cost-model-ineligible
-    until history corrects the build-side cardinality."""
+    the tens and hundreds of millions until history corrects them."""
     lies = {
         ("default", "dim"): TableStatistics(
             row_count=50_000_000.0,
@@ -98,6 +97,16 @@ def _join_runner(**session_props):
 
 JOIN_SQL = ("select f.fk, d.name, f.amt from fact f "
             "join dim d on f.fk = d.k order by f.amt")
+
+
+def _dim_estimate(explain_text: str):
+    """What EXPLAIN says history knows of the build side: ``dim``'s
+    scan line ends ``est~3 rows [source=hbo]`` once a run recorded its
+    3 rows; the connector's 50,000,000 is never rendered."""
+    (line,) = [ln for ln in explain_text.splitlines()
+               if "TableScan memory.default.dim" in ln]
+    _, est, tail = line.partition(" est~")
+    return tail if est else None
 
 
 # ---------------------------------------------------------------------------
@@ -139,24 +148,6 @@ def test_plan_node_fp_canonicalizes_literals_and_children():
     # same shape, different literal vectors -> identical fingerprints
     # node for node (k=5's history must steer k=9's plan)
     assert a == b
-    # strategy stamping must not move the fingerprint (a flip must not
-    # orphan the history that caused it)
-    join_root = _join_runner().create_plan(JOIN_SQL)
-
-    def find_join(n):
-        from trino_tpu.planner.plan import JoinNode
-
-        if isinstance(n, JoinNode):
-            return n
-        for s in n.sources:
-            got = find_join(s)
-            if got is not None:
-                return got
-
-    jn = find_join(join_root)
-    before = plan_node_fp(jn)
-    jn.strategy, jn.strategy_detail = "matmul", "whatever"
-    assert plan_node_fp(jn) == before
 
 
 def test_agg_step_canonicalization_single_shares_final():
@@ -286,22 +277,22 @@ def test_q_error():
 
 
 # ---------------------------------------------------------------------------
-# the acceptance loop: misestimated join flips strategy on re-run
+# the acceptance loop: a misestimated join is planned from history on
+# re-run
 
 
-def test_misestimated_join_flips_to_matmul_on_rerun():
+def test_misestimated_join_plans_from_history_on_rerun():
     r = _join_runner()
     ex1 = r.explain(JOIN_SQL)
-    assert "strategy=matmul" not in ex1      # connector lie: ineligible
+    assert _dim_estimate(ex1) is None        # the connector's lie stands
     res1 = r.execute(JOIN_SQL)
     assert res1.stats["hbo"]["material"] is True
     assert r.query_cache.plans.hbo_invalidations >= 1
     ex2 = r.explain(JOIN_SQL)
     # the loop closed: recorded build-side cardinality beat the lie
-    assert "strategy=matmul" in ex2
-    assert "source=hbo" in ex2
+    assert _dim_estimate(ex2) == "3 rows [source=hbo]"
     res2 = r.execute(JOIN_SQL)
-    assert res2.rows == res1.rows            # byte-equal flip
+    assert res2.rows == res1.rows            # byte-equal re-plan
     # converged: the third run re-uses the re-planned cached plan
     res3 = r.execute(JOIN_SQL)
     assert res3.rows == res1.rows
@@ -323,8 +314,8 @@ def test_hbo_disabled_restores_pre_hbo_behavior():
     assert res2.stats.get("plan_cache") == "hit"
     assert jit_stats.total() == before
     assert r.query_cache.plans.hbo_invalidations == 0
-    # and no strategy flip: the lie stands uncorrected
-    assert "strategy=matmul" not in r.explain(JOIN_SQL)
+    # and no estimate from history: the lie stands uncorrected
+    assert _dim_estimate(r.explain(JOIN_SQL)) is None
 
 
 def test_literal_sibling_shares_history():
@@ -336,8 +327,8 @@ def test_literal_sibling_shares_history():
     tpl = ("select f.fk, d.name, f.amt from fact f "
            "join dim d on f.fk = d.k where f.amt >= {} order by f.amt")
     r.execute(tpl.format(0))
-    ex = r.explain(tpl.format(15))
-    assert "source=hbo" in ex and "strategy=matmul" in ex
+    assert _dim_estimate(r.explain(tpl.format(15))) \
+        == "3 rows [source=hbo]"
 
 
 # ---------------------------------------------------------------------------
@@ -730,7 +721,7 @@ def test_sidecar_survives_process_restart_simulation(tmp_path):
     with warnings.catch_warnings():
         warnings.simplefilter("error")      # a corrupt load would raise
         ex = r2.explain(JOIN_SQL)
-    assert "strategy=matmul" in ex and "source=hbo" in ex
+    assert _dim_estimate(ex) == "3 rows [source=hbo]"
     assert r2.execute(JOIN_SQL).rows == res1.rows
 
 # ---------------------------------------------------------------------------
@@ -917,6 +908,48 @@ def test_distribution_gate_keeps_connector_choice():
     assert "distribution=partitioned" not in ex
     assert "distribution=broadcast [source=hbo]" not in ex
     assert stats_store.store().plan_flips.get("distribution", 0) == 0
+
+
+def test_plan_fp_says_only_what_is_executed():
+    """History teaches the planner a group count (3, where the
+    connector states 50,000,000 rows and no distinct count) and the
+    fragments stay what they were: so does their fingerprint, the
+    statement root's ``plan_fp``.  (Before PR 46 an aggregation carried
+    a label no operator read, history flipped it ``exchange`` ->
+    ``global-hash``, and the fingerprint moved with it.)"""
+    import re
+
+    from trino_tpu.parallel.distributed import DistributedQueryRunner
+    from trino_tpu.planner.fragmenter import (fragments_fingerprint,
+                                              fragments_str)
+
+    conn = LyingMemoryConnector({("default", "sales"): TableStatistics(
+        row_count=50_000_000.0)})
+    s = Session(catalog="memory", schema="default")
+    local = LocalQueryRunner({"memory": conn}, s)
+    local.execute("create table sales (g bigint, v bigint)")
+    local.execute("insert into sales values " + ", ".join(
+        f"({i % 3}, {i})" for i in range(40)))
+    r = DistributedQueryRunner({"memory": conn}, s, n_workers=2,
+                               desired_splits=2)
+    stmt = parse_statement(
+        "select g, sum(v) from sales group by g order by g")
+
+    def planned():
+        fragments = r.create_fragments(stmt, hbo=r._hbo_context(stmt))
+        return fragments_fingerprint(fragments), fragments_str(fragments)
+
+    fp1, text1 = planned()
+    assert "source=hbo" not in text1
+    res = r.execute("select g, sum(v) from sales group by g order by g")
+    assert res.rows == [(0, 273), (1, 247), (2, 260)]
+    fp2, text2 = planned()
+    assert "est~40 rows [source=hbo]" in text2   # history reached the plan
+    assert re.sub(r" est~\d+ rows \[source=hbo\]", "", text2) == text1
+    assert fp2 == fp1
+    (root,) = [sp for sp in res.stats["trace"]
+               if sp["name"] == "statement"]
+    assert root["attrs"]["plan_fp"] == fp1
 
 
 def test_spill_hint_refuses_broadcast():

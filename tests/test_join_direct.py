@@ -23,6 +23,7 @@ from trino_tpu.block import DevicePage, Dictionary, Page
 from trino_tpu.connectors.tpch import TpchConnector
 from trino_tpu.exec.memory import QueryMemoryPool
 from trino_tpu.ops import join as J
+from trino_tpu.resources.tpch_queries import TPCH_QUERIES
 from trino_tpu.runner import LocalQueryRunner
 from trino_tpu.sql.analyzer import Session
 
@@ -294,20 +295,55 @@ def _brute(join_type, build_cols, probe_cols, key_channels=(0,)):
     return sorted(out, key=repr)
 
 
-@pytest.mark.parametrize("join_type",
-                         ["inner", "left", "full", "semi", "anti"])
-def test_join_types_over_the_table(join_type):
-    rng = np.random.default_rng(17)
-    types_ = [T.BIGINT, T.BIGINT]
+def _uniform_nulls(rng):
     build_cols = [[int(v) if rng.random() > 0.1 else None
                    for v in rng.integers(50, 400, 700)], _payload(700)]
     probe_cols = [[int(v) if rng.random() > 0.1 else None
                    for v in rng.integers(0, 450, 1100)], _payload(1100)]
+    # 351 codes + 1, padded
+    return [T.BIGINT, T.BIGINT], build_cols, probe_cols, 3, 4 * 512
+
+
+def _zipf_nulls_150(rng):
+    # a skewed probe over a build of 150 keys, a tenth of both null
+    def keys(values):
+        return [int(v) if rng.random() >= 0.1 else None for v in values]
+
+    build_cols = [keys(rng.integers(0, 150, 768)), _payload(768)]
+    probe_cols = [keys(rng.zipf(1.8, 1024) % 229), _payload(1024)]
+    return [T.BIGINT, T.BIGINT], build_cols, probe_cols, 2, 4 * 256
+
+
+def _varchar_two_pools(rng):
+    # dictionary codes: ``_publish`` and ``_join_rows`` give build and
+    # probe a pool each, and the probe's holds 20 strings the build's
+    # lacks, so the probe remaps its codes into the build's pool
+    vocab = [f"k{i:03d}" for i in range(60)]
+    bk = [vocab[i] if rng.random() > 0.05 else None
+          for i in rng.integers(0, 40, 900)]
+    pk = [vocab[i] if rng.random() > 0.05 else None
+          for i in rng.integers(0, 60, 1100)]
+    return [T.VARCHAR, T.BIGINT], [bk, _payload(900)], \
+        [pk, _payload(1100)], 3, 4 * 64
+
+
+DISTRIBUTIONS = {"uniform_nulls": _uniform_nulls,
+                 "zipf_nulls_150": _zipf_nulls_150,
+                 "varchar_two_pools": _varchar_two_pools}
+
+
+@pytest.mark.parametrize("distribution", sorted(DISTRIBUTIONS))
+@pytest.mark.parametrize("join_type",
+                         ["inner", "left", "full", "semi", "anti"])
+def test_join_types_over_the_table(join_type, distribution):
+    rng = np.random.default_rng(17)
+    types_, build_cols, probe_cols, pages, table_bytes = \
+        DISTRIBUTIONS[distribution](rng)
     got, op = _join_rows(join_type, types_, (0,), build_cols, probe_cols)
     assert got == _brute(join_type, build_cols, probe_cols)
     m = op.metrics()
-    assert m["probe_pages"] == 3 and m["direct_probe_pages"] == 3
-    assert m["direct_table_bytes"] == 4 * 512   # 351 codes + 1, padded
+    assert m["probe_pages"] == pages and m["direct_probe_pages"] == pages
+    assert m["direct_table_bytes"] == table_bytes
     assert "probe_fallback" not in m
 
 
@@ -565,6 +601,20 @@ group by c_mktsegment order by c_mktsegment""", True),
 }
 
 
+# the statements that ran the one-hot matmul probe while the planner
+# chose a join's kernel from estimated key ranges (before PR 46), on the
+# schema where it chose it: dimension joins over a few dozen keys, semi
+# and anti joins among them.  (sql, ordered, joins whose build key is
+# two 64-bit columns — ``partsupp``'s — which no table covers)
+MICRO_STATEMENTS = {
+    "micro_customer_orders": ("""select c.c_custkey, o.o_orderkey
+from customer c join orders o on c.c_custkey = o.o_custkey""", False, 0),
+    **{f"micro_q{q:02d}": (TPCH_QUERIES[q], True, hashed)
+       for q, hashed in [(5, 1), (8, 0), (10, 0), (11, 0), (15, 0),
+                         (16, 0), (20, 1), (21, 0)]},
+}
+
+
 @pytest.fixture(scope="module")
 def conn():
     return TpchConnector(page_rows=8192)
@@ -581,19 +631,40 @@ def oracle(conn):
     return load_sqlite(conn, SCHEMA)
 
 
-@pytest.mark.parametrize("name", sorted(STATEMENTS))
-def test_statements_probe_by_direct_address(name, runner, oracle):
-    sql, ordered = STATEMENTS[name]
+@pytest.fixture(scope="module")
+def micro(conn):
+    """(runner, sqlite) over ``tpch.micro``."""
+    return (LocalQueryRunner({"tpch": conn},
+                             Session(catalog="tpch", schema="micro")),
+            load_sqlite(conn, "micro"))
+
+
+@pytest.mark.parametrize("schema, sql, ordered, hashed", [
+    pytest.param(SCHEMA, *STATEMENTS[name], 0, id=name)
+    for name in sorted(STATEMENTS)] + [
+    pytest.param("micro", *MICRO_STATEMENTS[name], id=name)
+    for name in sorted(MICRO_STATEMENTS)])
+def test_statements_probe_by_direct_address(schema, sql, ordered, hashed,
+                                            runner, oracle, micro):
+    if schema == "micro":
+        runner, oracle = micro
     res = runner.execute(sql)
     assert_same(res, oracle.execute(to_sqlite(sql)).fetchall(), ordered)
+    names = [s["name"] for s in res.stats["trace"]]
     joins = [s["attrs"] for s in res.stats["trace"]
-             if s["name"] in ("LookupJoinOperator", "MatmulJoinOperator")]
-    assert joins, [s["name"] for s in res.stats["trace"]]
+             if s["name"] == "LookupJoinOperator"]
+    assert joins and not [n for n in names
+                          if "Join" in n and n != "LookupJoinOperator"], names
+    by_search = [a for a in joins if "probe_fallback" in a]
+    assert len(by_search) == hashed, by_search
     for attrs in joins:
         assert attrs["probe_pages"] > 0
+        if attrs in by_search:
+            assert attrs["probe_fallback"] == "hashed key mode"
+            assert attrs["direct_probe_pages"] == 0
+            continue
         assert attrs["direct_probe_pages"] == attrs["probe_pages"], attrs
         assert attrs["direct_table_bytes"] > 0
-        assert "probe_fallback" not in attrs
 
 
 def test_explain_analyze_says_which_probe_ran(runner):

@@ -226,14 +226,27 @@ def test_a_build_behind_a_join_is_as_wide_as_its_rows(served_run):
                for b in named(trace, "HashBuilderOperator")) > 8
 
 
-def test_matmul_join_reads_through_host_read():
-    """Over the generator's catalog, which states key ranges, ``supplier
-    x nation`` is the one-hot matmul join: its span counts its lanes and
-    its one blocking read is on the statement's account."""
+def test_generator_catalog_q9_joins_on_the_one_path():
+    """Over the generator's catalog, which states key ranges (from them
+    the planner made ``supplier x nation`` a one-hot matmul join until
+    PR 46), every join is a lookup join: ``nation``'s 25 keys are probed
+    by direct address, ``partsupp``'s two-column key by search, and each
+    build that has a key range reads it once, on the statement's
+    account."""
     runner = LocalQueryRunner({"tpch": TpchConnector(page_rows=2048)},
                               Session(catalog="tpch", schema="tiny"))
     trace = runner.execute(TPCH_QUERIES[9]).stats["trace"]
-    matmul = named(trace, "MatmulJoinOperator")
-    assert matmul and all(m["attrs"]["probe_lanes"] > 0 for m in matmul)
+    joins = [s for s in trace if "Join" in s["name"]]
+    assert len(joins) == 5
+    assert {s["name"] for s in joins} == {"LookupJoinOperator"}
+    # the probe that was handed supplier's 100 rows
+    (nation,) = [j["attrs"] for j in joins
+                 if j["attrs"]["input_rows"] == 100]
+    assert nation["direct_probe_pages"] == nation["probe_pages"] == 1
+    assert nation["direct_table_bytes"] == 4 * 32   # 25 codes + 1, padded
+    builds = named(trace, "HashBuilderOperator")
+    ranged = [b for b in builds if b["attrs"]["key_mode"] != "hashed"]
+    assert len(builds) == 5 and len(ranged) == 4
     by_why = root_of(trace)["host_sync_by_why"]
-    assert by_why["matmul_join_key_range"][0] == len(matmul)
+    assert by_why["join_key_range"][0] == len(ranged)
+    assert "matmul_join_key_range" not in by_why

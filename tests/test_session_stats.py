@@ -86,3 +86,34 @@ def test_session_property_case_insensitive(runner):
     vals = dict((r[0], r[1])
                 for r in runner.execute("show session").rows)
     assert vals["join_distribution_type"] == "BROADCAST"
+
+
+@pytest.mark.parametrize("statement, refusal", [
+    ("set session join_strategy = 'MATMUL'",
+     "unknown session property: join_strategy"),
+    ("set session matmul_join_max_key_range = 64",
+     "unknown session property: matmul_join_max_key_range"),
+    ("set session aggregation_strategy = 'GLOBAL_HASH'",
+     "unknown session property: aggregation_strategy"),
+    ("set session global_hash_agg_max_table = 1024",
+     "unknown session property: global_hash_agg_max_table"),
+    ("set session device_exchange_sizing = 'legacy'",
+     "value 'legacy' out of range for device_exchange_sizing"),
+], ids=["join_strategy", "matmul_join_max_key_range",
+        "aggregation_strategy", "global_hash_agg_max_table",
+        "device_exchange_sizing_legacy"])
+def test_names_removed_with_their_kernels_are_refused(runner, statement,
+                                                      refusal):
+    """The join's probe and the grouping's merge are chosen where the
+    keys are seen (PR 46): the switches that chose them in the planner,
+    and the exchange sizing mode no deployment selected, are outside
+    input like any other unknown name or value."""
+    from trino_tpu.types import TrinoError
+
+    before = dict(runner.session.properties)
+    with pytest.raises(TrinoError) as refused:
+        runner.execute(statement)
+    assert refusal in str(refused.value)
+    assert refused.value.code == "INVALID_SESSION_PROPERTY"
+    assert runner.session.properties == before
+    assert len(runner.execute("show session").rows) == 67

@@ -1437,12 +1437,11 @@ def test_gate_passes_are_not_blind_on_the_real_repo(repo_findings):
     # must stay inside the trace-purity walk — the vmap unwrapping in
     # jit_entries is what keeps the batched path not-blind
     assert "trino_tpu.expr.compiler:PageProcessor._run" in entries
-    # the kernel-strategy entry points (round 12) must be inside the
-    # trace-purity walk — the matmul probe, the global-hash claim loop,
-    # and the per-key-range adaptive kernels are all hot jit'd code
-    for entry in ("trino_tpu.ops.matmul_join:_matmul_lo_count",
-                  "trino_tpu.ops.global_hash_agg:global_hash_insert",
-                  "trino_tpu.ops.global_hash_agg:global_hash_reduce",
+    # the join's direct-address table and probe and the per-key-range
+    # adaptive kernels must be inside the trace-purity walk: all hot
+    # jit'd code
+    for entry in ("trino_tpu.ops.join:_build_direct_offsets",
+                  "trino_tpu.ops.join:_probe_direct_counts",
                   "trino_tpu.ops.aggregation:_bucket_reduction_stats"):
         assert entry in entries, entry
     cached = _cached_functions(index)
@@ -1528,7 +1527,8 @@ def test_gate_passes_are_not_blind_on_the_real_repo(repo_findings):
                    "hash_group_ids", "hash_segment_reduce",
                    "sort_group_reduce", "join_build_sorted",
                    "join_probe_counts", "join_expand_matches",
-                   "matmul_join_probe", "grouped_topn_kernel",
+                   "join_probe_direct", "join_direct_table",
+                   "grouped_topn_kernel",
                    "device_exchange_program", "device_exchange_count",
                    "segment_reduce_pallas",
                    # round 17: masked agg/join lanes register through

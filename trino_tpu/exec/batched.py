@@ -23,9 +23,8 @@ Round 17 extends the vmappable stage set past filter/project:
   sentinel slot, so per-page partials, the concat merge, and the final
   projection all run as ``jit(vmap(...))`` lane programs. Per-lane
   dense group ids and counts demux on the host like any other column.
-- **joins** (``LookupJoinOperator`` — the matmul strategy's sorted
-  fallback kernels are byte-identical, so the batched path always uses
-  the sorted-index probes): the build side is literal-independent by
+- **joins** (``LookupJoinOperator``, probed by the two binary searches
+  over the sorted index): the build side is literal-independent by
   template construction (the aux pipelines are proved param-free), so
   ONE serial build serves all B lanes with its arrays broadcast
   (``in_axes=None``); probes mask invalid probe rows. inner/left

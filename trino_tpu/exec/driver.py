@@ -75,14 +75,6 @@ class OperatorStats:
                      f"compile {self.compile_ms:.1f}ms]")
         if self.metrics:
             m = self.metrics
-            if m.get("strategy"):
-                # kernel-strategy operators report what RAN (incl. a
-                # fallback) plus the cost-model estimate that picked it
-                base += f" [strategy {m['strategy']}"
-                for k in ("estimate", "fallback", "key_range"):
-                    if m.get(k):
-                        base += f" {k}={m[k]!r}"
-                base += "]"
             if m.get("probe_pages"):
                 # the join's candidate lookup: pages answered from the
                 # build's direct-address table, or why it has none
@@ -95,7 +87,7 @@ class OperatorStats:
                 base += "]"
             if m.get("adaptive"):
                 # the adaptive partial-agg decision (pass-through or
-                # per-key-range split) — no 'strategy' key on agg ops
+                # per-key-range split)
                 base += f" [adaptive {m['adaptive']}]"
             if m.get("grouping_paths"):
                 # pages by grouping path; ``dense`` are the ``hash``

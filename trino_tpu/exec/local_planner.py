@@ -21,7 +21,6 @@ from ..ops.aggregation import (ADAPTIVE_KEY_BUCKETS, ADAPTIVE_MIN_ROWS,
                                ADAPTIVE_RATIO_THRESHOLD, AggCall,
                                HashAggregationOperator)
 from ..ops.join import HashBuilderOperator, JoinBridge, LookupJoinOperator
-from ..ops.matmul_join import MatmulJoinOperator
 from ..ops.operator import (DeferredPagesSourceOperator,
                             EnforceSingleRowOperator, FilterProjectOperator,
                             LimitOperator, OffsetOperator, Operator,
@@ -69,8 +68,6 @@ def grouping_options(props: Dict) -> Dict:
             props, "adaptive_partial_aggregation_min_rows"),
         "adaptive_partial_buckets": SP.prop_value(
             props, "adaptive_partial_aggregation_key_range_buckets"),
-        "matmul_max_key_range": SP.prop_value(
-            props, "matmul_join_max_key_range"),
         "hybrid_join": SP.prop_value(props, "hybrid_join_enabled"),
         "hybrid_join_fanout": SP.prop_value(
             props, "hybrid_join_fanout"),
@@ -188,7 +185,6 @@ class LocalExecutionPlanner:
                  adaptive_partial_ratio: float = ADAPTIVE_RATIO_THRESHOLD,
                  adaptive_partial_min_rows: int = ADAPTIVE_MIN_ROWS,
                  adaptive_partial_buckets: int = ADAPTIVE_KEY_BUCKETS,
-                 matmul_max_key_range: int = 1024,
                  hybrid_join: bool = True,
                  hybrid_join_fanout: int = 0,
                  hybrid_join_max_depth: int = 3,
@@ -213,10 +209,6 @@ class LocalExecutionPlanner:
         self.adaptive_partial_ratio = adaptive_partial_ratio
         self.adaptive_partial_min_rows = adaptive_partial_min_rows
         self.adaptive_partial_buckets = adaptive_partial_buckets
-        #: densest key domain the matmul join strategy may one-hot
-        #: encode (``matmul_join_max_key_range``) — the operator's
-        #: runtime re-check of the cost model's range estimate
-        self.matmul_max_key_range = matmul_max_key_range
         #: dynamic hybrid hash join knobs (``hybrid_join_*`` session
         #: properties): graceful build degradation under memory pressure
         self.hybrid_join = hybrid_join
@@ -394,7 +386,6 @@ class LocalExecutionPlanner:
     def _v_JoinNode(self, node: JoinNode):
         return self._plan_join(node.join_type, node.left, node.right,
                                node.criteria, node.filter_expr,
-                               node.strategy, node.strategy_detail,
                                node=node)
 
     def _v_CrossJoinNode(self, node: CrossJoinNode):
@@ -422,9 +413,7 @@ class LocalExecutionPlanner:
 
     def _plan_join(self, join_type: str, left: PlanNode, right: PlanNode,
                    criteria: List[Tuple[Symbol, Symbol]],
-                   filter_expr: Optional[RowExpression],
-                   strategy: str = "sorted-index",
-                   strategy_detail: str = "", node=None):
+                   filter_expr: Optional[RowExpression], node=None):
         build_dfs = []
         if self.dynamic_filtering:
             from .dynamic_filter import plan_dynamic_filters
@@ -490,21 +479,10 @@ class LocalExecutionPlanner:
             def filter_fn(dp, _proc=proc, _params=jparams):
                 return _proc.process(dp, _params)
 
-        if strategy == "matmul":
-            # the cost model picked the blocked one-hot matmul probe;
-            # the operator re-checks the actual key range per build and
-            # falls back to the sorted index (reason in its metrics)
-            pops.append(MatmulJoinOperator(
-                ptypes, probe_keys, bridge, join_type, filter_fn,
-                max_lanes=self.join_max_lanes,
-                memory_limited=self._memory_constrained(),
-                max_key_range=self.matmul_max_key_range,
-                strategy_detail=strategy_detail))
-        else:
-            pops.append(LookupJoinOperator(
-                ptypes, probe_keys, bridge, join_type, filter_fn,
-                max_lanes=self.join_max_lanes,
-                memory_limited=self._memory_constrained()))
+        pops.append(LookupJoinOperator(
+            ptypes, probe_keys, bridge, join_type, filter_fn,
+            max_lanes=self.join_max_lanes,
+            memory_limited=self._memory_constrained()))
         if join_type in ("semi", "anti"):
             out_layout = dict(playout)
             out_types = ptypes

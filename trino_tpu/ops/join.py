@@ -1267,11 +1267,6 @@ class LookupJoinOperator(Operator):
         pkey_cols, pkey, pusable = self._probe_keys_u64(page, b)
         self._direct_table_bytes = b.direct.nbytes if b.direct else 0
         self._probe_fallback = b.direct_fallback
-        direct = self._probe_direct(page, b, pkey, pusable)
-        if direct is not None:
-            self._ready.append(direct)
-            self._added_since_get = True
-            return
         lo, count = self._probe_lo_count(b, pkey, pusable)
         self._pending.append(
             _looked_up(b, page, pkey_cols, pusable, lo, count))
@@ -1321,20 +1316,11 @@ class LookupJoinOperator(Operator):
         return DevicePage(page.types, page.cols, page.nulls,
                           jnp.asarray(res_valid), page.dictionaries)
 
-    def _probe_direct(self, page: DevicePage, b: "BuildSide", pkey,
-                      pusable):
-        """Strategy seam: a complete output page computed straight from
-        the probe keys (no candidate expansion), or None to run the
-        lo/count path below.  The matmul strategy
-        (``ops/matmul_join.py``) answers semi/anti membership here."""
-        return None
-
     def _probe_lo_count(self, b: "BuildSide", pkey, pusable):
-        """Strategy seam: each probe row's candidate range (lo, count)
-        against the sorted build index — two gathers from the build's
+        """Each probe row's candidate range (lo, count) against the
+        sorted build index — two gathers from the build's
         direct-address table where it has one, else two XLA-native
-        vectorized binary searches; the matmul strategy overrides with
-        the blocked one-hot matmul probe."""
+        vectorized binary searches."""
         if b.direct is not None:
             self._direct_pages += 1
             return _probe_direct_counts(b.direct.offsets, b.direct.span,
@@ -1539,11 +1525,9 @@ class LookupJoinOperator(Operator):
                                     nulls, valid, cap, dicts)
 
     def _probe_spilled_page(self, b: "BuildSide", sp):
-        """One parked probe page against one per-partition index —
-        straight through the base sorted-index kernels.  The strategy
-        seams (_probe_direct/_probe_lo_count) are deliberately
-        bypassed: the matmul strategy caches ONE table from the
-        resident build side and must not see per-partition indexes."""
+        """One parked probe page against one per-partition index, by
+        the two binary searches: a re-indexed partition carries no
+        direct-address table."""
         page = sp.to_device()
         pkey_cols, pkey, pusable = self._probe_keys_u64(page, b)
         lo, count = _probe_counts(b.key_sorted, b.usable_sorted, pkey,

@@ -1,16 +1,16 @@
 """Per-kernel-shape sizing history: remembered static capacities so
 repeat shapes reuse compiled programs.
 
-The matmul-join key-domain table and the global-hash aggregation table
-are jit'd at a STATIC capacity (one-hot width / table slots).  A
-capacity derived freshly from each query's data would drift run to run
-— padded_size buckets absorb most of it, but a workload oscillating
-around a pow2 boundary would still alternate between two compiled
-programs.  This history is the kernel-capacity analog of
-``parallel.device_exchange.ExchangeSizingHistory``: grow IMMEDIATELY on
-a larger observation (an undersized table means a fallback or an extra
-claim round; an oversized one only pads lanes), decay by EWMA so a
-transient spike doesn't pin the capacity forever, and always emit
+A batched burst's unified lane capacities (``exec/batched.py``) and the
+hybrid join's partition fan-out (``ops/join.py``) are STATIC arguments
+of jit'd programs.  A capacity derived freshly from each query's data
+would drift run to run — padded_size buckets absorb most of it, but a
+workload oscillating around a pow2 boundary would still alternate
+between two compiled programs.  This history is the kernel-capacity
+analog of ``parallel.device_exchange.ExchangeSizingHistory``: grow
+IMMEDIATELY on a larger observation (an undersized capacity means a
+fallback or a re-run; an oversized one only pads lanes), decay by EWMA
+so a transient spike doesn't pin the capacity forever, and always emit
 through ``padded_size`` so a stable workload re-lands on the identical
 jit cache entry.
 """

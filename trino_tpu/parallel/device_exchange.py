@@ -19,23 +19,22 @@ contract that downstream group-by/join kernels rely on.
 
 Sizing protocol (skew-adaptive): all_to_all lanes are fixed capacity
 (per_dest per sender/receiver pair), so per_dest must be chosen before
-the data collective compiles. Three modes (``device_exchange_sizing``
+the data collective compiles. Two modes (``device_exchange_sizing``
 session property):
 
 - ``exact``: a count-first pass — a tiny counting collective (per-sender
   destination histograms + psum/pmax, O(n*d) scalars, negligible vs the
   payload) — observes the exact max (sender, dest) load and sizes
-  per_dest exactly; the doubling retry below becomes dead code in
-  practice (kept as a bug backstop).
+  per_dest exactly; the doubling retry is dead code in practice (kept
+  as a bug backstop).
 - ``history`` (default): a process-wide EWMA of observed max loads keyed
   by exchange shape (types/keys/n/d — the plan-node signature),
   pow2-bucketed through ``padded_size`` so repeat shapes reuse the
   ``_exchange_program`` lru_cache; pre-sizes per_dest and skips the
   count pass once confident, falling back to ``exact`` until then.
-- ``legacy``: the original guess (2*cap/d); on lane overflow the host
-  doubles per_dest and re-runs the whole collective — under real skew
-  that pays the full shuffle twice or more (the 2x cost cliff the
-  count-first pass removes).
+  A stale presize overflows its lanes: the host doubles per_dest and
+  re-runs the collective (``a2a_retries``), and what it then observes
+  re-teaches the history.
 
 Hot-partition SPLITTING (scaled receivers): lanes are per (sender,
 dest) pair, so ONE partition holding most of the rows caps the whole
@@ -106,7 +105,7 @@ def device_exchange_supported(types_: Sequence[T.Type]) -> bool:
     return all(t.storage is not None for t in types_)
 
 
-SIZING_MODES = ("exact", "history", "legacy")
+SIZING_MODES = ("exact", "history")
 
 
 class ExchangeSizingHistory:
@@ -479,10 +478,8 @@ class DeviceExchange:
             tuple(str(t) for t in types_), kkey, n, d)
         sizing = self.sizing
         mode_used = sizing
-        # hot-partition splitting is a non-legacy feature (legacy IS the
-        # pre-split baseline) and needs >= 2 receivers to spread over
-        splittable = (self.hot_split_threshold < 1.0 and d > 1
-                      and sizing != "legacy")
+        # hot-partition splitting needs >= 2 receivers to spread over
+        splittable = self.hot_split_threshold < 1.0 and d > 1
         hot: set = set()
         per_dest = None
         if sizing == "history":
@@ -515,8 +512,6 @@ class DeviceExchange:
                     pair_max.reshape(n, d), hot, n, d), 16))
             else:
                 per_dest = padded_size(max(int(need), 16))
-        elif sizing == "legacy":
-            per_dest = padded_size(max(32, (2 * cap) // d))
         per_dest = min(per_dest, cap)
         # the hot set rides as a TRACED (n,) mask: split and unsplit
         # runs of one shape share one compiled program (no recompiles)

@@ -119,8 +119,8 @@ class DistributedQueryRunner:
         if stmt is None:
             stmt = parse_statement(sql)
         # EXPLAIN plans through the statement's history view, so the
-        # rendered join order / distribution / strategy choices are
-        # exactly what the next execution would run
+        # rendered join order and distribution choices are exactly
+        # what the next execution would run
         text = fragments_str(self.create_fragments(
             stmt, hbo=self._hbo_context(stmt)))
         prov = provenance_lines(self._root)

@@ -124,13 +124,11 @@ class ExchangePlanner:
         keys = node.group_keys
         if dist in (SINGLE, ANY):
             return AggregationNode(src, keys, node.aggregations,
-                                   node.step, None, node.strategy,
-                                   node.strategy_detail), dist
+                                   node.step), dist
         if keys and dist == _hash(keys):
             # already partitioned on the grouping keys: aggregate locally
             return AggregationNode(src, keys, node.aggregations,
-                                   node.step, None, node.strategy,
-                                   node.strategy_detail), dist
+                                   node.step), dist
         # partial -> exchange -> final
         state_symbols: List[Symbol] = []
         for out_sym, agg in node.aggregations:
@@ -146,8 +144,7 @@ class ExchangePlanner:
             ex = ExchangeNode(partial, "single", [])
             final_dist = SINGLE
         final = AggregationNode(ex, keys, node.aggregations, "final",
-                                state_symbols, node.strategy,
-                                node.strategy_detail)
+                                state_symbols)
         return final, final_dist
 
     def _v_DistinctNode(self, node: DistinctNode):
@@ -185,8 +182,7 @@ class ExchangePlanner:
             if ldist in (SINGLE, ANY):
                 right = self._to_single(right, rdist)
                 return JoinNode(node.join_type, left, right, node.criteria,
-                                node.filter_expr, node.strategy,
-                                node.strategy_detail), SINGLE
+                                node.filter_expr), SINGLE
             partitioned = True
         elif self.join_distribution == "BROADCAST":
             partitioned = False
@@ -231,8 +227,7 @@ class ExchangePlanner:
         if not partitioned:
             out_dist = ldist
         out = JoinNode(node.join_type, left, right, node.criteria,
-                       node.filter_expr, node.strategy,
-                       node.strategy_detail)
+                       node.filter_expr)
         if dist is not None:
             # plain attrs (the est_rows pattern): ride to EXPLAIN and
             # the history decision-node walk without moving the node's

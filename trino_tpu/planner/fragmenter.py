@@ -107,8 +107,8 @@ def fragments_fingerprint(fragments: List[PlanFragment]) -> str:
     """A hash of the fragment DAG as it will run: every fragment's
     partitioning, output kind, keys and inputs, and its nodes in tree
     order, each with its output symbols, a scan's table, an
-    aggregation's step and strategy, a join's type, criteria, strategy
-    and distribution.  No literal and no estimate: two statements of
+    aggregation's step, a join's type, criteria and distribution.
+    No literal, no estimate and no label: two statements of
     one shape differ here only if the planner ordered or distributed
     them apart (the statement root's ``plan_fp`` under
     ``DistributedQueryRunner``, as ``LocalExecutionPlan.fingerprint``
@@ -121,11 +121,11 @@ def fragments_fingerprint(fragments: List[PlanFragment]) -> str:
         if isinstance(node, TableScanNode):
             part.append(node.table.qualified_name)
         elif isinstance(node, AggregationNode):
-            part += [node.step, node.strategy]
+            part.append(node.step)
         elif isinstance(node, JoinNode):
             part += [node.join_type,
                      [(l.name, r.name) for l, r in node.criteria],
-                     node.strategy, getattr(node, "distribution", None)]
+                     getattr(node, "distribution", None)]
         elif isinstance(node, RemoteSourceNode):
             part.append(node.fragment_id)
         parts.append(part)

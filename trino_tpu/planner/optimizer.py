@@ -14,7 +14,7 @@ directly as recursive rewrites:
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, Sequence, Set, Tuple
 
 from .. import types as T
 from ..expr.ir import Call, Literal, RowExpression
@@ -42,7 +42,7 @@ def optimize(root: OutputNode, metadata: Metadata,
     PruneUnreferencedOutputs-style passes outside exploration).
     ``hbo`` (telemetry.stats_store.HboContext) feeds recorded runtime
     actuals into the cost-based rules — join-order exploration
-    (``hbo_reorder_joins_enabled``) and the kernel-strategy rules all
+    (``hbo_reorder_joins_enabled``) and the estimates EXPLAIN prints
     price through ONE shared node-memoized StatsCalculator per run;
     history beats connector estimates."""
     from .. import session_properties as SP
@@ -65,13 +65,14 @@ def optimize(root: OutputNode, metadata: Metadata,
     #: rule provenance for EXPLAIN (reference: in the Java engine each
     #: PlanNode carries its source rule via PlanNodeIdAllocator tags)
     out.optimizer_trace = list(engine.trace)
-    # kernel-strategy annotation runs LAST: the choices must land on
-    # the final plan nodes the local planner and EXPLAIN read.  It
-    # shares the run's calculator when the history views agree (they
-    # only diverge when hbo_reorder_joins_enabled gated reordering off)
-    out.optimizer_trace += annotate_kernel_strategies(
-        node, metadata, session, hbo=hbo,
-        calc=calc if reorder_hbo is hbo else None)
+    if hbo is not None:
+        # runs LAST: the estimates must land on the final plan nodes
+        # the local planner and EXPLAIN read.  It shares the run's
+        # calculator when the history views agree (they only diverge
+        # when hbo_reorder_joins_enabled gated reordering off)
+        out.optimizer_trace += annotate_estimates(
+            node, hbo, calc if reorder_hbo is hbo
+            else StatsCalculator(metadata, history=hbo))
     slots = template_param_slots(out)
     if slots:
         out.optimizer_trace.append((
@@ -310,157 +311,23 @@ class Optimizer:
 # ---------------------------------------------------------------------------
 
 
-# ---------------------------------------------------------------------------
-# kernel-strategy cost rules: MXU matmul join + global-hash aggregation
-# ("Density-optimized ... Matrix Multiplication for Join-Project" and
-# "Global Hash Tables Strike Back!", PAPERS.md).  ONE decision path for
-# the planner annotation and the session-property overrides.  The join
-# strategy reaches the operators (exec/local_planner hands it to the
-# joins); the aggregation strategy is an EXPLAIN annotation only: no
-# operator reads it and no planned statement runs ops/global_hash_agg.
-
-
-def _matmul_max_build_rows() -> int:
-    """The operator's f32-exactness bound, imported lazily (the ops
-    module pulls jax; the planner stays light until a join is costed)
-    so planner estimate and runtime re-check share one definition."""
-    from ..ops.matmul_join import MAX_BUILD_ROWS
-
-    return MAX_BUILD_ROWS
-
-
-def choose_join_strategy(node: "JoinNode", calc, override: str,
-                         max_range: int,
-                         will_spill: bool = False) -> Tuple[str, str]:
-    """('sorted-index' | 'matmul', detail).  The matmul probe wins when
-    the build key domain maps densely onto a small one-hot width: one
-    integer-ish (or dictionary-coded) equi key whose estimated range —
-    value span for integers, pool size ≈ NDV for strings — fits
-    ``max_range``, over a confidently-small build.  Everything else
-    keeps the sorted-index probe.  The operator re-checks the ACTUAL
-    range at build time and falls back, so a forced 'MATMUL' override
-    is safe on any join.
-
-    ``will_spill`` is the HBO-fed memory-pressure input: this node's
-    build spilled partitions on its last run, so a denser encoding
-    that avoids materializing the sorted index is worth 4x the normal
-    one-hot width (the matmul table is O(key range), not O(build
-    rows) — it sidesteps the partition machinery entirely)."""
-    if override == "SORTED_INDEX":
-        return "sorted-index", "forced by join_strategy"
-    if override == "MATMUL":
-        return "matmul", "forced by join_strategy"
-    if node.join_type not in ("inner", "semi", "anti") \
-            or len(node.criteria) != 1:
-        return "sorted-index", ""
-    eff_range = max_range * (4 if will_spill else 1)
-    spill_note = ", build will spill (hbo)" if will_spill else ""
-    right = calc.stats(node.right)
-    if not right.confident or right.row_count > _matmul_max_build_rows():
-        return "sorted-index", ""
-    _l, r = node.criteria[0]
-    rs = right.symbol(r.name)
-    t = r.type
-    if getattr(t, "is_pooled", False):
-        # dictionary codes ARE the dense domain; pool size ~ NDV
-        if rs.distinct_count is None or rs.distinct_count > eff_range:
-            return "sorted-index", ""
-        detail = (f"build~{right.row_count:.0f} rows, pool~"
-                  f"{rs.distinct_count:.0f} codes <= {eff_range}, "
-                  f"source={right.source}{spill_note}")
-        return "matmul", detail
-    storage = getattr(t, "storage", None)
-    import numpy as _np
-
-    if storage is None or _np.dtype(storage).kind not in "iub":
-        return "sorted-index", ""  # float/decimal-free zone: ints only
-    if rs.low is None or rs.high is None or rs.low < 0:
-        # the equality u64 encoding is range-contiguous only for
-        # non-negative keys (no sign bias); stats-unknown ranges stay
-        # on the sorted index
-        return "sorted-index", ""
-    key_range = rs.high - rs.low + 1
-    if key_range > eff_range:
-        return "sorted-index", ""
-    detail = (f"build~{right.row_count:.0f} rows, key range "
-              f"{key_range:.0f} <= {eff_range}, "
-              f"source={right.source}{spill_note}")
-    return "matmul", detail
-
-
-def choose_agg_strategy(ndv_estimate: float, n_devices: int = 1,
-                        override: str = "AUTOMATIC",
-                        max_table: Optional[int] = None,
-                        source: str = "observed") -> Tuple[str, str]:
-    """('exchange' | 'global-hash', detail).  The global-hash table is
-    replicated per device and merged by collective scatter-add, so it
-    wins exactly when 2x the group-count bound (load factor <= 0.5)
-    stays small — below ``global_hash_agg_max_table`` slots; past that
-    the all_to_all of partial groups moves fewer bytes than the table
-    all-reduce.  Shared verbatim by the planner annotation (which
-    passes the estimate's ``source`` — connector stats vs recorded
-    history) and the mesh runtime (which calls it with stage 1's
-    OBSERVED group count, the default source label)."""
-    if max_table is None:
-        from .. import session_properties as SP
-
-        max_table = SP.prop_value({}, "global_hash_agg_max_table")
-    if override == "EXCHANGE":
-        return "exchange", "forced by aggregation_strategy"
-    if override == "GLOBAL_HASH":
-        return "global-hash", "forced by aggregation_strategy"
-    table = 2 * max(int(ndv_estimate), 1)
-    if table <= max_table:
-        return "global-hash", (f"~{ndv_estimate:.0f} groups -> table "
-                               f"{table} <= {max_table} over "
-                               f"{n_devices} device(s), "
-                               f"source={source}")
-    return "exchange", (f"~{ndv_estimate:.0f} groups -> table {table} "
-                        f"> {max_table}, source={source}")
-
-
-def annotate_kernel_strategies(node: PlanNode, metadata: Metadata,
-                               session=None, hbo=None,
-                               calc=None) -> List[tuple]:
-    """Post-optimization pass: stamp every JoinNode with the probe
-    strategy and every grouped AggregationNode with the merge shape the
-    cost model picks, honoring the session overrides.  ``hbo`` feeds
-    recorded per-node actuals into the StatsCalculator, so observed
-    build-side cardinality and live group counts beat connector
-    guesses; every node additionally carries ``est_rows``/``est_source``
-    so EXPLAIN can annotate where each estimate came from.  Returns
-    (rule, detail) trace entries for EXPLAIN's provenance block."""
-    from .. import session_properties as SP
-    from .stats import StatsCalculator
-
-    if session is not None:
-        join_override = SP.value(session, "join_strategy")
-        agg_override = SP.value(session, "aggregation_strategy")
-        max_range = SP.value(session, "matmul_join_max_key_range")
-        max_table = SP.value(session, "global_hash_agg_max_table")
-    else:
-        join_override = agg_override = "AUTOMATIC"
-        max_range = SP.prop_value({}, "matmul_join_max_key_range")
-        max_table = SP.prop_value({}, "global_hash_agg_max_table")
-    if calc is None:
-        calc = StatsCalculator(metadata, history=hbo)
+def annotate_estimates(node: PlanNode, hbo, calc) -> List[tuple]:
+    """Post-optimization pass over the final plan when history-based
+    statistics are in play (``hbo`` is the statement's HboContext,
+    ``calc`` a StatsCalculator over it): every node carries
+    ``est_rows``/``est_source`` so EXPLAIN can say where each estimate
+    came from, and a join whose build spilled partitions on its last
+    run carries that record as ``hybrid_hint``.  Returns (rule, detail)
+    trace entries for EXPLAIN's provenance block."""
     trace: List[tuple] = []
 
     def walk(n: PlanNode):
         for s in n.sources:
             walk(s)
-        if hbo is not None:
-            st = calc.stats(n)
-            n.est_rows, n.est_source = st.row_count, st.source
+        st = calc.stats(n)
+        n.est_rows, n.est_source = st.row_count, st.source
         if isinstance(n, JoinNode):
-            spill_hint = hbo.spill_hint(hbo.fp(n)) \
-                if hbo is not None else None
-            strat, detail = choose_join_strategy(
-                n, calc, join_override, max_range,
-                will_spill=bool(spill_hint))
-            n.strategy, n.strategy_detail = strat, detail
-            if strat == "matmul":
-                trace.append(("MatmulJoinStrategy", detail))
+            spill_hint = hbo.spill_hint(hbo.fp(n))
             if spill_hint is not None:
                 # plain attribute (like est_rows): rides to the local
                 # planner without touching the node's fingerprint, so
@@ -471,21 +338,6 @@ def annotate_kernel_strategies(node: PlanNode, metadata: Metadata,
                               f"fanout={spill_hint.get('fanout')} "
                               f"fraction={spill_hint.get('fraction')} "
                               f"source=hbo"))
-        elif isinstance(n, AggregationNode) and n.group_keys:
-            st = calc.stats(n)
-            if not st.confident and agg_override == "AUTOMATIC":
-                # no trustworthy group-count estimate: keep the
-                # exchange shape rather than stamping a detail derived
-                # from the DEFAULT_ROWS placeholder (the join rule
-                # gates on confidence the same way)
-                n.strategy, n.strategy_detail = "exchange", ""
-                return
-            strat, detail = choose_agg_strategy(st.row_count, 1,
-                                                agg_override, max_table,
-                                                source=st.source)
-            n.strategy, n.strategy_detail = strat, detail
-            if strat == "global-hash":
-                trace.append(("GlobalHashAggStrategy", detail))
 
     walk(node)
     return trace
@@ -503,7 +355,7 @@ def _replace_source(node: PlanNode, src: PlanNode) -> PlanNode:
 
 
 #: fingerprint-neutral annotation attrs stamped onto final plan nodes
-#: (annotate_kernel_strategies, ExchangePlanner's distribution choice);
+#: (annotate_estimates, ExchangePlanner's distribution choice);
 #: a structural rebuild must carry them or the fragmenter would strip
 #: EXPLAIN provenance from every node above an exchange cut
 _ANNOTATION_ATTRS = ("est_rows", "est_source", "distribution",
@@ -529,12 +381,10 @@ def _rebuild_with_sources(node: PlanNode,
     if isinstance(node, AggregationNode):
         return AggregationNode(sources[0], node.group_keys,
                                node.aggregations, node.step,
-                               node.state_symbols, node.strategy,
-                               node.strategy_detail)
+                               node.state_symbols)
     if isinstance(node, JoinNode):
         return JoinNode(node.join_type, sources[0], sources[1],
-                        node.criteria, node.filter_expr, node.strategy,
-                        node.strategy_detail)
+                        node.criteria, node.filter_expr)
     if isinstance(node, CrossJoinNode):
         return CrossJoinNode(sources[0], sources[1])
     if isinstance(node, SortNode):

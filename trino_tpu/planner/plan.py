@@ -114,10 +114,6 @@ class AggregationNode(PlanNode):
     aggregations: List[Tuple[Symbol, Aggregation]]
     step: str = "single"  # single | partial | final
     state_symbols: Optional[List[Symbol]] = None
-    #: merge-shape strategy ('exchange' | 'global-hash') from the cost
-    #: model — the device-mesh path consults the same rule at run time
-    strategy: str = "exchange"
-    strategy_detail: str = ""
 
     @property
     def sources(self):
@@ -143,12 +139,6 @@ class JoinNode(PlanNode):
     right: PlanNode
     criteria: List[Tuple[Symbol, Symbol]]
     filter_expr: Optional[RowExpression] = None
-    #: probe-kernel strategy ('sorted-index' | 'matmul'), set by the
-    #: cost model (optimizer.annotate_kernel_strategies) and read by
-    #: the local planner; ``strategy_detail`` is the estimate that
-    #: picked it (EXPLAIN surface)
-    strategy: str = "sorted-index"
-    strategy_detail: str = ""
 
     @property
     def sources(self):
@@ -523,19 +513,11 @@ def plan_tree_str(node: PlanNode, indent: int = 0) -> str:
                   ", ".join(f"{s.name}:={a.function}"
                             f"({a.argument.name if a.argument else '*'})"
                             for s, a in node.aggregations))
-        if node.strategy != "exchange":
-            detail += f" strategy={node.strategy}"
-            if node.strategy_detail:
-                detail += f" [{node.strategy_detail}]"
     elif isinstance(node, JoinNode):
         detail = f" {node.join_type} on " + ", ".join(
             f"{l.name}={r.name}" for l, r in node.criteria)
         if node.filter_expr is not None:
             detail += f" filter {node.filter_expr!r}"
-        if node.strategy != "sorted-index":
-            detail += f" strategy={node.strategy}"
-            if node.strategy_detail:
-                detail += f" [{node.strategy_detail}]"
         # exchange planning's broadcast-vs-partitioned choice, with the
         # estimate source that decided it (hbo = observed build rows or
         # a spill-hinted build refusing broadcast)
@@ -559,7 +541,7 @@ def plan_tree_str(node: PlanNode, indent: int = 0) -> str:
                       for o in node.orderings))
     elif isinstance(node, OutputNode):
         detail = f" {node.column_names}"
-    # estimate provenance (annotate_kernel_strategies stamps these when
+    # estimate provenance (optimizer.annotate_estimates stamps these when
     # history-based statistics are in play): only hbo-sourced estimates
     # render, so plans without history keep today's byte-exact text
     if getattr(node, "est_source", None) == "hbo":

@@ -43,8 +43,7 @@ class PlanStats:
     """Per-node estimate (reference: cost/PlanNodeStatsEstimate.java).
     ``source`` names what produced the row count: ``connector``
     (statistics-derived guesses) or ``hbo`` (recorded runtime history
-    overrode the estimate) — EXPLAIN and the strategy details surface
-    it per estimate."""
+    overrode the estimate) — EXPLAIN surfaces it per estimate."""
 
     row_count: float = DEFAULT_ROWS
     symbols: Dict[str, SymbolStats] = field(default_factory=dict)

@@ -52,15 +52,9 @@ hot_partition_split_threshold,
 scale_writers_enabled
 rebalance_min_collectives                  parallel/distributed.py,
                                            parallel/worker.py
-join_strategy, aggregation_strategy        planner/optimizer.py
-matmul_join_max_key_range                  planner/optimizer.py,
-                                           exec/local_planner.py
 hybrid_join_enabled,                       exec/local_planner.py
 hybrid_join_fanout,                        (grouping_options)
 hybrid_join_max_depth
-global_hash_agg_max_table                  planner/optimizer.py
-                                           (mesh runtime via
-                                           choose_agg_strategy default)
 plan_cache_enabled, plan_cache_entries,    runner.py
 result_cache_enabled
 admission_batching_enabled,                server/protocol.py
@@ -356,23 +350,6 @@ register(SessionProperty(
     "runner; zero-cost when off (no-op spans, nothing shipped), and "
     "spans are never opened inside jit'd code"))
 register(SessionProperty(
-    "join_strategy", "varchar", "AUTOMATIC",
-    "Join probe kernel: AUTOMATIC (cost model picks from build NDV/"
-    "range stats) | SORTED_INDEX (sorted build index, probed by direct "
-    "address or binary search as the build's key range allows) | "
-    "MATMUL (blocked one-hot matmul over the dense key domain — the "
-    "MXU-native low-NDV path; infeasible builds fall back per build, "
-    "reason in EXPLAIN ANALYZE)",
-    lambda v: v in ("AUTOMATIC", "SORTED_INDEX", "MATMUL"),
-    normalize=str.upper))
-register(SessionProperty(
-    "matmul_join_max_key_range", "integer", 1024,
-    "Densest key domain the matmul join strategy will one-hot encode "
-    "(per-probe-row MACs); AUTOMATIC picks matmul only when the "
-    "build key range/pool size estimate fits (the measured low-NDV "
-    "win region — BENCH_ROLE=kernels reports the crossover)",
-    lambda v: v >= 2))
-register(SessionProperty(
     "hybrid_join_enabled", "boolean", True,
     "Dynamic hybrid hash join: a join build under memory pressure "
     "partitions by a splitmix64 key sub-hash, keeps hot partitions "
@@ -395,21 +372,6 @@ register(SessionProperty(
     "still exceeds the pool (each level quarters it); at the bound "
     "the partition joins anyway and may legitimately exceed the pool",
     lambda v: v >= 1))
-register(SessionProperty(
-    "aggregation_strategy", "varchar", "AUTOMATIC",
-    "Distributed GROUP BY merge shape: AUTOMATIC (cost model picks "
-    "from group-count estimates) | EXCHANGE (all_to_all of partial "
-    "groups + per-device merge-final) | GLOBAL_HASH (one replicated "
-    "device-resident table updated by collective scatter-add — the "
-    "low-NDV path of 'Global Hash Tables Strike Back!')",
-    lambda v: v in ("AUTOMATIC", "EXCHANGE", "GLOBAL_HASH"),
-    normalize=str.upper))
-register(SessionProperty(
-    "global_hash_agg_max_table", "integer", 16384,
-    "Largest global-hash aggregation table (slots, 2x the group-count "
-    "bound) AUTOMATIC will pick; past it the exchange+merge-final "
-    "shape moves fewer bytes than the table all-reduce",
-    lambda v: v >= 16))
 register(SessionProperty(
     "plan_cache_enabled", "boolean", True,
     "Cache analysis->plan->optimize output per normalized statement "
@@ -517,7 +479,7 @@ register(SessionProperty(
     "plan-node actuals (rows/bytes/peak memory/wall/flops) after every "
     "executed query, keyed by (statement shape, canonical node "
     "fingerprint), and let recorded history beat connector estimates "
-    "in the join/agg strategy rules, adaptive partial-agg seeding, "
+    "in join ordering and distribution, adaptive partial-agg seeding, "
     "admission sizing, and progress fallback. EXPLAIN annotates "
     "source=hbo per overridden estimate; a material misestimate on a "
     "decision node invalidates cached plans of the shape so the next "
@@ -560,10 +522,9 @@ register(SessionProperty(
     "(per_dest): EXACT = count-first pass (tiny counting collective, "
     "zero overflow retries by construction); HISTORY = EWMA of observed "
     "loads per exchange shape pre-sizes repeat shapes and skips the "
-    "count pass, falling back to EXACT until confident; LEGACY = "
-    "capacity guess with the doubling-retry overflow protocol (the 2x "
-    "re-shuffle cliff under skew)",
-    lambda v: v in ("exact", "history", "legacy"),
+    "count pass, falling back to EXACT until confident (a history "
+    "guess that still overflows re-runs at twice the capacity)",
+    lambda v: v in ("exact", "history"),
     normalize=str.lower))
 register(SessionProperty(
     "partial_stage_retry", "boolean", False,

@@ -24,8 +24,8 @@ node ACTUALLY produced and serves it back to every cost rule:
   (never a silent half-load).
 
 Consumers: ``planner.stats.StatsCalculator`` (history beats connector
-estimates — ``PlanStats.source`` says which won), the join/agg strategy
-rules, adaptive partial aggregation seeding, admission/retry memory
+estimates — ``PlanStats.source`` says which won), join reordering and
+distribution, adaptive partial aggregation seeding, admission/retry memory
 sizing, live-progress fallback, ``system.runtime.plan_stats``, and the
 ``trino_hbo_*`` metric families.
 
@@ -86,11 +86,9 @@ def snapshot_key(snapshot_fp) -> str:
     return repr(snapshot_fp)
 
 
-#: plan-node fields the fingerprint must NOT see: the strategy fields
-#: are what history itself flips (a flip must not orphan the history
-#: that caused it), and partial-step state symbols are an exchange-
-#: planning artifact
-_SKIP_NODE_FIELDS = {"strategy", "strategy_detail", "state_symbols"}
+#: plan-node fields the fingerprint must NOT see: partial-step state
+#: symbols are an exchange-planning artifact
+_SKIP_NODE_FIELDS = {"state_symbols"}
 
 #: aggregation/ranking step canonicalization: exchange planning splits
 #: a ``single`` node into ``partial`` + ``final`` AFTER the optimizer
@@ -500,7 +498,7 @@ class RuntimeStatsStore:
         """Bounded, JSON-safe snapshot of the MOST RECENT statements —
         the coordinator piggybacks this on worker ``configure()`` so
         worker-local planning decisions (adaptive partial-agg seeding,
-        local strategy picks) see the same cardinalities the
+        hybrid join fan-out) see the same cardinalities the
         coordinator planned from. Bounded by recency, not size-on-
         disk: a replacement worker spawned mid-life gets the freshest
         history, and the RPC payload stays small."""
