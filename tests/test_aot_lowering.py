@@ -222,6 +222,23 @@ def test_join_kernels_compile_for_v5e(one_chip):
              one_chip, sds((PAGE,), jnp.int64), sds((PAGE,), jnp.int64))
 
 
+def test_fact_table_build_compiles_for_v5e(one_chip):
+    """q21's builds at SF1: ``lineitem`` whole (6.0 M rows in 23 pages
+    of 262,144 lanes, padded to 2^23) with the two columns a residual
+    ``l_suppkey <> l1.l_suppkey`` needs; sixteen times q3's build, and
+    well inside the chip's 16 GB."""
+    from trino_tpu.ops.join import _build_sorted
+
+    build = 1 << 23
+    u64 = sds((build,), jnp.uint64)
+    flag = sds((build,), jnp.bool_)
+    cols = (sds((build,), jnp.int64), sds((build,), jnp.int64))
+    memory = _compile(_build_sorted.jit, one_chip, u64, flag, cols,
+                      (flag,) * 2, flag).memory_analysis()
+    assert memory.temp_size_in_bytes + memory.argument_size_in_bytes \
+        + memory.output_size_in_bytes < 1 << 30
+
+
 def test_join_expansion_compiles_without_a_loop_for_v5e(one_chip):
     """What the chip runs for a probe page's expansion (PR 38): the
     histogram's scatter and two prefix sums, no ``while`` — at a

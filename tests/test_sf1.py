@@ -1,7 +1,7 @@
 """SF1-scale correctness (slow; run with ``pytest -m slow``).
 
 Reference analog: the benchto/TPC-H suites run at real scale factors —
-these tests run q1/q3/q6/q13/q18 at SF1 (6M lineitem rows) against
+these tests run q1/q3/q6/q9/q13/q18/q21 at SF1 (6M lineitem rows) against
 expected values computed ONCE by a sqlite oracle over the same generated
 data (``tests/sf1_expected.py``; regenerate with the script in that
 file's history if the generator changes).  This is the scale gate the

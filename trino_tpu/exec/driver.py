@@ -63,6 +63,11 @@ class OperatorStats:
     #: the local planner when history-based statistics are recording;
     #: telemetry.stats_store keys actuals by it) — None outside HBO
     node_fp: Optional[str] = None
+    #: the operator sits between a scan that a dynamic filter masked
+    #: and its chain's first join (set with ``metrics``): its rows are
+    #: what the mask left, the count of the plan that hung the filter
+    #: there and not of the operator's node, so history files nothing
+    masked_input: bool = False
 
     def line(self) -> str:
         ms = self.wall_ns / 1e6
@@ -84,6 +89,11 @@ class OperatorStats:
                     base += f", table {m['direct_table_bytes'] / 1e6:.1f} MB"
                 if m.get("probe_fallback"):
                     base += f", sorted index: {m['probe_fallback']}"
+                if "residual_rows" in m:
+                    # a join whose key carries a residual predicate:
+                    # the candidate matches it gathered and tested
+                    base += (f", residual over {m['residual_rows']} rows "
+                             f"in {m['residual_lanes']} lanes")
                 base += "]"
             if m.get("adaptive"):
                 # the adaptive partial-agg decision (pass-through or
@@ -326,6 +336,15 @@ class Driver:
             peak = getattr(ctx, "peak", 0) if ctx is not None else 0
             if peak:
                 st.metrics = dict(st.metrics or {}, peak_bytes=peak)
+        masked = False
+        for st in self.stats:
+            m = st.metrics or {}
+            if "rows_read" in m:            # the chain's scan
+                masked = m["rows_read"] > st.output_rows
+            elif "join_type" in m:
+                masked = False
+            else:
+                st.masked_input = masked
 
     def blocked_tokens(self) -> List:
         """Listen tokens of currently-blocked operators. Meaningful

@@ -1228,6 +1228,11 @@ class LookupJoinOperator(Operator):
                "direct_probe_pages": self._direct_pages,
                "expand_lanes": self._expand_lanes,
                "expand_rows": self._expand_rows}
+        if self.filter_fn is not None:
+            # a residual predicate on the key: every expansion's lanes
+            # were gathered from both sides and run through it
+            out["residual_lanes"] = self._expand_lanes
+            out["residual_rows"] = self._expand_rows
         if self._direct_table_bytes:
             out["direct_table_bytes"] = self._direct_table_bytes
         elif self._probe_fallback:

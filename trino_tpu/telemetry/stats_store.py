@@ -694,10 +694,22 @@ class HboContext:
         (summed across tasks — every task of a stage runs the same
         chain, so same-fp entries are shards of one plan node)."""
         by_fp: Dict[str, dict] = {}
+        unfiled = set()
         for st in op_stats:
             fp = getattr(st, "node_fp", None)
             if not fp:
                 continue
+            if getattr(st, "masked_input", False):
+                # the rows a dynamic filter's mask left (``exec/driver.
+                # py``).  Filed, the node reads small while it is probed
+                # and whole once it is the build side, and the join
+                # order swings between the two (q21's filter on
+                # orders); the node keeps what it read unmasked, or the
+                # connector's estimate (one masked shard of a node
+                # unfiles the others')
+                unfiled.add(fp)
+                continue
+            metrics = getattr(st, "metrics", None) or {}
             cur = by_fp.get(fp)
             if cur is None:
                 cur = by_fp[fp] = {
@@ -707,24 +719,17 @@ class HboContext:
             # a scan's actual is what it read, not what a dynamic
             # filter's mask left of it: the node's fingerprint knows
             # nothing of the filters one plan hung on it
-            cur["rows"] += (getattr(st, "metrics", None) or {}).get(
-                "rows_read", st.output_rows)
+            cur["rows"] += metrics.get("rows_read", st.output_rows)
             cur["bytes"] += getattr(st, "device_bytes", 0.0) or 0.0
             cur["wall_ms"] += st.wall_ns / 1e6
             cur["flops"] += getattr(st, "flops", 0.0) or 0.0
-            peak = (st.metrics or {}).get("peak_bytes") \
-                if getattr(st, "metrics", None) else None
-            if peak:
-                cur["peak_bytes"] += peak
-            verdict = (st.metrics or {}).get("adaptive_verdict") \
-                if getattr(st, "metrics", None) else None
-            if verdict is not None:
-                cur["adaptive"] = verdict
-            hspill = (st.metrics or {}).get("hybrid_spill") \
-                if getattr(st, "metrics", None) else None
-            if hspill is not None:
-                cur["spill"] = hspill
-        return list(by_fp.values())
+            if metrics.get("peak_bytes"):
+                cur["peak_bytes"] += metrics["peak_bytes"]
+            if metrics.get("adaptive_verdict") is not None:
+                cur["adaptive"] = metrics["adaptive_verdict"]
+            if metrics.get("hybrid_spill") is not None:
+                cur["spill"] = metrics["hybrid_spill"]
+        return [a for fp, a in by_fp.items() if fp not in unfiled]
 
     def record(self, root, metadata, op_stats: Iterable,
                peak_bytes: float = 0.0, scan_rows: float = 0.0,

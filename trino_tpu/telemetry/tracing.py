@@ -522,6 +522,7 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
                         "join_type", "probe_pages", "direct_probe_pages",
                         "direct_table_bytes", "probe_fallback",
                         "probe_lanes", "expand_lanes", "expand_rows",
+                        "residual_lanes", "residual_rows",
                         "key_mode", "build_lanes"):
                 if st.metrics.get(key) is not None:
                     span["attrs"][key] = st.metrics[key]
