@@ -204,39 +204,40 @@ def test_q1_device_step_compiles_for_v5e(one_chip):
 
 def test_join_kernels_compile_for_v5e(one_chip):
     """Sorted-index join at q3-SF1 sizes: 1.5 M ``orders`` build rows
-    padded to 2^21, one 65,536-row probe page."""
+    padded to 2^21 (the index alone is sorted, whatever the build's
+    columns), one 65,536-row probe page."""
     from trino_tpu.ops.join import (_build_sorted, _expand_matches,
                                     _probe_counts)
 
     build = 1 << 21
     u64 = sds((build,), jnp.uint64)
     flag = sds((build,), jnp.bool_)
-    cols = (sds((build,), jnp.int64), sds((build,), jnp.int32),
-            sds((build,), jnp.int32))
-    _compile(_build_sorted.jit, one_chip, u64, flag, cols,
-             (flag,) * 3, flag)
-    _compile(_probe_counts.jit, one_chip, u64, flag,
+    _compile(_build_sorted.jit, one_chip, u64, flag, flag)
+    _compile(_probe_counts.jit, one_chip, u64,
              sds((PAGE,), jnp.uint64), sds((PAGE,), jnp.bool_))
-    _compile(lambda lo, count: _expand_matches.jit(lo, count,
-                                                   out_cap=2 * PAGE),
-             one_chip, sds((PAGE,), jnp.int64), sds((PAGE,), jnp.int64))
+    _compile(lambda lo, count, perm: _expand_matches.jit(
+        lo, count, perm, out_cap=2 * PAGE),
+             one_chip, sds((PAGE,), jnp.int64), sds((PAGE,), jnp.int64),
+             sds((build,), jnp.int32))
 
 
 def test_fact_table_build_compiles_for_v5e(one_chip):
     """q21's builds at SF1: ``lineitem`` whole (6.0 M rows in 23 pages
-    of 262,144 lanes, padded to 2^23) with the two columns a residual
-    ``l_suppkey <> l1.l_suppkey`` needs; sixteen times q3's build, and
-    well inside the chip's 16 GB."""
+    of 262,144 lanes, padded to 2^23): the index is (u64 key, int32
+    row) a lane in and out and the sort's own buffers, whatever columns
+    the residual ``l_suppkey <> l1.l_suppkey`` needs; sixteen times
+    q3's build, and well inside the chip's 16 GB."""
     from trino_tpu.ops.join import _build_sorted
 
     build = 1 << 23
     u64 = sds((build,), jnp.uint64)
     flag = sds((build,), jnp.bool_)
-    cols = (sds((build,), jnp.int64), sds((build,), jnp.int64))
-    memory = _compile(_build_sorted.jit, one_chip, u64, flag, cols,
-                      (flag,) * 2, flag).memory_analysis()
+    compiled = _compile(_build_sorted.jit, one_chip, u64, flag, flag)
+    memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes + memory.argument_size_in_bytes \
         + memory.output_size_in_bytes < 1 << 30
+    key_sorted, perm = compiled.out_info
+    assert (key_sorted.dtype, perm.dtype) == (jnp.uint64, jnp.int32)
 
 
 def test_join_expansion_compiles_without_a_loop_for_v5e(one_chip):
@@ -251,12 +252,14 @@ def test_join_expansion_compiles_without_a_loop_for_v5e(one_chip):
     from trino_tpu.ops.join import _expand_verified, _semi_matched
 
     build = sds((1 << 21,), jnp.int64)
+    perm = sds((1 << 21,), jnp.int32)
 
-    def expand(lo, count, pk, bk, out_cap):
-        return _expand_verified(lo, count, (pk,), (bk,), out_cap=out_cap)
+    def expand(lo, count, perm, pk, bk, out_cap):
+        return _expand_verified(lo, count, perm, (pk,), (bk,),
+                                out_cap=out_cap)
 
-    def semi(lo, count, pk, bk, out_cap):
-        return _semi_matched(lo, count, (pk,), (bk,), lo.shape[0],
+    def semi(lo, count, perm, pk, bk, out_cap):
+        return _semi_matched(lo, count, perm, (pk,), (bk,), lo.shape[0],
                              out_cap=out_cap)
 
     for fn, rows, out_cap in ((expand, PAGE, PAGE),
@@ -265,17 +268,18 @@ def test_join_expansion_compiles_without_a_loop_for_v5e(one_chip):
                               (semi, 1 << 18, 16)):
         idx = sds((rows,), jnp.int32)
         text = _compile(partial(fn, out_cap=out_cap), one_chip, idx, idx,
-                        sds((rows,), jnp.int64), build).as_text()
+                        perm, sds((rows,), jnp.int64), build).as_text()
         assert (" while(" in text) == (out_cap == 16)
 
 
 def test_two_column_key_join_compiles_for_v5e(one_chip):
     """q9's ``partsupp`` join at SF1 (``sf1_q9_join6``): the key is two
     bigints, so the build is ``hashed`` and has no direct-address table.
-    The 323,326 joined rows with their ten columns are sorted at 2^21
-    lanes, a resident ``partsupp`` page of 262,144 rows probes the
-    sorted index by two binary searches, and the expansion verifies
-    both raw key columns."""
+    The 323,326 joined rows are indexed at 2^21 lanes (their ten
+    columns stay where they arrived), a resident ``partsupp`` page of
+    262,144 rows probes the sorted index by two binary searches, and
+    the expansion verifies both raw key columns where ``perm`` says
+    they lie."""
     from functools import partial
 
     from trino_tpu.ops.join import (_build_sorted, _expand_verified,
@@ -284,17 +288,17 @@ def test_two_column_key_join_compiles_for_v5e(one_chip):
     build, rows = 1 << 21, 1 << 18
     u64 = sds((build,), jnp.uint64)
     flag = sds((build,), jnp.bool_)
-    cols = (sds((build,), jnp.int64),) * 8 + (sds((build,), jnp.int32),) * 2
-    _compile(_build_sorted.jit, one_chip, u64, flag, cols,
-             (flag,) * 10, flag)
-    _compile(_probe_counts.jit, one_chip, u64, flag,
+    _compile(_build_sorted.jit, one_chip, u64, flag, flag)
+    _compile(_probe_counts.jit, one_chip, u64,
              sds((rows,), jnp.uint64), sds((rows,), jnp.bool_))
     idx = sds((rows,), jnp.int32)
     key, bkey = sds((rows,), jnp.int64), sds((build,), jnp.int64)
     text = _compile(
-        partial(lambda lo, count, p0, p1, b0, b1, out_cap: _expand_verified(
-            lo, count, (p0, p1), (b0, b1), out_cap=out_cap), out_cap=rows),
-        one_chip, idx, idx, key, key, bkey, bkey).as_text()
+        partial(lambda lo, count, perm, p0, p1, b0, b1, out_cap:
+                _expand_verified(lo, count, perm, (p0, p1), (b0, b1),
+                                 out_cap=out_cap), out_cap=rows),
+        one_chip, idx, idx, sds((build,), jnp.int32), key, key, bkey,
+        bkey).as_text()
     assert " while(" not in text
 
 
@@ -312,10 +316,11 @@ def test_join_expansion_at_the_matches_width_compiles_for_v5e(one_chip):
     rows, out_cap, build = 1 << 18, 1 << 14, 1 << 19
     idx = sds((rows,), jnp.int32)
     text = _compile(
-        partial(lambda lo, count, pk, bk, out_cap: _expand_verified(
-            lo, count, (pk,), (bk,), out_cap=out_cap), out_cap=out_cap),
-        one_chip, idx, idx, sds((rows,), jnp.int64),
-        sds((build,), jnp.int64)).as_text()
+        partial(lambda lo, count, perm, pk, bk, out_cap: _expand_verified(
+            lo, count, perm, (pk,), (bk,), out_cap=out_cap),
+            out_cap=out_cap),
+        one_chip, idx, idx, sds((build,), jnp.int32),
+        sds((rows,), jnp.int64), sds((build,), jnp.int64)).as_text()
     assert " while(" not in text
     pcols = (sds((rows,), jnp.int64),) * 5 + (sds((rows,), jnp.int32),)
     bcols = (sds((build,), jnp.int64),) * 8 + (sds((build,), jnp.int32),) * 2
@@ -340,11 +345,11 @@ def test_direct_probe_compiles_for_v5e(one_chip):
 
     build, kp, probe = 1 << 20, 1 << 23, 1 << 19
     u64 = sds((build,), jnp.uint64)
-    flag = sds((build,), jnp.bool_)
     span = sds((3,), jnp.uint64)
-    _compile(_key_span, one_chip, u64, flag)
-    _compile(lambda k, u, s: _build_direct_offsets.jit(k, u, s, kp=kp),
-             one_chip, u64, flag, span)
+    perm = sds((build,), jnp.int32)
+    _compile(_key_span, one_chip, u64, perm)
+    _compile(lambda k, s: _build_direct_offsets.jit(k, s, kp=kp),
+             one_chip, u64, span)
     _compile(_probe_direct_counts.jit, one_chip, sds((kp,), jnp.int32),
              span, sds((probe,), jnp.uint64), sds((probe,), jnp.bool_))
 

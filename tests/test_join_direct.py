@@ -68,7 +68,7 @@ def _both_lookups(types_, key_channels, build_cols, probe_cols):
     b = bridge.build
     assert b.direct is not None, b.direct_fallback
     pkey, pusable = _probe_keys(types_, key_channels, bridge, probe_cols)
-    want = J._probe_counts(b.key_sorted, b.usable_sorted, pkey, pusable)
+    want = J._probe_counts(b.key_sorted, pkey, pusable)
     got = J._probe_direct_counts(b.direct.offsets, b.direct.span, pkey,
                                  pusable)
     return got, want, np.asarray(pusable), np.asarray(pkey)
@@ -456,7 +456,7 @@ def test_table_is_reserved_beside_the_build(pool_bytes, table):
     ctx = pool.create_context("join-build")
     bridge, _ = _publish(types_, (0,), build_cols, memory_context=ctx)
     b = bridge.build
-    retained = 256 * (10 + 2 * 9)
+    retained = 256 * (13 + 2 * 9)    # key 8 + perm 4 + valid 1
     if table:
         assert b.direct.nbytes == 4 * 4096
         assert ctx.reserved == retained + b.direct.nbytes
@@ -541,8 +541,7 @@ def test_one_padded_length_compiles_one_probe_program():
                                     [probe, _payload(512)])
         got = J._probe_direct_counts(b.direct.offsets, b.direct.span,
                                      pkey, pusable)
-        want = J._probe_counts(b.key_sorted, b.usable_sorted, pkey,
-                               pusable)
+        want = J._probe_counts(b.key_sorted, pkey, pusable)
         np.testing.assert_array_equal(np.asarray(got[0]),
                                       np.asarray(want[0]))
         np.testing.assert_array_equal(np.asarray(got[1]),

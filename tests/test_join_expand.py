@@ -129,13 +129,23 @@ def _inputs(name, dtype):
     return lo.astype(dtype), count.astype(dtype), out_cap
 
 
+def _ident(lo, out_cap):
+    """A build whose rows arrived sorted, wide enough for every lane's
+    sorted position, dead lanes' too: ``perm`` is the identity, so the
+    expansion's arrival rows ARE the positions the oracles compute
+    (a build that arrived in another order: ``test_join_build_index``)."""
+    return jnp.arange(int(np.max(np.asarray(lo))) + out_cap + 1,
+                      dtype=jnp.int32)
+
+
 @pytest.mark.parametrize("dtype", [np.int32, np.int64],
                          ids=["int32", "int64"])
 @pytest.mark.parametrize("name", CASES)
 def test_live_lanes_equal_numpy_reference(name, dtype):
     lo, count, out_cap = _inputs(name, dtype)
     probe_idx, build_idx, lane_valid = J._expand_matches(
-        jnp.asarray(lo), jnp.asarray(count), out_cap=out_cap)
+        jnp.asarray(lo), jnp.asarray(count), _ident(lo, out_cap),
+        out_cap=out_cap)
     want_probe, want_build, total = _numpy_reference(lo, count, out_cap)
     live = min(total, out_cap)
     assert want_probe.shape[0] == live
@@ -152,7 +162,7 @@ def test_all_lanes_equal_the_search(name, dtype):
     """Dead lanes too: same dtypes, same bits as the replaced function."""
     lo, count, out_cap = _inputs(name, dtype)
     got = J._expand_matches(jnp.asarray(lo), jnp.asarray(count),
-                            out_cap=out_cap)
+                            _ident(lo, out_cap), out_cap=out_cap)
     want = jax.jit(partial(_search_oracle, out_cap=out_cap))(
         jnp.asarray(lo), jnp.asarray(count))
     for g, w in zip(got, want):
@@ -168,7 +178,7 @@ def test_mixed_dtypes_as_the_direct_probe_gives_them():
     for lo_t, count_t in ((np.int64, np.int32), (np.int32, np.int64)):
         got = J._expand_matches(jnp.asarray(lo.astype(lo_t)),
                                 jnp.asarray(count.astype(count_t)),
-                                out_cap=out_cap)
+                                _ident(lo, out_cap), out_cap=out_cap)
         for g, w in zip(got, want):
             assert g.dtype == w.dtype
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
@@ -178,7 +188,8 @@ def test_mixed_dtypes_as_the_direct_probe_gives_them():
 #
 # ``exec/batched.py`` runs ``_expand_verified_impl`` / ``_semi_matched_impl``
 # as jit(vmap(lane, in_axes=(0, None))): the probe page, ``lo`` and
-# ``count`` stacked over the batch, the build's key columns shared.
+# ``count`` stacked over the batch, the build's ``perm`` and key columns
+# shared.
 
 _BATCH = ["zeros_between_matches", "all_zero_page",
           "overflow_total_over_out_cap", "one_row_over_out_cap"]
@@ -202,10 +213,14 @@ def _batch(rows=512, out_cap=256, build=4096):
             jnp.asarray(np.stack(pkeys)), jnp.asarray(bkey), out_cap)
 
 
+_PERM = jnp.arange(4096, dtype=jnp.int32)   # _batch's build, as it sorted
+
+
 def test_expand_matches_under_vmap_equals_each_lane():
     lo, count, _, _, out_cap = _batch()
     got = jax.jit(jax.vmap(partial(J._expand_matches_impl,
-                                   out_cap=out_cap)))(lo, count)
+                                   out_cap=out_cap),
+                           in_axes=(0, 0, None)))(lo, count, _PERM)
     for b in range(lo.shape[0]):
         want = _search_oracle(lo[b], count[b], out_cap)
         for g, w in zip(got, want):
@@ -223,11 +238,12 @@ def test_expand_verified_under_the_batched_axes():
 
     def lane(batched, shared):
         lo, count, pkey = batched
-        return J._expand_verified_impl(lo, count, (pkey,), shared,
+        perm, bkeys = shared
+        return J._expand_verified_impl(lo, count, perm, (pkey,), bkeys,
                                        out_cap=out_cap)
 
     got = jax.jit(jax.vmap(lane, in_axes=(0, None)))(
-        (lo, count, pkey), (bkey,))
+        (lo, count, pkey), (_PERM, (bkey,)))
     kept = 0
     for b in range(lo.shape[0]):
         want = _verified_by_search(lo[b], count[b], pkey[b], bkey,
@@ -244,11 +260,12 @@ def test_semi_matched_under_the_batched_axes():
 
     def lane(batched, shared):
         lo, count, pkey = batched
-        return J._semi_matched_impl(lo, count, (pkey,), shared,
+        perm, bkeys = shared
+        return J._semi_matched_impl(lo, count, perm, (pkey,), bkeys,
                                     probe_cap=rows, out_cap=out_cap)
 
     got = np.asarray(jax.jit(jax.vmap(lane, in_axes=(0, None)))(
-        (lo, count, pkey), (bkey,)))
+        (lo, count, pkey), (_PERM, (bkey,))))
     assert got.any()
     for b in range(lo.shape[0]):
         probe_idx, _, keep = _verified_by_search(
@@ -275,6 +292,7 @@ def _tpu_text(fn, *args):
 
 def _q3_args():
     return (_sds((_PAGE,), jnp.int32), _sds((_PAGE,), jnp.int32),
+            _sds((_BUILD,), jnp.int32),
             (_sds((_PAGE,), jnp.int64),), (_sds((_BUILD,), jnp.int64),))
 
 
@@ -301,7 +319,7 @@ def test_semi_matched_lowers_without_a_loop(out_cap):
 def test_a_narrow_expansion_keeps_the_search(out_cap):
     """The shape test's other side: no scatter over the page's rows."""
     text = _tpu_text(partial(J._expand_matches, out_cap=out_cap),
-                     *_q3_args()[:2])
+                     *_q3_args()[:3])
     assert "stablehlo.while" in text
     assert "stablehlo.scatter" not in text
 

@@ -490,20 +490,20 @@ class _AggAccumulator:
 def _join_probe_lane(key_channels: Tuple, key_pooled: Tuple,
                      key_types: Tuple, key_mode: str):
     """One lane's candidate ranges against the SHARED sorted build
-    index (build arrays broadcast via ``in_axes=None``). Pooled probe
+    keys (build arrays broadcast via ``in_axes=None``). Pooled probe
     keys remap into the build's code space through the same LUT the
     serial ``_probe_key_cols`` builds; masked probe rows count 0."""
 
     def lane(batched, shared):
         cols, nulls, valid = batched
-        remap_luts, bkeys, busable = shared
+        remap_luts, bkeys = shared
         pkey_cols = [remap_luts[i][cols[c]] if key_pooled[i] else cols[c]
                      for i, c in enumerate(key_channels)]
         pkey, panynull = _key_u64(
             pkey_cols, [nulls[c] for c in key_channels], list(key_types),
             key_mode)
         pusable = valid & ~panynull if panynull is not None else valid
-        lo, count = _probe_counts_impl(bkeys, busable, pkey, pusable)
+        lo, count = _probe_counts_impl(bkeys, pkey, pusable)
         return lo, count
 
     return lane
@@ -511,22 +511,23 @@ def _join_probe_lane(key_channels: Tuple, key_pooled: Tuple,
 
 def _join_expand_lane(key_channels: Tuple, key_pooled: Tuple,
                       out_cap: int, left: bool):
-    """One inner/left lane: expand candidates at the unified capacity,
-    verify raw keys, gather the joined output (left appends the
-    unmatched-probe lanes at the end, exactly like the serial path —
-    output row order is capacity-independent, so a grown capacity
-    stays byte-equal after compaction)."""
+    """One inner/left lane: expand candidates at the unified capacity
+    (their build rows through the shared index's ``perm``, the build's
+    columns lying in arrival order), verify raw keys, gather the joined
+    output (left appends the unmatched-probe lanes at the end, exactly
+    like the serial path — output row order is capacity-independent, so
+    a grown capacity stays byte-equal after compaction)."""
 
     def lane(batched, shared):
         cols, nulls, valid, lo, count = batched
-        remap_luts, bkey_cols, bcols, bnulls = shared
+        remap_luts, perm, bkey_cols, bcols, bnulls = shared
         pkey_cols = [remap_luts[i][cols[c]] if key_pooled[i] else cols[c]
                      for i, c in enumerate(key_channels)]
-        probe_idx, build_idx, keep = _expand_verified_impl(
-            lo, count, tuple(pkey_cols), bkey_cols, out_cap=out_cap)
+        probe_idx, build_row, keep = _expand_verified_impl(
+            lo, count, perm, tuple(pkey_cols), bkey_cols, out_cap=out_cap)
         return _finalize_join_impl(
             tuple(cols), tuple(nulls), valid, bcols, bnulls,
-            probe_idx, build_idx, keep, left=left)
+            probe_idx, build_row, keep, left=left)
 
     return lane
 
@@ -537,11 +538,11 @@ def _join_semi_lane(key_channels: Tuple, key_pooled: Tuple, out_cap: int,
 
     def lane(batched, shared):
         cols, valid, lo, count = batched
-        remap_luts, bkey_cols = shared
+        remap_luts, perm, bkey_cols = shared
         pkey_cols = [remap_luts[i][cols[c]] if key_pooled[i] else cols[c]
                      for i, c in enumerate(key_channels)]
         matched = _semi_matched_impl(
-            lo, count, tuple(pkey_cols), bkey_cols,
+            lo, count, perm, tuple(pkey_cols), bkey_cols,
             probe_cap=valid.shape[0], out_cap=out_cap)
         return valid & ~matched if anti else valid & matched
 
@@ -664,7 +665,7 @@ def execute_batched(plan, param_types, bindings: Sequence[Tuple],
             "batched_join_probe", ("probe",) + cfg,
             lambda: _join_probe_lane(kc, pooled, key_types, b.key_mode))
         lo, count = probe((page.cols, page.nulls, page.valid),
-                          (remap_luts, b.key_sorted, b.usable_sorted))
+                          (remap_luts, b.key_sorted))
         # ONE deliberate host sync per probe page: the unified lane
         # capacity must be a static shape. Already-spilled lanes are
         # excluded so their (re-run serially anyway) fan-out cannot
@@ -688,7 +689,7 @@ def execute_batched(plan, param_types, bindings: Sequence[Tuple],
                 lambda: _join_semi_lane(kc, pooled, lane_cap,
                                         op.join_type == "anti"))
             new_valid = kern((page.cols, page.valid, lo, count),
-                             (remap_luts, bkey_cols))
+                             (remap_luts, b.perm, bkey_cols))
             return _BatchPage(page.types, page.cols, page.nulls,
                               new_valid, page.dicts, True)
         left = op.join_type == "left"
@@ -697,7 +698,7 @@ def execute_batched(plan, param_types, bindings: Sequence[Tuple],
             lambda: _join_expand_lane(kc, pooled, lane_cap, left))
         out_cols, out_nulls, out_valid = kern(
             (page.cols, page.nulls, page.valid, lo, count),
-            (remap_luts, bkey_cols, b.cols, b.nulls))
+            (remap_luts, b.perm, bkey_cols, b.cols, b.nulls))
         return _BatchPage(list(op.output_types), out_cols, out_nulls,
                           out_valid, list(page.dicts) + list(b.dictionaries),
                           True)

@@ -94,7 +94,14 @@ class OperatorStats:
                     # the candidate matches it gathered and tested
                     base += (f", residual over {m['residual_rows']} rows "
                              f"in {m['residual_lanes']} lanes")
-                base += "]"
+                base += (f", {m.get('build_row_lanes', 0)} build rows "
+                         "through perm]")
+            if "build_lanes" in m:
+                # the join's build: the (key, row) index's width and
+                # the columns gathered into sorted order at that width
+                base += (f" [index {m['build_lanes']} lanes, "
+                         f"{m.get('build_carried_cols', 0)} columns "
+                         "carried]")
             if m.get("adaptive"):
                 # the adaptive partial-agg decision (pass-through or
                 # per-key-range split)

@@ -6,10 +6,13 @@ PagesIndex + JoinHash open-addressing) + ``LookupJoinOperator.java`` /
 semi joins.
 
 TPU redesign: open-addressing probes are scatter/gather-chase loops that
-map poorly to XLA. Instead the build side becomes a **sorted index**: key
+map poorly to XLA. Instead the build side gets a **sorted index**: key
 columns normalize to uint64 (exact for single keys; packed or hashed for
-multi-key), ``lax.sort`` orders the build rows, and a probe looks up each
-probe row's candidate range ``(lo, count)`` in that index. Which lookup
+multi-key), one ``lax.sort`` of (key, row) orders the keys and says which
+arrival lane each sorted position came from (``perm``) — the build's
+columns are never moved: they stay in arrival order and are read through
+``perm``, at the lanes a probe page's matches take — and a probe looks up
+each probe row's candidate range ``(lo, count)`` in that index. Which lookup
 runs is read off the build, once, when it is published
 (``_attach_direct_table``): a build whose keys are exact and span a range
 the chip can hold a table over gets a **direct-address table** of offsets
@@ -58,7 +61,7 @@ from ..block import DevicePage, padded_size
 from ..telemetry.profiler import instrument
 from ..telemetry.tracing import host_read
 from .operator import Operator
-from .sortkeys import group_operands, sort_carrying
+from .sortkeys import group_operands
 
 
 def _canonical_codes(codes, dictionary):
@@ -121,19 +124,35 @@ _U64_SENTINEL = np.uint64(0xFFFFFFFFFFFFFFFF)
 
 
 @jax.jit
-def _build_sorted(key_u64, anynull, cols, nulls, valid):
-    """Sort the build rows by key; null-key or invalid lanes sort last.
-    ``valid`` rides along so FULL OUTER can emit unmatched build rows
-    (including null-key rows, which are never ``usable``)."""
+def _build_sorted(key_u64, anynull, valid):
+    """The build's index, (key_sorted, perm): ONE sort of (key, row) and
+    nothing else — no column is carried and none is gathered, the key
+    the sort hands back is the sorted key.
+
+    ``perm[i]`` is the arrival lane of sorted position i. Null-key or
+    invalid lanes are dead: they take the u64 sentinel and sort last —
+    among usable rows whose key IS the sentinel (a bigint -1), so the
+    key cannot tell them apart; the payload does: a dead lane carries
+    ``~lane`` (negative), which costs no third operand
+    (``sortkeys.sort_carrying`` on what operands cost to compile) and
+    no gather. ``perm >= 0`` is the sorted positions' usable flag,
+    ``_arrival_rows`` decodes the lane."""
     from .. import jit_stats
 
     jit_stats.bump("join_build_sorted")
-    usable = valid & ~anynull if anynull is not None else valid
-    sort_key = jnp.where(usable, key_u64, _U64_SENTINEL)
-    (s_key,), s = sort_carrying(
-        [sort_key], [usable, valid] + list(cols) + list(nulls))
-    n = len(cols)
-    return s_key, s[0], s[1], tuple(s[2:2 + n]), tuple(s[2 + n:])
+    usable = valid & ~anynull
+    row = jnp.arange(key_u64.shape[0], dtype=jnp.int32)
+    return tuple(jax.lax.sort(
+        [jnp.where(usable, key_u64, _U64_SENTINEL),
+         jnp.where(usable, row, ~row)], num_keys=1))
+
+
+def _arrival_rows(perm, build_idx):
+    """(build_row, live): the arrival lanes of sorted positions
+    ``build_idx`` — one int32 gather at the caller's lanes — and whether
+    each is a usable build row."""
+    p = perm[build_idx]
+    return jnp.where(p < 0, ~p, p), p >= 0
 
 
 # profiled entry point (telemetry.profiler): cost/compile attribution
@@ -146,8 +165,7 @@ _build_sorted = instrument("join_build_sorted", _build_sorted)
 # with the build arrays broadcast (in_axes=None), so one param-free
 # build serves every lane of a literal batch. Host callers use the
 # jitted+instrumented bindings below.
-def _probe_counts_impl(build_keys, build_usable, probe_keys,
-                       probe_usable):
+def _probe_counts_impl(build_keys, probe_keys, probe_usable):
     from .. import jit_stats
 
     jit_stats.bump("join_probe_counts")
@@ -192,11 +210,15 @@ def _lane_rows(off_end, out_cap: int):
     return jnp.minimum(ended, rows - 1).astype(jnp.int32)
 
 
-def _expand_matches_impl(lo, count, out_cap: int):
-    """Candidate pairs: output lane j -> (probe_row, build_row). The
-    row's build offset comes by one gather of ``lo - (off_end - count)``;
-    all lane arithmetic is int32 (``out_cap`` is bounded by
-    ``max_lanes``, the build by the device)."""
+def _expand_matches_impl(lo, count, perm, out_cap: int):
+    """Candidate pairs: output lane j -> (probe_row, build_row), the
+    build row as an ARRIVAL lane of the build (``perm`` of the sorted
+    position ``lo + k``), so every later read of a build column is one
+    gather of the column where it arrived. The row's build offset comes
+    by one gather of ``lo - (off_end - count)``; all lane arithmetic is
+    int32 (``out_cap`` is bounded by ``max_lanes``, the build by the
+    device). A candidate that is a dead lane of the build (reachable
+    only by a probe key at the u64 sentinel) is no lane."""
     from .. import jit_stats
 
     jit_stats.bump("join_expand_matches")
@@ -205,9 +227,9 @@ def _expand_matches_impl(lo, count, out_cap: int):
     j = jnp.arange(out_cap, dtype=jnp.int32)
     probe_idx = _lane_rows(off_end, out_cap)
     delta = (lo - (off_end - count)).astype(jnp.int32)
-    build_idx = j + delta[probe_idx]
-    lane_valid = j < total
-    return probe_idx, jnp.maximum(build_idx, 0), lane_valid
+    build_row, live = _arrival_rows(
+        perm, jnp.maximum(j + delta[probe_idx], 0))
+    return probe_idx, build_row, (j < total) & live
 
 
 _expand_matches = instrument(
@@ -220,8 +242,8 @@ _expand_matches = instrument(
 #
 # For a build whose u64 keys are exact and span [klo, khi], ``offsets[c]``
 # is the number of usable build rows with key < klo + c: exactly what
-# ``searchsorted(side="left")`` answers for key klo + c (usable rows sort
-# first, unusable ones to the sentinel, past every usable key), and
+# ``searchsorted(side="left")`` answers for key klo + c (dead lanes sort
+# to the sentinel, past every usable key below it), and
 # ``offsets[c + 1]`` is what ``side="right"`` answers.
 
 #: the most a direct-address table may take (int32 offsets, padded to a
@@ -231,10 +253,12 @@ DIRECT_TABLE_MAX_BYTES = 256 << 20
 
 
 @jax.jit
-def _key_span(key_sorted, usable_sorted):
+def _key_span(key_sorted, perm):
     """u64[3]: the usable build rows' number, least and greatest key
-    (usable rows sort first, so they are the ends of that prefix)."""
-    n = jnp.sum(usable_sorted, dtype=jnp.int32)
+    (dead lanes sort last, at the sentinel, so position n - 1 holds the
+    greatest usable key — the sentinel itself where a usable key ties
+    with them)."""
+    n = jnp.sum(perm >= 0, dtype=jnp.int32)
     return jnp.stack([n.astype(jnp.uint64), key_sorted[0],
                       key_sorted[jnp.maximum(n - 1, 0)]])
 
@@ -248,18 +272,18 @@ def _span_range(span):
 
 
 @partial(jax.jit, static_argnames=("kp",))
-def _build_direct_offsets(key_sorted, usable_sorted, span, kp: int):
+def _build_direct_offsets(key_sorted, span, kp: int):
     """int32[kp] offsets over ``key - klo`` in ONE pass over the sorted
-    build rows: a scatter-add of ones (indices ascending: the rows are
-    sorted, dead lanes go past the end and are dropped) and a cumsum.
+    keys: a scatter-add of ones (indices ascending: the keys are
+    sorted; dead lanes lie at the sentinel, past the range of a build
+    that gets a table, go past the end and are dropped) and a cumsum.
     ``kp`` > the number of codes, so ``offsets[range]`` = usable rows."""
     from .. import jit_stats
 
     jit_stats.bump("join_direct_table")
     _, klo, krange = _span_range(span)
     off = key_sorted - klo
-    live = usable_sorted & (off < krange)
-    idx = jnp.where(live, off, np.uint64(kp)).astype(jnp.int32)
+    idx = jnp.where(off < krange, off, np.uint64(kp)).astype(jnp.int32)
     cnt = jnp.zeros(kp, dtype=jnp.int32).at[idx].add(
         1, mode="drop", indices_are_sorted=True)
     return jnp.cumsum(cnt) - cnt
@@ -275,7 +299,8 @@ def _probe_direct_counts(offsets, span, probe_keys, probe_usable):
     """``_probe_counts``' (lo, count) by two gathers: bit-identical for
     every usable probe row below the u64 sentinel (at the sentinel the
     searches count the build's dead lanes as candidates, which the
-    raw-key verification then drops; the table counts none)."""
+    expansion drops by ``perm``'s sign; a build with a usable key there
+    gets no table)."""
     from .. import jit_stats
 
     jit_stats.bump("join_probe_direct")
@@ -306,9 +331,14 @@ class DirectTable:
 
 @dataclass
 class BuildSide:
+    """A published build: the index — ``key_sorted`` (u64, dead lanes at
+    the sentinel) and ``perm`` (int32: sorted position -> arrival lane,
+    ``~lane`` for a dead one; ``_build_sorted``) — over columns, null
+    masks and ``valid`` that lie in ARRIVAL order, as the builder
+    concatenated them. Nothing but the index is sorted."""
     key_sorted: "jax.Array"
-    usable_sorted: "jax.Array"
-    valid_sorted: "jax.Array"
+    perm: "jax.Array"
+    valid: "jax.Array"
     cols: Tuple
     nulls: Tuple
     types: List
@@ -335,7 +365,7 @@ def _attach_direct_table(b: BuildSide, ctx=None) -> None:
     if any(b.types[c] in (T.DOUBLE, T.REAL) for c in b.key_channels):
         b.direct_fallback = "float key"
         return
-    span = _key_span(b.key_sorted, b.usable_sorted)
+    span = _key_span(b.key_sorted, b.perm)
     n, klo, khi = (int(v) for v in host_read(span, "join_key_range"))
     if n and khi == int(_U64_SENTINEL):
         b.direct_fallback = "key at the u64 sentinel"
@@ -360,8 +390,7 @@ def _attach_direct_table(b: BuildSide, ctx=None) -> None:
         except (MemoryExceededError, NodeMemoryExceededError):
             b.direct_fallback = "memory reservation refused"
             return
-    offsets = _build_direct_offsets(b.key_sorted, b.usable_sorted, span,
-                                    kp=kp)
+    offsets = _build_direct_offsets(b.key_sorted, span, kp=kp)
     if ctx is not None:
         ctx.free(nbytes, revocable=False)
     b.direct = DirectTable(offsets, span)
@@ -570,10 +599,11 @@ def _host_spilled(types_, cols: List[np.ndarray], nulls: List[np.ndarray],
 def _assemble_build_side(input_types, key_channels, cols, nulls, valid,
                          cap: int, dicts) -> BuildSide:
     """Canonicalize key codes, pick the key mode, normalize to u64 and
-    sort: the tail of the build publish, shared by the resident index
-    and each deferred cold-partition index (the hybrid join builds one
-    per unspilled partition; the mode decision is type-static, so every
-    partition encodes identically)."""
+    sort (key, row): the tail of the build publish, shared by the
+    resident index and each deferred cold-partition index (the hybrid
+    join builds one per unspilled partition; the mode decision is
+    type-static, so every partition encodes identically). The columns
+    go into the ``BuildSide`` as they came."""
     kc = list(key_channels)
     cols = list(cols)
     # pooled keys (strings AND array/map/row composites) join on
@@ -610,19 +640,18 @@ def _assemble_build_side(input_types, key_channels, cols, nulls, valid,
         mode = "packed" if all(fits32) else "hashed"
     key, anynull = _key_u64([cols[c] for c in kc],
                             [nulls[c] for c in kc], key_types, mode)
-    ks, us, vs, scols, snulls = _build_sorted(
+    key_sorted, perm = _build_sorted(
         key, anynull if anynull is not None
-        else jnp.zeros(cap, dtype=bool), tuple(cols), tuple(nulls),
-        valid)
-    return BuildSide(ks, us, vs, scols, snulls, list(input_types),
-                     dicts, kc, mode)
+        else jnp.zeros(cap, dtype=bool), valid)
+    return BuildSide(key_sorted, perm, valid, tuple(cols), tuple(nulls),
+                     list(input_types), dicts, kc, mode)
 
 
 def _build_side_from_spilled(input_types, key_channels,
                              pages: List) -> BuildSide:
-    """One cold partition's sorted index from its parked pages: host
-    concat (disk-parked pages stream back through serde.read_spill_file
-    via host()), one upload, then the shared assembly tail."""
+    """One cold partition's index from its parked pages: host concat
+    (disk-parked pages stream back through serde.read_spill_file via
+    host()), one upload, then the shared assembly tail."""
     from ..block import unify_dictionaries
 
     hosts = [p.host() for p in pages]
@@ -641,7 +670,9 @@ def _build_side_from_spilled(input_types, key_channels,
 
 
 class HashBuilderOperator(Operator):
-    """Accumulates the build side and publishes a sorted index."""
+    """Accumulates the build side and publishes it as a ``BuildSide``:
+    its pages concatenated in arrival order, and the sorted (key, row)
+    index over them — no column is carried through the sort."""
 
     def __init__(self, input_types: Sequence[T.Type],
                  key_channels: Sequence[int], bridge: JoinBridge,
@@ -951,7 +982,9 @@ class HashBuilderOperator(Operator):
     def metrics(self) -> dict:
         """What was published: how the keys were assembled (``single``
         / ``packed`` / ``hashed`` - the last has no direct-address
-        table) and the index's width in lanes."""
+        table), the index's width in lanes and the columns gathered
+        into sorted order at that width (none: the probe translates its
+        matches' lanes instead, the join's ``build_row_lanes``)."""
         out = dict(self._published)
         hs = self._hstate
         if hs is None:
@@ -1020,8 +1053,9 @@ class HashBuilderOperator(Operator):
                     # target the fan-out sizing uses
                     continue
                 try:
-                    self._ctx.reserve(uploads + 2 * total,
-                                      revocable=False)
+                    self._ctx.reserve(
+                        uploads + total + self._index_bytes(),
+                        revocable=False)
                     break
                 except MemoryExceededError:
                     if _demote_once() <= 0:
@@ -1034,11 +1068,13 @@ class HashBuilderOperator(Operator):
             total, uploads = prepare_finish(self._ctx, self._pages)
             all_spilled = bool(self._pages) and all(
                 isinstance(p, SpilledPage) for p in self._pages)
-            # transient: concat + sorted copy, plus per-page re-uploads
-            # on the mixed path (the all-spilled path concatenates in
-            # host RAM and uploads once — no per-page residency)
-            self._ctx.reserve((2 * total if all_spilled
-                               else uploads + 2 * total), revocable=False)
+            # transient: the concatenation and the sort's two operands
+            # with its two results, plus per-page re-uploads on the
+            # mixed path (the all-spilled path concatenates in host RAM
+            # and uploads once — no per-page residency)
+            self._ctx.reserve(
+                (0 if all_spilled else uploads) + total
+                + self._index_bytes(), revocable=False)
         if self._pages:
             spilled = [p for p in self._pages if isinstance(p, SpilledPage)]
             if spilled and len(spilled) == len(self._pages):
@@ -1083,10 +1119,11 @@ class HashBuilderOperator(Operator):
             cap, dicts)
         self._pages = []  # release the input pages; only the index remains
         if self._ctx is not None:
-            # retain only the published index: sorted key (8B) + usable
-            # + valid (1B each) + per-channel data/null lanes
-            # — and its dynamic filters' membership tables
-            retained = cap * (10 + sum(c.dtype.itemsize + 1 for c in cols)) \
+            # retain what was published: the index — sorted key (8B) and
+            # perm (4B) — over valid (1B) and the per-channel data/null
+            # lanes as they arrived, and its dynamic filters' membership
+            # tables
+            retained = cap * (13 + sum(c.dtype.itemsize + 1 for c in cols)) \
                 + sum(df.table_bytes for _, df in self.dynamic_filters)
             self._ctx.close()
             self._ctx.reserve(retained, revocable=False)
@@ -1098,8 +1135,16 @@ class HashBuilderOperator(Operator):
         else:
             _attach_direct_table(build, self._ctx)
         self._published = {"key_mode": build.key_mode,
-                           "build_lanes": int(build.key_sorted.shape[0])}
+                           "build_lanes": int(build.key_sorted.shape[0]),
+                           "build_carried_cols": 0}
         self.bridge.set_build(build)
+
+    def _index_bytes(self) -> int:
+        """What sorting the accumulated pages takes beside their
+        concatenation: ``_build_sorted``'s two operands and two results,
+        (u64 key, int32 row) a lane each."""
+        cap = padded_size(sum(p.capacity for p in self._pages))
+        return 2 * cap * (8 + 4)
 
     def _collect_dynamic_filters(self, cols, nulls, valid):
         """Fill the join's dynamic filters over ALL build rows — the
@@ -1143,8 +1188,9 @@ class LookupJoinOperator(Operator):
     Output layout: all probe channels, then (inner/left/full) all build
     channels — build channels NULL on unmatched left rows. semi/anti emit
     probe channels only. FULL OUTER additionally OR-accumulates a
-    matched flag per (sorted) build row across all probe pages and, once
-    the probe side finishes, emits one final page of unmatched build rows
+    matched flag per build row (in the build's arrival order) across all
+    probe pages and, once the probe side finishes, emits one final page
+    of unmatched build rows — the build's own columns under that mask —
     with NULL probe channels (reference: LookupJoinOperator's
     OuterLookupSource / buildOuter position iterator,
     operator/join/LookupJoinOperator.java:36)."""
@@ -1195,8 +1241,9 @@ class LookupJoinOperator(Operator):
         #: the probe input finished, then [{"depth", "build", "probe"}]
         #: processed one partition per get_output call
         self._deferred: Optional[List[dict]] = None
-        # FULL OUTER state: per-sorted-build-row matched flag (device,
-        # cap+1 lanes — the last is the dead-lane sink) + the dictionary
+        # FULL OUTER state: per-build-row matched flag in the build's
+        # arrival order (device, cap+1 lanes — the last is the
+        # dead-lane sink) + the dictionary
         # pools of the last probe page (the unmatched-build page's probe
         # channels are all-NULL, but string channels still need a pool)
         self._build_matched = None
@@ -1227,7 +1274,11 @@ class LookupJoinOperator(Operator):
                "probe_lanes": self._probe_lanes,
                "direct_probe_pages": self._direct_pages,
                "expand_lanes": self._expand_lanes,
-               "expand_rows": self._expand_rows}
+               "expand_rows": self._expand_rows,
+               # every expansion lane's sorted position is translated
+               # to its arrival lane through the build's ``perm``: what
+               # the probe pays for a build that carries no column
+               "build_row_lanes": self._expand_lanes}
         if self.filter_fn is not None:
             # a residual predicate on the key: every expansion's lanes
             # were gathered from both sides and run through it
@@ -1330,8 +1381,7 @@ class LookupJoinOperator(Operator):
             self._direct_pages += 1
             return _probe_direct_counts(b.direct.offsets, b.direct.span,
                                         pkey, pusable)
-        return _probe_counts(b.key_sorted, b.usable_sorted, pkey,
-                             pusable)
+        return _probe_counts(b.key_sorted, pkey, pusable)
 
     def get_output(self):
         """The next joined page. The oldest looked-up page is expanded
@@ -1375,8 +1425,8 @@ class LookupJoinOperator(Operator):
         self._expand_rows += tot
         for *probe, lane_cap in self._chunk_units(rec, tot):
             self._expand_lanes += lane_cap
-            out, keep, bidx = self._make_out(rec["b"], *probe, lane_cap)
-            self._mark_full(keep, bidx, rec["page"].dictionaries)
+            out, keep, brow = self._make_out(rec["b"], *probe, lane_cap)
+            self._mark_full(keep, brow, rec["page"].dictionaries)
             self._ready.append(out)
 
     def _chunk_units(self, rec: dict, total: int) -> List:
@@ -1535,32 +1585,32 @@ class LookupJoinOperator(Operator):
         direct-address table."""
         page = sp.to_device()
         pkey_cols, pkey, pusable = self._probe_keys_u64(page, b)
-        lo, count = _probe_counts(b.key_sorted, b.usable_sorted, pkey,
-                                  pusable)
+        lo, count = _probe_counts(b.key_sorted, pkey, pusable)
         self._expand(_looked_up(b, page, pkey_cols, pusable, lo, count))
 
-    def _mark_full(self, keep, build_idx, pdicts):
+    def _mark_full(self, keep, build_row, pdicts):
         """FULL OUTER bookkeeping: OR an expansion's kept lanes into the
         per-build-row matched flags."""
         if self.join_type != "full" or keep is None:
             return
         b = self.bridge.build
-        bcap = int(b.valid_sorted.shape[0])
+        bcap = int(b.valid.shape[0])
         if self._build_matched is None:
             self._build_matched = jnp.zeros(bcap + 1, dtype=bool)
         self._build_matched = _mark_build_matched(
-            self._build_matched, keep, build_idx)
+            self._build_matched, keep, build_row)
         self._probe_dicts = pdicts
 
     def _unmatched_build_page(self) -> DevicePage:
         """FULL OUTER tail: build rows no kept lane ever matched, with
-        all probe channels NULL."""
+        all probe channels NULL — the build's own columns under another
+        mask, nothing gathered."""
         from ..block import Dictionary
 
         b = self.bridge.build
-        cap = int(b.valid_sorted.shape[0])
-        unmatched = b.valid_sorted if self._build_matched is None \
-            else b.valid_sorted & ~self._build_matched[:cap]
+        cap = int(b.valid.shape[0])
+        unmatched = b.valid if self._build_matched is None \
+            else b.valid & ~self._build_matched[:cap]
         pcols = [jnp.zeros(cap, dtype=t.storage) for t in self.probe_types]
         pnulls = [jnp.ones(cap, dtype=bool) for _ in self.probe_types]
         pdicts = self._probe_dicts
@@ -1637,13 +1687,14 @@ class LookupJoinOperator(Operator):
                   pusable, lo, count, lane_cap: int) -> Tuple:
         """One expansion at static capacity ``lane_cap`` against build
         side ``b`` (the resident index, or a per-partition index during
-        the deferred hybrid pass): returns (out_page, keep, build_idx).
-        keep/build_idx feed the FULL OUTER marker and are None for
-        semi/anti (no build channels in the output)."""
+        the deferred hybrid pass): returns (out_page, keep, build_row).
+        keep/build_row (the lanes' build rows in arrival order) feed
+        the FULL OUTER marker and are None for semi/anti (no build
+        channels in the output)."""
         if self.join_type in ("semi", "anti"):
             if self.filter_fn is None:
                 matched = _semi_matched(
-                    lo, count,
+                    lo, count, b.perm,
                     tuple(pkey_cols),
                     tuple(b.cols[c] for c in b.key_channels),
                     page.valid.shape[0], out_cap=lane_cap)
@@ -1652,12 +1703,12 @@ class LookupJoinOperator(Operator):
                 # l1.l_suppkey): expand candidate lanes, verify keys,
                 # evaluate the filter over the combined probe+build row,
                 # then segment-OR back onto probe rows
-                probe_idx, build_idx, keep = _expand_verified(
-                    lo, count,
+                probe_idx, build_row, keep = _expand_verified(
+                    lo, count, b.perm,
                     tuple(pkey_cols),
                     tuple(b.cols[c] for c in b.key_channels),
                     out_cap=lane_cap)
-                lanes = _gather_lanes(page, b, probe_idx, build_idx, keep)
+                lanes = _gather_lanes(page, b, probe_idx, build_row, keep)
                 matched = _segment_any(self.filter_fn(lanes).valid,
                                        probe_idx, page.valid.shape[0])
             if self.join_type == "semi":
@@ -1667,24 +1718,24 @@ class LookupJoinOperator(Operator):
             return (DevicePage(page.types, page.cols, page.nulls,
                                new_valid, page.dictionaries), None, None)
 
-        probe_idx, build_idx, keep = _expand_verified(
-            lo, count,
+        probe_idx, build_row, keep = _expand_verified(
+            lo, count, b.perm,
             tuple(pkey_cols),
             tuple(b.cols[c] for c in b.key_channels), out_cap=lane_cap)
         if self.filter_fn is not None:
             # ON-clause residual runs BEFORE left-join padding: lanes
             # failing it make the probe row unmatched, not dropped
-            lanes = _gather_lanes(page, b, probe_idx, build_idx, keep)
+            lanes = _gather_lanes(page, b, probe_idx, build_row, keep)
             keep = self.filter_fn(lanes).valid
         out_cols, out_nulls, out_valid = _finalize_join(
             tuple(page.cols), tuple(page.nulls), page.valid,
             tuple(b.cols), tuple(b.nulls),
-            probe_idx, build_idx, keep,
+            probe_idx, build_row, keep,
             left=self.join_type in ("left", "full"))
         types = self.output_types
         dicts = list(page.dictionaries) + list(b.dictionaries)
         return (DevicePage(types, list(out_cols), list(out_nulls),
-                           out_valid, dicts), keep, build_idx)
+                           out_valid, dicts), keep, build_row)
 
 
 def _looked_up(b: "BuildSide", page: DevicePage, pkey_cols, pusable, lo,
@@ -1697,8 +1748,9 @@ def _looked_up(b: "BuildSide", page: DevicePage, pkey_cols, pusable, lo,
 
 
 def _finalize_join_impl(pcols, pnulls, pvalid, bcols, bnulls,
-                        probe_idx, build_idx, keep, left: bool):
-    """Gather joined output lanes; for LEFT, append one lane per probe
+                        probe_idx, build_row, keep, left: bool):
+    """Gather joined output lanes (``build_row``: the build's arrival
+    lanes, where ``bcols`` lie); for LEFT, append one lane per probe
     row, valid iff the row matched no kept lane (NULL build columns).
 
     Raw implementation (see ``_probe_counts_impl``); host callers use
@@ -1709,8 +1761,8 @@ def _finalize_join_impl(pcols, pnulls, pvalid, bcols, bnulls,
         n_extra = pvalid.shape[0]
         extra_probe = jnp.arange(n_extra, dtype=probe_idx.dtype)
         probe_idx = jnp.concatenate([probe_idx, extra_probe])
-        build_idx = jnp.concatenate(
-            [build_idx, jnp.zeros(n_extra, dtype=build_idx.dtype)])
+        build_row = jnp.concatenate(
+            [build_row, jnp.zeros(n_extra, dtype=build_row.dtype)])
         keep = jnp.concatenate([keep, pvalid & ~matched])
         build_is_null = jnp.concatenate(
             [jnp.zeros(lane_cap, dtype=bool),
@@ -1719,9 +1771,9 @@ def _finalize_join_impl(pcols, pnulls, pvalid, bcols, bnulls,
         build_is_null = jnp.zeros(lane_cap, dtype=bool)
 
     out_cols = tuple(c[probe_idx] for c in pcols) + \
-        tuple(c[build_idx] for c in bcols)
+        tuple(c[build_row] for c in bcols)
     out_nulls = tuple(n[probe_idx] for n in pnulls) + \
-        tuple(n[build_idx] | build_is_null for n in bnulls)
+        tuple(n[build_row] | build_is_null for n in bnulls)
     return out_cols, out_nulls, keep
 
 
@@ -1729,32 +1781,33 @@ _finalize_join = partial(jax.jit, static_argnames=("left",))(
     _finalize_join_impl)
 
 
-def _gather_lanes(page: DevicePage, b: "BuildSide", probe_idx, build_idx,
+def _gather_lanes(page: DevicePage, b: "BuildSide", probe_idx, build_row,
                   keep) -> DevicePage:
     """Combined probe+build rows for candidate lanes (residual-filter
     evaluation layout: probe channels, then build channels)."""
     return DevicePage(
         list(page.types) + list(b.types),
         [c[probe_idx] for c in page.cols]
-        + [c[build_idx] for c in b.cols],
+        + [c[build_row] for c in b.cols],
         [n[probe_idx] for n in page.nulls]
-        + [n[build_idx] for n in b.nulls],
+        + [n[build_row] for n in b.nulls],
         keep,
         list(page.dictionaries) + list(b.dictionaries))
 
 
-def _expand_verified_impl(lo, count, pkey_cols, bkey_cols, out_cap: int):
-    """Candidate lanes with raw-key verification applied (for
-    residual-filtered semi/anti joins).
+def _expand_verified_impl(lo, count, perm, pkey_cols, bkey_cols,
+                          out_cap: int):
+    """Candidate lanes (probe_idx, build_row, keep) with raw-key
+    verification applied; ``bkey_cols`` lie in the build's arrival
+    order, where ``build_row`` points.
 
     Raw implementation (see ``_probe_counts_impl``); host callers use
     the jitted ``_expand_verified`` binding below."""
-    probe_idx, build_idx, lane_valid = _expand_matches_impl(
-        lo, count, out_cap)
-    keep = lane_valid
+    probe_idx, build_row, keep = _expand_matches_impl(
+        lo, count, perm, out_cap)
     for pc, bc in zip(pkey_cols, bkey_cols):
-        keep = keep & (pc[probe_idx] == bc[build_idx])
-    return probe_idx, build_idx, keep
+        keep = keep & (pc[probe_idx] == bc[build_row])
+    return probe_idx, build_row, keep
 
 
 _expand_verified = partial(jax.jit, static_argnames=("out_cap",))(
@@ -1762,11 +1815,12 @@ _expand_verified = partial(jax.jit, static_argnames=("out_cap",))(
 
 
 @jax.jit
-def _mark_build_matched(acc, keep, build_idx):
-    """OR kept lanes into the per-sorted-build-row matched accumulator
-    (last lane of ``acc`` is the dead-lane sink)."""
+def _mark_build_matched(acc, keep, build_row):
+    """OR kept lanes into the per-build-row matched accumulator, in the
+    build's arrival order like its ``valid`` (last lane of ``acc`` is
+    the dead-lane sink)."""
     sink = acc.shape[0] - 1
-    return acc.at[jnp.where(keep, build_idx, sink)].max(True)
+    return acc.at[jnp.where(keep, build_row, sink)].max(True)
 
 
 def _segment_any_impl(keep, probe_idx, probe_cap: int):
@@ -1780,21 +1834,16 @@ _segment_any = partial(jax.jit, static_argnames=("probe_cap",))(
     _segment_any_impl)
 
 
-def _semi_matched_impl(lo, count, pkey_cols, bkey_cols, probe_cap: int,
-                       out_cap: int):
+def _semi_matched_impl(lo, count, perm, pkey_cols, bkey_cols,
+                       probe_cap: int, out_cap: int):
     """Per-probe-row matched flag: expand candidates, verify raw keys,
     segment-OR back onto probe rows (collision-safe for any key mode).
 
     Raw implementation (see ``_probe_counts_impl``); host callers use
     the jitted ``_semi_matched`` binding below."""
-    probe_idx, build_idx, lane_valid = _expand_matches_impl(
-        lo, count, out_cap)
-    keep = lane_valid
-    for pc, bc in zip(pkey_cols, bkey_cols):
-        keep = keep & (pc[probe_idx] == bc[build_idx])
-    matched = jnp.zeros(probe_cap + 1, dtype=bool)
-    matched = matched.at[jnp.where(keep, probe_idx, probe_cap)].max(True)
-    return matched[:-1]
+    probe_idx, _, keep = _expand_verified_impl(
+        lo, count, perm, pkey_cols, bkey_cols, out_cap)
+    return _segment_any_impl(keep, probe_idx, probe_cap)
 
 
 _semi_matched = partial(jax.jit, static_argnames=("probe_cap", "out_cap"))(

@@ -509,8 +509,9 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
             span["attrs"]["input_rows"] = driver.stats[i - 1].output_rows
         if st.metrics:
             # the scan operator's host-side counters, the aggregation's
-            # partial widths, merges, groups and probe rounds, and the
-            # join's type and probe counters, under their names
+            # partial widths, merges, groups and probe rounds, the
+            # join's type and probe counters and the build's index,
+            # under their names
             for key in ("generate_s", "upload_s", "wait_s",
                         "readahead_pages", "readahead_ready",
                         "resident_pages", "resident_bytes",
@@ -523,7 +524,8 @@ def add_driver_spans(tracer: Tracer, driver, parent) -> int:
                         "direct_table_bytes", "probe_fallback",
                         "probe_lanes", "expand_lanes", "expand_rows",
                         "residual_lanes", "residual_rows",
-                        "key_mode", "build_lanes"):
+                        "build_row_lanes", "key_mode", "build_lanes",
+                        "build_carried_cols"):
                 if st.metrics.get(key) is not None:
                     span["attrs"][key] = st.metrics[key]
         tracer._record(span)
